@@ -1,0 +1,323 @@
+#!/usr/bin/env python
+"""Bring-up proof: the trainer and the server, full width, on the attached TPU.
+
+    python chip_smoke.py             # one chip: kernels, train gpt2, train
+                                     # resnet50, serve gpt2
+    python chip_smoke.py --chips=4   # four chips: GPT-2 medium across meshes
+                                     # against a one-device run, nothing else
+
+One process runs every phase in turn (a chip belongs to one process; nothing
+here starts another).  There is no CPU mode: without a TPU the script exits
+non-zero before any phase.  A phase that fails raises there and then — no
+phase is wrapped in try/except — so exit code 0 means every check held.
+
+The LAST line of stdout is the result, and only that line is the contract:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+Everything else printed (parity errors, losses, compile seconds, peak bytes,
+kernel-call counts, served tokens) is smoke output, not a benchmark result.
+"""
+
+import argparse
+import gc
+import json
+import math
+import os
+import re
+import sys
+import time
+
+import jax
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+OUT = os.path.join(REPO, "chiprun_out", "chip_smoke")
+
+GPT2_BATCH, GPT2_ACCUM = 32, 4
+LN_VOCAB = math.log(50257)  # a fresh model's next-token loss
+# Per-step loss agreement between a mesh and the one-device run.  Same seed,
+# same batches, same math; bf16 matmuls reduce in a different order once
+# heads, batch or sequence are split, and Adam turns that into slightly
+# different parameters from the second update on.  Seen on the chip: 8e-5
+# over four steps at a loss of 11.0.
+MESH_LOSS_TOL = 0.005
+# Device 0 may hold this much more than the mean of the four (stray scalars,
+# the input batch in flight) before the state counts as piled on one chip.
+DEVICE0_MARGIN = 1.25
+
+
+def say(phase, **fields):
+    print(json.dumps({"phase": phase, **fields}), flush=True)
+
+
+def device_bytes(key="peak_bytes_in_use"):
+    return {d.id: d.memory_stats()[key] for d in jax.local_devices()}
+
+
+def train_cli(tag, steps, *flags):
+    """One run through ``train.py``'s entry point, every step's loss logged
+    to a metrics file; returns [(step, loss), ...] after the checks every
+    such run must pass."""
+    from distributed_tensorflow_tpu import train_lib
+
+    metrics = os.path.join(OUT, f"{tag}.jsonl")
+    if os.path.exists(metrics):
+        os.remove(metrics)
+    result = train_lib.main([*flags, f"--steps={steps}", "--log_every=1",
+                             f"--metrics_file={metrics}"])
+    with open(metrics) as f:
+        rows = [json.loads(line) for line in f if line.strip()]
+    losses = [(r["step"], r["loss"]) for r in rows if "loss" in r]
+    assert result["final_step"] == steps, result
+    assert len(losses) >= 2, losses
+    assert all(math.isfinite(v) for _, v in losses), losses
+    return losses
+
+
+def kernel_calls(train_step, state, batch, rng):
+    """Compile the jitted step once more and count the Pallas kernels and
+    collectives in it.  With the arguments the run itself used this is a
+    compile-cache hit; lowered from bare shapes the same step gets other
+    private function names, so another cache key (found on the chip: 141 s)."""
+    t0 = time.perf_counter()
+    hlo = train_step.lower(state, batch, rng).compile().as_text()
+    counts = {name: len(re.findall(rf"\b{name}(?:-start)?\(", hlo))
+              for name in ("all-reduce", "all-gather", "reduce-scatter",
+                           "collective-permute", "all-to-all")}
+    return hlo.count("tpu_custom_call"), counts, time.perf_counter() - t0
+
+
+# --------------------------------------------------------------------------
+# one chip
+# --------------------------------------------------------------------------
+
+def phase_kernels():
+    """Flash forward, backward, kv_mask and in-kernel dropout against the
+    dense reference, on the chip (the dropout PRNG runs nowhere else)."""
+    from scripts import validate_tpu
+
+    t0 = time.perf_counter()
+    validate_tpu.validate_parity()
+    validate_tpu.validate_kv_mask()
+    validate_tpu.validate_kernel_dropout()
+    say("kernels", ok=True, secs=round(time.perf_counter() - t0, 1))
+
+
+def phase_train_gpt2(steps=6):
+    from distributed_tensorflow_tpu import cluster as cluster_lib
+    from distributed_tensorflow_tpu import train_lib
+    from distributed_tensorflow_tpu.data.pipeline import make_global_batches
+    from distributed_tensorflow_tpu.models import get_workload
+    from distributed_tensorflow_tpu.training import BF16
+
+    t0 = time.perf_counter()
+    losses = train_cli(
+        "train_gpt2", steps, "--model=gpt2", "--flash_attention",
+        f"--batch_size={GPT2_BATCH}", f"--grad_accum_steps={GPT2_ACCUM}",
+        "--precision=bf16")
+    run_secs = time.perf_counter() - t0
+    assert abs(losses[0][1] - LN_VOCAB) < 0.5, (losses[0], LN_VOCAB)
+
+    # The same jitted step, built the way train_lib.run builds it.
+    mesh = cluster_lib.build_mesh(cluster_lib.MeshConfig())
+    workload = get_workload(
+        "gpt2", mesh=mesh, batch_size=GPT2_BATCH,
+        grad_accum_steps=GPT2_ACCUM, use_flash_attention=True)
+    init, _, _, train_step, batch_sh = train_lib.build_step(
+        workload, mesh, precision=BF16, grad_accum_steps=GPT2_ACCUM,
+        total_steps=steps)
+    batch = next(make_global_batches(
+        workload.data_fn(GPT2_BATCH), batch_sh[workload.example_key]))
+    kernels, _, relower_secs = kernel_calls(
+        train_step, init(), batch, jax.random.key(1))
+    assert kernels > 0, "no tpu_custom_call in the GPT-2 step"
+    say("train_gpt2", ok=True, preset="medium",
+        seq=batch[workload.example_key].shape[1], batch=GPT2_BATCH,
+        grad_accum=GPT2_ACCUM, losses=losses,
+        kernel_calls=kernels, run_secs=round(run_secs, 1),
+        relower_compile_secs=round(relower_secs, 1),
+        peak_bytes=device_bytes())
+
+
+def phase_train_resnet50(steps=4):
+    t0 = time.perf_counter()
+    losses = train_cli("train_resnet50", steps, "--model=resnet50",
+                       "--batch_size=256", "--precision=bf16")
+    say("train_resnet50", ok=True, image=224, batch=256, losses=losses,
+        run_secs=round(time.perf_counter() - t0, 1),
+        peak_bytes=device_bytes())
+
+
+def phase_serve_gpt2(requests=16):
+    import serve
+    from distributed_tensorflow_tpu.serve.driver import _horizons
+
+    t0 = time.perf_counter()
+    argv = [
+        "--model=gpt2", "--preset=medium", "--continuous",
+        "--cache_mode=paged", "--block_size=16", "--megastep=4",
+        "--async_decode", f"--steps={requests}", "--num_slots=8",
+        "--prompt_lens=16,48,128", "--max_new_tokens=32",
+        "--min_new_tokens=8"]
+    out = serve.main(argv)
+    cycle = _horizons(serve.parse_args(argv))
+    expected = sum(cycle[i % len(cycle)] for i in range(requests))
+    assert out["preset"] == "medium" and out["device"]["platform"] == "tpu"
+    assert out["requests"] == requests == out["completed"], out
+    assert out["tokens_generated"] == expected, (out, expected)
+    assert out["cancelled"] == 0 and out["rejected_retries"] == 0, out
+    assert out["compile_post_warmup"] == 0, out
+    say("serve_gpt2", ok=True, requests=requests,
+        tokens_generated=out["tokens_generated"],
+        compile_total=out["compile_total"],
+        megastep_launches=out["megastep_launches"],
+        run_secs=round(time.perf_counter() - t0, 1),
+        peak_bytes=device_bytes())
+
+
+# --------------------------------------------------------------------------
+# four chips
+# --------------------------------------------------------------------------
+
+def _train_on_mesh(axes, devices, steps, inspect=True):
+    """GPT-2 medium (dropout 0: in-kernel masks are seeded per shard) for a
+    few steps on ``MeshConfig(**axes)`` over ``devices`` — the calls
+    train_lib.run and bench.py make, with an explicit mesh.  ``inspect``
+    also counts what the compiled step holds (``kernel_calls``)."""
+    from distributed_tensorflow_tpu import cluster as cluster_lib
+    from distributed_tensorflow_tpu import train_lib
+    from distributed_tensorflow_tpu.data.pipeline import make_global_batches
+    from distributed_tensorflow_tpu.models import get_workload
+    from distributed_tensorflow_tpu.models.gpt2 import GPT2Config
+    from distributed_tensorflow_tpu.training import BF16, TrainLoop
+    from distributed_tensorflow_tpu.training.loop import Hook
+
+    class Collect(Hook):
+        def __init__(self):
+            self.losses = []
+
+        def on_metrics(self, loop, metrics_step, metrics):
+            self.losses.append((metrics_step, metrics["loss"]))
+
+    mesh = cluster_lib.build_mesh(cluster_lib.MeshConfig(**axes), devices)
+    workload = get_workload(
+        "gpt2", mesh=mesh, config=GPT2Config.medium(dropout=0.0),
+        batch_size=GPT2_BATCH, grad_accum_steps=GPT2_ACCUM,
+        use_flash_attention=True)
+    t0 = time.perf_counter()
+    init, _, _, train_step, batch_sh = train_lib.build_step(
+        workload, mesh, precision=BF16, grad_accum_steps=GPT2_ACCUM,
+        total_steps=steps)
+    collect = Collect()
+    batches = make_global_batches(workload.data_fn(GPT2_BATCH),
+                                  batch_sh[workload.example_key])
+    rng = jax.random.key(1)
+    loop = TrainLoop(
+        train_step, init(), batches, hooks=[collect],
+        examples_per_step=GPT2_BATCH, metrics_every=1, rng=rng)
+    state = loop.run(steps)
+    jax.block_until_ready(state)
+    secs = time.perf_counter() - t0
+    assert int(jax.device_get(state.step)) == steps
+    assert [s for s, _ in collect.losses] == list(range(1, steps + 1))
+    program = (kernel_calls(train_step, state, next(batches), rng)
+               if inspect else None)
+    return mesh, state, collect.losses, secs, program
+
+
+def _check_state_placement(mesh, state):
+    """Every leaf holds 1/ways of its bytes on each device, where ways is
+    the product of the mesh axes its spec names; all devices hold state and
+    device 0 no more than its share."""
+    from distributed_tensorflow_tpu.parallel.sharding import spec_ways
+
+    split = 0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(state):
+        ways = spec_ways(mesh, *leaf.sharding.spec)
+        split += ways > 1
+        for shard in leaf.addressable_shards:
+            assert shard.data.nbytes * ways == leaf.nbytes, (
+                jax.tree_util.keystr(path), leaf.sharding.spec, ways)
+    in_use = device_bytes("bytes_in_use")
+    assert all(b > 0 for b in in_use.values()), in_use
+    mean = sum(in_use.values()) / len(in_use)
+    first = in_use[mesh.devices.flat[0].id]
+    assert first <= mean * DEVICE0_MARGIN, (in_use, DEVICE0_MARGIN)
+    return split, in_use
+
+
+def phase_meshes(steps=4):
+    devices = jax.devices()
+    assert len(devices) == 4, devices
+    _, state, ref_losses, secs, _ = _train_on_mesh(
+        {}, devices[:1], steps, inspect=False)
+    say("mesh_reference", devices=[devices[0].id], losses=ref_losses,
+        secs=round(secs, 1))
+    del state
+    gc.collect()
+
+    expect = {"tensor": ("all-reduce",),
+              "context": ("all-reduce", "collective-permute")}
+    for axes in ({"data": 2, "tensor": 2}, {"data": 2, "context": 2}):
+        mesh, state, losses, secs, program = _train_on_mesh(
+            axes, devices, steps)
+        kernels, collectives, _ = program
+        deltas = [abs(a - b) for (_, a), (_, b) in zip(losses, ref_losses)]
+        assert all(math.isfinite(v) for _, v in losses), losses
+        assert max(deltas) <= MESH_LOSS_TOL, (axes, losses, ref_losses)
+        split, in_use = _check_state_placement(mesh, state)
+        assert split > 0 or "tensor" not in axes, "tensor=2 split no leaf"
+        assert kernels > 0, f"no tpu_custom_call on mesh {axes}"
+        inner = next(a for a in axes if a != "data")
+        for name in expect[inner]:
+            assert collectives[name] > 0, (axes, name, collectives)
+        say("mesh", axes=axes,
+            device_ids=[d.id for d in mesh.devices.flat], losses=losses,
+            max_loss_delta=max(deltas), tol=MESH_LOSS_TOL,
+            leaves_split=split, bytes_in_use=in_use, kernel_calls=kernels,
+            collectives=collectives, secs=round(secs, 1))
+        del state
+        gc.collect()
+
+    # And once through the command line, as the README gives it.
+    losses = train_cli("train_gpt2_tensor2", 3, "--model=gpt2", "--tensor=2",
+                       "--flash_attention")
+    assert abs(losses[0][1] - LN_VOCAB) < 0.5, (losses[0], LN_VOCAB)
+    say("mesh_cli", argv="--model=gpt2 --tensor=2 --flash_attention",
+        losses=losses, peak_bytes=device_bytes())
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="1: the one-chip phases; 4: only the path across "
+                         "chips and the one-device run it is compared with")
+    flags = ap.parse_args(argv)
+
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        sys.exit(f"chip_smoke.py needs a TPU; JAX found {devices}")
+    if len(devices) < flags.chips:
+        sys.exit(f"--chips={flags.chips} but JAX found {len(devices)}")
+
+    from distributed_tensorflow_tpu import compile_cache
+
+    os.makedirs(OUT, exist_ok=True)
+    say("start", chips=flags.chips, compile_cache=compile_cache.configure(),
+        jax=jax.__version__)
+    t0 = time.perf_counter()
+    if flags.chips == 4:
+        phase_meshes()
+    else:
+        for phase in (phase_kernels, phase_train_gpt2, phase_train_resnet50,
+                      phase_serve_gpt2):
+            phase()
+            gc.collect()
+    say("done", secs=round(time.perf_counter() - t0, 1))
+    print(json.dumps({"ok": True, "device": {
+        "platform": devices[0].platform, "kind": devices[0].device_kind,
+        "count": len(devices)}}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -34,6 +34,7 @@ from distributed_tensorflow_tpu.cluster.topology import (
     Topology,
     build_hybrid_mesh,
     build_mesh,
+    device_summary,
     single_axis_mesh,
 )
 
@@ -60,6 +61,7 @@ __all__ = [
     "Topology",
     "build_hybrid_mesh",
     "build_mesh",
+    "device_summary",
     "single_axis_mesh",
     "assert_same_program",
     "barrier",

@@ -22,24 +22,20 @@ XLA elides trivial collectives, so unused axes are free.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import math
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax.experimental import mesh_utils
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-try:  # jax >= 0.5: explicit axis types on Mesh
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: no AxisType; plain Mesh behaves as Auto
-    AxisType = None
+logger = logging.getLogger(__name__)
 
 
 def _make_mesh(dev_array: np.ndarray) -> Mesh:
-    """Mesh with Auto axis types where the jax version supports them."""
-    if AxisType is None:
-        return Mesh(dev_array, MESH_AXES)
+    """Mesh over ``MESH_AXES`` with Auto axis types (GSPMD-driven)."""
     return Mesh(
         dev_array, MESH_AXES, axis_types=(AxisType.Auto,) * len(MESH_AXES)
     )
@@ -120,9 +116,20 @@ def build_mesh(
             dev_array = mesh_utils.create_device_mesh(
                 shape, devices=devices, allow_split_physical_axes=True
             )
-        except (ValueError, NotImplementedError):
-            # CPU test meshes and odd shapes: fall back to row-major layout.
+            assignment = "create_device_mesh (physical topology)"
+        except (ValueError, NotImplementedError) as e:
+            # Shapes the topology-aware assignment cannot place.  On a real
+            # multi-chip host this costs ICI locality, so it must be seen.
+            level = (logging.WARNING if devices[0].platform == "tpu"
+                     else logging.INFO)
+            logger.log(level, "create_device_mesh refused mesh %s (%s); "
+                       "using row-major device order", shape, e)
             dev_array = np.array(devices).reshape(shape)
+            assignment = "row-major"
+        logger.info(
+            "mesh %s assigned by %s; device ids %s",
+            {a: n for a, n in sizes.items() if n > 1}, assignment,
+            [d.id for d in dev_array.flat])
     return _make_mesh(dev_array)
 
 
@@ -213,6 +220,15 @@ def single_axis_mesh(
     """All devices on one named axis (pure-DP MultiWorkerMirrored shape)."""
     overrides = {} if axis == "data" else {"data": 1, axis: -1}
     return build_mesh(MeshConfig(**overrides), devices)
+
+
+def device_summary() -> Dict[str, Any]:
+    """The device a result line ran on, as JAX reports it.  Every JSON
+    line the entry points print carries this, so a shrunken CPU run can
+    never be read as a chip run."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
 
 
 @dataclasses.dataclass(frozen=True)

@@ -13,7 +13,7 @@ The adapter is HOST-side glue only — tensorflow never touches the device
 (the north star's "no GPU in the loop" applies to TF itself here: the
 dataset runs its C++ pipeline on CPU, numpy arrays cross into jax).  It is
 intentionally NOT the performance path: the native loader + data service
-own that (BASELINE.md); this is the porting on-ramp.
+own that; this is the porting on-ramp.
 
 tensorflow is imported lazily so the module (and the package) stays
 importable in TF-less deployments.

@@ -22,6 +22,8 @@ from typing import Callable, Optional
 
 import jax
 
+from distributed_tensorflow_tpu.training.loop import Hook
+
 logger = logging.getLogger(__name__)
 
 
@@ -238,7 +240,7 @@ class HealthChecker:
             raise err
 
 
-class HealthCheckHook:
+class HealthCheckHook(Hook):
     """Training-loop hook running a ``HealthChecker``: probes start at loop
     ``begin`` under a startup grace window, tighten to
     ``failures_before_action`` once the first step completes, and are

@@ -64,13 +64,12 @@ class BertConfig:
     scan_unroll: int = 1
     # Pallas fused attention (non-causal); attention-prob dropout runs
     # in-kernel (TPU PRNG), so the recipe matches dense.
-    # Default is per-phase, set by make_workload from measurement (v5e,
-    # 2026-07-30, masked batches): dense wins at seq 128 (867 vs 781
-    # seq/s/chip — the (T,T) tile is small enough that XLA's fused dense
-    # path beats the kernel's fixed overheads), flash wins at seq 512
-    # (219 vs 128 seq/s/chip, +71% — phase 2, where the score tile starts
-    # to dominate HBM traffic).  Crossover is between those; make_workload
-    # enables flash at seq >= 256.
+    # Default is per-phase, set by make_workload from a measurement that
+    # predates the current chip attachment (v5e, masked batches): dense won
+    # at seq 128 (the (T,T) tile is small enough that XLA's fused dense path
+    # beats the kernel's fixed overheads), flash won clearly at seq 512
+    # (phase 2, where the score tile starts to dominate HBM traffic).  The
+    # crossover is between those; make_workload enables flash at seq >= 256.
     use_flash_attention: bool = False
     # Ring attention kv-chunk size (0 = whole blocks; see GPT2Config)
     ring_chunk_size: int = 0
@@ -126,6 +125,7 @@ class EncoderLayer(nn.Module):
                 q, k, v, causal=False, kv_mask=input_mask,
                 dropout_rate=drop,
                 dropout_rng=self.make_rng("dropout") if drop > 0 else None,
+                mesh=self.mesh,
             ).reshape(B, T, d)
         else:
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(head_dim)

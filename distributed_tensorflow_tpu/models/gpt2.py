@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import os
 from typing import Any, Dict, Optional
 
@@ -43,6 +44,8 @@ from distributed_tensorflow_tpu.parallel.sharding import (
     transformer_rules,
 )
 
+logger = logging.getLogger(__name__)
+
 
 @dataclasses.dataclass(frozen=True)
 class GPT2Config:
@@ -59,8 +62,9 @@ class GPT2Config:
     # Unroll factor for the layer scan (nn.scan unroll): >1 trades compile
     # time for fewer loop iterations, letting XLA fuse the per-layer grad
     # writes into the stacked (L, ...) buffers across unrolled layers —
-    # attacks the dynamic-update-slice grad-stacking overhead (measured
-    # 15.4% of GPT-2 step time at unroll=1; see BASELINE.md).
+    # attacks the dynamic-update-slice grad-stacking overhead (a sizeable
+    # share of GPT-2 step time at unroll=1 in profiles that predate the
+    # current chip attachment).
     scan_unroll: int = 1
     # Rematerialize each block in backward (jax.checkpoint): trades ~30%
     # more FLOPs for activation memory ~ O(sqrt) — the TPU-native answer to
@@ -94,8 +98,9 @@ class GPT2Config:
 
     @classmethod
     def medium(cls, **kw):  # 355M — the reference's config
-        # unroll=4 measured best on v5e (28.3k -> 30.5k tok/s at batch 16):
-        # fewer scan iterations amortize the stacked-grad DUS writes.
+        # unroll=4 measured best on v5e in a round that predates the
+        # current chip attachment: fewer scan iterations amortize the
+        # stacked-grad DUS writes.
         kw.setdefault("scan_unroll", 4)
         return cls(d_model=1024, n_layer=24, n_head=16, **kw)
 
@@ -313,11 +318,13 @@ class Block(nn.Module):
         elif cfg.use_flash_attention:
             # Attention-prob dropout runs IN-KERNEL (TPU PRNG, identical
             # keep mask regenerated in backward) — the flash path keeps the
-            # dense path's training recipe.
+            # dense path's training recipe.  Under a mesh the kernel runs
+            # per (batch, head) shard inside a shard_map (``mesh=``).
             drop = 0.0 if deterministic else cfg.dropout
             ctx = flash_attention(
                 q, k, v, causal=True, dropout_rate=drop,
                 dropout_rng=self.make_rng("dropout") if drop > 0 else None,
+                mesh=self.mesh,
             ).reshape(B, T, d)
         else:
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(head_dim)
@@ -643,16 +650,18 @@ class GPT2(nn.Module):
 
         params = self.scope.get_variable("params", "blocks")
         staged, xm, _ = _pipe_staging(self.cfg, self.mesh, params, x)
-        y = pipeline_apply(_pipe_stage_fn(self.cfg), staged, xm,
+        y = pipeline_apply(_pipe_stage_fn(self.cfg, self.mesh), staged, xm,
                            mesh=self.mesh, axis="pipe")
         return jnp.reshape(y, x.shape)
 
 
-def _pipe_stage_fn(cfg):
+def _pipe_stage_fn(cfg, mesh):
     """One pipeline stage = a scan over its L/S layers (remat per layer),
     SHARED by the GPipe (``_pipelined_blocks``) and 1F1B
-    (``_pipe_1f1b_loss``) paths — one definition, zero schedule drift."""
-    block = Block(cfg, mesh=None, deterministic=True)
+    (``_pipe_1f1b_loss``) paths — one definition, zero schedule drift.
+    The block keeps the mesh: the stage runs manual over ``pipe`` only, and
+    the flash kernel nests its own shard_map over the remaining axes."""
+    block = Block(cfg, mesh=mesh, deterministic=True)
 
     def stage_fn(stage_params, h):
         def body(h, layer_params):
@@ -784,7 +793,7 @@ def _pipe_1f1b_loss(module: "GPT2", params, batch: Dict[str, jax.Array],
     tokens = batch["tokens"]
     B, T = tokens.shape
     d = cfg.d_model
-    stage_fn = _pipe_stage_fn(cfg)
+    stage_fn = _pipe_stage_fn(cfg, mesh)
     ln_f = nn.LayerNorm(dtype=jnp.float32)
 
     def tail_fn(tp, y_mb, t_mb):
@@ -932,8 +941,8 @@ def _guard_dense_attention_memory(cfg, *, seq, batch_size, grad_accum_steps,
 
     The non-flash path materializes (B, H, T, T) score/prob buffers (f32
     softmax + bf16 probs, forward AND recomputed in backward under remat).
-    GPT-2 medium at seq 1024, per-chip microbatch 16 measured OOM on a
-    16 GB v5e (BASELINE.md) — silently, deep inside XLA allocation.  Guard
+    GPT-2 medium at seq 1024, per-chip microbatch 16 ran out of memory on a
+    16 GB v5e — an opaque failure deep inside XLA allocation.  Guard
     here with the actionable fix, instead of an opaque RESOURCE_EXHAUSTED:
     turn on --flash_attention (streams the tiles through VMEM) or raise
     --grad_accum_steps (shrinks the microbatch).
@@ -962,16 +971,9 @@ def _guard_dense_attention_memory(cfg, *, seq, batch_size, grad_accum_steps,
     # seq-1024 OOMs at microbatch 16 (6.4 GiB by this model) and fits at
     # microbatch 4 (1.6 GiB) on a 16 GiB v5e.
     approx_bytes = 6 * micro * heads * seq * seq * 4
-    # Budget = 1/4 of device memory (the rest is params/acts/grads).
-    # Bigger-HBM chips (v4/v5p) get a proportionally higher ceiling;
-    # platforms that don't report memory use the 16 GiB v5e assumption.
-    hbm = 16 * 1024**3
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        hbm = int(stats.get("bytes_limit", hbm)) or hbm
-    except Exception:
-        pass
-    budget = hbm // 4
+    # Budget = 1/4 of device memory (the rest is params/acts/grads), so
+    # bigger-HBM chips (v4/v5p) get a proportionally higher ceiling.
+    budget = _device_memory_bytes() // 4
     if approx_bytes > budget:
         raise ValueError(
             f"dense attention at microbatch {micro} x {cfg.n_head} heads x "
@@ -980,6 +982,28 @@ def _guard_dense_attention_memory(cfg, *, seq, batch_size, grad_accum_steps,
             "(streams score tiles through VMEM, no (T, T) buffer) or raise "
             "--grad_accum_steps to shrink the per-chip microbatch."
         )
+
+
+_ASSUMED_HBM_BYTES = 16 * 1024**3  # one v5e chip
+
+
+def _device_memory_bytes() -> int:
+    """This process's first device's memory limit (only addressable devices
+    report stats).  A TPU that does not report one is an error; other
+    backends (the CPU tests) get the v5e figure, logged."""
+    dev = jax.local_devices()[0]
+    limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
+    if limit:
+        return limit
+    if dev.platform == "tpu":
+        raise RuntimeError(
+            f"{dev} reports no memory_stats()['bytes_limit']; the dense-"
+            "attention memory guard cannot size its budget (set "
+            "DTT_SKIP_DENSE_ATTN_GUARD=1 to bypass it)")
+    logger.info(
+        "dense-attention guard: %s reports no memory limit; assuming %d GiB "
+        "(one v5e chip)", dev.platform, _ASSUMED_HBM_BYTES // 1024**3)
+    return _ASSUMED_HBM_BYTES
 
 
 def make_workload(
@@ -1028,9 +1052,7 @@ def make_workload(
                 "axis would be inert; pick one"
             )
         if cfg.dropout > 0:
-            import logging
-
-            logging.getLogger(__name__).warning(
+            logger.warning(
                 "pipe>1: disabling dropout (GPipe stage fn is deterministic)"
             )
             cfg = dataclasses.replace(cfg, dropout=0.0)

@@ -8,7 +8,7 @@ TPU-first design notes:
   best onto the MXU's (8,128)/(128,128) tiles.
 - bf16 compute, f32 master params (``Precision``); BatchNorm mean/var
   reductions, running stats, and the softmax stay f32; BN's elementwise
-  normalization runs bf16 (+17.7% measured, see ``norm_dtype``).
+  normalization runs bf16 (see ``norm_dtype``).
 - BatchNorm under global-batch jit is *sync* BatchNorm: the mean/variance
   reductions span the full data-parallel batch and XLA inserts the
   cross-replica collectives.  The reference's MultiWorkerMirroredStrategy
@@ -78,7 +78,8 @@ def augment_images(batch, rng, *, pad: Optional[int] = None):
     randomness per step comes from the step rng; eval never calls this
     (train_lib._wrap_from_record wires it train-only).
 
-    Implementation note (measured on v5e-1, batch 256x224^2 uint8): the
+    Implementation note (measured on v5e-1, batch 256x224^2 uint8, before
+    the current chip attachment): the
     textbook composition — bernoulli ``where`` flip, ``jnp.pad(edge)``,
     per-image ``vmap(dynamic_slice)`` — costs 170-316 ms/step (the vmapped
     slice lowers to a pathological gather and the fused uint8 chain
@@ -150,8 +151,9 @@ class ResNet(nn.Module):
     num_classes: int = 1000
     num_filters: int = 64
     dtype: Any = jnp.bfloat16
-    # BN normalization compute dtype.  bf16 measured +17.7% images/sec on
-    # v5e (2225 vs 1891 img/s, identical loss curve); numerically safe
+    # BN normalization compute dtype.  bf16 measured clearly faster on v5e
+    # with an identical loss curve, in a round that predates the current
+    # chip attachment; numerically safe
     # because flax's BatchNorm keeps the mean/var reductions and the
     # running batch_stats in f32 regardless of this dtype.
     norm_dtype: Any = jnp.bfloat16

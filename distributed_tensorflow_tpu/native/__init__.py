@@ -14,6 +14,7 @@ from distributed_tensorflow_tpu.native.loader import (
     RecordSetLoader,
     make_record_loader,
     native_available,
+    reader_name,
 )
 
 __all__ = [
@@ -22,4 +23,5 @@ __all__ = [
     "RecordSetLoader",
     "make_record_loader",
     "native_available",
+    "reader_name",
 ]

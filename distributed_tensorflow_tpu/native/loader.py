@@ -5,8 +5,11 @@ token blocks, recsys rows).  The sharding contract mirrors tf.data
 AutoShardPolicy.DATA ($TF/python/data/ops/options.py:89 — SURVEY.md §3.4):
 record i belongs to shard ``i % shard_count``.
 
-Falls back to a numpy implementation with identical semantics when a C++
-toolchain is unavailable (``native_available()`` reports which is active).
+The library is built on first use from ``dtt_loader.cpp`` (tracked by git;
+the built ``_build/`` is not, so a fresh checkout compiles its own).  When a
+C++ toolchain is unavailable a numpy implementation with identical semantics
+takes over, at WARNING; ``reader_name()`` says which one is feeding, and
+``train.py --data_dir`` / ``bench.py --input=loader`` print it.
 """
 
 from __future__ import annotations
@@ -81,6 +84,11 @@ def _load_library() -> Optional[ctypes.CDLL]:
 
 def native_available() -> bool:
     return _load_library() is not None
+
+
+def reader_name() -> str:
+    """Which implementation feeds record batches: ``native`` | ``numpy``."""
+    return "native" if native_available() else "numpy"
 
 
 RECORD_MAGIC = b"DTTREC01"

@@ -20,7 +20,7 @@ Design (round-4 schedule — FlashAttention-2 style grid streaming):
 - Head layout: q/k/v arrive as (B, T, H, D) and are transposed to
   (B·H, T, D) for the kernels.  A transpose-free layout (viewing
   (B, T, H·D) and selecting each head's D-slice via BlockSpec index maps)
-  was attempted this round and is impossible under Mosaic's tiling rule —
+  was attempted and is impossible under Mosaic's tiling rule —
   the last block dim must be 128-divisible or equal to the full array dim,
   and a D=64 lane slice is neither (lowering rejects it).  See
   ``_to_heads`` for the measurement note.
@@ -54,26 +54,40 @@ Design (round-4 schedule — FlashAttention-2 style grid streaming):
   ring attention consumes per key block.  Dropout composes exactly with
   the ring combine (l/lse always use undropped probabilities), so the
   with_lse path supports it too — each block pair seeded distinctly.
-- Non-TPU platforms and awkward shapes fall back to the dense XLA path with
-  identical numerics (f32 softmax); its backward is XLA autodiff.  The
-  fallback's dropout uses ``jax.random`` — same distribution, different
-  mask realization than the kernel PRNG (documented, tested for moments).
+- Non-TPU platforms take the dense XLA path with identical numerics (f32
+  softmax); its backward is XLA autodiff.  Its dropout uses ``jax.random``
+  — same distribution, different mask realization than the kernel PRNG
+  (documented, tested for moments).  On a TPU the dense path is taken only
+  for shapes the kernel cannot tile, and then it says so: one WARNING per
+  shape with the reason (``_supported``) — a caller who asked for the
+  kernel never gets the (T, T) buffer without a word.
+- Under a mesh (``mesh=``) the kernel runs inside a ``shard_map`` over the
+  batch axes (``data``, ``fsdp``) and, for heads, ``tensor``: Mosaic
+  kernels cannot be partitioned automatically, so a bare ``pallas_call``
+  handed to GSPMD on more than one chip is refused by the compiler.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
+import math
 import os
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.sharding import Mesh, PartitionSpec as P
+
+logger = logging.getLogger(__name__)
 
 # Block sizes: 512x512 measured best on v5e for the GPT-2 shapes (B=16,
-# T=1024, H=16, D=64): 28.2k tok/s vs 19.6k at 128x128 — the 128-blocks'
-# (128, 64) x (64, 128) matmuls underfeed the MXU pipeline; 512-blocks
-# amortize the per-iteration VPU work (exp/mask) over 16x the MACs.
+# T=1024, H=16, D=64) in a round of measurements that predates the current
+# chip attachment: the 128-blocks' (128, 64) x (64, 128) matmuls underfeed
+# the MXU pipeline; 512-blocks amortize the per-iteration VPU work
+# (exp/mask) over 16x the MACs.
 # Shorter sequences clamp to T (min below), so small models are unaffected.
 BLOCK_Q = int(os.environ.get("DTT_FLASH_BLOCK_Q", "512"))
 BLOCK_K = int(os.environ.get("DTT_FLASH_BLOCK_K", "512"))
@@ -266,9 +280,9 @@ def _fwd_kernel(*refs, causal, scale, block_q, block_k, save_lse,
 # Resident-schedule kernels: the whole loop operand (K/V for fwd+dQ, nothing
 # extra for dK/dV, which streams) stays in VMEM and the kernel iterates it
 # with an in-register fori_loop.  Measured faster than the streaming grid at
-# production T (31.0k vs 28.5k GPT-2 tok/s at T=1024, v5e, this round):
-# loop carries live in vector registers instead of scratch round-trips and
-# there is no per-block grid prologue.  Chosen by `_resident_*_bytes` when
+# production T (T=1024, v5e) in a round that predates the current chip
+# attachment: loop carries live in vector registers instead of scratch
+# round-trips and there is no per-block grid prologue.  Chosen by `_resident_*_bytes` when
 # the windows fit; the streaming kernels above are the long-T schedule.
 # ---------------------------------------------------------------------------
 
@@ -500,7 +514,7 @@ def _to_heads(x):
     D-slice in the BlockSpec index map) was attempted and is IMPOSSIBLE
     under Mosaic's tiling rule: the last block dim must be 128-divisible or
     equal to the array dim, and a per-head D=64 lane slice is neither
-    (measured this round: lowering rejects block (1, bq, 64) on array
+    (lowering rejects block (1, bq, 64) on array
     (B, T, 1024)).  The transpose is therefore structural for D=64 heads.
     """
     B, T, H, D = x.shape
@@ -922,19 +936,43 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
             _from_heads(dv, B, H))
 
 
+def _platform() -> str:
+    return jax.devices()[0].platform
+
+
+_WARNED_SHAPES = set()
+
+
 def _supported(q, causal, dropout_rate=0.0):
+    """Whether the Pallas kernel runs for this (per-shard) shape.
+
+    Off-TPU the answer is quietly no (the dense path is what the CPU tests
+    use).  On a TPU a refusal is logged once per shape at WARNING with the
+    reason, so the dense path never hides behind ``--flash_attention``."""
     B, T, H, D = q.shape
-    if jax.devices()[0].platform != "tpu" and not _interpret():
+    on_tpu = _platform() == "tpu"
+    if not on_tpu and not _interpret():
         return False
     if dropout_rate > 0.0 and _interpret():
         # The TPU PRNG (prng_seed/prng_random_bits) has no interpreter
-        # lowering; CPU tests of dropout exercise the dense fallback, the
-        # kernel PRNG path is validated on hardware
-        # (scripts/validate_tpu.py: validate_kernel_dropout).
+        # lowering; CPU tests of dropout exercise the dense path, the
+        # kernel PRNG path is validated on hardware (chip_smoke.py via
+        # scripts/validate_tpu.py: validate_kernel_dropout).
         return False
+    reason = None
     if _fit_block(T, BLOCK_Q) is None or _fit_block(T, BLOCK_K) is None:
-        return False
-    return D in (64, 128, 256) or D % 128 == 0 or _interpret()
+        reason = (f"seq len {T} has no 128-multiple block divisor "
+                  f"(<= {BLOCK_Q}/{BLOCK_K})")
+    elif not (D in (64, 128, 256) or D % 128 == 0 or _interpret()):
+        reason = f"head dim {D} is not 64/128/256 or a multiple of 128"
+    if reason is None:
+        return True
+    if on_tpu and (tuple(q.shape), reason) not in _WARNED_SHAPES:
+        _WARNED_SHAPES.add((tuple(q.shape), reason))
+        logger.warning(
+            "flash attention: DENSE path for shape %s on TPU — %s; the "
+            "(T, T) score buffer materializes in HBM", tuple(q.shape), reason)
+    return False
 
 
 def _dense_from_seed(q, k, v, kv_mask, seed, *, causal, scale, dropout_rate):
@@ -1029,6 +1067,173 @@ def _flash_lse_bwd(causal, scale, dropout_rate, res, cts):
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
+# Mesh axes the kernel's operands are split over: batch rows over the data
+# axes, heads over ``tensor`` (the column-parallel qkv layout hands each
+# tensor shard whole heads).  The sequence axis is ring attention's job.
+_BATCH_AXES = ("data", "fsdp")
+_HEAD_AXIS = "tensor"
+
+
+class _MeshLayout:
+    """How one kernel call's operands split over ``mesh``, and the
+    ``shard_map`` that runs it per shard.
+
+    The map is manual over every mesh axis that an enclosing ``shard_map``
+    has not already made manual (the pipeline stages are manual over
+    ``pipe``; the call nests inside and takes the rest), so no axis is left
+    for GSPMD to partition the kernel over."""
+
+    def __init__(self, mesh: Mesh):
+        manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+        self.mesh = mesh
+        self.nested = bool(manual)
+        self.free = tuple(a for a in mesh.axis_names if a not in manual)
+        self.batch = tuple(a for a in _BATCH_AXES
+                           if a in self.free and mesh.shape[a] > 1)
+        self.head = (_HEAD_AXIS if _HEAD_AXIS in self.free
+                     and mesh.shape[_HEAD_AXIS] > 1 else None)
+        self.n_batch = math.prod(mesh.shape[a] for a in self.batch)
+        self.n_head = mesh.shape[self.head] if self.head else 1
+        b = self.batch or None
+        self.qkv = P(b, None, self.head, None)   # (B, T, H, D)
+        self.mask = P(b, None)                   # (B, Tk)
+        self.lse = P(b, self.head, None, None)   # (B, H, T, LANES)
+
+    def shard_shape(self, q) -> jax.ShapeDtypeStruct:
+        """The (B, T, H, D) one shard's kernel call sees."""
+        B, T, H, D = q.shape
+        return jax.ShapeDtypeStruct(
+            (max(1, B // self.n_batch), T, max(1, H // self.n_head), D),
+            q.dtype)
+
+    def check_divides(self, q) -> None:
+        B, _, H, _ = q.shape
+        if B % self.n_batch or H % self.n_head:
+            raise ValueError(
+                f"flash attention under mesh {dict(self.mesh.shape)}: batch "
+                f"{B} and heads {H} must divide over {self.batch}="
+                f"{self.n_batch} and {self.head!r}={self.n_head} — the "
+                "kernel runs in a shard_map with static per-shard shapes "
+                "(raise --batch_size, lower --grad_accum_steps, or shrink "
+                "the mesh axis)")
+
+    def shard_seed(self, seed, q_local):
+        """Offset the dropout seed per shard: the kernels seed tile b with
+        ``seed + b`` for b < local B·H, so stepping by that many per shard
+        gives every score tile on the mesh its own PRNG stream."""
+        shard = 0
+        for a in self.batch + ((self.head,) if self.head else ()):
+            shard = shard * self.mesh.shape[a] + lax.axis_index(a)
+        return seed + shard * (q_local.shape[0] * q_local.shape[2])
+
+    def run(self, local, operands, in_specs, out_specs):
+        out = jax.shard_map(
+            local,
+            # nested in a manual region only the context mesh is allowed
+            mesh=None if self.nested else self.mesh,
+            in_specs=tuple(in_specs),
+            out_specs=out_specs,
+            axis_names=set(self.free),
+            check_vma=False,
+        )(*operands)
+        # An enclosing VMA-checked region (the pipeline's) types q as
+        # varying over its manual axes; the unchecked map's results come
+        # back untyped, and a custom_vjp rule must return what it was given.
+        outer = tuple(jax.typeof(operands[0]).vma)
+        if outer:
+            out = jax.tree.map(
+                lambda x: lax.pcast(x, outer, to="varying"), out)
+        return out
+
+
+def _mesh_layout(q, mesh: Optional[Mesh]) -> Optional[_MeshLayout]:
+    """The layout when the call has to be split (more than one device and
+    an axis left to go manual over), else None."""
+    if mesh is None or mesh.size == 1:
+        return None
+    lay = _MeshLayout(mesh)
+    return lay if lay.free else None
+
+
+def _with_optional(operands, in_specs, lay, kv_mask, seed):
+    """Append the optional mask/seed operands with their specs."""
+    if kv_mask is not None:
+        operands.append(kv_mask)
+        in_specs.append(lay.mask)
+    if seed is not None:
+        operands.append(seed)
+        in_specs.append(P())
+
+
+def _split_optional(rest, lay, kv_mask, seed, q_local):
+    rest = list(rest)
+    m_ = rest.pop(0) if kv_mask is not None else None
+    s_ = lay.shard_seed(rest.pop(0), q_local) if seed is not None else None
+    return m_, s_
+
+
+def _sharded_fwd(lay, q, k, v, kv_mask, seed, *, save_lse, **kw):
+    """``_flash_fwd_tpu`` per shard; lse comes back as (B, H, T, LANES)."""
+
+    def local(q_, k_, v_, *rest):
+        m_, s_ = _split_optional(rest, lay, kv_mask, seed, q_)
+        out, lse = _flash_fwd_tpu(q_, k_, v_, m_, save_lse=save_lse,
+                                  seed=s_, **kw)
+        if not save_lse:
+            return out
+        return out, lse.reshape(q_.shape[0], q_.shape[2], *lse.shape[1:])
+
+    operands, in_specs = [q, k, v], [lay.qkv] * 3
+    _with_optional(operands, in_specs, lay, kv_mask, seed)
+    return lay.run(local, operands, in_specs,
+                   (lay.qkv, lay.lse) if save_lse else lay.qkv)
+
+
+def _sharded_bwd(lay, q, k, v, o, lse, g, kv_mask, seed, **kw):
+    """``_flash_bwd_tpu`` per shard (lse in the (B, H, T, LANES) layout)."""
+
+    def local(q_, k_, v_, o_, lse_, g_, *rest):
+        m_, s_ = _split_optional(rest, lay, kv_mask, seed, q_)
+        lse_ = lse_.reshape(-1, *lse_.shape[2:])
+        return _flash_bwd_tpu(q_, k_, v_, o_, lse_, g_, m_, None, seed=s_,
+                              **kw)
+
+    operands = [q, k, v, o, lse, g]
+    in_specs = [lay.qkv] * 4 + [lay.lse, lay.qkv]
+    _with_optional(operands, in_specs, lay, kv_mask, seed)
+    return lay.run(local, operands, in_specs, (lay.qkv,) * 3)
+
+
+# The custom_vjp sits OUTSIDE the shard_maps: forward and backward are each
+# one map with explicit specs, so autodiff never has to carry residuals
+# across a (possibly nested) manual region on its own.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_sharded(q, k, v, kv_mask, seed, causal, scale, dropout_rate,
+                   mesh):
+    return _sharded_fwd(_MeshLayout(mesh), q, k, v, kv_mask, seed,
+                        save_lse=False, causal=causal, scale=scale,
+                        dropout_rate=dropout_rate)
+
+
+def _flash_sharded_fwd(q, k, v, kv_mask, seed, causal, scale, dropout_rate,
+                       mesh):
+    out, lse = _sharded_fwd(_MeshLayout(mesh), q, k, v, kv_mask, seed,
+                            save_lse=True, causal=causal, scale=scale,
+                            dropout_rate=dropout_rate)
+    return out, (q, k, v, kv_mask, seed, out, lse)
+
+
+def _flash_sharded_bwd(causal, scale, dropout_rate, mesh, res, g):
+    q, k, v, kv_mask, seed, o, lse = res
+    dq, dk, dv = _sharded_bwd(_MeshLayout(mesh), q, k, v, o, lse, g,
+                              kv_mask, seed, causal=causal, scale=scale,
+                              dropout_rate=dropout_rate)
+    return dq, dk, dv, None, None
+
+
+_flash_sharded.defvjp(_flash_sharded_fwd, _flash_sharded_bwd)
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -1039,6 +1244,7 @@ def flash_attention(
     kv_mask: Optional[jax.Array] = None,
     dropout_rate: float = 0.0,
     dropout_rng: Optional[jax.Array] = None,
+    mesh: Optional[Mesh] = None,
 ) -> jax.Array:
     """Fused attention. q/k/v: (B, T, H, D) -> (B, T, H, D).
 
@@ -1053,6 +1259,11 @@ def flash_attention(
     dense fallback uses ``jax.random`` (same distribution, different mask
     realization).  ``dropout_rate=0`` (default) compiles the dropout-free
     kernels.
+
+    ``mesh``: the mesh the caller's arrays are sharded over.  When the
+    kernel runs (TPU, or the interpreter) on more than one device it is
+    wrapped in a ``shard_map`` over the batch axes and ``tensor`` (heads);
+    the dense path needs no wrapping, GSPMD partitions it.
     """
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
@@ -1061,7 +1272,13 @@ def flash_attention(
         if dropout_rng is None:
             raise ValueError("dropout_rate > 0 requires dropout_rng")
         seed = _seed_operand(dropout_rng)
-    return _flash(q, k, v, kv_mask, seed, causal, scale, float(dropout_rate))
+    rate = float(dropout_rate)
+    lay = _mesh_layout(q, mesh)
+    if lay is not None and _supported(lay.shard_shape(q), causal, rate):
+        lay.check_divides(q)
+        return _flash_sharded(q, k, v, kv_mask, seed, causal, scale, rate,
+                              mesh)
+    return _flash(q, k, v, kv_mask, seed, causal, scale, rate)
 
 
 def flash_attention_with_lse(

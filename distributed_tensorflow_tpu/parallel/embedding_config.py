@@ -54,9 +54,10 @@ class TableConfig:
     combiner: str = "mean"
     optimizer: Optional[optax.GradientTransformation] = None
     # Stored-row dtype (TPUEmbedding reduced-precision tables role).
-    # bfloat16 halves the gather/param bytes of the lookup — measured ~3%
-    # SLOWER at emb_dim 64 on v5e (rows below the HBM granule; BASELINE.md
-    # r5) but halves table param bytes — while the optimizer keeps an f32
+    # bfloat16 halves the gather/param bytes of the lookup — slightly
+    # SLOWER at emb_dim 64 on v5e in a round that predates the current chip
+    # attachment (rows below the HBM granule), but it halves table param
+    # bytes — while the optimizer keeps an f32
     # master copy + f32 moments (``f32_master_of``), so update math never
     # accumulates in bf16.  None = inherit MultiTableEmbedding.param_dtype.
     dtype: Any = None

@@ -22,6 +22,8 @@ Three levels of API:
 
 from __future__ import annotations
 
+import logging
+import math
 import re
 from typing import Any, Optional, Sequence, Tuple
 
@@ -31,6 +33,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 P = PartitionSpec
 PyTree = Any
+
+logger = logging.getLogger(__name__)
 
 
 def _path_str(path) -> str:
@@ -65,10 +69,13 @@ class ShardingRules:
         out._rules = [(re.compile(p), s) for p, s in rules] + list(self._rules)
         return out
 
-    def spec_for(self, path: str, shape: Tuple[int, ...] = ()) -> PartitionSpec:
+    def spec_for(self, path: str, shape: Tuple[int, ...] = (),
+                 mesh: Optional[Mesh] = None) -> PartitionSpec:
+        """First matching rule, fitted to ``shape`` (and, given ``mesh``,
+        to what its axis sizes divide — see ``_fit_spec``)."""
         for pat, spec in self._rules:
             if pat.search(path):
-                return _fit_spec(spec, shape)
+                return _fit_spec(spec, shape, mesh, path)
         return P()
 
     def shardings_for(self, mesh: Mesh, tree: PyTree) -> PyTree:
@@ -76,18 +83,44 @@ class ShardingRules:
 
         def _one(path, leaf):
             shape = tuple(getattr(leaf, "shape", ()) or ())
-            return NamedSharding(mesh, self.spec_for(_path_str(path), shape))
+            return NamedSharding(
+                mesh, self.spec_for(_path_str(path), shape, mesh))
 
         return jax.tree_util.tree_map_with_path(_one, tree)
 
 
-def _fit_spec(spec: PartitionSpec, shape: Tuple[int, ...]) -> PartitionSpec:
-    """Pad/trim a PartitionSpec to a concrete rank (extra dims replicated)."""
+def spec_ways(mesh: Mesh, *entries) -> int:
+    """How many ways the given PartitionSpec entries split a dimension (or,
+    given a whole spec's entries, an array) on ``mesh``: the product of the
+    sizes of the axes they name."""
+    return math.prod(
+        mesh.shape.get(a, 1) for e in entries if e
+        for a in ((e,) if isinstance(e, str) else e))
+
+
+def _fit_spec(spec: PartitionSpec, shape: Tuple[int, ...],
+              mesh: Optional[Mesh] = None, path: str = "") -> PartitionSpec:
+    """Pad/trim a PartitionSpec to a concrete rank (extra dims replicated).
+
+    With ``mesh``, a dimension the named axes do not divide stays whole:
+    the rule tables are written for tiny test shapes and for real ones
+    (GPT-2's 50257-row ``wte`` under ``tensor=2``), and jit's
+    ``out_shardings`` refuses an uneven split.  Nothing changes on meshes
+    whose axes divide (or have size 1), so one-chip runs and their
+    checkpoints are untouched; the un-split leaf is logged.
+    """
     if not shape:
         return P()
-    entries = list(spec)
-    if len(entries) > len(shape):
-        entries = entries[: len(shape)]
+    entries = list(spec)[: len(shape)]
+    if mesh is not None:
+        for d, entry in enumerate(entries):
+            ways = spec_ways(mesh, entry)
+            if shape[d] % ways:
+                logger.info(
+                    "sharding rule %s for %r: dim %d (size %d) is not "
+                    "divisible by %d; keeping it whole", spec, path, d,
+                    shape[d], ways)
+                entries[d] = None
     return P(*entries)
 
 

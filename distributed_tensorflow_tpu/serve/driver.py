@@ -221,10 +221,22 @@ def _auto_preset(args: ServeArgs) -> Optional[str]:
         return args.preset
     if args.model != "gpt2":
         return None
-    import jax
-
     # CPU smoke serves the test config; real TPUs serve the paper's model.
-    return "medium" if jax.devices()[0].platform == "tpu" else "tiny"
+    # The choice is logged and the JSON line carries ``preset`` and
+    # ``device``, so a tiny CPU run can never be read as the chip.
+    platform = cluster_lib.device_summary()["platform"]
+    preset = "medium" if platform == "tpu" else "tiny"
+    logger.info("no --preset given: serving gpt2 %r on platform %r",
+                preset, platform)
+    return preset
+
+
+def _identity_keys(engine: ServeEngine) -> Dict[str, Any]:
+    """What ran where — on every JSON line this driver returns."""
+    return {
+        "preset": engine.preset,
+        "device": cluster_lib.device_summary(),
+    }
 
 
 def _horizons(args: ServeArgs) -> List[int]:
@@ -688,6 +700,7 @@ def _drive_loadgen(args: ServeArgs, engine: ServeEngine, batcher,
         "checkpoint_step": engine.restored_step,
         "compile_total": int(cstats["compile_total"]),
         "compile_post_warmup": int(cstats["compile_total"] - compile_warm),
+        **_identity_keys(engine),
     }
     out.update(_lifecycle_keys(stats, args))
     if gstats is not None:
@@ -858,6 +871,7 @@ def _drive(args: ServeArgs, engine: ServeEngine) -> Dict[str, Any]:
         "queue_wait_p50_ms": round(stats.get("queue_wait_p50_ms", 0.0), 3),
         "queue_wait_p99_ms": round(stats.get("queue_wait_p99_ms", 0.0), 3),
         "checkpoint_step": engine.restored_step,
+        **_identity_keys(engine),
     }
     cstats = engine.compile_stats()
     out["programs_cached"] = int(cstats["programs_cached"])

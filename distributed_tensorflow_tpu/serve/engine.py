@@ -272,6 +272,9 @@ class ServeEngine:
         self.workload: Workload = get_workload(
             model, mesh=self.mesh, **workload_overrides)
         self.model = model
+        # Named size of the served config (None = the workload's default):
+        # the driver's JSON line reports it next to the device.
+        self.preset: Optional[str] = workload_overrides.get("preset")
         self.module = self.workload.module
         # Fail fast on a decode-incompatible mesh: KV-cache decode runs
         # the scanned block stack directly, which a pipeline-split mesh

@@ -21,6 +21,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_tensorflow_tpu import cluster as cluster_lib
+from distributed_tensorflow_tpu import compile_cache
 from distributed_tensorflow_tpu.checkpoint import CheckpointManager
 from distributed_tensorflow_tpu.data import DevicePrefetchIterator
 from distributed_tensorflow_tpu.models import Workload, available_models, get_workload
@@ -47,9 +48,9 @@ logger = logging.getLogger(__name__)
 class TrainArgs:
     model: str = "mnist"
     arch: Optional[str] = None  # sub-architecture (wide_deep | dlrm)
-    flash_attention: bool = False  # gpt2: Pallas fused attention, forward
-    # and backward (~6.6x tokens/s vs dense+accum on v5e; attention-prob
-    # dropout runs in-kernel — see GPT2Config)
+    flash_attention: bool = False  # gpt2/bert: Pallas fused attention,
+    # forward and backward (attention-prob dropout runs in-kernel — see
+    # GPT2Config; speed not measured on the current chip)
     ring_chunk_size: int = 0  # gpt2/bert with --context>1: kv-chunk size
     # bounding per-ring-step attention memory (0 = whole blocks)
     pipe_schedule: str = "gpipe"  # gpt2 with --pipe>1: gpipe | 1f1b
@@ -100,10 +101,11 @@ def parse_args(argv=None) -> TrainArgs:
     p.add_argument("--arch", type=str, default=None,
                    help="sub-architecture for recsys models: wide_deep|dlrm")
     p.add_argument("--flash_attention", action="store_true",
-                   help="gpt2: use the Pallas fused-attention kernels "
+                   help="gpt2/bert: use the Pallas fused-attention kernels "
                         "(forward AND backward — no (T,T) score buffer in "
-                        "either pass; ~6.6x tokens/s vs dense+accum on "
-                        "v5e; attention-prob dropout runs in-kernel)")
+                        "either pass; attention-prob dropout runs "
+                        "in-kernel; speed not measured on the current "
+                        "chip)")
     p.add_argument("--ring_chunk_size", type=int, default=0,
                    help="gpt2/bert with --context>1: consume ring-attention "
                         "kv blocks in chunks of this many keys (bounds "
@@ -127,7 +129,7 @@ def parse_args(argv=None) -> TrainArgs:
     p.add_argument("--table_dtype", choices=("f32", "bf16"), default="f32",
                    help="wide_deep: stored embedding-row dtype (bf16 halves "
                         "table param bytes; optimizer keeps an f32 master — "
-                        "measured ~3% slower on v5e, BASELINE.md r5)")
+                        "speed not measured on the current chip)")
     p.add_argument("--job_name", type=str, default=None,
                    help="TF1 launcher contract: ps|worker|chief|evaluator")
     p.add_argument("--task_index", type=int, default=None)
@@ -194,7 +196,7 @@ def _wrap_from_record(workload: Workload, fn, *, train: bool = False):
     return lambda p, b, rng: fn(p, pre(b, rng), rng)
 
 
-def build_state_and_step(
+def build_step(
     workload: Workload,
     mesh,
     *,
@@ -204,7 +206,14 @@ def build_state_and_step(
     total_steps: int = 1000,
     seed: int = 0,
 ):
-    """Initialize a sharded TrainState + sharded compiled train step."""
+    """The sharded step, built without touching device memory.
+
+    Returns ``(init, abstract_state, state_shardings, train_step,
+    batch_shardings)``: ``init()`` materializes the sharded TrainState,
+    ``train_step`` is the jitted step.  Both can be lowered from shapes
+    alone (``init.lower()``, ``train_step.lower(abstract_state, ...)``) —
+    how the tests ask what program a full-width mesh gets without running
+    it."""
     lr = learning_rate if learning_rate is not None else workload.learning_rate
     schedule = optax.warmup_cosine_decay_schedule(
         init_value=0.0,
@@ -235,7 +244,7 @@ def build_state_and_step(
     # One rule table shards params AND optimizer moments: regex paths match
     # both "params/.../kernel" and "opt_state/.../mu/.../kernel".
     state_shardings = workload.rules.shardings_for(mesh, abstract_state)
-    state = jax.jit(init_fn, out_shardings=state_shardings)()
+    init = jax.jit(init_fn, out_shardings=state_shardings)
 
     # shard_map paths (ring attention over `context`, GPipe over `pipe`)
     # need static per-shard shapes: every microbatch must divide the batch
@@ -270,7 +279,15 @@ def build_state_and_step(
         out_shardings=(state_shardings, None),
         donate_argnums=(0,),
     ), True)
-    return state, state_shardings, train_step, batch_shardings
+    return init, abstract_state, state_shardings, train_step, batch_shardings
+
+
+def build_state_and_step(workload: Workload, mesh, **kwargs):
+    """Initialize a sharded TrainState + sharded compiled train step
+    (``build_step`` plus the one call that allocates)."""
+    init, _, state_shardings, train_step, batch_shardings = build_step(
+        workload, mesh, **kwargs)
+    return init(), state_shardings, train_step, batch_shardings
 
 
 # Mesh axes each workload can actually honor.  Axes a workload cannot honor
@@ -302,8 +319,9 @@ def validate_mesh_axes(args: TrainArgs) -> None:
 
 def run(args: TrainArgs) -> Dict[str, Any]:
     """Full entrypoint. Returns final host metrics (for tests/benchmarks)."""
-    # force=True: the TPU plugin may have configured root handlers already,
-    # which would silently swallow basicConfig and therefore all INFO logs.
+    # force=True: a library imported earlier may have configured root
+    # handlers already, which would silently swallow basicConfig and
+    # therefore all INFO logs.
     logging.basicConfig(
         level=logging.INFO,
         format="%(asctime)s %(name)s %(levelname)s: %(message)s",
@@ -438,9 +456,12 @@ def run(args: TrainArgs) -> Dict[str, Any]:
             record_paths,
         )
 
+        from distributed_tensorflow_tpu.native import reader_name
+
         paths = record_paths(args.data_dir, args.model)
-        logger.info("native record loader: %d file(s), %s%s", len(paths),
-                    paths[0], "" if len(paths) == 1 else " ..")
+        logger.info("record loader (reader=%s): %d file(s), %s%s",
+                    reader_name(), len(paths), paths[0],
+                    "" if len(paths) == 1 else " ..")
         host_iter = record_data_fn(
             paths, workload, seed=args.seed,
             shard_index=stream_index, shard_count=stream_shards,
@@ -556,6 +577,7 @@ def run(args: TrainArgs) -> Dict[str, Any]:
     result = {
         "final_step": int(jax.device_get(final_state.step)),
         **loop.last_logged_metrics,
+        "device": cluster_lib.device_summary(),
     }
     logger.info("done: %s", result)
     return result
@@ -646,7 +668,9 @@ def run_evaluator(args: TrainArgs) -> Dict[str, Any]:
 
 
 def main(argv=None):
-    result = run(parse_args(argv))
+    args = parse_args(argv)
+    compile_cache.configure()
+    result = run(args)
     if result:
         print(result)
     return result

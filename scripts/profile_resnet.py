@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Profile the ResNet-50 bench step and print a roofline summary.
 
-Produces the evidence behind BASELINE.md's "HBM-bandwidth-bound" claim for
+Produces the evidence for or against an "HBM-bandwidth-bound" reading of
 the north-star metric:
 
 1. captures a ``jax.profiler`` trace of the hot loop (TensorBoard-viewable
@@ -22,7 +22,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
 
 # v5e (TPU v5 lite) per-chip peaks, for the roofline denominators.
 V5E_PEAK_BF16_TFLOPS = 197.0
@@ -75,12 +74,14 @@ def main():
     import jax
 
     from distributed_tensorflow_tpu import cluster as cluster_lib
+    from distributed_tensorflow_tpu import compile_cache
     from distributed_tensorflow_tpu.data import per_host_batch_size
     from distributed_tensorflow_tpu.data.pipeline import make_global_batches
     from distributed_tensorflow_tpu.models import get_workload
     from distributed_tensorflow_tpu.train_lib import build_state_and_step
     from distributed_tensorflow_tpu.training import BF16
 
+    compile_cache.configure()
     mesh = cluster_lib.build_mesh(cluster_lib.MeshConfig(data=1))
     wl = get_workload("resnet50", batch_size=args.batch,
                       image_size=args.image_size)

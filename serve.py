@@ -48,11 +48,8 @@ are shed with backpressure errors.
 import argparse
 import json
 import logging
-import os
 import signal
 import threading
-
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
 
 
 def _megastep_arg(value):
@@ -310,9 +307,12 @@ def main(argv=None):
             signal.signal(signal.SIGTERM, _raise_interrupt)
         except ValueError:
             pass  # embedded interpreter without signal support
+    from distributed_tensorflow_tpu import compile_cache
     from distributed_tensorflow_tpu.serve import run_serve
 
-    result = run_serve(parse_args(argv))
+    args = parse_args(argv)
+    compile_cache.configure()
+    result = run_serve(args)
     print(json.dumps(result))
     return result
 

@@ -128,10 +128,13 @@ class TestDonatedCacheInvariant:
             ignore=shutil.ignore_patterns("__pycache__"))
         engine = scratch / "distributed_tensorflow_tpu" / "serve" / "engine.py"
         src = engine.read_text()
-        anchor = 'self._obs["prefill"].observe(time.perf_counter() - t0)'
-        assert anchor in src
-        engine.write_text(
-            src.replace(anchor, anchor + "\n        _stale = cache", 1))
+        # The launch rebinds ``cache`` to its result, which is what makes
+        # the later reads legal; bind the result elsewhere and the
+        # function's own ``return (nxt, cache)`` reads the donated buffer.
+        anchor = "nxt, cache, counts = self._generate_fns[key]("
+        assert src.count(anchor) == 1
+        engine.write_text(src.replace(
+            anchor, "nxt, _fresh, counts = self._generate_fns[key]("))
 
         _FACTS_CACHE.clear()
         _DEVICE_CACHE.clear()

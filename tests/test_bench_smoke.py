@@ -30,7 +30,10 @@ def test_bench_prints_one_json_line():
     lines = [l for l in proc.stdout.strip().splitlines() if l.strip()]
     assert lines, f"no stdout; stderr: {proc.stderr[-2000:]}"
     out = json.loads(lines[-1])  # the contract: last line is the JSON
-    for key in ("metric", "value", "unit", "vs_baseline", "spread"):
+    for key in ("metric", "value", "unit", "device", "spread"):
         assert key in out, f"missing {key!r} in {out}"
+    # A shrunken CPU run names its device and never the chip metric.
+    assert out["device"]["platform"] == "cpu"
+    assert "cpu_smoke" in out["metric"]
     assert out["value"] > 0
     assert out["spread"]["n"] == 1

@@ -1,0 +1,218 @@
+"""The main path's kernels, compiled for a TPU v5e that is described, not
+attached (on-chip-measurement guide, section 2).
+
+Every other kernel test runs in the Pallas interpreter, which cannot see what
+the chip's compiler refuses: a block that does not tile, too much VMEM, a
+Mosaic kernel handed to the automatic partitioner.  These cases ask the real
+compiler, at real widths, for about two seconds each and no chip time.
+Nothing runs, so nothing here says anything about results or speed.
+
+All cases live in this one file: the compile-only libtpu is loaded once per
+process, and ``ALLOW_MULTIPLE_LIBTPU_LOAD`` lets parallel test workers load it
+side by side (no chip is held, so the multi-process lock protects nothing).
+"""
+
+import dataclasses
+import importlib
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+from distributed_tensorflow_tpu.cluster.topology import MESH_AXES
+
+fa = importlib.import_module("distributed_tensorflow_tpu.ops.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or it cannot describe
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    # A described-device executable is written to the persistent cache but
+    # cannot be read back without a chip; keep the cache out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def steer_to_the_kernel(monkeypatch):
+    """``jax.devices()`` is the CPU here, so the kernel's own platform check
+    would pick the dense path; the test steers it, the program has no such
+    option."""
+    monkeypatch.delenv("DTT_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+
+
+def described_mesh(topo, **axes):
+    shape = tuple(axes.get(a, 1) for a in MESH_AXES)
+    devices = np.array(topo.devices[:int(np.prod(shape))])
+    return Mesh(devices.reshape(shape), MESH_AXES,
+                axis_types=(AxisType.Auto,) * len(MESH_AXES))
+
+
+def compiled_text(fn, *structs):
+    return jax.jit(fn).lower(*structs).compile().as_text()
+
+
+def one_chip(topo, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(
+        shape, dtype,
+        sharding=jax.sharding.SingleDeviceSharding(topo.devices[0]))
+
+
+GPT2_MEDIUM = (8, 1024, 16, 64)   # one grad-accum microbatch of 32/4
+GPT2_LONG = (1, 8192, 16, 64)     # past the resident-VMEM schedule
+BERT_BASE = (32, 512, 12, 64)
+BERT_SEQ128 = (64, 128, 12, 64)
+BERT_SEQ768 = (8, 768, 12, 64)    # 768 = 2 x 384: the block is fitted
+RING_BLOCK = (4, 512, 16, 64)     # one context=2 shard of seq 1024
+
+# (id, shape, causal, kv_mask, dropout, backward, kernel calls expected)
+KERNEL_CASES = [
+    ("gpt2-fwd", GPT2_MEDIUM, True, False, 0.0, False, 1),
+    ("gpt2-bwd", GPT2_MEDIUM, True, False, 0.0, True, 3),
+    ("gpt2-bwd-dropout", GPT2_MEDIUM, True, False, 0.1, True, 3),
+    ("gpt2-long-bwd", GPT2_LONG, True, False, 0.0, True, 3),
+    ("bert-fwd-mask", BERT_BASE, False, True, 0.0, False, 1),
+    ("bert-bwd-mask", BERT_BASE, False, True, 0.0, True, 3),
+    ("bert-bwd-mask-dropout", BERT_BASE, False, True, 0.1, True, 3),
+    ("bert128-bwd-mask", BERT_SEQ128, False, True, 0.0, True, 3),
+    ("bert768-bwd", BERT_SEQ768, False, False, 0.0, True, 3),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,causal,masked,dropout,backward,calls",
+    [c[1:] for c in KERNEL_CASES], ids=[c[0] for c in KERNEL_CASES])
+def test_flash_kernel_compiles_for_v5e(topo, shape, causal, masked, dropout,
+                                       backward, calls):
+    B, T, _, _ = shape
+    qkv = one_chip(topo, shape)
+    mask = one_chip(topo, (B, T), jnp.int32) if masked else None
+    rng = jax.random.key(0) if dropout else None
+
+    def attend(q, k, v, m):
+        return fa.flash_attention(
+            q, k, v, causal=causal, kv_mask=m, dropout_rate=dropout,
+            dropout_rng=rng).astype(jnp.float32).sum()
+
+    fn = jax.grad(attend, argnums=(0, 1, 2)) if backward else attend
+    hlo = compiled_text(fn, qkv, qkv, qkv, mask)
+    assert hlo.count("tpu_custom_call") >= calls, (
+        "the Pallas kernel is not in the compiled program")
+
+
+def test_ring_block_kernel_with_lse_cotangent_compiles_for_v5e(topo):
+    """What ring attention consumes per kv block: (out, lse), both with a
+    cotangent, so the backward kernels take the g_lse operand."""
+    qkv = one_chip(topo, RING_BLOCK)
+
+    def block(q, k, v):
+        out, lse = fa.flash_attention_with_lse(q, k, v, causal=False)
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    hlo = compiled_text(jax.grad(block, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    assert hlo.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("axes", [{"data": 4}, {"data": 2, "tensor": 2}],
+                         ids=["data4", "data2xtensor2"])
+def test_gpt2_attention_layer_compiles_on_four_chips(topo, axes):
+    """A bare pallas_call under a four-device mesh is refused ("Mosaic
+    kernels cannot be automatically partitioned"); the call sites hand the
+    kernel to a shard_map over the batch axes and ``tensor``.  One block of
+    GPT-2 medium, forward and backward, on the described 2x2."""
+    from distributed_tensorflow_tpu.models.gpt2 import Block, GPT2Config
+
+    mesh = described_mesh(topo, **axes)
+    cfg = GPT2Config.medium(dropout=0.0, use_flash_attention=True)
+    block = Block(cfg, mesh=mesh)
+    x = jax.ShapeDtypeStruct(
+        (8, 1024, cfg.d_model), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("data", None, None)))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, P())),
+        jax.eval_shape(block.init, jax.random.key(0),
+                       jnp.zeros((8, 1024, cfg.d_model), jnp.bfloat16)))
+
+    def loss(p, h):
+        return block.apply(p, h)[0].astype(jnp.float32).sum()
+
+    hlo = compiled_text(jax.grad(loss), params, x)
+    assert hlo.count("tpu_custom_call") >= 3
+
+
+def test_bert_attention_layer_with_mask_compiles_on_four_chips(topo):
+    from distributed_tensorflow_tpu.models.bert import BertConfig, EncoderLayer
+
+    mesh = described_mesh(topo, data=2, tensor=2)
+    cfg = BertConfig.base(dropout=0.0, use_flash_attention=True)
+    layer = EncoderLayer(cfg, mesh=mesh)
+    x = jax.ShapeDtypeStruct(
+        (32, 512, cfg.d_model), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("data", None, None)))
+    mask = jax.ShapeDtypeStruct(
+        (32, 512), jnp.int32, sharding=NamedSharding(mesh, P("data", None)))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, P())),
+        jax.eval_shape(layer.init, jax.random.key(0),
+                       jnp.zeros((32, 512, cfg.d_model), jnp.bfloat16)))
+
+    def loss(p, h, m):
+        return layer.apply(p, h, m)[0].astype(jnp.float32).sum()
+
+    hlo = compiled_text(jax.grad(loss), params, x, mask)
+    assert hlo.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("inner", ["data", "tensor"])
+def test_flash_inside_pipeline_stage_compiles_on_four_chips(topo, inner):
+    """Inside the pipeline's shard_map (manual over ``pipe`` only) the
+    kernel nests a second map over the remaining axes; one GPT-2-medium
+    layer per stage, forward and backward through the GPipe schedule."""
+    from distributed_tensorflow_tpu.models.gpt2 import (
+        Block, GPT2Config, _pipe_stage_fn, _pipe_staging)
+    from distributed_tensorflow_tpu.parallel.pipeline import pipeline_apply
+
+    mesh = described_mesh(topo, pipe=2, **{inner: 2})
+    cfg = dataclasses.replace(
+        GPT2Config.medium(dropout=0.0, use_flash_attention=True), n_layer=2)
+    stage_fn = _pipe_stage_fn(cfg, mesh)
+    replicated = NamedSharding(mesh, P())
+    x = jax.ShapeDtypeStruct((16, 1024, cfg.d_model), jnp.bfloat16,
+                             sharding=replicated)
+
+    def one_layer(key):
+        return Block(cfg).init(
+            key, jnp.zeros((2, 1024, cfg.d_model), jnp.bfloat16))["params"]
+
+    layer = jax.eval_shape(one_layer, jax.random.key(0))
+    blocks = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((cfg.n_layer,) + s.shape, s.dtype,
+                                       sharding=replicated), layer)
+
+    def loss(p, h):
+        staged, xm, _ = _pipe_staging(cfg, mesh, p, h)
+        y = pipeline_apply(stage_fn, staged, xm, mesh=mesh, axis="pipe")
+        return y.astype(jnp.float32).sum()
+
+    hlo = compiled_text(jax.grad(loss), blocks, x)
+    assert hlo.count("tpu_custom_call") >= 3

@@ -328,3 +328,96 @@ class TestFlashDropout:
         q, k, v = make_qkv(seed=15)
         with pytest.raises(ValueError):
             flash_attention(q, k, v, dropout_rate=0.5)
+
+
+class TestFlashOnMesh:
+    """``flash_attention(mesh=...)``: under a mesh the kernel runs per
+    (batch, head) shard inside a shard_map, because a Mosaic kernel cannot
+    be handed to the automatic partitioner.  The interpreter stands in for
+    the chip; tests/test_chip_compile.py asks the TPU compiler itself."""
+
+    MESHES = [
+        {"data": 8},
+        {"data": 4, "tensor": 2},
+        {"data": 2, "fsdp": 2, "tensor": 2},
+        {"tensor": 2, "data": 1, "pipe": 4},  # idle axes stay out of the way
+    ]
+
+    @pytest.mark.parametrize("axes", MESHES,
+                             ids=lambda a: "x".join(f"{k}{v}"
+                                                    for k, v in a.items()))
+    @pytest.mark.parametrize("masked", [False, True])
+    def test_matches_dense_fwd_and_bwd(self, monkeypatch, devices8, axes,
+                                       masked):
+        monkeypatch.setenv("DTT_PALLAS_INTERPRET", "1")
+        from distributed_tensorflow_tpu.cluster import MeshConfig, build_mesh
+        from distributed_tensorflow_tpu.ops import flash_attention
+        from distributed_tensorflow_tpu.ops.flash_attention import _dense
+
+        mesh = build_mesh(MeshConfig(**axes), devices8)
+        q, k, v = make_qkv(B=8, T=128, H=4, D=16, seed=21)
+        mask = None
+        if masked:
+            lengths = np.array([128, 100, 64, 1, 127, 33, 128, 90])
+            mask = jnp.asarray(
+                (np.arange(128)[None] < lengths[:, None]).astype(np.int32))
+        w = jnp.asarray(
+            np.random.RandomState(22).randn(*q.shape).astype(np.float32))
+        scale = 1 / np.sqrt(q.shape[-1])
+
+        def sharded(q_, k_, v_):
+            return jnp.sum(w * flash_attention(
+                q_, k_, v_, causal=not masked, kv_mask=mask, mesh=mesh))
+
+        def dense(q_, k_, v_):
+            return jnp.sum(w * _dense(
+                q_, k_, v_, causal=not masked, scale=scale, kv_mask=mask))
+
+        got, g_got = jax.jit(jax.value_and_grad(sharded, (0, 1, 2)))(q, k, v)
+        want, g_want = jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-4)
+        for a, b in zip(g_got, g_want):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
+
+    def test_takes_the_shard_map_only_when_the_kernel_runs(self, devices8):
+        """Off-TPU without the interpreter the dense path is partitioned by
+        GSPMD as before: no shard_map, no divisibility demand."""
+        from distributed_tensorflow_tpu.cluster import MeshConfig, build_mesh
+        from distributed_tensorflow_tpu.ops import flash_attention
+
+        mesh = build_mesh(MeshConfig(), devices8)
+        q, k, v = make_qkv(B=2, T=128, H=2, D=16, seed=23)  # 2 % 8 != 0
+        jaxpr = jax.make_jaxpr(
+            lambda *a: flash_attention(*a, mesh=mesh))(q, k, v)
+        assert "shard_map" not in str(jaxpr)
+
+    def test_indivisible_batch_is_refused_with_the_reason(self, monkeypatch,
+                                                          devices8):
+        monkeypatch.setenv("DTT_PALLAS_INTERPRET", "1")
+        from distributed_tensorflow_tpu.cluster import MeshConfig, build_mesh
+        from distributed_tensorflow_tpu.ops import flash_attention
+
+        mesh = build_mesh(MeshConfig(), devices8)
+        q, k, v = make_qkv(B=2, T=128, H=2, D=16, seed=24)
+        with pytest.raises(ValueError, match="must divide over"):
+            flash_attention(q, k, v, mesh=mesh)
+
+    def test_tpu_refusal_is_logged_once_per_shape(self, monkeypatch, caplog):
+        """A caller who asked for the kernel on a TPU never gets the dense
+        path without a word: one WARNING per shape, with the reason."""
+        import importlib
+        import logging
+
+        fa = importlib.import_module(
+            "distributed_tensorflow_tpu.ops.flash_attention")
+        monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+        monkeypatch.setattr(fa, "_WARNED_SHAPES", set())
+        awkward = jax.ShapeDtypeStruct((2, 200, 4, 64), jnp.bfloat16)
+        with caplog.at_level(logging.WARNING, logger=fa.__name__):
+            assert not fa._supported(awkward, True)
+            assert not fa._supported(awkward, True)
+            assert fa._supported(
+                jax.ShapeDtypeStruct((2, 256, 4, 64), jnp.bfloat16), True)
+        hits = [r for r in caplog.records if "DENSE path" in r.getMessage()]
+        assert len(hits) == 1 and "seq len 200" in hits[0].getMessage()

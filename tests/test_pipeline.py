@@ -233,3 +233,31 @@ class TestPipeline:
         want = sequential(stages, x)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-6)
+
+
+@pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
+@pytest.mark.parametrize("inner", ["data", "tensor"])
+def test_flash_kernel_inside_pipeline_stages(monkeypatch, inner, schedule):
+    """The stages run manual over ``pipe`` only; the flash kernel nests its
+    own shard_map over the remaining axes (a Mosaic kernel cannot be left to
+    the partitioner, not even over size-1 auto axes).  With batch rows or
+    heads split inside the stage, the losses must equal the dense-attention
+    pipeline's.  (Four devices: 1F1B over data x tensor x pipe, dense or
+    not, aborts in XLA:CPU's SPMD partitioner — see ROADMAP.)"""
+    from distributed_tensorflow_tpu.models import get_workload
+    from distributed_tensorflow_tpu.models.gpt2 import GPT2Config
+    from tests.helpers import stream_fed_losses
+
+    monkeypatch.setenv("DTT_PALLAS_INTERPRET", "1")
+    mesh = build_mesh(MeshConfig(**{"data": 1, "pipe": 2, inner: 2}),
+                      jax.devices()[:4])
+
+    def losses(flash):
+        wl = get_workload(
+            "gpt2", config=GPT2Config.tiny(), batch_size=16, seq_len=128,
+            grad_accum_steps=1, mesh=mesh, pipe_schedule=schedule,
+            use_flash_attention=flash)  # 8 microbatches of 2 rows
+        return stream_fed_losses(wl, mesh)
+
+    # bf16 activations: the kernel and XLA round the softmax differently
+    np.testing.assert_allclose(losses(True), losses(False), rtol=1e-4)

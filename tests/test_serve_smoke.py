@@ -180,7 +180,7 @@ def test_serve_entrypoint_sampling_mix_prints_one_json_line():
 def test_bench_serve_mode_prints_one_json_line():
     out = _run([os.path.join(REPO, "bench.py"), "--mode=serve",
                 "--serve_requests=16"])
-    for key in ("metric", "value", "unit", "vs_baseline",
+    for key in ("metric", "value", "unit", "device", "preset",
                 "p50_latency_ms", "p99_latency_ms",
                 "ttft_p50_ms", "tpot_mean_ms", "slot_occupancy",
                 "fixed_tokens_per_sec", "continuous_speedup",

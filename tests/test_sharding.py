@@ -52,6 +52,67 @@ class TestShardingRules:
         assert rules.spec_for("h_0/ln_1/scale", (768,)) == P()
 
 
+    def test_indivisible_dim_stays_whole_on_a_mesh(self, mesh_2d):
+        """Given the mesh, a rule does not split a dimension its axes do not
+        divide (jit's out_shardings refuses an uneven split); dims that do
+        divide, and every mesh whose axes have size 1, are untouched."""
+        rules = ShardingRules([(r"wte", P("tensor", "fsdp"))])
+        assert rules.spec_for("wte", (50257, 1024), mesh_2d) \
+            == P(None, "fsdp")
+        assert rules.spec_for("wte", (50258, 1024), mesh_2d) \
+            == P("tensor", "fsdp")
+        # compound entries divide by the product of their axes
+        rules = ShardingRules([(r"pool", P(("data", "tensor"), None))])
+        assert rules.spec_for("pool", (12, 4), mesh_2d) == P(None, None)
+        assert rules.spec_for("pool", (16, 4), mesh_2d) \
+            == P(("data", "tensor"), None)
+
+
+class TestFullWidthMeshes:
+    """The rule tables at the PUBLISHED widths, which the tiny test configs
+    (vocab 256) never exercised: GPT-2 medium's 50257-row ``wte`` under
+    ``tensor=2`` used to fail in ``build_state_and_step``.  Shapes only —
+    the sharded ``init`` is lowered, never run, so no memory is touched."""
+
+    @pytest.mark.parametrize("axes", [
+        {"tensor": 2}, {"fsdp": 2}, {"pipe": 2},
+        {"tensor": 2, "fsdp": 2, "pipe": 2},
+    ], ids=lambda a: "x".join(f"{k}{v}" for k, v in a.items()))
+    def test_gpt2_medium_state_builds(self, devices8, axes):
+        from distributed_tensorflow_tpu.cluster import MeshConfig, build_mesh
+        from distributed_tensorflow_tpu.models import get_workload
+        from distributed_tensorflow_tpu.parallel.sharding import spec_ways
+        from distributed_tensorflow_tpu.train_lib import build_step
+
+        mesh = build_mesh(MeshConfig(**axes), devices8)
+        workload = get_workload("gpt2", mesh=mesh, use_flash_attention=True)
+        assert workload.module.cfg.vocab_size == 50257
+        init, abstract, shardings, _, _ = build_step(
+            workload, mesh, grad_accum_steps=workload.grad_accum_steps)
+        init.lower()  # an indivisible out_sharding is refused here
+        wte, c_attn = (shardings.params[k] for k in ("wte", "blocks"))
+        c_attn = c_attn["c_attn"]["kernel"]
+        assert spec_ways(mesh, wte.spec[0]) == 1  # 50257 rows: kept whole
+        # everything that divides is still split as the table says
+        assert spec_ways(mesh, *c_attn.spec) \
+            == int(np.prod(list(axes.values())))
+        assert abstract.params["wte"].shape == (50257, 1024)
+
+    def test_one_device_specs_are_what_they_were(self):
+        """On one device every axis has size 1 and divides everything: the
+        repair changes no spec, so one-chip checkpoints restore as before."""
+        from distributed_tensorflow_tpu.cluster import MeshConfig, build_mesh
+        from distributed_tensorflow_tpu.models.gpt2 import gpt2_rules
+
+        mesh = build_mesh(MeshConfig(), jax.devices()[:1])
+        rules = gpt2_rules()
+        for path, shape in (("params/wte", (50257, 1024)),
+                            ("params/blocks/c_attn/kernel", (24, 1024, 3072)),
+                            ("opt_state/0/mu/wte", (50257, 1024))):
+            assert rules.spec_for(path, shape, mesh) \
+                == rules.spec_for(path, shape)
+
+
 class TestFsdpSharding:
     def test_large_params_sharded_small_replicated(self, devices8):
         from distributed_tensorflow_tpu.cluster import MeshConfig, build_mesh

@@ -7,7 +7,8 @@ call, or (worse) record trace-time values as if they were per-step.
 
 The rule finds every function compiled in a module — ``@jax.jit`` /
 ``@pjit`` decorations, ``jax.jit(fn)`` / ``jax.jit(self.method)`` /
-``jax.jit(functools.partial(fn, ...))`` call sites, and jitted lambdas —
+``jax.jit(functools.partial(fn, ...))`` / ``jax.jit(_named(name, fn, ...))``
+call sites, and jitted lambdas —
 then BFS-walks the intra-module call graph from those roots (module
 functions plus same-class ``self.method()`` calls) and flags:
 
@@ -113,6 +114,10 @@ def _jit_roots(module: Module, imports: ImportMap, index: _FunctionIndex
             if name and imports.canonical(name) in (
                     "functools.partial", "partial") and arg.args:
                 return resolve(arg.args[0], at)
+            if name == "_named" and len(arg.args) >= 2:
+                # serve.engine._named("program", fn, *bound): a partial
+                # under a name of its own.
+                return resolve(arg.args[1], at)
         return None
 
     for node in ast.walk(module.tree):

@@ -7,7 +7,8 @@ Two checks:
    dependency-free obs core (``obs.metrics`` / ``obs.trace`` /
    ``obs.exporters``) must never import jax or flax (they run in the
    metrics HTTP server and exporter threads and must stay importable
-   without an accelerator runtime), and ``models`` / ``training`` /
+   without an accelerator runtime; ``ALLOWED`` lists the one exception,
+   the tracer's use of ``jax.profiler``), and ``models`` / ``training`` /
    ``data`` never import ``serve`` (serving sits ABOVE training, not
    beside it).  Forbidden-edge checks look at every import, including
    lazy function-scoped ones — moving an import inside a function does
@@ -52,6 +53,20 @@ LAYER_MAP: List[Tuple[str, str, str]] = [
 ]
 
 
+# (importer prefix, import prefix it may use all the same, why)
+ALLOWED: List[Tuple[str, str, str]] = [
+    (f"{_PKG}.obs.trace", "jax.profiler",
+     "the tracer records whenever a profiler session is open and writes "
+     "its loop spans into that session's trace; jax.profiler starts no "
+     "backend"),
+]
+
+
+def _allowed(importer: str, target: str) -> bool:
+    return any(_prefix_match(importer, src) and _prefix_match(target, dst)
+               for src, dst, _why in ALLOWED)
+
+
 def _prefix_match(name: str, prefix: str) -> bool:
     return name == prefix or name.startswith(prefix + ".")
 
@@ -76,7 +91,8 @@ class LayeringRule(Rule):
             imports = ImportMap(module)
             for rec in imports.records:
                 for (_src, dst, why) in rules:
-                    if _prefix_match(rec.target, dst):
+                    if (_prefix_match(rec.target, dst)
+                            and not _allowed(module.name, rec.target)):
                         lazy = "" if rec.toplevel else " (even lazily)"
                         findings.append(Finding(
                             rule=self.id, path=module.relpath, line=rec.line,

@@ -13,7 +13,6 @@ automatically.
 from __future__ import annotations
 
 import logging
-import time
 from typing import Any, Optional
 
 import jax
@@ -21,7 +20,7 @@ import orbax.checkpoint as ocp
 from etils import epath
 
 from distributed_tensorflow_tpu.obs import metrics as obs_metrics
-from distributed_tensorflow_tpu.obs.trace import default_tracer
+from distributed_tensorflow_tpu.obs.trace import default_tracer, now
 
 logger = logging.getLogger(__name__)
 PyTree = Any
@@ -96,14 +95,13 @@ class CheckpointManager:
         save was started, honoring save_interval_steps like TF's manager)."""
         if step in self._mngr.all_steps():
             return False
-        t0 = time.monotonic()
-        saved = self._mngr.save(
-            step, args=ocp.args.StandardSave(state), force=force
-        )
-        t1 = time.monotonic()
-        self._obs["save"].observe(t1 - t0)
-        self._tracer.add_span("checkpoint_save", cat="checkpoint",
-                              start=t0, end=t1, args={"step": int(step)})
+        t0 = now()
+        with self._tracer.span("checkpoint_save", cat="checkpoint",
+                               args={"step": int(step)}):
+            saved = self._mngr.save(
+                step, args=ocp.args.StandardSave(state), force=force
+            )
+        self._obs["save"].observe(now() - t0)
         if saved:
             logger.info("checkpoint save started at step %d -> %s", step,
                         self.directory)
@@ -120,13 +118,12 @@ class CheckpointManager:
         if step is None:
             raise FileNotFoundError(f"No checkpoint found in {self.directory}")
         abstract = jax.tree.map(_abstractify, template)
-        t0 = time.monotonic()
-        out = self._mngr.restore(
-            step, args=ocp.args.StandardRestore(abstract))
-        t1 = time.monotonic()
-        self._obs["restore"].observe(t1 - t0)
-        self._tracer.add_span("checkpoint_restore", cat="checkpoint",
-                              start=t0, end=t1, args={"step": int(step)})
+        t0 = now()
+        with self._tracer.span("checkpoint_restore", cat="checkpoint",
+                               args={"step": int(step)}):
+            out = self._mngr.restore(
+                step, args=ocp.args.StandardRestore(abstract))
+        self._obs["restore"].observe(now() - t0)
         return out
 
     def restore_or_init(self, state: PyTree) -> PyTree:
@@ -150,12 +147,11 @@ class CheckpointManager:
             step = self.latest_step()
         if step is None:
             raise FileNotFoundError(f"No checkpoint found in {self.directory}")
-        t0 = time.monotonic()
-        tree = self._mngr.restore(step, args=ocp.args.StandardRestore())
-        t1 = time.monotonic()
-        self._obs["restore"].observe(t1 - t0)
-        self._tracer.add_span("checkpoint_restore", cat="checkpoint",
-                              start=t0, end=t1, args={"step": int(step)})
+        t0 = now()
+        with self._tracer.span("checkpoint_restore", cat="checkpoint",
+                               args={"step": int(step)}):
+            tree = self._mngr.restore(step, args=ocp.args.StandardRestore())
+        self._obs["restore"].observe(now() - t0)
         # A TrainState round-trips through StandardSave as a dict of its
         # pytree fields; tolerate an attr-style container too.
         if isinstance(tree, dict):

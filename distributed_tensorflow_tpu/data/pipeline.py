@@ -173,7 +173,9 @@ class DevicePrefetchIterator:
         # scraping the iterator directly.  Lazy import — obs pulls in
         # training.loop, and data.pipeline must stay importable first.
         from distributed_tensorflow_tpu.obs import metrics as obs_metrics
+        from distributed_tensorflow_tpu.obs import trace as obs_trace
 
+        self._tracer = obs_trace.default_tracer()
         self._obs_registry = obs_metrics.default_registry()
         self.obs_namespace = self._obs_registry.register_stats(
             "prefetch", self.stats)
@@ -217,6 +219,10 @@ class DevicePrefetchIterator:
         return self
 
     def __next__(self):
+        with self._tracer.span("prefetch_wait", cat="data"):
+            return self._next_staged()
+
+    def _next_staged(self):
         with self._lock:
             t0 = time.perf_counter()
             while not self._queue and not self._done and self._error is None:

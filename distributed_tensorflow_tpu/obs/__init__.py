@@ -11,10 +11,11 @@ TPU-native: metrics come off the compiled step at throttled intervals
 
 On top of that sits the unified layer: ``obs.metrics`` (thread-safe
 Counter/Gauge/Histogram registry every serve/train component reports
-into), ``obs.trace`` (per-request span flight recorder → Chrome trace
-JSON), ``obs.exporters`` (Prometheus ``/metrics`` endpoint + JSONL
-writer).  The log-line hooks below are thin readers of the registry's
-stats-provider bridge.
+into), ``obs.trace`` (the one span recorder: the scheduler's, the train
+loop's and each request's spans, on under ``--trace_out`` or any profiler
+session → Chrome trace JSON and raw intervals), ``obs.exporters``
+(Prometheus ``/metrics`` endpoint).  The log-line hooks below are thin
+readers of the registry's stats-provider bridge.
 """
 
 # metrics/trace/exporters are dependency-free (no imports back into the
@@ -33,7 +34,6 @@ from distributed_tensorflow_tpu.obs.lifecycle import (
     LifecycleRecorder,
 )
 from distributed_tensorflow_tpu.obs.exporters import (
-    JsonlMetricsWriter,
     MetricsServer,
     render_prometheus,
     write_chrome_trace,
@@ -54,7 +54,6 @@ __all__ = [
     "EMPTY_LIFECYCLE_STATS",
     "Gauge",
     "Histogram",
-    "JsonlMetricsWriter",
     "LifecycleRecorder",
     "MetricsFileWriter",
     "MetricsServer",

@@ -1,14 +1,11 @@
-"""Exporters: Prometheus text scrape endpoint, JSONL writer, trace dump.
+"""Exporters: Prometheus text scrape endpoint, trace dump.
 
-Three ways the registry leaves the process:
+Two ways the process's measurements leave it:
 
 - :class:`MetricsServer` — a daemon-thread HTTP server answering
   ``GET /metrics`` with the Prometheus text exposition format, the
   aggregation substrate the multi-host-serve roadmap item scrapes
   per host.  ``port=0`` binds an ephemeral port (tests).
-- :class:`JsonlMetricsWriter` — appends one JSON object per ``write()``
-  for headless runs with no scraper (same spirit as
-  ``obs.tensorboard.MetricsFileWriter`` but for registry instruments).
 - :func:`write_chrome_trace` — dumps the flight recorder to a
   Perfetto-loadable file.
 
@@ -19,11 +16,9 @@ data structure with no I/O.
 from __future__ import annotations
 
 import http.server
-import json
 import logging
 import math
 import threading
-import time
 from typing import Optional
 
 from distributed_tensorflow_tpu.obs.metrics import (
@@ -40,7 +35,6 @@ logger = logging.getLogger(__name__)
 __all__ = [
     "render_prometheus",
     "MetricsServer",
-    "JsonlMetricsWriter",
     "write_chrome_trace",
 ]
 
@@ -136,49 +130,6 @@ class MetricsServer:
         self._thread.join(timeout=10.0)
 
     def __enter__(self) -> "MetricsServer":
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> None:
-        self.close()
-
-
-class JsonlMetricsWriter:
-    """One JSON object per ``write()``: every counter/gauge value plus
-    histogram sum/count/p50/p99 — greppable offline metrics."""
-
-    def __init__(self, path: str, registry: Optional[Registry] = None):
-        self.path = path
-        self.registry = registry or default_registry()
-        self._f = open(path, "a")
-        self._lock = threading.Lock()
-
-    def write(self, step: Optional[int] = None) -> None:
-        rec = {"time": time.time()}
-        if step is not None:
-            rec["step"] = int(step)
-        for fam in self.registry.families():
-            for key, child in fam.samples():
-                name = fam.name
-                if key:
-                    name += "{" + ",".join(
-                        f"{k}={v}" for k, v in zip(fam.labelnames, key)
-                    ) + "}"
-                if isinstance(fam, Histogram):
-                    rec[f"{name}_sum"] = child.sum
-                    rec[f"{name}_count"] = child.count
-                    rec[f"{name}_p50"] = child.quantile(0.5)
-                    rec[f"{name}_p99"] = child.quantile(0.99)
-                else:
-                    rec[name] = child.value
-        with self._lock:
-            self._f.write(json.dumps(rec) + "\n")
-            self._f.flush()
-
-    def close(self) -> None:
-        with self._lock:
-            self._f.close()
-
-    def __enter__(self) -> "JsonlMetricsWriter":
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:

@@ -4,8 +4,8 @@ With iteration-level scheduling, chunked prefill, megastep decode, the
 async launch ring, and preempt/swap/resume all in one loop, a request's
 wall time is spread across phases no single counter isolates.  The
 ``LifecycleRecorder`` is a thread-safe host-side tap: scheduler, engine,
-tiering, and gateway hooks feed it typed events stamped on monotonic
-clocks, and it folds each request's event stream into an exact-partition
+tiering, and gateway hooks feed it typed events stamped on the tracer's
+clock (``obs.trace.now``), and it folds each request's event stream into an exact-partition
 breakdown the moment the request retires:
 
     wall = queue_wait + prefill + decode_compute + fetch_wait
@@ -37,8 +37,9 @@ from __future__ import annotations
 import collections
 import json
 import threading
-import time
 from typing import Any, Dict, List, Optional
+
+from distributed_tensorflow_tpu.obs.trace import now
 
 __all__ = [
     "EVENTS",
@@ -195,7 +196,7 @@ class LifecycleRecorder:
                **args: Any) -> None:
         """Record one typed event for request ``rid`` (0 = loop-level).
 
-        ``t`` is the event's monotonic timestamp (defaults to now); any
+        ``t`` is the event's ``obs.trace.now()`` timestamp (defaults to now); any
         extra kwargs ride into the JSONL line verbatim and, for
         ``TOKEN_STREAMED``, feed the breakdown fold (``n``,
         ``dispatch_t``, ``wait_s``).
@@ -203,7 +204,7 @@ class LifecycleRecorder:
         if kind not in EVENTS:
             raise ValueError(f"unknown lifecycle event {kind!r}")
         if t is None:
-            t = time.monotonic()
+            t = now()
         line = None
         with self._lock:
             self._events_total += 1
@@ -262,7 +263,7 @@ class LifecycleRecorder:
         if not items:
             return
         if t is None:
-            t = time.monotonic()
+            t = now()
         lines = None
         flush = None
         with self._lock:

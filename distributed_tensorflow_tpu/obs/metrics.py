@@ -4,8 +4,8 @@ The serving stack (fixed, continuous, paged) and the train loop each kept
 private counters readable only through ad-hoc ``stats()`` dicts.  This
 module is the single aggregation point: components register instruments
 against a process-global :class:`Registry` (or a private one in tests),
-exporters (`obs.exporters`) render the registry as Prometheus text or
-JSONL, and the log-line hooks (`obs.serve`, `obs.prefetch`) read component
+exporters (`obs.exporters`) render the registry as Prometheus text, and
+the log-line hooks (`obs.serve`, `obs.prefetch`) read component
 snapshots back out of the same registry via the stats-provider bridge.
 
 Design constraints:

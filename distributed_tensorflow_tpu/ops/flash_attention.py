@@ -614,6 +614,7 @@ def _flash_fwd_tpu(q, k, v, kv_mask, *, causal, scale, save_lse,
             dimension_semantics=semantics,
         ),
         interpret=_interpret(),
+        name="flash_fwd",
     )(*operands)
     out = _from_heads(res[0], B, H)
     if save_lse:
@@ -853,6 +854,7 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
             dimension_semantics=dq_semantics,
         ),
         interpret=_interpret(),
+        name="flash_dq",
     )(*dq_operands)
 
     # dK/dV: resident = q/o/g/lse windows stay in VMEM (fori_loop over q
@@ -930,6 +932,7 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
             dimension_semantics=dkv_semantics,
         ),
         interpret=_interpret(),
+        name="flash_dkv",
     )(*dkv_operands)
 
     return (_from_heads(dq, B, H), _from_heads(dk, B, H),

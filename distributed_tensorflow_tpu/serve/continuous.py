@@ -178,7 +178,6 @@ import dataclasses
 import logging
 import queue
 import threading
-import time
 from concurrent.futures import Future
 from typing import Any, Dict, List, Optional, Tuple, Union
 
@@ -187,7 +186,7 @@ import numpy as np
 
 from distributed_tensorflow_tpu.obs import metrics as obs_metrics
 from distributed_tensorflow_tpu.obs.lifecycle import EMPTY_LIFECYCLE_STATS
-from distributed_tensorflow_tpu.obs.trace import default_tracer
+from distributed_tensorflow_tpu.obs.trace import default_tracer, now as _now
 from distributed_tensorflow_tpu.serve.batcher import (
     ServeOverloadedError,
     _percentile,
@@ -219,6 +218,24 @@ _AUTOTUNE_EVERY = 16
 _AUTOTUNE_MIN_SAMPLES = 8
 _AUTOTUNE_MAX_K = 32
 
+# Trace lane (``tid``) of the ``launch`` spans, dispatch to fetch-done: they
+# overlap the loop's own spans on lane 0, so they get a lane of their own.
+_LAUNCH_LANE = -1
+
+
+@dataclasses.dataclass
+class _Turnover:
+    """A slot between two requests: opened at the retirement of one with
+    another waiting in the queue, stamped as the successor is admitted
+    and as its prefill launch ends, closed by the first decode launch
+    that carries the successor (the ``slot_turnover`` span)."""
+    retired_at: float
+    rid_out: int
+    queued_at_retire: int
+    rid_in: Optional[int] = None
+    admitted_at: Optional[float] = None
+    prefilled_at: Optional[float] = None
+
 
 def _continuous_instruments(registry=None):
     """The iteration-level families on top of the shared serve set."""
@@ -237,6 +254,10 @@ def _continuous_instruments(registry=None):
                      0.025, 0.05, 0.1, 0.25, 0.5, 1.0)),
         "request": r.histogram(
             "dtt_serve_request_seconds", "Submit to retirement"),
+        "slot_turnover": r.histogram(
+            "dtt_serve_slot_turnover_seconds",
+            "Retirement of a slot's request, with the queue non-empty, to "
+            "the first decode launch that carries its successor"),
         "active_slots": r.gauge(
             "dtt_serve_active_slots", "Slots currently decoding"),
         "prefix_hits": r.counter(
@@ -329,7 +350,7 @@ class _SlotRequest:
     max_new_tokens: int
     eos_token: Optional[int]
     future: Future
-    submitted: float                 # time.monotonic() at submit
+    submitted: float                 # obs.trace.now() at submit
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     tokens: List[int] = dataclasses.field(default_factory=list)
@@ -457,7 +478,7 @@ class _InflightMegastep:
     # its own horizons.
     pending: Dict[int, int]
     steps: int                       # the K this launch compiled with
-    dispatch_t: float                # time.monotonic() at dispatch
+    dispatch_t: float                # obs.trace.now() at dispatch
     seq: int                         # _launch_seq at dispatch
     clock_dev: Any = None            # on-device iteration clock output
     # Device handles the fetch thread resolves (set at construction):
@@ -908,6 +929,9 @@ class ContinuousScheduler:
             f"serve/{name}", self.stats
         )
         self._tracer = default_tracer()
+        # slot -> its open turnover (retirement with a request waiting ->
+        # first decode launch of the successor).  Loop thread only.
+        self._turnover: Dict[int, _Turnover] = {}
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name=name)
         if start:
@@ -983,7 +1007,7 @@ class ContinuousScheduler:
         req = _SlotRequest(
             prompt=prompt, max_new_tokens=max_new_tokens,
             eos_token=self.eos_token if eos_token is None else eos_token,
-            future=Future(), submitted=time.monotonic(),
+            future=Future(), submitted=_now(),
             sampling=sampling, on_token=on_token)
         if self.prefix_cache:
             # Hash the prompt's full blocks HERE on the client thread —
@@ -1137,7 +1161,7 @@ class ContinuousScheduler:
         RESIDENT slot to finish its stream.  Returns True when all active
         slots retired in time.  Call ``close()`` afterwards; idempotent
         and safe to call on an already-stopped scheduler."""
-        deadline = time.monotonic() + float(timeout)
+        deadline = _now() + float(timeout)
         with self._cond:
             self._draining = True
             shed = [r for r in self._queue if not r.future.done()]
@@ -1161,7 +1185,7 @@ class ContinuousScheduler:
             finished = self._cond.wait_for(
                 lambda: ((not self._active and not self._preempted)
                          or self._stopped),
-                timeout=max(0.0, deadline - time.monotonic()))
+                timeout=max(0.0, deadline - _now()))
         return bool(finished)
 
     @property
@@ -1436,16 +1460,21 @@ class ContinuousScheduler:
         chunked prefill) runs BEFORE the decode call — with async decode
         on, that host work overlaps the previous iteration's in-flight
         device launch instead of alternating with it."""
-        admits: List[_SlotRequest] = []
-        gen_swapped = False
-        host_t0 = time.monotonic()
+        with self._tracer.span("iteration", cat="serve"):
+            return self._iteration_body()
+
+    def _iteration_body(self) -> bool:
         with self._cond:
             while (not self._stopped and not self._active
                    and not self._queue
                    and not self._preempted
                    and self._pending_gen is None
                    and not self._ring):
-                self._cond.wait()
+                # Parked for want of work: no queue, no active slot,
+                # nothing in flight.  Device idle time under this span is
+                # the traffic's, not the scheduler's.
+                with self._tracer.span("idle_wait", cat="serve"):
+                    self._cond.wait()
             stopped = self._stopped
             cancels = ([] if stopped else
                        [r for r in self._active.values() if r.cancelled])
@@ -1468,6 +1497,41 @@ class ContinuousScheduler:
             for req in cancels:
                 if req.finished_at is None:
                     self._retire(req)
+        with self._tracer.span("host_sched", cat="serve") as host_span:
+            admitted, refill = self._host_sched()
+            host_span.set(admitted=admitted, inflight=len(self._ring))
+        if refill:
+            # Megastep admission alignment: a K-step launch pins
+            # its rows for K iterations, so a request that missed
+            # this boundary by milliseconds would decode phase-
+            # shifted from its wave forever, wasting masked
+            # slot-steps at every retirement.  When this iteration
+            # admitted something and (as of the locked admission
+            # pass above) the queue and free slots were both
+            # non-empty, keep admitting and prefilling, THEN
+            # launch the fused step — rows admitted together
+            # advance and retire together.  Never taken when this
+            # iteration admitted nothing (a blocked head of line
+            # must not starve decode), and a no-op for K=1, whose
+            # admission granularity is already one step.
+            return False
+        self._decode_once()
+        if self.megastep_auto:
+            with self._lock:
+                due = (not self._autotune_frozen
+                       and self._iterations % _AUTOTUNE_EVERY == 0)
+            if due:
+                self._autotune_eval()
+        return False
+
+    def _host_sched(self) -> Tuple[int, bool]:
+        """The host-scheduling half of an iteration: generation install,
+        the admission walk, ``_admit`` and ``_prefill_step`` (whose
+        launches are its ``prefill_chunk`` children).  Returns how many
+        requests it admitted and whether the megastep admission alignment
+        asks for another round before the decode launch."""
+        admits: List[_SlotRequest] = []
+        gen_swapped = False
         with self._cond:
             if self._pending_gen is not None:
                 # Install the staged weight generation: every
@@ -1507,7 +1571,7 @@ class ContinuousScheduler:
                         and self._queue[0].blocked_since is None):
                     # Head of line is waiting on BLOCKS, not slots:
                     # start its reservation-wait span.
-                    self._queue[0].blocked_since = time.monotonic()
+                    self._queue[0].blocked_since = _now()
             self._obs["depth"].set(len(self._queue))
             refill = (self.megastep > 1 and bool(admits)
                       and bool(self._queue) and bool(self._free)
@@ -1553,35 +1617,7 @@ class ContinuousScheduler:
                               and not self._draining)
         self._admit(admits)
         self._prefill_step()
-        if self._tracer.enabled:
-            self._tracer.add_span(
-                "host_sched", cat="serve", tid=0,
-                start=host_t0, end=time.monotonic(),
-                args={"admitted": len(admits),
-                      "inflight": len(self._ring)})
-        if refill:
-            # Megastep admission alignment: a K-step launch pins
-            # its rows for K iterations, so a request that missed
-            # this boundary by milliseconds would decode phase-
-            # shifted from its wave forever, wasting masked
-            # slot-steps at every retirement.  When this iteration
-            # admitted something and (as of the locked admission
-            # pass above) the queue and free slots were both
-            # non-empty, keep admitting and prefilling, THEN
-            # launch the fused step — rows admitted together
-            # advance and retire together.  Never taken when this
-            # iteration admitted nothing (a blocked head of line
-            # must not starve decode), and a no-op for K=1, whose
-            # admission granularity is already one step.
-            return False
-        self._decode_once()
-        if self.megastep_auto:
-            with self._lock:
-                due = (not self._autotune_frozen
-                       and self._iterations % _AUTOTUNE_EVERY == 0)
-            if due:
-                self._autotune_eval()
-        return False
+        return len(admits), refill
 
     def _pick_slot_locked(self, req: _SlotRequest) -> Optional[int]:
         """Index into ``self._free`` of the slot to admit ``req`` into, or
@@ -1686,7 +1722,7 @@ class ContinuousScheduler:
             with self._cond:
                 if self._stopped:
                     break
-                now = time.monotonic()
+                now = _now()
                 cands: List[_SlotRequest] = list(self._preempted)
                 if not self._draining:
                     cands.extend(self._queue)
@@ -1817,7 +1853,8 @@ class ContinuousScheduler:
         self._block_tables[slot, :] = self._allocator.trash_block(shard)
         self._dev_block_tables = None  # host table reset
         self._fresh[slot] = False
-        if self._tracer.enabled:
+        self._turnover.pop(slot, None)  # freed without a retirement
+        if self._tracer.recording:
             self._tracer.add_instant(
                 "preempt", cat="serve", tid=req.rid,
                 args={"request_id": req.rid, "slot": slot,
@@ -1907,7 +1944,8 @@ class ContinuousScheduler:
         else:
             self._dev_last_tok = None  # host vector is newer
         req.next_prefill_offset = len(req.prompt)  # not prefilling
-        if self._tracer.enabled:
+        self._turnover.pop(slot, None)  # taken without an admission
+        if self._tracer.recording:
             self._tracer.add_instant(
                 "resume_swap", cat="serve", tid=req.rid,
                 args={"request_id": req.rid, "slot": slot,
@@ -2023,6 +2061,36 @@ class ContinuousScheduler:
             self._slot_blocks[req.slot][:full], req.prefix_keys[:full],
             self._slot_shard[req.slot])
 
+    def _turnover_prefilled(self, req: _SlotRequest, t: float) -> None:
+        """The successor's prefill launch has ended (its first token is
+        host-visible): the slot now waits only for a decode launch."""
+        turn = self._turnover.get(req.slot)
+        if turn is not None and turn.rid_in == req.rid:
+            turn.prefilled_at = t
+
+    def _turnover_launched(self, slots, t: float) -> None:
+        """A decode launch over ``slots`` starts at ``t``: close the
+        turnover of every slot whose successor it carries for the first
+        time.  The span runs from the retirement to this launch and its
+        three waits (for the iteration's end, for the prefill launch, for
+        this launch) sum to its length."""
+        if not self._turnover:
+            return
+        for slot in slots:
+            turn = self._turnover.get(slot)
+            if turn is None or turn.prefilled_at is None:
+                continue
+            del self._turnover[slot]
+            self._obs["slot_turnover"].observe(t - turn.retired_at)
+            self._tracer.add_span(
+                "slot_turnover", cat="serve", tid=slot,
+                start=turn.retired_at, end=t,
+                args={"wait_iteration_s": turn.admitted_at - turn.retired_at,
+                      "wait_prefill_s": turn.prefilled_at - turn.admitted_at,
+                      "wait_launch_s": t - turn.prefilled_at,
+                      "rid_out": turn.rid_out, "rid_in": turn.rid_in,
+                      "queued_at_retire": turn.queued_at_retire})
+
     def _admit(self, admits: List[_SlotRequest]) -> None:
         """Admission: map the cached prefix, init the chunk state machine
         and make the request RESIDENT.  No prefill compute runs here —
@@ -2033,9 +2101,9 @@ class ContinuousScheduler:
         under the loop lock — once, at admit — so chunk-boundary
         allocations can never fail mid-prefill."""
         for req in admits:
-            admitted_at = time.monotonic()
+            admitted_at = _now()
             queue_wait_s = admitted_at - req.submitted
-            if self._tracer.enabled:
+            if self._tracer.recording:
                 self._tracer.add_span(
                     "queue_wait", cat="serve", tid=req.rid,
                     start=req.submitted, end=admitted_at,
@@ -2058,6 +2126,9 @@ class ContinuousScheduler:
             req.next_prefill_offset = start
             req.prefix_cached = start
             req.prefill_started_at = admitted_at
+            turn = self._turnover.get(req.slot)
+            if turn is not None:
+                turn.admitted_at, turn.rid_in = admitted_at, req.rid
             with self._lock:
                 self._admitted += 1
                 self._active[req.slot] = req
@@ -2126,92 +2197,91 @@ class ContinuousScheduler:
                 req.prefill_idle += 1
                 continue
             req.prefill_idle = 0
-            chunk_start = time.monotonic()
-            self._ensure_blocks(req, off + chunk)
+            chunk_start = _now()
             # Only the FINAL chunk's token is emitted — mid-prefill
             # chunks' outputs are discarded, so only the final chunk
             # commits to the penalty counts.
             final = (off + chunk) >= len(req.prompt)
-            tok_dev, self._cache, self._counts = (
-                self.engine.prefill_into_slots(
-                    self._cache, req.prompt[None, off:off + chunk],
-                    [req.slot],
-                    sampling=sampling_lib.pack(
-                        [req.sampling], [len(req.tokens)]),
-                    counts=self._counts, commit=np.array([final]),
-                    counter=self._next_counter(), params=req.gen.params,
-                    start_offsets=[off] if off else None,
-                    **self._paged_call_kwargs()))
-            spent += chunk
-            req.next_prefill_offset = off + chunk
-            req.prefill_chunks += 1
-            if (self._lifecycle is not None
-                    and self._lifecycle.verbose_loop_events):
-                # Export-only: chunk boundaries colour the JSONL trace;
-                # the fold's prefill phase keys off ADMITTED ->
-                # FIRST_TOKEN alone.
-                self._lifecycle.record(
-                    req.rid, "PREFILL_CHUNK", offset=int(off),
-                    chunk_tokens=int(chunk),
-                    chunk_index=int(req.prefill_chunks - 1))
-            first_decoded = False
-            deferred = final and self.async_decode
-            if deferred:
-                # Defer the first-token fetch into the launch ring: the
-                # chunk's launch interleaves with in-flight decode
-                # fetches instead of blocking the loop mid-iteration.
-                # The slot stays OUT of the decode-active set
-                # (``req.tokens`` empty) until the resolve lands its
-                # token, so no decode launch dispatches it early.
-                rec = _InflightPrefill(
-                    req=req, dispatch_t=chunk_start, fetch_payload=tok_dev)
-                self._enqueue_fetch(rec)
-                self._ring.append(rec)
-                with self._lock:
-                    self._ring_depth_hist[len(self._ring)] += 1
-                    self._obs["ring_depth"].set(len(self._ring))
-                # The depth bound applies to deferred chunks too: several
-                # slots finishing prefill in one iteration must not stack
-                # the ring past what the flag promises.
-                while len(self._ring) >= self.async_depth:
-                    self._resolve_next()
-            elif final:
-                tok = int(self._fetch_host(tok_dev)[0])
-                now = time.monotonic()
-                # A recompute-resumed request already stamped its TTFT
-                # on its first admission — never restamp.
-                first_decoded = req.first_token_at is None
-                if first_decoded:
-                    req.first_token_at = now
-                    if self._lifecycle is not None:
-                        self._lifecycle.record(
-                            req.rid, "FIRST_TOKEN", t=now,
-                            chunks=int(req.prefill_chunks))
-                req.last_token_at = now
-                req.tokens.append(tok)
-                self._last_tok[req.slot, 0] = tok
-                self._dev_last_tok = None  # host vector is newer
-                self._register_prefix(req)
-                self._emit_tokens(req)
-            if self._tracer.enabled:
-                now = time.monotonic()
-                self._tracer.add_span(
-                    "prefill_chunk", cat="serve", tid=req.rid,
-                    start=chunk_start, end=now,
+            with self._tracer.span(
+                    "prefill_chunk", cat="serve",
                     args={"request_id": req.rid, "slot": req.slot,
                           "offset": int(off), "chunk_tokens": int(chunk),
-                          "chunk_index": int(req.prefill_chunks - 1),
-                          "final": bool(final)})
-                if final:
-                    self._tracer.add_span(
-                        "prefill", cat="serve", tid=req.rid,
-                        start=req.prefill_started_at,
-                        end=now,
-                        args={"request_id": req.rid, "slot": req.slot,
-                              "prompt_len": int(len(req.prompt)),
-                              "prefix_tokens_cached": int(
-                                  req.prefix_cached),
-                              "chunks": int(req.prefill_chunks)})
+                          "chunk_index": int(req.prefill_chunks),
+                          "final": bool(final)}):
+                self._ensure_blocks(req, off + chunk)
+                tok_dev, self._cache, self._counts = (
+                    self.engine.prefill_into_slots(
+                        self._cache, req.prompt[None, off:off + chunk],
+                        [req.slot],
+                        sampling=sampling_lib.pack(
+                            [req.sampling], [len(req.tokens)]),
+                        counts=self._counts, commit=np.array([final]),
+                        counter=self._next_counter(),
+                        params=req.gen.params,
+                        start_offsets=[off] if off else None,
+                        **self._paged_call_kwargs()))
+                spent += chunk
+                req.next_prefill_offset = off + chunk
+                req.prefill_chunks += 1
+                if (self._lifecycle is not None
+                        and self._lifecycle.verbose_loop_events):
+                    # Export-only: chunk boundaries colour the JSONL
+                    # trace; the fold's prefill phase keys off ADMITTED
+                    # -> FIRST_TOKEN alone.
+                    self._lifecycle.record(
+                        req.rid, "PREFILL_CHUNK", offset=int(off),
+                        chunk_tokens=int(chunk),
+                        chunk_index=int(req.prefill_chunks - 1))
+                first_decoded = False
+                deferred = final and self.async_decode
+                if deferred:
+                    # Defer the first-token fetch into the launch ring:
+                    # the chunk's launch interleaves with in-flight decode
+                    # fetches instead of blocking the loop mid-iteration.
+                    # The slot stays OUT of the decode-active set
+                    # (``req.tokens`` empty) until the resolve lands its
+                    # token, so no decode launch dispatches it early.
+                    rec = _InflightPrefill(
+                        req=req, dispatch_t=chunk_start,
+                        fetch_payload=tok_dev)
+                    self._enqueue_fetch(rec)
+                    self._ring.append(rec)
+                    with self._lock:
+                        self._ring_depth_hist[len(self._ring)] += 1
+                        self._obs["ring_depth"].set(len(self._ring))
+                    # The depth bound applies to deferred chunks too:
+                    # several slots finishing prefill in one iteration
+                    # must not stack the ring past what the flag promises.
+                    while len(self._ring) >= self.async_depth:
+                        self._resolve_next()
+                elif final:
+                    tok = int(self._fetch_host(tok_dev)[0])
+                    now = _now()
+                    self._turnover_prefilled(req, now)
+                    # A recompute-resumed request already stamped its
+                    # TTFT on its first admission — never restamp.
+                    first_decoded = req.first_token_at is None
+                    if first_decoded:
+                        req.first_token_at = now
+                        if self._lifecycle is not None:
+                            self._lifecycle.record(
+                                req.rid, "FIRST_TOKEN", t=now,
+                                chunks=int(req.prefill_chunks))
+                    req.last_token_at = now
+                    req.tokens.append(tok)
+                    self._last_tok[req.slot, 0] = tok
+                    self._dev_last_tok = None  # host vector is newer
+                    self._register_prefix(req)
+                    self._emit_tokens(req)
+            if final and self._tracer.recording:
+                self._tracer.add_span(
+                    "prefill", cat="serve", tid=req.rid,
+                    start=req.prefill_started_at, end=_now(),
+                    args={"request_id": req.rid, "slot": req.slot,
+                          "prompt_len": int(len(req.prompt)),
+                          "prefix_tokens_cached": int(
+                              req.prefix_cached),
+                          "chunks": int(req.prefill_chunks)})
             with self._lock:
                 self._prefill_chunks += 1
                 self._prefill_backlog -= chunk
@@ -2316,7 +2386,7 @@ class ContinuousScheduler:
         active_slots = list(decoding)
         if not active_slots:
             return
-        iter_start = time.monotonic()
+        iter_start = _now()
         for slot in active_slots:
             # The upcoming step writes each slot's position
             # prompt + len(tokens) - 1; cross a block boundary -> allocate.
@@ -2343,17 +2413,23 @@ class ContinuousScheduler:
                    else self._last_tok)
         samp = self._sampling_vector(decoding)
         launches: List[Tuple[List[int], Any]] = []
-        for generation in sorted(by_gen):
-            slots = by_gen[generation]
-            active = np.zeros((self.num_slots,), bool)
-            active[slots] = True
-            tok_dev, self._cache, self._counts = self.engine.decode_slots(
-                self._cache, last_in, active,
-                sampling=samp, counts=self._counts,
-                counter=self._next_counter(),
-                params=decoding[slots[0]].gen.params,
-                **self._paged_call_kwargs())
-            launches.append((slots, tok_dev))
+        with self._tracer.span(
+                "dispatch", cat="serve",
+                args={"active_slots": len(active_slots),
+                      "generations": len(by_gen)}):
+            for generation in sorted(by_gen):
+                slots = by_gen[generation]
+                active = np.zeros((self.num_slots,), bool)
+                active[slots] = True
+                tok_dev, self._cache, self._counts = (
+                    self.engine.decode_slots(
+                        self._cache, last_in, active,
+                        sampling=samp, counts=self._counts,
+                        counter=self._next_counter(),
+                        params=decoding[slots[0]].gen.params,
+                        **self._paged_call_kwargs()))
+                launches.append((slots, tok_dev))
+        self._turnover_launched(active_slots, iter_start)
         # Chain the device tokens into the next iteration only when ONE
         # generation ran: the single-step program's output is not
         # alive-gated, so with two groups each output carries garbage at
@@ -2370,14 +2446,8 @@ class ContinuousScheduler:
             self._last_occupancy = len(active_slots)
             self._note_dispatch_locked(iter_start)
             self._note_fetch_done_locked(
-                self._launch_seq, time.monotonic())
-        if self._tracer.enabled:
-            self._tracer.add_span(
-                "iteration", cat="serve", tid=0,
-                start=iter_start, end=time.monotonic(),
-                args={"active_slots": len(active_slots),
-                      "generations": len(by_gen)})
-        step_done = time.monotonic()
+                self._launch_seq, _now())
+        step_done = _now()
         gaps = []
         lc_batch = [] if self._lifecycle is not None else None
         to_retire = []
@@ -2476,60 +2546,59 @@ class ContinuousScheduler:
                 req.max_new_tokens))
         if not active_slots:
             return None
-        dispatch_t = time.monotonic()
+        dispatch_t = _now()
         by_gen: Dict[int, List[int]] = {}
         for slot in active_slots:
             by_gen.setdefault(decoding[slot].gen.generation, []).append(slot)
-        # The megastep carry IS alive-gated, so chaining it through
-        # sequential generation groups is exact: group 2's rows ride
-        # through group 1's scan untouched, and the final carry holds
-        # every row's true last token — a valid device-resident input
-        # for the next iteration unconditionally.
-        carry = (self._dev_last_tok if self._dev_last_tok is not None
-                 else self._last_tok)
-        fresh = fresh_tokens = None
-        if self._dev_last_tok is not None and self._fresh.any():
-            fresh = self._fresh.copy()
-            fresh_tokens = self._last_tok[:, 0].copy()
-        if self._dev_clock is not None:
-            clock = self._dev_clock
-        else:
-            with self._lock:
-                clock = np.int32(self._device_clock)
-        samp = self._sampling_vector(decoding)
-        launches: List[Tuple[List[int], Any, Any]] = []
-        for generation in sorted(by_gen):
-            slots = by_gen[generation]
-            active = np.zeros((self.num_slots,), bool)
-            active[slots] = True
-            (toks_dev, carry, steps_dev, clock, self._cache,
-             self._counts) = (
-                self.engine.decode_megastep(
-                    self._cache, carry, active, horizon, steps=K,
-                    eos_rows=eos_rows,
-                    sampling=samp, counts=self._counts,
-                    counter=self._next_counter(K),
-                    params=decoding[slots[0]].gen.params,
-                    fresh_tokens=fresh_tokens, fresh=fresh, clock=clock,
-                    **self._paged_call_kwargs()))
-            fresh = fresh_tokens = None  # the first launch merged them
-            launches.append((slots, toks_dev, steps_dev))
-        self._dev_last_tok = carry
-        self._dev_clock = clock
-        self._fresh[:] = False
-        with self._lock:
-            self._iterations += 1
-            self._occupancy_sum += len(active_slots)
-            self._last_occupancy = len(active_slots)
-            self._note_dispatch_locked(dispatch_t)
-            seq = self._launch_seq
-        self._dispatch_s.append(time.monotonic() - dispatch_t)
-        if self._tracer.enabled:
-            self._tracer.add_span(
-                "dispatch", cat="serve", tid=0,
-                start=dispatch_t, end=time.monotonic(),
+        with self._tracer.span(
+                "dispatch", cat="serve",
                 args={"active_slots": len(active_slots),
-                      "generations": len(by_gen), "megastep": K})
+                      "generations": len(by_gen), "megastep": K}):
+            # The megastep carry IS alive-gated, so chaining it through
+            # sequential generation groups is exact: group 2's rows ride
+            # through group 1's scan untouched, and the final carry holds
+            # every row's true last token — a valid device-resident input
+            # for the next iteration unconditionally.
+            carry = (self._dev_last_tok if self._dev_last_tok is not None
+                     else self._last_tok)
+            fresh = fresh_tokens = None
+            if self._dev_last_tok is not None and self._fresh.any():
+                fresh = self._fresh.copy()
+                fresh_tokens = self._last_tok[:, 0].copy()
+            if self._dev_clock is not None:
+                clock = self._dev_clock
+            else:
+                with self._lock:
+                    clock = np.int32(self._device_clock)
+            samp = self._sampling_vector(decoding)
+            launches: List[Tuple[List[int], Any, Any]] = []
+            for generation in sorted(by_gen):
+                slots = by_gen[generation]
+                active = np.zeros((self.num_slots,), bool)
+                active[slots] = True
+                (toks_dev, carry, steps_dev, clock, self._cache,
+                 self._counts) = (
+                    self.engine.decode_megastep(
+                        self._cache, carry, active, horizon, steps=K,
+                        eos_rows=eos_rows,
+                        sampling=samp, counts=self._counts,
+                        counter=self._next_counter(K),
+                        params=decoding[slots[0]].gen.params,
+                        fresh_tokens=fresh_tokens, fresh=fresh, clock=clock,
+                        **self._paged_call_kwargs()))
+                fresh = fresh_tokens = None  # the first launch merged them
+                launches.append((slots, toks_dev, steps_dev))
+            self._dev_last_tok = carry
+            self._dev_clock = clock
+            self._fresh[:] = False
+            with self._lock:
+                self._iterations += 1
+                self._occupancy_sum += len(active_slots)
+                self._last_occupancy = len(active_slots)
+                self._note_dispatch_locked(dispatch_t)
+                seq = self._launch_seq
+        self._dispatch_s.append(_now() - dispatch_t)
+        self._turnover_launched(active_slots, dispatch_t)
         if self._lifecycle is not None and self._lifecycle.verbose_loop_events:
             # Loop-level event (rid 0): launch cadence for the JSONL
             # export; the per-request attribution rides the
@@ -2575,9 +2644,9 @@ class ContinuousScheduler:
                    for (slots, _, _), (toks, steps)
                    in zip(rec.launches, outs_host)]
         clock_now = int(clock_host)
-        if self._tracer.enabled:
+        if self._tracer.recording:
             self._tracer.add_span(
-                "fetch", cat="serve", tid=0,
+                "launch", cat="serve", tid=_LAUNCH_LANE,
                 start=rec.dispatch_t, end=fetch_done,
                 args={"megastep": K, "launches": len(rec.launches)})
         if self._lifecycle is not None and self._lifecycle.verbose_loop_events:
@@ -2640,11 +2709,13 @@ class ContinuousScheduler:
             self._obs["device_idle"].set(self._idle_fraction_locked())
 
     def _fetch_host(self, value):
-        """THE host-fetch point for launch outputs: one explicit
-        ``jax.device_get`` — already an ndarray, no extra ``np.asarray``
-        round-trip — so every host sync in the hot loop routes through
-        a single sanctioned helper."""
-        return jax.device_get(value)
+        """THE loop thread's host-fetch point for launch outputs: one
+        explicit ``jax.device_get`` — already an ndarray, no extra
+        ``np.asarray`` round-trip — so every host sync in the hot loop
+        routes through a single sanctioned helper, inside the ``fetch``
+        span: the loop blocked on the device."""
+        with self._tracer.span("fetch", cat="serve"):
+            return jax.device_get(value)
 
     def _flush_inflight(self) -> None:
         """Resolve EVERY in-flight launch, oldest first.  The barrier
@@ -2685,13 +2756,14 @@ class ContinuousScheduler:
         holding the scheduler lock (the Future wait would invert the
         lock order against the fetch thread's result hand-back)."""
         if rec.enqueued:
-            t0 = time.monotonic()
-            out, t_done = rec.fetched.result()
-            waited = time.monotonic() - t0
+            with self._tracer.span("fetch", cat="serve"):
+                t0 = _now()
+                out, t_done = rec.fetched.result()
+                waited = _now() - t0
             with self._lock:
                 self._fetch_wait_s += waited
             return out, t_done, waited
-        return self._fetch_host(rec.fetch_payload), time.monotonic(), 0.0
+        return self._fetch_host(rec.fetch_payload), _now(), 0.0
 
     def _enqueue_fetch(self, rec) -> None:
         """Hand a just-dispatched record to the fetch thread (lazily
@@ -2718,9 +2790,10 @@ class ContinuousScheduler:
             if rec is None:
                 return
             try:
+                # Not ``_fetch_host``: that span is the LOOP thread
+                # blocked on the device; this wait is the overlap.
                 rec.fetched.set_result(
-                    (self._fetch_host(rec.fetch_payload),
-                     time.monotonic()))
+                    (jax.device_get(rec.fetch_payload), _now()))
             except BaseException as e:  # noqa: BLE001 — rethrown at resolve
                 rec.fetched.set_exception(e)
 
@@ -2887,7 +2960,7 @@ class ContinuousScheduler:
         if not drafts:
             return False  # fall through: never build a k=0 verify
         K = self.spec_k
-        iter_start = time.monotonic()
+        iter_start = _now()
         tokens_in = np.zeros((self.num_slots, K + 1), np.int32)
         tokens_in[:, 0] = self._last_tok[:, 0]
         draft_lens = np.zeros((self.num_slots,), np.int32)
@@ -2906,18 +2979,24 @@ class ContinuousScheduler:
             by_gen.setdefault(decoding[slot].gen.generation, []).append(slot)
         samp = self._sampling_vector(decoding)
         launches: List[Tuple[List[int], Any, Any]] = []
-        for generation in sorted(by_gen):
-            slots = by_gen[generation]
-            active = np.zeros((self.num_slots,), bool)
-            active[slots] = True
-            targets_dev, accepted_dev, self._cache, self._counts = (
-                self.engine.verify_slots(
-                    self._cache, tokens_in, active, draft_lens,
-                    sampling=samp, counts=self._counts,
-                    counter=self._next_counter(K + 1),
-                    params=decoding[slots[0]].gen.params,
-                    **self._paged_call_kwargs()))
-            launches.append((slots, targets_dev, accepted_dev))
+        with self._tracer.span(
+                "dispatch", cat="serve",
+                args={"active_slots": len(active_slots),
+                      "generations": len(by_gen), "spec_k": K,
+                      "drafted": int(draft_lens.sum())}):
+            for generation in sorted(by_gen):
+                slots = by_gen[generation]
+                active = np.zeros((self.num_slots,), bool)
+                active[slots] = True
+                targets_dev, accepted_dev, self._cache, self._counts = (
+                    self.engine.verify_slots(
+                        self._cache, tokens_in, active, draft_lens,
+                        sampling=samp, counts=self._counts,
+                        counter=self._next_counter(K + 1),
+                        params=decoding[slots[0]].gen.params,
+                        **self._paged_call_kwargs()))
+                launches.append((slots, targets_dev, accepted_dev))
+        self._turnover_launched(active_slots, iter_start)
         # The next iteration's input token is the per-slot LAST kept
         # target — host-assembled from the fetch below, so the device
         # token chain breaks here by design.
@@ -2932,15 +3011,8 @@ class ContinuousScheduler:
                     self._fetch_host(accepted_dev))
                    for slots, targets_dev, accepted_dev in launches]
         with self._lock:
-            self._note_fetch_done_locked(spec_seq, time.monotonic())
-        if self._tracer.enabled:
-            self._tracer.add_span(
-                "iteration", cat="serve", tid=0,
-                start=iter_start, end=time.monotonic(),
-                args={"active_slots": len(active_slots),
-                      "generations": len(by_gen), "spec_k": K,
-                      "drafted": int(draft_lens.sum())})
-        step_done = time.monotonic()
+            self._note_fetch_done_locked(spec_seq, _now())
+        step_done = _now()
         gaps: List[float] = []
         emitted_per_slot: List[int] = []
         appended = 0
@@ -3049,7 +3121,7 @@ class ContinuousScheduler:
         if not drafts:
             return None  # fall through: never build a k=0 verify
         K = self.spec_k
-        dispatch_t = time.monotonic()
+        dispatch_t = _now()
         tokens_in = np.zeros((self.num_slots, K + 1), np.int32)
         # Column 0 is dead weight in chain mode — the device substitutes
         # the carry — but fill it so the host array stays well-formed.
@@ -3084,14 +3156,19 @@ class ContinuousScheduler:
             with self._lock:
                 clock = np.int32(self._device_clock)
         samp = self._sampling_vector(decoding)
-        (targets_dev, accepted_dev, carry_out, clock_out, self._cache,
-         self._counts) = self.engine.verify_slots(
-            self._cache, tokens_in, active, draft_lens,
-            sampling=samp, counts=self._counts,
-            counter=self._next_counter(K + 1),
-            params=decoding[active_slots[0]].gen.params,
-            chain=True, carry=carry, fresh_tokens=fresh_tokens,
-            fresh=fresh, clock=clock, **self._paged_call_kwargs())
+        with self._tracer.span(
+                "dispatch", cat="serve",
+                args={"active_slots": len(active_slots), "spec_k": K,
+                      "drafted": int(draft_lens.sum())}):
+            (targets_dev, accepted_dev, carry_out, clock_out, self._cache,
+             self._counts) = self.engine.verify_slots(
+                self._cache, tokens_in, active, draft_lens,
+                sampling=samp, counts=self._counts,
+                counter=self._next_counter(K + 1),
+                params=decoding[active_slots[0]].gen.params,
+                chain=True, carry=carry, fresh_tokens=fresh_tokens,
+                fresh=fresh, clock=clock, **self._paged_call_kwargs())
+        self._turnover_launched(active_slots, dispatch_t)
         launches = [(active_slots, targets_dev, accepted_dev)]
         self._dev_last_tok = carry_out
         self._dev_clock = clock_out
@@ -3102,12 +3179,6 @@ class ContinuousScheduler:
             self._last_occupancy = len(active_slots)
             self._note_dispatch_locked(dispatch_t)
             seq = self._launch_seq
-        if self._tracer.enabled:
-            self._tracer.add_span(
-                "dispatch", cat="serve", tid=0,
-                start=dispatch_t, end=time.monotonic(),
-                args={"active_slots": len(active_slots), "spec_k": K,
-                      "drafted": int(draft_lens.sum())})
         return _InflightSpec(
             launches=launches, decoding=decoding, pending=pending,
             draft_lens={s: int(draft_lens[s]) for s in active_slots},
@@ -3125,9 +3196,9 @@ class ContinuousScheduler:
                    for (slots, _, _), (targets, accepted)
                    in zip(rec.launches, outs_host)]
         clock_now = int(clock_host)
-        if self._tracer.enabled:
+        if self._tracer.recording:
             self._tracer.add_span(
-                "fetch", cat="serve", tid=0,
+                "launch", cat="serve", tid=_LAUNCH_LANE,
                 start=rec.dispatch_t, end=fetch_done,
                 args={"spec_k": rec.k, "launches": len(rec.launches)})
         gaps: List[float] = []
@@ -3206,6 +3277,7 @@ class ContinuousScheduler:
         req = rec.req
         if req.finished_at is not None:
             return  # retired while the chunk was in flight
+        self._turnover_prefilled(req, fetch_done)
         tok = int(host[0])
         # A recompute-resumed request already stamped its TTFT on its
         # first admission — never restamp.
@@ -3282,7 +3354,7 @@ class ContinuousScheduler:
             first = req.streamed == 0
             req.streamed = len(req.tokens)
             if first:
-                ttfb_s = time.monotonic() - req.submitted
+                ttfb_s = _now() - req.submitted
                 self._ttfb_ms.append(ttfb_s * 1e3)
                 self._obs["ttfb"].observe(ttfb_s)
             cb = req.on_token
@@ -3304,17 +3376,19 @@ class ContinuousScheduler:
             req.on_token = None
 
     def _retire(self, req: _SlotRequest) -> None:
-        req.finished_at = time.monotonic()
-        if self._tracer.enabled:
-            if req.first_token_at is not None:
-                self._tracer.add_span(
-                    "decode", cat="serve", tid=req.rid,
-                    start=req.first_token_at, end=req.finished_at,
-                    args={"request_id": req.rid, "slot": req.slot,
-                          "tokens": int(len(req.tokens))})
-            self._tracer.add_instant(
-                "retire", cat="serve", tid=req.rid,
-                args={"request_id": req.rid, "slot": req.slot})
+        with self._tracer.span(
+                "retire", cat="serve",
+                args={"request_id": req.rid, "slot": req.slot}):
+            self._retire_body(req)
+
+    def _retire_body(self, req: _SlotRequest) -> None:
+        req.finished_at = _now()
+        if req.first_token_at is not None and self._tracer.recording:
+            self._tracer.add_span(
+                "decode", cat="serve", tid=req.rid,
+                start=req.first_token_at, end=req.finished_at,
+                args={"request_id": req.rid, "slot": req.slot,
+                      "tokens": int(len(req.tokens))})
         if self.paged is not None:
             # Bulk-free the slot's blocks and point its table row back at
             # its shard's trash block BEFORE the slot can go inactive —
@@ -3354,6 +3428,7 @@ class ContinuousScheduler:
             self._blocks_hist[used] += 1
             self._active.pop(req.slot, None)
             self._free.append(req.slot)
+            queued = len(self._queue)
             self._retired += 1
             self._obs["retirements"].inc()
             self._obs["active_slots"].set(len(self._active))
@@ -3393,6 +3468,13 @@ class ContinuousScheduler:
                             / (len(req.tokens) - 1))
             # Wake drain() waiters when the last resident slot retires.
             self._cond.notify_all()
+        # A turnover opens only with a request waiting: a slot that stands
+        # empty for want of traffic is not one.
+        if queued:
+            self._turnover[req.slot] = _Turnover(
+                req.finished_at, req.rid, queued)
+        else:
+            self._turnover.pop(req.slot, None)
         if self._lifecycle is not None:
             self._lifecycle.record(
                 req.rid, "CANCELLED" if was_cancelled else "RETIRED",

@@ -68,6 +68,18 @@ PyTree = Any
 _launch_lock = threading.Lock()
 
 
+def _named(name: str, fn: Callable, *bound) -> Callable:
+    """``fn`` (with ``bound`` leading arguments) under a ``__name__`` of
+    its own, so that the program ``jax.jit`` makes of it is ``jit_<name>``
+    in the HLO and on the profiler's ``XLA Modules`` line.  A
+    ``functools.partial`` has no name (JAX calls every one
+    ``jit__unknown``) and a lambda only ``<lambda>``: without this the
+    trace cannot say which launch is which program."""
+    named = functools.partial(fn, *bound)
+    named.__name__ = name
+    return named
+
+
 def _engine_instruments(registry=None):
     """Engine-side families: one compile-event counter per program kind
     (a burst after warmup is normal; compiles during steady-state serving
@@ -337,7 +349,7 @@ class ServeEngine:
             variables = jax.jit(init_fn, out_shardings=shardings)()
         self.params = variables.pop("params")
         self.model_state = variables  # e.g. {"batch_stats": ...} for resnet
-        self._predict_fn = jax.jit(self._predict_apply)
+        self._predict_fn = jax.jit(_named("predict", self._predict_apply))
 
     # -- generate (gpt2 KV-cache decode) -------------------------------------
 
@@ -429,14 +441,15 @@ class ServeEngine:
                 if "step" not in self._generate_fns:
                     self._note_compile("decode_step")
                     self._generate_fns["step"] = jax.jit(
-                        self._decode_apply, donate_argnums=(1,))
+                        _named("decode_step", self._decode_apply),
+                        donate_argnums=(1,))
                 return self._generate_fns["step"]
             key = ("step", temperature, top_k)
             if key not in self._generate_fns:
                 self._note_compile("decode_step")
                 self._generate_fns[key] = jax.jit(
-                    functools.partial(self._sampled_decode_apply,
-                                      temperature, top_k),
+                    _named("sampled_decode", self._sampled_decode_apply,
+                           temperature, top_k),
                     donate_argnums=(1,))
             return self._generate_fns[key]
 
@@ -458,8 +471,8 @@ class ServeEngine:
             shapes = jax.eval_shape(mk)
             shardings = gpt2_cache_rules().shardings_for(self.mesh, shapes)
             self._cache_init_fns[key] = jax.jit(
-                lambda: jax.tree.map(
-                    lambda s: jnp.zeros(s.shape, s.dtype), shapes),
+                _named("cache_init", lambda: jax.tree.map(
+                    lambda s: jnp.zeros(s.shape, s.dtype), shapes)),
                 out_shardings=shardings,
             )
         return self._cache_init_fns[key]()
@@ -499,8 +512,8 @@ class ServeEngine:
             shapes = jax.eval_shape(mk)
             shardings = gpt2_cache_rules().shardings_for(self.mesh, shapes)
             self._cache_init_fns[key] = jax.jit(
-                lambda: jax.tree.map(
-                    lambda s: jnp.zeros(s.shape, s.dtype), shapes),
+                _named("slot_cache_init", lambda: jax.tree.map(
+                    lambda s: jnp.zeros(s.shape, s.dtype), shapes)),
                 out_shardings=shardings,
             )
         return self._cache_init_fns[key]()
@@ -566,8 +579,8 @@ class ServeEngine:
                 per_shard_pools=paged.data_shards > 1,
             ).shardings_for(self.mesh, shapes)
             self._cache_init_fns[key] = jax.jit(
-                lambda: jax.tree.map(
-                    lambda s: jnp.zeros(s.shape, s.dtype), shapes),
+                _named("paged_cache_init", lambda: jax.tree.map(
+                    lambda s: jnp.zeros(s.shape, s.dtype), shapes)),
                 out_shardings=shardings,
             )
         return self._cache_init_fns[key]()
@@ -766,7 +779,8 @@ class ServeEngine:
             if key not in self._generate_fns:
                 self._note_compile("slot_prefill")
                 self._generate_fns[key] = jax.jit(
-                    functools.partial(self._prefill_slots_apply, paged),
+                    _named("prefill_slots", self._prefill_slots_apply,
+                           paged),
                     donate_argnums=(1, 2))
             nxt, cache, counts = self._generate_fns[key](
                 self.params if params is None else params, cache, counts,
@@ -856,7 +870,8 @@ class ServeEngine:
             if key not in self._generate_fns:
                 self._note_compile("slot_decode")
                 self._generate_fns[key] = jax.jit(
-                    functools.partial(self._decode_slots_apply, paged),
+                    _named("decode_slots", self._decode_slots_apply,
+                           paged),
                     donate_argnums=(1, 2))
             tokens_dev = last_tokens
             if not isinstance(tokens_dev, jax.Array):
@@ -972,7 +987,8 @@ class ServeEngine:
         with _launch_lock:
             if key not in self._block_fns:
                 self._note_compile("block_gather")
-                self._block_fns[key] = jax.jit(self._gather_block_apply)
+                self._block_fns[key] = jax.jit(
+                    _named("block_gather", self._gather_block_apply))
             slices = self._block_fns[key](cache, np.int32(block))
             return jax.device_get(slices)
 
@@ -987,7 +1003,8 @@ class ServeEngine:
             if key not in self._block_fns:
                 self._note_compile("block_scatter")
                 self._block_fns[key] = jax.jit(
-                    self._scatter_block_apply, donate_argnums=(0,))
+                    _named("block_scatter", self._scatter_block_apply),
+                    donate_argnums=(0,))
             return self._block_fns[key](cache, np.int32(block), payload)
 
     def bind_slot_rows(self, cache: PyTree, slot_ids, starts) -> PyTree:
@@ -1001,7 +1018,8 @@ class ServeEngine:
             if key not in self._block_fns:
                 self._note_compile("slot_bind")
                 self._block_fns[key] = jax.jit(
-                    self._bind_rows_apply, donate_argnums=(0,))
+                    _named("slot_bind", self._bind_rows_apply),
+                    donate_argnums=(0,))
             return self._block_fns[key](
                 cache, np.asarray(slot_ids, np.int32),
                 np.asarray(starts, np.int32))
@@ -1014,7 +1032,8 @@ class ServeEngine:
         with _launch_lock:
             if key not in self._block_fns:
                 self._note_compile("counts_gather")
-                self._block_fns[key] = jax.jit(self._counts_row_apply)
+                self._block_fns[key] = jax.jit(
+                    _named("counts_gather", self._counts_row_apply))
             row = self._block_fns[key](counts, np.int32(slot))
             return np.asarray(jax.device_get(row))
 
@@ -1026,7 +1045,8 @@ class ServeEngine:
             if key not in self._block_fns:
                 self._note_compile("counts_bind")
                 self._block_fns[key] = jax.jit(
-                    self._counts_bind_apply, donate_argnums=(0,))
+                    _named("counts_bind", self._counts_bind_apply),
+                    donate_argnums=(0,))
             return self._block_fns[key](
                 counts, np.int32(slot), np.asarray(row, np.int32))
 
@@ -1198,7 +1218,8 @@ class ServeEngine:
             if key not in self._generate_fns:
                 self._note_compile("slot_megastep")
                 self._generate_fns[key] = jax.jit(
-                    functools.partial(self._megastep_apply, steps, paged),
+                    _named("decode_megastep", self._megastep_apply,
+                           steps, paged),
                     donate_argnums=(1, 2))
             tokens_dev = last_tokens
             if not isinstance(tokens_dev, jax.Array):
@@ -1396,7 +1417,7 @@ class ServeEngine:
                 fn = (self._verify_chain_apply if chain
                       else self._verify_slots_apply)
                 self._generate_fns[key] = jax.jit(
-                    functools.partial(fn, k, paged),
+                    _named(key[0], fn, k, paged),
                     donate_argnums=(1, 2))
             tokens_dev = jax.device_put(tokens, batch_sharding(self.mesh))
             if chain:

@@ -24,7 +24,7 @@ import time
 from typing import Callable, Dict, List, Optional, Sequence
 
 from distributed_tensorflow_tpu.obs import metrics as obs_metrics
-from distributed_tensorflow_tpu.obs.trace import default_tracer
+from distributed_tensorflow_tpu.obs.trace import default_tracer, now
 from distributed_tensorflow_tpu.serve.batcher import (
     DynamicBatcher,
     ServeOverloadedError,
@@ -224,7 +224,7 @@ class FleetRouter:
         with self._lock:
             if self._closed:
                 raise RuntimeError("FleetRouter is closed")
-        t0 = time.monotonic()
+        t0 = now()
         ranked = self._ranked()
         for rank, (score, idx, rep) in enumerate(ranked):
             try:
@@ -240,9 +240,9 @@ class FleetRouter:
             if rank > 0:
                 self._obs["redispatch"].inc(rank)
             fut.replica = rep.replica_id
-            if self._tracer.enabled:
+            if self._tracer.recording:
                 self._tracer.add_span(
-                    "fleet_route", start=t0, end=time.monotonic(),
+                    "fleet_route", start=t0, end=now(),
                     cat="fleet", tid=getattr(fut, "rid", 0),
                     args={"replica": rep.replica_id,
                           "attempts": rank + 1,

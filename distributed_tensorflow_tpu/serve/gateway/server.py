@@ -44,14 +44,13 @@ from __future__ import annotations
 import json
 import logging
 import threading
-import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
 from distributed_tensorflow_tpu.obs import metrics as obs_metrics
-from distributed_tensorflow_tpu.obs.trace import default_tracer
+from distributed_tensorflow_tpu.obs.trace import default_tracer, now
 from distributed_tensorflow_tpu.serve import sampling as sampling_lib
 from distributed_tensorflow_tpu.serve.batcher import ServeOverloadedError
 from distributed_tensorflow_tpu.serve.gateway.cancel import CancelRegistry
@@ -219,10 +218,10 @@ class GatewayServer:
         gid = self._registry.register(
             fut, stream=ts,
             canceller=lambda: self._cancel_backend(fut))
-        open_t = time.monotonic()
+        open_t = now()
         tracer = default_tracer()
         rid = getattr(fut, "rid", None)
-        if tracer.enabled and rid is not None:
+        if tracer.recording and rid is not None:
             # Start the per-rid flow: the scheduler's admission finishes
             # it, so Perfetto draws gateway lane -> scheduler lane per
             # request.  A gateway span closes the lane at _finish.
@@ -264,10 +263,10 @@ class GatewayServer:
         and free the inflight seat.  Must never raise and never call
         into the scheduler."""
         tracer = default_tracer()
-        if tracer.enabled and open_t is not None and rid is not None:
+        if tracer.recording and open_t is not None and rid is not None:
             tracer.add_span(
                 "gateway", cat="gateway", tid=int(rid),
-                start=open_t, end=time.monotonic(),
+                start=open_t, end=now(),
                 args={"gid": gid, "request_id": int(rid)})
         try:
             if ts is not None:

@@ -268,7 +268,9 @@ class TrainLoop:
         # the partially-initialized obs package whenever training.loop is
         # imported first.
         from distributed_tensorflow_tpu.obs import metrics as obs_metrics
+        from distributed_tensorflow_tpu.obs import trace as obs_trace
 
+        self._tracer = obs_trace.default_tracer()
         reg = obs_metrics.default_registry()
         self._obs_step_time = reg.histogram(
             "dtt_train_step_seconds",
@@ -309,7 +311,8 @@ class TrainLoop:
             return None, None
         step, tree = self._pending_metrics
         self._pending_metrics = None
-        host_tree = jax.device_get(tree)
+        with self._tracer.span("metrics_fetch", cat="train"):
+            host_tree = jax.device_get(tree)
         host = {k: float(np.asarray(v)) for k, v in host_tree.items()}
         self._obs_flushes.inc()
         return step, host
@@ -317,8 +320,10 @@ class TrainLoop:
     def _deliver(self, metrics_step: int, host: Dict[str, float]) -> None:
         self.last_metrics_step = metrics_step
         self.last_step_metrics = host
-        for h in self.hooks:
-            h.on_metrics(self, metrics_step, host)
+        with self._tracer.span("hooks", cat="train",
+                               args={"call": "on_metrics"}):
+            for h in self.hooks:
+                h.on_metrics(self, metrics_step, host)
 
     def flush_metrics(self) -> Optional[Dict[str, float]]:
         """Consume the in-flight metrics fetch immediately (end of a run
@@ -355,15 +360,22 @@ class TrainLoop:
         folded in-step (or split host-side on the legacy path), and metric
         fetches are started asynchronously and consumed an interval later.
         """
+        with self._tracer.span("step", cat="train"):
+            return self._one_step(completed_steps, train_step)
+
+    def _one_step(self, completed_steps: int, train_step) -> int:
         fn = train_step if train_step is not None else self.train_step
         try:
-            batch = next(self.data_iter)
+            with self._tracer.span("next_batch", cat="train"):
+                batch = next(self.data_iter)
         except StopIteration:
             self.request_stop()
             self.last_step_metrics = None
             return completed_steps
         t0 = time.perf_counter()
-        self.state, metrics = fn(self.state, batch, self._step_rng(fn))
+        rng = self._step_rng(fn)
+        with self._tracer.span("dispatch", cat="train"):
+            self.state, metrics = fn(self.state, batch, rng)
         self._obs_step_time.observe(time.perf_counter() - t0)
         self._obs_steps.inc()
         completed_steps += 1
@@ -374,11 +386,19 @@ class TrainLoop:
             if host_metrics is not None:
                 self._deliver(mstep, host_metrics)
         self.last_step_metrics = host_metrics
-        for h in self.hooks:
-            h.after_step(self, completed_steps, host_metrics)
+        with self._tracer.span("hooks", cat="train",
+                               args={"call": "after_step"}):
+            for h in self.hooks:
+                h.after_step(self, completed_steps, host_metrics)
         return completed_steps
 
     def run(self, num_steps: int) -> TrainState:
+        # Parent of the steps' spans: what a run spends outside them (the
+        # hooks' begin and end, the state's step fetched, the last flush).
+        with self._tracer.span("run", cat="train"):
+            return self._run(num_steps)
+
+    def _run(self, num_steps: int) -> TrainState:
         for h in self.hooks:
             h.begin(self)
         start = int(jax.device_get(self.state.step))

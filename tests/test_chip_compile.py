@@ -15,6 +15,7 @@ side by side (no chip is held, so the multi-process lock protects nothing).
 import dataclasses
 import importlib
 import os
+import re
 
 import numpy as np
 import pytest
@@ -70,6 +71,16 @@ def compiled_text(fn, *structs):
     return jax.jit(fn).lower(*structs).compile().as_text()
 
 
+def kernel_names(hlo):
+    """The names the compiled program gives its Pallas calls: the profiler
+    shows an executed instruction by this text, so a kernel's ``name=`` is
+    how a device trace tells the three apart.  The compiler names the
+    instruction after the innermost scope of its ``op_name``:
+    ``%flash_fwd.3`` under a module's scope, ``%jvp_flash_fwd_.1`` bare."""
+    return set(re.findall(
+        r"%\w*?(flash_(?:fwd|dq|dkv))[\w.]* = [^\n]*tpu_custom_call", hlo))
+
+
 def one_chip(topo, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(
         shape, dtype,
@@ -116,6 +127,8 @@ def test_flash_kernel_compiles_for_v5e(topo, shape, causal, masked, dropout,
     hlo = compiled_text(fn, qkv, qkv, qkv, mask)
     assert hlo.count("tpu_custom_call") >= calls, (
         "the Pallas kernel is not in the compiled program")
+    assert kernel_names(hlo) == (
+        {"flash_fwd", "flash_dq", "flash_dkv"} if backward else {"flash_fwd"})
 
 
 def test_ring_block_kernel_with_lse_cotangent_compiles_for_v5e(topo):
@@ -157,6 +170,8 @@ def test_gpt2_attention_layer_compiles_on_four_chips(topo, axes):
 
     hlo = compiled_text(jax.grad(loss), params, x)
     assert hlo.count("tpu_custom_call") >= 3
+    # Inside the shard_map too the instructions carry the kernels' names.
+    assert kernel_names(hlo) == {"flash_fwd", "flash_dq", "flash_dkv"}
 
 
 def test_bert_attention_layer_with_mask_compiles_on_four_chips(topo):

@@ -127,12 +127,18 @@ class PagedKVConfig:
     (Kwon et al., SOSP 2023; PAPERS.md).
 
     Instead of one dense ``(num_slots, max_total_len)`` K/V row per slot,
-    K/V live in a ``(num_blocks, block_size, heads, head_dim)`` pool per
-    layer and each slot maps its logical positions to physical blocks
-    through a host-managed ``(num_slots, max_blocks_per_slot)`` int32 block
-    table passed into every decode call.  A request only pins the blocks
-    its current length actually covers, so a 30-token request no longer
-    reserves a full worst-case row.
+    K/V live in a ``(num_blocks, block_size, heads * head_dim)`` pool per
+    layer — ``(layers, num_blocks, block_size, heads * head_dim)`` under
+    the scanned stack — and each slot maps its logical positions to
+    physical blocks through a host-managed ``(num_slots,
+    max_blocks_per_slot)`` int32 block table passed into every decode
+    call.  A request only pins the blocks its current length actually
+    covers, so a 30-token request no longer reserves a full worst-case
+    row.  Heads are merged into the minor dimension (head h owns columns
+    ``[h * head_dim, (h + 1) * head_dim)``) so a block's rows are a
+    multiple of the TPU's 128 lanes wide: a head size of 64 in the minor
+    dimension would be padded to 128 and every program would re-lay the
+    pool first.
 
     Physical block 0 is the TRASH block: never allocated to a request,
     it absorbs the garbage K/V that inactive decode rows write (their
@@ -280,7 +286,7 @@ class Block(nn.Module):
     paged: Optional[PagedKVConfig] = None  # block-table cache (serve path)
 
     @nn.compact
-    def __call__(self, x, slot_ids=None, block_tables=None):
+    def __call__(self, x, slot_ids=None, block_tables=None, layer=None):
         cfg = self.cfg
         deterministic = self.deterministic
         d, h = cfg.d_model, cfg.n_head
@@ -297,7 +303,7 @@ class Block(nn.Module):
             # Paged serve path: K/V in a fixed pool of blocks, each slot's
             # logical positions routed through its block-table row.
             ctx = self._paged_cached_attention(
-                q, k, v, slot_ids, block_tables).reshape(B, T, d)
+                q, k, v, slot_ids, block_tables, layer).reshape(B, T, d)
         elif self.decode:
             # Serve path: exact attention over the preallocated KV cache.
             # Takes precedence over ring/flash — both are training-shape
@@ -415,11 +421,13 @@ class Block(nn.Module):
         probs = probs.astype(cfg.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)
 
-    def _paged_cached_attention(self, q, k, v, slot_ids, block_tables):
+    def _paged_cached_attention(self, q, k, v, slot_ids, block_tables,
+                                layer=None):
         """Exact attention over the block-table KV pool.
 
-        K/V storage is a ``(num_blocks, block_size, H, hd)`` pool; logical
-        position ``p`` of slot ``s`` lives at physical block
+        K/V storage is a ``(num_blocks, block_size, H * hd)`` pool (heads
+        merged into the lane-dense minor dimension); logical position
+        ``p`` of slot ``s`` lives at physical block
         ``block_tables[s, p // block_size]``, offset ``p % block_size``.
         Each call scatters its new K/V into the owning blocks (one write
         per (row, token) — offsets are unique within a call because slot
@@ -428,6 +436,14 @@ class Block(nn.Module):
         same masked softmax as the dense slot path.  Unallocated table
         entries point at trash block 0, whose (finite garbage) contents
         sit past each row's ``cache_index`` and are causally masked.
+
+        ``layer`` is the scanned stack's layer index: the cache variables
+        are then the WHOLE ``(layers, ...)`` stacks, carried through the
+        layer loop, and this layer scatters at ``[layer, block, offset]``
+        and gathers ``pool[layer, table rows]`` — the pool is updated in
+        place and never sliced or re-stacked per layer.  ``None`` (the
+        unrolled ``h_i`` layout, and init) means the variables are this
+        layer's own.
 
         With ``kv_dtype="int8"`` the pool stores per-token symmetrically
         quantized values plus ``(num_blocks, block_size)`` f32 scale
@@ -446,14 +462,14 @@ class Block(nn.Module):
         """
         cfg, pg = self.cfg, self.paged
         B, T, h, head_dim = q.shape
-        bs = pg.block_size
+        bs, d = pg.block_size, h * head_dim
         store_dtype = pg.storage_dtype(cfg.dtype)
         kp = self.variable(
             "cache", "cached_key_pool",
-            lambda: jnp.zeros((pg.num_blocks, bs, h, head_dim), store_dtype))
+            lambda: jnp.zeros((pg.num_blocks, bs, d), store_dtype))
         vp = self.variable(
             "cache", "cached_value_pool",
-            lambda: jnp.zeros((pg.num_blocks, bs, h, head_dim), store_dtype))
+            lambda: jnp.zeros((pg.num_blocks, bs, d), store_dtype))
         if pg.quantized:
             ksc = self.variable(
                 "cache", "key_scale",
@@ -465,37 +481,37 @@ class Block(nn.Module):
             "cache", "cache_index",
             lambda: jnp.zeros((B,), jnp.int32))
 
-        idx = ci.value[slot_ids]                              # (B,)
+        def at(*index):
+            return index if layer is None else (layer,) + index
+
+        idx = ci.value[at(slot_ids)]                          # (B,)
         rows_bt = jnp.maximum(block_tables, 0)[slot_ids]      # (B, max_blk)
         pos = idx[:, None] + jnp.arange(T)[None, :]           # (B, T)
         pb = jnp.take_along_axis(rows_bt, pos // bs, axis=1)  # (B, T)
         off = pos % bs
-        flat_pb, flat_off = pb.reshape(-1), off.reshape(-1)
+        cells = at(pb.reshape(-1), off.reshape(-1))
         if pg.quantized:
             kq, k_scale = _quantize_kv_int8(k)
             vq, v_scale = _quantize_kv_int8(v)
-            kp.value = kp.value.at[flat_pb, flat_off].set(
-                kq.reshape(B * T, h, head_dim))
-            vp.value = vp.value.at[flat_pb, flat_off].set(
-                vq.reshape(B * T, h, head_dim))
-            ksc.value = ksc.value.at[flat_pb, flat_off].set(
-                k_scale.reshape(-1))
-            vsc.value = vsc.value.at[flat_pb, flat_off].set(
-                v_scale.reshape(-1))
+            kp.value = kp.value.at[cells].set(kq.reshape(B * T, d))
+            vp.value = vp.value.at[cells].set(vq.reshape(B * T, d))
+            ksc.value = ksc.value.at[cells].set(k_scale.reshape(-1))
+            vsc.value = vsc.value.at[cells].set(v_scale.reshape(-1))
         else:
-            kp.value = kp.value.at[flat_pb, flat_off].set(
-                k.astype(store_dtype).reshape(B * T, h, head_dim))
-            vp.value = vp.value.at[flat_pb, flat_off].set(
-                v.astype(store_dtype).reshape(B * T, h, head_dim))
-        ci.value = ci.value.at[slot_ids].set(idx + T)
+            kp.value = kp.value.at[cells].set(
+                k.astype(store_dtype).reshape(B * T, d))
+            vp.value = vp.value.at[cells].set(
+                v.astype(store_dtype).reshape(B * T, d))
+        ci.value = ci.value.at[at(slot_ids)].set(idx + T)
 
-        gk = kp.value[rows_bt]                # (B, max_blk, bs, H, hd)
-        gv = vp.value[rows_bt]
+        rows = at(rows_bt)
+        gk = kp.value[rows]                   # (B, max_blk, bs, H * hd)
+        gv = vp.value[rows]
         if pg.quantized:
             gk = (gk.astype(jnp.float32)
-                  * ksc.value[rows_bt][..., None, None]).astype(cfg.dtype)
+                  * ksc.value[rows][..., None]).astype(cfg.dtype)
             gv = (gv.astype(jnp.float32)
-                  * vsc.value[rows_bt][..., None, None]).astype(cfg.dtype)
+                  * vsc.value[rows][..., None]).astype(cfg.dtype)
         else:
             gk = gk.astype(cfg.dtype)
             gv = gv.astype(cfg.dtype)
@@ -598,18 +614,32 @@ class GPT2(nn.Module):
             # lifted scope rejects the mutable cache writes.
             use_remat = cfg.remat and not decode
             body = nn.remat(Block, prevent_cse=False) if use_remat else Block
+            # Paged decode CARRIES the cache through the layer loop: each
+            # layer updates the stacked (L, ...) pools in place at its own
+            # index.  Scanned over axis 0 they would be sliced per layer
+            # and re-stacked, a copy of every pool each token step.  Init
+            # (which only fixes the shapes) stacks per-layer variables.
+            if paged is not None and not self.is_initializing():
+                cache_vars = dict(
+                    variable_axes={"params": 0}, variable_carry="cache",
+                    in_axes=(nn.broadcast, nn.broadcast, 0))
+                layers = (jnp.arange(cfg.n_layer, dtype=jnp.int32),)
+            else:
+                cache_vars = dict(
+                    variable_axes={"params": 0, "cache": 0},
+                    in_axes=nn.broadcast)
+                layers = ()
             Scanned = nn.scan(
                 body,
-                variable_axes={"params": 0, "cache": 0},
                 split_rngs={"params": True, "dropout": True},
-                in_axes=nn.broadcast,  # slot_ids/tables shared by every layer
                 length=cfg.n_layer,
                 unroll=cfg.scan_unroll,
+                **cache_vars,  # slot_ids/tables are shared by every layer
             )
             x, _ = Scanned(
                 cfg, mesh=self.mesh, deterministic=deterministic,
                 decode=decode, paged=paged, name="blocks",
-            )(x, slot_ids, block_tables)
+            )(x, slot_ids, block_tables, *layers)
         else:
             for i in range(cfg.n_layer):
                 x, _ = Block(
@@ -895,6 +925,11 @@ def gpt2_cache_rules(per_shard_pools: bool = False) -> ShardingRules:
     is written from (``transformer_rules``), so decode runs TP without any
     resharding at the cache boundary.  Scalar indices stay replicated.
 
+    Paged pools are ``(num_blocks, block_size, H * head_dim)`` —
+    ``(L, num_blocks, block_size, H * head_dim)`` under "blocks" — with the
+    merged minor dimension over ``tensor``: heads are contiguous in it, so
+    a chip holds the columns of exactly the heads it computes.
+
     ``per_shard_pools=True`` (``PagedKVConfig.data_shards > 1``) shards the
     paged pools' block dimension over the data axes as well: the allocator
     partitions block ids contiguously per data shard and pins every slot's
@@ -906,22 +941,23 @@ def gpt2_cache_rules(per_shard_pools: bool = False) -> ShardingRules:
     if per_shard_pools:
         pool_rules = [
             (r"blocks/cached_(key|value)_pool",
-             P(None, ("data", "fsdp"), None, "tensor", None)),
+             P(None, ("data", "fsdp"), None, "tensor")),
             (r"cached_(key|value)_pool",
-             P(("data", "fsdp"), None, "tensor", None)),
+             P(("data", "fsdp"), None, "tensor")),
             (r"blocks/(key|value)_scale", P(None, ("data", "fsdp"))),
             (r"(key|value)_scale", P(("data", "fsdp"))),
         ]
     else:
         pool_rules = [
-            # Paged pools (L, num_blocks, block_size, H, hd): in the
+            # Paged pools (L, num_blocks, block_size, H * hd): in the
             # replicated layout the block dim is NOT a batch dim — any
             # slot's tokens can live in any block — so only heads shard
-            # (over ``tensor``, same layout the qkv projection writes);
-            # scale tables replicate.
+            # (over ``tensor``: heads are contiguous in the merged minor
+            # dimension, so each chip holds the columns of the heads the
+            # qkv projection writes there); scale tables replicate.
             (r"blocks/cached_(key|value)_pool",
-             P(None, None, None, "tensor", None)),
-            (r"cached_(key|value)_pool", P(None, None, "tensor", None)),
+             P(None, None, None, "tensor")),
+            (r"cached_(key|value)_pool", P(None, None, "tensor")),
             (r"(key|value)_scale", P()),
         ]
     return ShardingRules(

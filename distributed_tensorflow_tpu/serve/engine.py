@@ -521,7 +521,7 @@ class ServeEngine:
     def init_paged_cache(self, num_slots: int, total_len: int, *,
                          paged) -> PyTree:
         """ONE resident block-table KV cache (``cache_mode="paged"``):
-        per-layer ``(num_blocks, block_size, heads, head_dim)`` K/V pools
+        per-layer ``(num_blocks, block_size, heads * head_dim)`` K/V pools
         (plus f32 scale tables under ``kv_dtype="int8"``) and the same
         per-slot ``(num_slots,)`` index vectors as the dense slot cache.
         The ``(num_slots, max_blocks_per_slot)`` block table itself is NOT
@@ -901,12 +901,12 @@ class ServeEngine:
 
     #: Paged-pool cache leaves the tiering swap path moves per block —
     #: leaf name -> block-axis offset from the END of the shape (pools
-    #: are (..., num_blocks, bs, H, hd), scale tables (..., num_blocks,
+    #: are (..., num_blocks, bs, H * hd), scale tables (..., num_blocks,
     #: bs)); counting from the end keeps the slice correct whether or
     #: not the scanned layer stack adds a leading dim.
     _POOL_BLOCK_AXES = {
-        "cached_key_pool": 4,
-        "cached_value_pool": 4,
+        "cached_key_pool": 3,
+        "cached_value_pool": 3,
         "key_scale": 2,
         "value_scale": 2,
     }

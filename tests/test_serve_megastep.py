@@ -12,11 +12,13 @@ against chunked prefill, the prefix cache, and hot weight reload at a
 megastep boundary.  EOS fired at an inner scan step j < K must trim on
 host to the exact K=1 output — no post-EOS token leaks."""
 
+import contextlib
 import time
 
 import numpy as np
 import pytest
 
+from distributed_tensorflow_tpu.models.gpt2 import GPT2Config
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
 
 
@@ -74,31 +76,45 @@ class TestMegastepParity:
     """Greedy output must be bit-identical K on vs off: the scan changes
     HOW MANY iterations one dispatch covers, never what any row decodes."""
 
-    @pytest.mark.parametrize("cache_mode", ["dense", "paged"])
-    def test_megastep_on_off_token_identical(self, gpt2_engine, cache_mode):
-        vocab = gpt2_engine.module.cfg.vocab_size
-        reqs = _mixed_requests(vocab)
+    @pytest.mark.parametrize(
+        "cache_mode", ["dense", "paged", "paged-unrolled", "paged-int8"])
+    def test_megastep_on_off_token_identical(self, gpt2_engine, mesh_dp,
+                                             cache_mode):
+        """``paged-unrolled`` holds a pool a layer (``scan_layers=False``)
+        where ``paged`` carries the stacked pools through the layer loop
+        inside the fused steps' loop; ``paged-int8`` stores quantised K/V,
+        so it is held to its own K=1 stream and not to the reference."""
         kwargs = dict(num_slots=8, max_total_len=32)
-        if cache_mode == "paged":
+        if cache_mode != "dense":
             kwargs.update(cache_mode="paged", block_size=4)
-        with ContinuousScheduler(gpt2_engine, **kwargs) as sched:
-            baseline = _run_all(sched, reqs)
-        # K=8 swallows every horizon whole; K=3 forces ragged chains
-        # (horizon 5 = one full scan + a 2-live-step tail).
-        for steps in (8, 3):
-            with ContinuousScheduler(gpt2_engine, megastep=steps,
-                                     **kwargs) as sched:
-                fused = _run_all(sched, reqs)
-                stats = sched.stats()
-                assert stats["megastep"] == float(steps)
-                # The amortization claim: strictly fewer launches than
-                # decoded tokens (K=1 pays one launch per token).
-                assert 0 < stats["megastep_launches"] \
-                    < stats["megastep_tokens"]
-            for (prompt, horizon), base, out in zip(reqs, baseline, fused):
-                np.testing.assert_array_equal(out, base)
-                np.testing.assert_array_equal(
-                    out, _fixed_reference(gpt2_engine, prompt, horizon))
+        if cache_mode == "paged-int8":
+            kwargs.update(kv_dtype="int8")
+        with (ServeEngine("gpt2", mesh=mesh_dp,
+                          config=GPT2Config.tiny(scan_layers=False))
+              if cache_mode == "paged-unrolled"
+              else contextlib.nullcontext(gpt2_engine)) as engine:
+            vocab = engine.module.cfg.vocab_size
+            reqs = _mixed_requests(vocab)
+            with ContinuousScheduler(engine, **kwargs) as sched:
+                baseline = _run_all(sched, reqs)
+            # K=8 swallows every horizon whole; K=3 forces ragged chains
+            # (horizon 5 = one full scan + a 2-live-step tail).
+            for steps in (8, 3):
+                with ContinuousScheduler(engine, megastep=steps,
+                                         **kwargs) as sched:
+                    fused = _run_all(sched, reqs)
+                    stats = sched.stats()
+                    assert stats["megastep"] == float(steps)
+                    # The amortization claim: strictly fewer launches than
+                    # decoded tokens (K=1 pays one launch per token).
+                    assert 0 < stats["megastep_launches"] \
+                        < stats["megastep_tokens"]
+                for (prompt, horizon), base, out in zip(
+                        reqs, baseline, fused):
+                    np.testing.assert_array_equal(out, base)
+                    if cache_mode != "paged-int8":
+                        np.testing.assert_array_equal(
+                            out, _fixed_reference(engine, prompt, horizon))
 
     @pytest.mark.parametrize("cache_mode", ["dense", "paged"])
     def test_parity_on_2d_mesh(self, mesh_2d, cache_mode):

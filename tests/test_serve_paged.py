@@ -287,6 +287,204 @@ class TestInt8KV:
 
 
 # ---------------------------------------------------------------------------
+# The pool's layout: lane-dense blocks, carried through the layer loop
+# ---------------------------------------------------------------------------
+
+def _unstacked(params, n_layer):
+    """Scanned ``blocks`` parameters as the unrolled ``h_i`` layout."""
+    out = {k: v for k, v in params.items() if k != "blocks"}
+    for i in range(n_layer):
+        out[f"h_{i}"] = jax.tree.map(lambda p: p[i], params["blocks"])
+    return out
+
+
+def _cache_shapes(model, num_slots, total_len, **kwargs):
+    return jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((num_slots, total_len), jnp.int32),
+        decode=True, slot_ids=jnp.arange(num_slots), **kwargs))["cache"]
+
+
+def _empty_cache(model, num_slots, total_len, **kwargs):
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                        _cache_shapes(model, num_slots, total_len, **kwargs))
+
+
+class TestPoolLayout:
+    NUM_SLOTS, TOTAL, BLOCK = 4, 16, 4
+
+    def _tables(self):
+        # slots 3 and 0 are served; their blocks interleave in the pool.
+        bt = np.zeros((self.NUM_SLOTS, self.TOTAL // self.BLOCK), np.int32)
+        bt[3] = [2, 5, 7, 1]
+        bt[0] = [4, 3, 8, 6]
+        return jnp.asarray(bt)
+
+    @pytest.mark.parametrize("scan_layers", [True, False],
+                             ids=["scan", "unrolled"])
+    def test_pool_leaves_are_lane_dense(self, scan_layers):
+        cfg = GPT2Config.tiny(scan_layers=scan_layers)
+        pcfg = PagedKVConfig(block_size=self.BLOCK, num_blocks=9,
+                             kv_dtype="int8")
+        cache = _cache_shapes(GPT2(cfg), self.NUM_SLOTS, self.TOTAL,
+                              paged=pcfg, block_tables=self._tables())
+        lead = (cfg.n_layer,) if scan_layers else ()
+        layer = cache["blocks"] if scan_layers else cache["h_0"]
+        for name in ("cached_key_pool", "cached_value_pool"):
+            assert layer[name].shape == lead + (9, self.BLOCK, cfg.d_model)
+            assert layer[name].dtype == jnp.int8
+        for name in ("key_scale", "value_scale"):
+            assert layer[name].shape == lead + (9, self.BLOCK)
+        assert layer["cache_index"].shape == lead + (self.NUM_SLOTS,)
+
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("scan_layers", [True, False],
+                             ids=["scan", "unrolled"])
+    def test_paged_follows_dense_slot_cache(self, scan_layers, kv_dtype):
+        """A prefill of two slots and four decode steps, the paged pool
+        against the dense slot cache on the same tokens: the same logits
+        and the same greedy token at every position (int8 within its
+        quantisation error), with the layer loop scanned (pool carried)
+        and unrolled (a pool a layer)."""
+        cfg = GPT2Config.tiny(scan_layers=scan_layers)
+        model = GPT2(cfg)
+        stacked = GPT2(GPT2Config.tiny()).init(
+            jax.random.key(0), jnp.zeros((2, 6), jnp.int32))["params"]
+        params = stacked if scan_layers else _unstacked(stacked, cfg.n_layer)
+        pcfg = PagedKVConfig(block_size=self.BLOCK, num_blocks=9,
+                             kv_dtype=kv_dtype)
+        tables = self._tables()
+        paged = {"paged": pcfg, "block_tables": tables}
+        dense_cache = _empty_cache(model, self.NUM_SLOTS, self.TOTAL)
+        paged_cache = _empty_cache(model, self.NUM_SLOTS, self.TOTAL, **paged)
+
+        def step(cache, tokens, slots, **kwargs):
+            logits, mutated = model.apply(
+                {"params": params, "cache": cache}, tokens, decode=True,
+                slot_ids=slots, mutable=["cache"], **kwargs)
+            return np.asarray(logits[:, -1], np.float32), mutated["cache"]
+
+        slots = jnp.asarray([3, 0])
+        tokens = jax.random.randint(jax.random.key(1), (2, 6), 0,
+                                    cfg.vocab_size)
+        atol = 0.05 if kv_dtype == "int8" else 0.0
+        for _ in range(5):
+            want, dense_cache = step(dense_cache, tokens, slots)
+            got, paged_cache = step(paged_cache, tokens, slots, **paged)
+            np.testing.assert_allclose(got, want, rtol=0.0, atol=atol)
+            if kv_dtype is None:
+                np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
+            tokens = jnp.asarray(want.argmax(-1))[:, None]   # teacher-forced
+        index = (paged_cache["blocks"]["cache_index"][0] if scan_layers
+                 else paged_cache["h_0"]["cache_index"])
+        np.testing.assert_array_equal(index, [10, 0, 0, 10])
+
+    @pytest.mark.parametrize("scan_layers", [True, False],
+                             ids=["scan", "unrolled"])
+    def test_scheduler_parity_scan_layers(self, mesh_dp, scan_layers):
+        """End to end through the scheduler and a fused decode: greedy
+        streams through the block tables match the fixed-batch dense
+        reference token for token with the layer stack scanned and
+        unrolled."""
+        with ServeEngine("gpt2", mesh=mesh_dp,
+                         config=GPT2Config.tiny(
+                             scan_layers=scan_layers)) as eng:
+            reqs = _mixed_requests(eng.module.cfg.vocab_size, n=10, seed=13)
+            with ContinuousScheduler(eng, num_slots=8, max_total_len=32,
+                                     cache_mode="paged", block_size=8,
+                                     megastep=3) as sched:
+                futs = [sched.submit(p, max_new_tokens=m) for p, m in reqs]
+                outs = [f.result(timeout=300) for f in futs]
+            for (prompt, horizon), out in zip(reqs, outs):
+                np.testing.assert_array_equal(
+                    out, _fixed_reference(eng, prompt, horizon))
+
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+    def test_block_swap_round_trip_is_byte_exact(self, gpt2_engine, kv_dtype):
+        """KV tiering's unit: one block gathered to the host and scattered
+        into another block arrives byte for byte (scales with it under
+        int8), and no other block changes."""
+        pcfg = PagedKVConfig(block_size=8, num_blocks=33, kv_dtype=kv_dtype)
+        cache = gpt2_engine.init_paged_cache(8, 32, paged=pcfg)
+        tables = np.zeros((8, 4), np.int32)
+        tables[2] = [5, 6, 0, 0]
+        prompt = np.arange(1, 13, dtype=np.int32)[None, :]
+        _, cache = gpt2_engine.prefill_into_slots(
+            cache, prompt, [2], paged=pcfg, block_tables=tables)
+        cfg = gpt2_engine.module.cfg
+        payload = gpt2_engine.gather_kv_block(cache, 5, paged=pcfg)
+        pools = [p for p in payload if p.ndim == 3]
+        assert len(payload) == (4 if kv_dtype == "int8" else 2)
+        assert [p.shape for p in pools] == [(cfg.n_layer, 8, cfg.d_model)] * 2
+        assert all(np.any(p != 0) for p in payload)
+        before = jax.device_get(cache)
+        cache = gpt2_engine.scatter_kv_block(cache, 9, payload, paged=pcfg)
+        for got, want in zip(
+                gpt2_engine.gather_kv_block(cache, 9, paged=pcfg), payload):
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(got, want)
+        after = jax.device_get(cache)
+        for name, leaf in after["blocks"].items():
+            old = before["blocks"][name]
+            if name == "cache_index":
+                np.testing.assert_array_equal(leaf, old)
+                continue
+            others = np.delete(np.arange(33), 9)
+            np.testing.assert_array_equal(leaf[:, others], old[:, others])
+
+    @pytest.mark.parametrize("per_shard", [False, True],
+                             ids=["tensor2", "tensor2-data_shards2"])
+    @pytest.mark.parametrize("scan_layers", [True, False],
+                             ids=["scan", "unrolled"])
+    def test_cache_rules_shard_the_same_bytes(self, devices8, scan_layers,
+                                              per_shard):
+        """On data=2 x tensor=2 every chip holds, of each pool, the heads
+        the (.., heads, head_dim) layout gave it (now a contiguous range of
+        columns), and under per-shard pools its data shard's blocks; the
+        scale tables follow the blocks."""
+        from distributed_tensorflow_tpu.cluster import MeshConfig, build_mesh
+        from distributed_tensorflow_tpu.models.gpt2 import gpt2_cache_rules
+
+        mesh = build_mesh(MeshConfig(data=2, tensor=2), devices8[:4])
+        cfg = GPT2Config.tiny(scan_layers=scan_layers)
+        pcfg = PagedKVConfig(block_size=4, num_blocks=10, kv_dtype="int8",
+                             data_shards=2 if per_shard else 1)
+        shapes = _cache_shapes(GPT2(cfg), 4, 16, paged=pcfg,
+                               block_tables=jnp.zeros((4, 4), jnp.int32))
+        shardings = gpt2_cache_rules(per_shard_pools=per_shard).shardings_for(
+            mesh, shapes)
+        layer = "blocks" if scan_layers else "h_0"
+        head_dim = cfg.d_model // cfg.n_head
+        whole = slice(None)
+        for data in range(2):
+            for tensor in range(2):
+                device = mesh.devices[tuple(
+                    {"data": data, "tensor": tensor}.get(axis, 0)
+                    for axis in mesh.axis_names)]
+                blocks = (slice(5 * data, 5 * data + 5) if per_shard
+                          else whole)
+                heads = slice(tensor * cfg.n_head // 2,
+                              (tensor + 1) * cfg.n_head // 2)
+                columns = slice(heads.start * head_dim, heads.stop * head_dim)
+                for name in ("cached_key_pool", "cached_value_pool"):
+                    leaf, sh = shapes[layer][name], shardings[layer][name]
+                    index = sh.devices_indices_map(leaf.shape)[device]
+                    assert _slices(index[-3:], leaf.shape[-3:]) == _slices(
+                        (blocks, whole, columns), leaf.shape[-3:])
+                    if scan_layers:
+                        assert _slices(index[:1], leaf.shape[:1]) == _slices(
+                            (whole,), leaf.shape[:1])
+                for name in ("key_scale", "value_scale"):
+                    leaf, sh = shapes[layer][name], shardings[layer][name]
+                    index = sh.devices_indices_map(leaf.shape)[device]
+                    assert _slices(index[-2:], leaf.shape[-2:]) == _slices(
+                        (blocks, whole), leaf.shape[-2:])
+
+
+def _slices(index, shape):
+    return [s.indices(n) for s, n in zip(index, shape)]
+
+
+# ---------------------------------------------------------------------------
 # Backpressure + admission-time rejection
 # ---------------------------------------------------------------------------
 

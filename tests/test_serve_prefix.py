@@ -259,6 +259,50 @@ class TestPrefixParity:
             np.testing.assert_array_equal(out, ref)
         assert s["prefix_hits"] == 6.0  # 3 mappable blocks x 2 hits
 
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+    def test_suffix_prefill_leaves_shared_blocks_byte_identical(
+            self, gpt2_engine, kv_dtype):
+        """What the cache rests on, at the engine: a suffix prefill from a
+        block-aligned offset scatters at positions >= the offset only, so
+        the blocks it shares stay byte for byte what the first request
+        wrote (every pool leaf, scales included), while its gather reads
+        them: its first token is the one a full prefill picks."""
+        import jax
+        from distributed_tensorflow_tpu.models.gpt2 import PagedKVConfig
+
+        pcfg = PagedKVConfig(block_size=4, num_blocks=17, kv_dtype=kv_dtype)
+        vocab = gpt2_engine.module.cfg.vocab_size
+        prompt = np.random.default_rng(8).integers(
+            0, vocab, size=(1, 11), dtype=np.int32)
+        tables = np.zeros((8, 4), np.int32)
+        tables[1] = [3, 4, 5, 0]        # the first request, whole prompt
+        tables[6] = [3, 4, 9, 0]        # shares blocks 3 and 4
+        tables[2] = [10, 11, 12, 0]     # control: the same prompt in full
+        cache = gpt2_engine.init_paged_cache(8, 16, paged=pcfg)
+        first, cache = gpt2_engine.prefill_into_slots(
+            cache, prompt, [1], paged=pcfg, block_tables=tables)
+        before = jax.device_get(cache)["blocks"]
+        suffix, cache = gpt2_engine.prefill_into_slots(
+            cache, prompt[:, 8:], [6], paged=pcfg, block_tables=tables,
+            start_offsets=[8])
+        full, cache = gpt2_engine.prefill_into_slots(
+            cache, prompt, [2], paged=pcfg, block_tables=tables)
+        after = jax.device_get(cache)["blocks"]
+        pools = [n for n in after if n != "cache_index"]
+        assert len(pools) == (4 if kv_dtype == "int8" else 2)
+        for name in pools:
+            for block in (3, 4, 5):     # shared prefix + the first's tail
+                np.testing.assert_array_equal(
+                    after[name][:, block], before[name][:, block])
+            assert np.any(after[name][:, 9] != before[name][:, 9])
+            # the suffix's own block holds what the full prefill wrote
+            np.testing.assert_array_equal(
+                after[name][:, 9, :3], after[name][:, 12, :3])
+        np.testing.assert_array_equal(np.asarray(suffix), np.asarray(full))
+        np.testing.assert_array_equal(np.asarray(first), np.asarray(full))
+        np.testing.assert_array_equal(after["cache_index"][:, [1, 6, 2]],
+                                      [[11, 11, 11]] * 2)
+
     def test_parity_under_tensor_parallel_mesh(self, mesh_2d):
         """Same oracle on data=4 x tensor=2: cached-block K/V is sharded
         over the tensor axis exactly like freshly-prefilled K/V."""

@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import math
 import time
 from typing import Any, Dict, List, Optional
 
@@ -15,7 +16,7 @@ import numpy as np
 from benchmark.harness import device as device_lib
 from benchmark.harness import program, traffic, weights
 from benchmark.harness.profile import traced
-from benchmark.harness.spans import Spans
+from benchmark.harness.spans import GcPauses, HostStalls, Spans, XlaCompiles
 from benchmark.harness.stats import percentile
 from benchmark.reference import precision as ref_precision
 
@@ -141,9 +142,14 @@ def reference_gaps(cell, seed, abstract_params, prompts, picks,
     """For each request, at every answered position, how far the picked
     token's logit lies below the reference's best: one array a request.
     The reference runs once over prompt + picked tokens, several requests
-    a forward, all padded to the slot length.  With ``pick_own`` it returns
-    instead the tokens this arithmetic itself puts first at those positions
-    (the control: a lower precision in the reference's place)."""
+    a forward.  Each request is padded to the shortest of the cell's
+    ``reference_padded_lengths`` that holds it (the slot length alone
+    where the cell gives none), and a forward of shorter rows takes as
+    many more of them, so every forward holds the same number of
+    positions and the reference compiles one program a length.  With
+    ``pick_own`` it returns instead the tokens this arithmetic itself puts
+    first at those positions (the control: a lower precision in the
+    reference's place)."""
     import jax.numpy as jnp
 
     ref = program.reference_module(cell.config)
@@ -151,8 +157,14 @@ def reference_gaps(cell, seed, abstract_params, prompts, picks,
     f32 = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32), abstract_params)
     params = weights.make_params(seed, f32)
-    length = int(cell.cell["scheduler"]["max_total_len"])
-    rows = int(cell.cell["correct"]["reference_rows_per_forward"])
+    longest = int(cell.cell["scheduler"]["max_total_len"])
+    positions = longest * int(
+        cell.cell["correct"]["reference_rows_per_forward"])
+    lengths = sorted(int(n) for n in cell.cell["correct"].get(
+        "reference_padded_lengths", [longest]))
+    if lengths[-1] != longest:
+        raise ValueError(f"reference_padded_lengths {lengths} must end at "
+                         f"the slot length {longest}")
 
     @jax.jit
     def forward(p, tokens, picked):
@@ -162,21 +174,28 @@ def reference_gaps(cell, seed, abstract_params, prompts, picks,
         at = jnp.take_along_axis(logits, picked[..., None], axis=-1)[..., 0]
         return jnp.max(logits, axis=-1) - at
 
-    out = []
-    for start in range(0, len(prompts), rows):
-        tokens = np.zeros((rows, length), np.int32)
-        picked = np.zeros((rows, length), np.int32)
-        spans = []
-        for i, (prompt, pick) in enumerate(zip(
-                prompts[start:start + rows], picks[start:start + rows])):
-            seq = np.concatenate([prompt, pick])[:-1]
-            tokens[i, :len(seq)] = seq
-            first = len(prompt) - 1
-            picked[i, first:first + len(pick)] = pick
-            spans.append((first, first + len(pick)))
-        result = np.asarray(forward(params, jnp.asarray(tokens),
-                                    jnp.asarray(picked)))
-        out.extend(result[i, lo:hi] for i, (lo, hi) in enumerate(spans))
+    by_length = {}
+    for i, (prompt, pick) in enumerate(zip(prompts, picks)):
+        needed = len(prompt) + len(pick) - 1
+        by_length.setdefault(
+            next(n for n in lengths if n >= needed), []).append(i)
+    out = [None] * len(prompts)
+    for length, members in sorted(by_length.items()):
+        rows = positions // length
+        for start in range(0, len(members), rows):
+            tokens = np.zeros((rows, length), np.int32)
+            picked = np.zeros((rows, length), np.int32)
+            spans = []
+            for row, i in enumerate(members[start:start + rows]):
+                seq = np.concatenate([prompts[i], picks[i]])[:-1]
+                tokens[row, :len(seq)] = seq
+                first = len(prompts[i]) - 1
+                picked[row, first:first + len(picks[i])] = picks[i]
+                spans.append((i, first, first + len(picks[i])))
+            result = np.asarray(forward(params, jnp.asarray(tokens),
+                                        jnp.asarray(picked)))
+            for row, (i, lo, hi) in enumerate(spans):
+                out[i] = result[row, lo:hi]
     return out
 
 
@@ -198,14 +217,23 @@ def run(cell, *, seed: int, seconds: float, trace: bool, devices, peaks,
         cell.traffic, seed, seconds) if r.due_s < window]
     lead_in = float(cell.traffic["lead_in_s"])
     lead = [r for r in requests if r.due_s < 0]
+    # Set-up's garbage (the engine's traces, the compiler's leftovers, the
+    # requests just drawn) is collected and the survivors frozen here, as
+    # the training driver does: the load generator shares the server's
+    # interpreter, and a full collection of that heap stops both for a
+    # quarter of a second (tools/silence_hunt.py).  The collector stays on.
+    gc.collect()
+    gc.freeze()
+    xla = XlaCompiles().start()
     served = offer(lead, sched, spans, time.monotonic() + lead_in)
     mark("lead_in_offered")
     spans.clear()
 
-    with traced(trace, cell, spans) as profile:
+    stalls, pauses = HostStalls(), GcPauses()
+    with traced(trace, cell, spans) as profile, stalls, pauses:
         stats_start = sched.stats()
         setup_s = time.perf_counter() - started
-        t0 = time.monotonic()
+        t0, t0_perf = time.monotonic(), time.perf_counter()
         in_window = offer([r for r in requests if r.due_s >= 0],
                           sched, spans, t0)
         with spans.span("wait_request"):
@@ -219,6 +247,8 @@ def run(cell, *, seed: int, seconds: float, trace: bool, devices, peaks,
     answered = [r for r in served if not r.withdrawn]
     drain(answered, spans, t_end + float(cell.cell["drain_seconds"]))
     drained_t = time.monotonic()
+    xla.stop()
+    gc.unfreeze()
     compiles = engine.compile_stats()["compile_total"] - compiles_warm
     memory = device_lib.memory_peak(devices)
     say("memory", **memory)
@@ -227,14 +257,26 @@ def run(cell, *, seed: int, seconds: float, trace: bool, devices, peaks,
     timed = [r for r in in_window if not r.withdrawn]
     ttft = [r.ttft_s(drained_t) for r in timed]
     tpot = [r.tpot_s for r in timed if r.ok and r.tpot_s is not None]
-    delivered = sum(n for r in served for t, n in r.batches
-                    if t0 <= t <= t_end)
+    # Where in the window the tokens fell: a second in which the host was
+    # taken from the process, or the device waited, shows as a dip.
+    by_second = [0] * max(1, math.ceil(window))
+    for r in served:
+        for t, n in r.batches:
+            if t0 <= t <= t_end:
+                by_second[min(int(t - t0), len(by_second) - 1)] += n
+    delivered = sum(by_second)
     say("window", offered=len(served), lead_in_requests=len(lead),
         answered=len(answered), failed=failed, unfinished_at_close=unfinished,
         withdrawn_at_close=withdrawn, window_s=window,
         queue_depth_at_open=stats_start.get("queue_depth"),
         queue_depth_at_close=stats_end.get("queue_depth"),
         drain_s=drained_t - t_end, tokens_in_window=delivered,
+        tokens_by_second=by_second, host_stall_s_longest=stalls.longest,
+        host_stall_ended_at_s=(stalls.longest_ended or 0.0) - t0_perf,
+        host_stall_s_total=stalls.total, gc_pause_s=pauses.seconds,
+        gc_pause_s_longest=pauses.longest, gc_collections=pauses.count,
+        # (seconds after the window opened, seconds it took), lead-in too
+        xla_compiles_after_warmup=[[t - t0_perf, s] for t, s in xla.ended],
         compile_post_warmup=int(compiles), setup_s=setup_s,
         ttft_samples=len(ttft), tpot_samples=len(tpot),
         ttft_p50_ms=1e3 * percentile(ttft, 50) if ttft else None,

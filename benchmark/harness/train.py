@@ -5,7 +5,6 @@ from __future__ import annotations
 
 import gc
 import math
-import threading
 import time
 from typing import Any, Dict
 
@@ -16,7 +15,7 @@ from benchmark.harness import device as device_lib
 from benchmark.harness import flops, program, traffic, weights
 from benchmark.harness import train_reference as tr
 from benchmark.harness.profile import traced
-from benchmark.harness.spans import Spans
+from benchmark.harness.spans import GcPauses, HostStalls, Spans
 from benchmark.reference import precision as ref_precision
 
 
@@ -206,61 +205,6 @@ def reference_side(cell, seed, abstract_params, devices, dot_name="exact"):
         int(correct["reference_rows_per_block"]), devices)
 
 
-class _HostStalls:
-    """How late the host wakes a thread that asks for ``tick`` seconds of
-    sleep, while it is open: the longest such delay and the time of all
-    over 50 ms.  A host that is taken away from the process (its cores are
-    shared on a one-chip machine), or a call that keeps the interpreter's
-    lock, shows here whether or not the device had to wait for it."""
-
-    def __init__(self, tick=0.02):
-        self.tick, self.longest, self.total = tick, 0.0, 0.0
-        self.longest_ended = None       # perf_counter when it woke
-        self._stop = threading.Event()
-        self._thread = threading.Thread(target=self._watch, daemon=True)
-
-    def _watch(self):
-        last = time.perf_counter()
-        while not self._stop.wait(self.tick):
-            now = time.perf_counter()
-            late = now - last - self.tick
-            if late > self.longest:
-                self.longest, self.longest_ended = late, now
-            if late > 0.05:
-                self.total += late
-            last = now
-
-    def __enter__(self):
-        self._thread.start()
-        return self
-
-    def __exit__(self, *exc):
-        self._stop.set()
-        self._thread.join()
-
-
-class _GcPauses:
-    """Time the garbage collector takes from the host while it is open."""
-
-    def __init__(self):
-        self.seconds, self.count, self._start = 0.0, 0, None
-
-    def _watch(self, phase, info):
-        if phase == "start":
-            self._start = time.perf_counter()
-        elif self._start is not None:
-            self.seconds += time.perf_counter() - self._start
-            self.count += 1
-            self._start = None
-
-    def __enter__(self):
-        gc.callbacks.append(self._watch)
-        return self
-
-    def __exit__(self, *exc):
-        gc.callbacks.remove(self._watch)
-
-
 def run(cell, *, seed: int, seconds: float, trace: bool, devices, peaks,
         started: float, say) -> Dict[str, Any]:
     spans = Spans()
@@ -279,7 +223,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, devices, peaks,
     # of that heap falls into the window; the collector stays on.
     gc.collect()
     gc.freeze()
-    pauses, stalls = _GcPauses(), _HostStalls()
+    pauses, stalls = GcPauses(), HostStalls()
     # Set-up read every step's loss; the window fetches one loss in
     # ``metrics_every`` steps, an interval late, as the program's own runs
     # do, so the host runs that many steps ahead of the device and a host

@@ -18,6 +18,7 @@ import dataclasses
 import functools
 import glob
 import gzip
+import heapq
 import os
 import re
 from typing import Dict, Iterable, List, Sequence, Tuple
@@ -186,22 +187,34 @@ def idle_gaps(lines: Dict[str, List[Event]], window: Interval) -> List[Interval]
 def attribute_gaps(gaps: Sequence[Interval], host: Sequence[Event],
                    prefix: str = "bench/") -> Dict[str, float]:
     """Idle seconds by what the host was doing: each gap goes, piece by
-    piece, to the innermost benchmark span that covers the piece."""
-    spans = sorted((e for e in host if e.name.startswith(prefix)),
-                   key=lambda e: e.seconds)             # innermost first
+    piece, to the innermost (shortest) span that covers the piece.  One
+    sweep over the edges of gaps and spans together: a saturated server's
+    trace has a gap between every two instructions and thousands of
+    spans, and gap by span took minutes."""
+    spans = [e for e in host if e.name.startswith(prefix)]
+    edges = [(s.end, 0, i) for i, s in enumerate(spans)]
+    edges += [(s.start, 1, i) for i, s in enumerate(spans)]
+    edges += [(b, 2, -1) for a, b in gaps if b > a]
+    edges += [(a, 3, -1) for a, b in gaps if b > a]
+    edges.sort()
     out: Dict[str, float] = {}
-    for gap in gaps:
-        left = [gap]
-        for span in spans:
-            covered = clip(left, (span.start, span.end))
-            if covered:
-                key = span.name[len(prefix):]
-                out[key] = out.get(key, 0.0) + total(covered)
-                left = subtract(left, union(covered))
-            if not left:
-                break
-        if left:
-            out["unattributed"] = out.get("unattributed", 0.0) + total(left)
+    open_spans: List[Tuple[float, int]] = []    # heap: (length, span)
+    closed = set()
+    in_gap, before = False, 0.0
+    for at, kind, i in edges:
+        if in_gap and at > before:
+            while open_spans and open_spans[0][1] in closed:
+                heapq.heappop(open_spans)
+            key = (spans[open_spans[0][1]].name[len(prefix):]
+                   if open_spans else "unattributed")
+            out[key] = out.get(key, 0.0) + at - before
+        before = at
+        if kind == 1:
+            heapq.heappush(open_spans, (spans[i].seconds, i))
+        elif kind == 0:
+            closed.add(i)
+        else:
+            in_gap = kind == 3
     return out
 
 

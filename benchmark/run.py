@@ -8,7 +8,9 @@ seed, warms up the cell's own shapes (set-up), measures for ``--seconds``,
 checks what the timed path produced against the plain reference, prints a
 line per thing it looked at, and as the LAST line of stdout one JSON object:
 ``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` (and
-``breakdown`` with ``--trace 1``).  ``--trace 0`` reports the end-to-end
+``breakdown`` with ``--trace 1``), and last in it ``compared``: each number
+the comparison held against a limit, with that limit.  The same numbers are
+the last lines of stderr.  ``--trace 0`` reports the end-to-end
 metrics; ``--trace 1`` runs a shorter window under the profiler and reports
 the per-layer metrics.  Without an accelerator, with fewer chips than the
 cell asks for, or on a device kind that ``peaks.json`` lacks, it exits
@@ -21,6 +23,7 @@ _STARTED = time.perf_counter()      # set-up is counted from process start
 
 import argparse    # noqa: E402
 import json        # noqa: E402
+import math        # noqa: E402
 import os          # noqa: E402
 import sys         # noqa: E402
 
@@ -64,9 +67,19 @@ def main(argv=None):
         trace=args.trace, device=device_lib.describe(devices),
         compile_cache=device_lib.place_compile_cache())
 
+    compared = {}
+
+    def say_and_keep(event, **fields):
+        if event == "compared":
+            value = fields["value"]     # no Infinity in the result's JSON
+            compared[fields["number"]] = {
+                "value": value if math.isfinite(value) else repr(value),
+                "limit": fields["limit"]}
+        say(event, **fields)
+
     result = DRIVERS[cell.cell["kind"]](
         cell, seed=args.seed, seconds=args.seconds, trace=bool(args.trace),
-        devices=devices, peaks=peaks, started=_STARTED, say=say)
+        devices=devices, peaks=peaks, started=_STARTED, say=say_and_keep)
 
     # memory_peak_bytes is the sum of the two numbers beside it, see
     # harness/device.py: buffers as the runtime read them, scratch as the
@@ -90,6 +103,10 @@ def main(argv=None):
             name: {"value": result["end_to_end"][name], "unit": unit}
             for name, unit in units.items()}
         line["device"] = device_lib.describe(devices, **extra)
+    line["compared"] = compared
+    for number, row in compared.items():
+        print(f"compared {number} {row['value']!r} limit {row['limit']!r}",
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

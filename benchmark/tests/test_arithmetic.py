@@ -128,6 +128,36 @@ def test_idle_gaps_go_to_the_innermost_host_span():
                                  "window": 1.0})
 
 
+def test_every_piece_of_a_gap_goes_to_the_shortest_span_over_it():
+    """The sweep against the definition, point by point on a fine grid,
+    over spans that nest, overlap across threads and leave holes."""
+    import random
+
+    rng = random.Random(5)
+    for _ in range(30):
+        host = [xplane.Event("other", 0.0, 10.0)]
+        for _ in range(rng.randint(0, 10)):
+            start = rng.randint(0, 90) / 10
+            host.append(xplane.Event("bench/" + rng.choice("abc"), start,
+                                     start + rng.randint(1, 60) / 10))
+        edges = sorted(rng.sample(range(0, 100), 2 * rng.randint(0, 6)))
+        gaps = [(a / 10, b / 10) for a, b in zip(edges[::2], edges[1::2])]
+        want = {}
+        for a, b in gaps:
+            for tick in range(round(a * 10), round(b * 10)):
+                at = (tick + 0.5) / 10
+                over = [e for e in host[1:] if e.start <= at <= e.end]
+                key = (min(over, key=lambda e: e.seconds).name[6:]
+                       if over else "unattributed")
+                want[key] = want.get(key, 0.0) + 0.1
+        got = xplane.attribute_gaps(gaps, host)
+        assert set(got) == set(want)
+        # spans of one length over one piece: either may have it
+        if len({e.seconds for e in host[1:]}) == len(host) - 1:
+            assert got == pytest.approx(want)
+        assert sum(got.values()) == pytest.approx(sum(want.values()))
+
+
 def test_exposed_collective_time_is_what_compute_does_not_cover():
     op = lambda name, code, a, b: xplane.Event(
         f"%{name} = f32[8]{{0}} {code}(f32[8]{{0}} %x)", a, b)

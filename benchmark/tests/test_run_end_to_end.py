@@ -127,6 +127,10 @@ def test_a_sound_serving_run_is_correct():
     assert result["correct"], _compared(lines)
     window = next(l for l in lines if l["event"] == "window")
     assert window["offered"] == 20 and window["lead_in_requests"] >= 1
+    # Where in the window the tokens fell, and what stopped the host.
+    assert sum(window["tokens_by_second"]) == window["tokens_in_window"]
+    assert window["gc_pause_s_longest"] >= 0 <= window["host_stall_s_longest"]
+    assert isinstance(window["xla_compiles_after_warmup"], list)
     assert result["attempted"] == 20 - window["withdrawn_at_close"]
     assert result["failed"] == 0
     for name in ("serve_tokens_per_s", "setup_s"):
@@ -141,7 +145,7 @@ def test_requests_without_a_token_at_the_close_are_withdrawn_not_failed():
     """Far above capacity the queue is long at the close: what the client
     withdraws is neither attempted nor failed, and the rest is correct."""
     cell = _cell("serve.gpt2-tiny")
-    cell.traffic["arrivals"]["rate_per_s"] = 200
+    cell.traffic["arrivals"]["rate_per_s"] = 1000
     lines = []
     result = DRIVERS["serve"](
         cell, seed=5, seconds=1.0, trace=False, devices=jax.devices()[:1],
@@ -189,3 +193,37 @@ def test_the_float8_control_fails_the_serving_limit():
                                    "fp8", pick_own=True)
         control = worst(serve.reference_gaps(cell, seed, abstract, prompts, low))
         assert sound <= limit < control, (seed, sound, limit, control)
+
+
+def test_the_result_line_ends_with_each_number_compared_beside_its_limit(
+        monkeypatch, capsys):
+    """``run.py`` itself, past its look for a chip: the numbers compared
+    come last in the result line and are the last lines of stderr."""
+    import importlib.util
+    import json
+
+    path = os.path.join(spec.BENCH_DIR, "run.py")
+    module_spec = importlib.util.spec_from_file_location("bench_run", path)
+    run = importlib.util.module_from_spec(module_spec)
+    module_spec.loader.exec_module(run)
+    monkeypatch.setattr(device, "require_chips",
+                        lambda chips: jax.devices()[:chips])
+    monkeypatch.setattr(device, "load_peaks", lambda kind: {})
+    monkeypatch.setattr(device, "place_compile_cache", lambda: "off")
+    cell = _cell("serve.gpt2-tiny")
+    monkeypatch.setattr(spec, "load_cell", lambda name: cell)
+    assert run.main(["--workload", "serve.gpt2-tiny", "--seed", "7",
+                     "--seconds", "1.5"]) == 0
+    out, err = capsys.readouterr()
+    line = json.loads(out.splitlines()[-1])
+    assert list(line)[-1] == "compared" and line["correct"]
+    assert set(line["compared"]) == {
+        "served_logit_gap_max", "compile_post_warmup",
+        "requests_not_answered_in_full"}
+    limit = cell.cell["correct"]["limits"]
+    gap = line["compared"]["served_logit_gap_max"]
+    assert gap["limit"] == limit["served_logit_gap_max"] >= gap["value"] >= 0
+    last = err.splitlines()[-len(line["compared"]):]
+    assert [l.split()[:2] for l in last] == [
+        ["compared", number] for number in line["compared"]]
+    assert all(l.split()[3] == "limit" for l in last)

@@ -1,7 +1,8 @@
 """Runs a cell's sets the way the driver's check does: every run a new
 process of ``benchmark/run.py``, the same seeds in each set, and per metric
 the spread of each set (distance between the first and third quartile, as
-``statistics.quantiles(values, n=4)`` gives them, over the median).  This
+``statistics.quantiles(values, n=4)`` gives them, over the median), of the
+whole set and of the set less its farthest run.  This
 parent never touches JAX, so each child gets the chip.  Result lines go to
 ``chiprun_out/sets/<cell>.jsonl``.
 
@@ -44,6 +45,14 @@ def spread(values):
     return (q3 - q1) / statistics.median(values)
 
 
+def without_farthest(values):
+    """The set less the run farthest from its median, which the driver's
+    check leaves out when it asks whether a bound is too tight."""
+    mid = statistics.median(values)
+    far = max(range(len(values)), key=lambda i: abs(values[i] - mid))
+    return [v for i, v in enumerate(values) if i != far]
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -83,10 +92,15 @@ def main(argv=None):
     for name in sets[0][0]["metrics"]:
         per_set = [[r["metrics"][name]["value"] for r in rows] for rows in sets]
         if len(seeds) >= 2:
+            kept = [without_farthest(v) for v in per_set]
             print(json.dumps({
                 "metric": name,
                 "medians": [statistics.median(v) for v in per_set],
                 "spreads": [spread(v) for v in per_set],
+                "spreads_without_farthest": [
+                    spread(v) if len(v) >= 2 else None for v in kept],
+                "ranges_without_farthest": [
+                    (max(v) - min(v)) / statistics.median(v) for v in kept],
                 "first_runs": [v[0] for v in per_set]}), flush=True)
 
 

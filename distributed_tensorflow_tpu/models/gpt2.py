@@ -35,7 +35,7 @@ from flax import linen as nn
 from jax.sharding import Mesh
 
 from distributed_tensorflow_tpu.data.pipeline import synthetic_lm
-from distributed_tensorflow_tpu.ops import flash_attention
+from distributed_tensorflow_tpu.ops import flash_attention, paged_attention
 from distributed_tensorflow_tpu.parallel.ring_attention import ring_attention
 from distributed_tensorflow_tpu.models import Workload
 from distributed_tensorflow_tpu.parallel.sharding import (
@@ -286,7 +286,8 @@ class Block(nn.Module):
     paged: Optional[PagedKVConfig] = None  # block-table cache (serve path)
 
     @nn.compact
-    def __call__(self, x, slot_ids=None, block_tables=None, layer=None):
+    def __call__(self, x, slot_ids=None, block_tables=None, live=None,
+                 layer=None):
         cfg = self.cfg
         deterministic = self.deterministic
         d, h = cfg.d_model, cfg.n_head
@@ -303,7 +304,8 @@ class Block(nn.Module):
             # Paged serve path: K/V in a fixed pool of blocks, each slot's
             # logical positions routed through its block-table row.
             ctx = self._paged_cached_attention(
-                q, k, v, slot_ids, block_tables, layer).reshape(B, T, d)
+                q, k, v, slot_ids, block_tables, live, layer,
+            ).reshape(B, T, d)
         elif self.decode:
             # Serve path: exact attention over the preallocated KV cache.
             # Takes precedence over ring/flash — both are training-shape
@@ -422,7 +424,7 @@ class Block(nn.Module):
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)
 
     def _paged_cached_attention(self, q, k, v, slot_ids, block_tables,
-                                layer=None):
+                                live=None, layer=None):
         """Exact attention over the block-table KV pool.
 
         K/V storage is a ``(num_blocks, block_size, H * hd)`` pool (heads
@@ -431,34 +433,58 @@ class Block(nn.Module):
         ``block_tables[s, p // block_size]``, offset ``p % block_size``.
         Each call scatters its new K/V into the owning blocks (one write
         per (row, token) — offsets are unique within a call because slot
-        ids are), then gathers the slot's whole table row back into a
-        contiguous ``(B, max_blocks * block_size, H, hd)`` view for the
-        same masked softmax as the dense slot path.  Unallocated table
-        entries point at trash block 0, whose (finite garbage) contents
-        sit past each row's ``cache_index`` and are causally masked.
+        ids are), then attends over each row's first ``cache_index + T``
+        positions.  Unallocated table entries point at trash block 0,
+        whose (finite garbage) contents sit past each row's
+        ``cache_index``.
 
         ``layer`` is the scanned stack's layer index: the cache variables
         are then the WHOLE ``(layers, ...)`` stacks, carried through the
         layer loop, and this layer scatters at ``[layer, block, offset]``
-        and gathers ``pool[layer, table rows]`` — the pool is updated in
-        place and never sliced or re-stacked per layer.  ``None`` (the
+        and reads ``pool[layer]`` through the table — the pool is updated
+        in place and never sliced or re-stacked per layer.  ``None`` (the
         unrolled ``h_i`` layout, and init) means the variables are this
         layer's own.
 
+        One algorithm, two implementations of the attention that follows
+        the scatter, chosen by what the call can observe
+        (``ops.paged_attention.supported``), never by a flag:
+
+        - **The kernel** (``ops.paged_attention.paged_decode_attention``)
+          for a decode step (``T == 1``) over a pool stored in the compute
+          type, on one TPU device (or under ``DTT_PALLAS_INTERPRET=1``): it
+          walks the table, fetches only the blocks a row's length covers
+          straight from the pool, and folds them into an online softmax
+          with f32 scores.  ``live`` (``(B,)`` bool, the caller's mask of
+          rows whose step counts) lets it skip dead and inactive rows
+          altogether: they fetch nothing and attend to nothing.  Its sums
+          run in another order than the gather path's, so a greedy token
+          may differ from that path's where two logits tie within bf16
+          rounding.
+        - **The gather path**, for everything else (prefill and verify
+          shapes, int8 and cast-on-write pools, sharded pools, the CPU) and
+          as the reference the kernel is tested against: it gathers the
+          slot's whole table row back into a contiguous ``(B, max_blocks *
+          block_size, H, hd)`` view for the same masked softmax as the
+          dense slot path; trash entries are causally masked.  When the
+          storage dtype equals the compute dtype and ``max_blocks *
+          block_size == max_total_len``, its post-gather math is
+          shape-identical to the dense slot path — greedy streams match it
+          token for token.  ``live`` changes nothing here.
+
         With ``kv_dtype="int8"`` the pool stores per-token symmetrically
         quantized values plus ``(num_blocks, block_size)`` f32 scale
-        tables, dequantized here in the gather; any other ``kv_dtype``
-        is a plain cast on write.  When the storage dtype equals the
-        compute dtype and ``max_blocks * block_size == max_total_len``,
-        the post-gather math is shape-identical to the dense slot path —
-        greedy streams match it token for token.
+        tables, dequantized in the gather; any other ``kv_dtype`` is a
+        plain cast on write.
 
         Prefix caching rides on this unchanged: a suffix prefill arrives
         with ``cache_index`` preset to the block-aligned start, so the
         scatter only writes positions ``>= start`` (shared prefix blocks
         are never touched) while the gather still pulls the slot's WHOLE
         table row — the mapped cached blocks below ``start`` — and the
-        ``k_pos <= q_pos`` causal mask admits them for every query.
+        ``k_pos <= q_pos`` causal mask admits them for every query; a
+        decode step after it reaches the shared blocks through the table
+        like any other.
         """
         cfg, pg = self.cfg, self.paged
         B, T, h, head_dim = q.shape
@@ -504,6 +530,17 @@ class Block(nn.Module):
                 v.astype(store_dtype).reshape(B * T, d))
         ci.value = ci.value.at[at(slot_ids)].set(idx + T)
 
+        if paged_attention.supported(
+                query_len=T, block_size=bs, width=d, pool_dtype=store_dtype,
+                compute_dtype=cfg.dtype, mesh=self.mesh,
+                data_shards=pg.data_shards):
+            paged_attention.note_path(paged_attention.KERNEL)
+            lengths = idx + T
+            if live is not None:
+                lengths = jnp.where(live, lengths, 0)
+            return paged_attention.paged_decode_attention(
+                q, kp.value, vp.value, rows_bt, lengths, layer=layer)
+        paged_attention.note_path(paged_attention.GATHER)
         rows = at(rows_bt)
         gk = kp.value[rows]                   # (B, max_blk, bs, H * hd)
         gv = vp.value[rows]
@@ -536,7 +573,7 @@ class GPT2(nn.Module):
     def __call__(self, tokens, *, deterministic: bool = True,
                  return_hidden: bool = False, decode: bool = False,
                  slot_ids=None, paged: Optional[PagedKVConfig] = None,
-                 block_tables=None):
+                 block_tables=None, live=None):
         cfg = self.cfg
         B, T = tokens.shape
         if slot_ids is not None and not decode:
@@ -553,6 +590,10 @@ class GPT2(nn.Module):
                     "max_blocks_per_slot) int32 logical->physical block map")
         elif block_tables is not None:
             raise ValueError("block_tables only applies with paged=...")
+        if live is not None and paged is None:
+            raise ValueError(
+                "live (the mask of rows whose step counts) only applies "
+                "with paged=...")
         wte = self.param(
             "wte",
             nn.initializers.normal(0.02),
@@ -622,13 +663,13 @@ class GPT2(nn.Module):
             if paged is not None and not self.is_initializing():
                 cache_vars = dict(
                     variable_axes={"params": 0}, variable_carry="cache",
-                    in_axes=(nn.broadcast, nn.broadcast, 0))
-                layers = (jnp.arange(cfg.n_layer, dtype=jnp.int32),)
+                    in_axes=(nn.broadcast, nn.broadcast, nn.broadcast, 0))
+                more = (live, jnp.arange(cfg.n_layer, dtype=jnp.int32))
             else:
                 cache_vars = dict(
                     variable_axes={"params": 0, "cache": 0},
                     in_axes=nn.broadcast)
-                layers = ()
+                more = ()
             Scanned = nn.scan(
                 body,
                 split_rngs={"params": True, "dropout": True},
@@ -639,13 +680,13 @@ class GPT2(nn.Module):
             x, _ = Scanned(
                 cfg, mesh=self.mesh, deterministic=deterministic,
                 decode=decode, paged=paged, name="blocks",
-            )(x, slot_ids, block_tables, *layers)
+            )(x, slot_ids, block_tables, *more)
         else:
             for i in range(cfg.n_layer):
                 x, _ = Block(
                     cfg, mesh=self.mesh, deterministic=deterministic,
                     decode=decode, paged=paged, name=f"h_{i}",
-                )(x, slot_ids, block_tables)
+                )(x, slot_ids, block_tables, live)
         x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
         if return_hidden:
             # Chunked-CE path: the loss computes logits per T-chunk itself

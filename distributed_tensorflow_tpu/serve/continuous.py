@@ -1234,6 +1234,8 @@ class ContinuousScheduler:
         # locked obs counters only, and runs BEFORE the scheduler lock so
         # no lock-order edge forms against the launch paths.
         compile_stats = self.engine.compile_stats()
+        attention = self.engine.decode_attention_launches()
+        attention_launches = sum(attention.values())
         # Host-KV-tier telemetry: the pool has its own lock, read it
         # before the scheduler lock (same no-lock-order-edge discipline
         # as compile_stats).  Zeros when tiering is off so dashboards,
@@ -1367,6 +1369,12 @@ class ContinuousScheduler:
                 "sampling_configs_active": float(sampling_configs),
                 "programs_cached": compile_stats["programs_cached"],
                 "compile_total": compile_stats["compile_total"],
+                # How often the block-table attention kernel engages: the
+                # share of paged decode launches whose program was traced
+                # with it (0 with no such launch yet, and on the CPU).
+                "decode_attention_kernel_share": (
+                    attention["kernel"] / attention_launches
+                    if attention_launches else 0.0),
                 # SLO scheduling: preempt/resume traffic, parked
                 # requests, host-KV-tier bytes, and TTFT-deadline
                 # goodput (fraction of deadline-carrying completions

@@ -38,6 +38,7 @@ from distributed_tensorflow_tpu import cluster as cluster_lib
 from distributed_tensorflow_tpu.checkpoint import CheckpointManager
 from distributed_tensorflow_tpu.models import Workload, get_workload
 from distributed_tensorflow_tpu.obs import metrics as obs_metrics
+from distributed_tensorflow_tpu.ops import paged_attention
 from distributed_tensorflow_tpu.parallel.sharding import (
     apply_shardings,
     batch_sharding,
@@ -115,6 +116,12 @@ def _engine_instruments(registry=None):
             "dtt_serve_verify_seconds",
             "Host-side speculative-verify dispatch duration "
             "(one (num_slots, k+1) forward)"),
+        "decode_attention": r.counter(
+            "dtt_serve_decode_attention_launches_total",
+            "Paged decode launches (decode_slots, decode_megastep) by the "
+            "attention path the launched program was traced with: the "
+            "block-table kernel, or the whole-row gather",
+            labelnames=("path",)),
     }
 
 
@@ -311,6 +318,8 @@ class ServeEngine:
         # the donated-position story uniform within each cache.
         self._block_fns: Dict[Any, Callable] = {}
         self._cache_init_fns: Dict[Any, Callable] = {}
+        # Program-cache key -> {attention path: times traced with it}.
+        self._attention_paths: Dict[Any, Dict[str, int]] = {}
         self._obs = _engine_instruments()
         self.restored_step: Optional[int] = None
         # Base sampling key (in-step RNG: folded with a step counter inside
@@ -428,6 +437,51 @@ class ServeEngine:
             "programs_cached": self._obs["programs_cached"].value,
             "compile_total": self._obs["compile_total"].value,
         }
+
+    def _recording_paths(self, key, fn: Callable) -> Callable:
+        """``fn``, a program body that runs the model over the slot cache,
+        putting on record what its paged attention chose
+        (``ops.paged_attention``: the block-table kernel or the whole-row
+        gather) under the program's cache key.  The body runs only while
+        ``jax.jit`` traces it, and the choice is made there from what the
+        call can observe, so this record is the only place it shows."""
+
+        @functools.wraps(fn)
+        def body(*args):
+            with paged_attention.record_paths() as paths:
+                out = fn(*args)
+            if paths:
+                path = paged_attention.one_path(paths)
+                traced = self._attention_paths.setdefault(key, {})
+                traced[path] = traced.get(path, 0) + 1
+            return out
+
+        return body
+
+    def attention_paths(self) -> Dict[str, Dict[str, int]]:
+        """Per program kind (``slot_prefill``, ``slot_decode``,
+        ``slot_megastep``, ``slot_verify``...: the kinds ``compile_stats``
+        counts), how many times a program of that kind was traced with
+        each paged attention path.  Empty for programs over the dense
+        slot cache."""
+        out: Dict[str, Dict[str, int]] = {}
+        for key, traced in list(self._attention_paths.items()):
+            kind = out.setdefault(key[0], {})
+            for path, n in traced.items():
+                kind[path] = kind.get(path, 0) + n
+        return out
+
+    def decode_attention_launches(self) -> Dict[str, float]:
+        """Paged decode launches so far by the launched program's
+        attention path (the process-wide counter, like ``compile_stats``)."""
+        return {path: self._obs["decode_attention"].labels(path=path).value
+                for path in (paged_attention.KERNEL, paged_attention.GATHER)}
+
+    def _count_decode_launch(self, key) -> None:
+        traced = self._attention_paths.get(key)
+        if traced:
+            self._obs["decode_attention"].labels(
+                path=paged_attention.one_path(traced)).inc()
 
     def _decode_step_fn(self, temperature: float, top_k: int) -> Callable:
         """Jitted fixed-batch decode step for one sampling config.  The
@@ -678,9 +732,12 @@ class ServeEngine:
         return jax.tree_util.tree_map_with_path(_one, cache)
 
     @staticmethod
-    def _paged_kwargs(paged, block_tables):
+    def _paged_kwargs(paged, block_tables, live=None):
+        """``live`` is the caller's mask of rows whose step counts: the
+        paged attention may skip the others' reads."""
         return ({} if paged is None
-                else {"paged": paged, "block_tables": block_tables})
+                else {"paged": paged, "block_tables": block_tables,
+                      "live": live})
 
     def _prefill_slots_apply(self, paged, params, cache, counts, tokens,
                              slot_ids, block_tables, rng, counter, starts,
@@ -779,8 +836,8 @@ class ServeEngine:
             if key not in self._generate_fns:
                 self._note_compile("slot_prefill")
                 self._generate_fns[key] = jax.jit(
-                    _named("prefill_slots", self._prefill_slots_apply,
-                           paged),
+                    _named("prefill_slots", self._recording_paths(
+                        key, self._prefill_slots_apply), paged),
                     donate_argnums=(1, 2))
             nxt, cache, counts = self._generate_fns[key](
                 self.params if params is None else params, cache, counts,
@@ -800,7 +857,7 @@ class ServeEngine:
         logits, mutated = self.module.apply(
             {"params": params, "cache": cache}, tokens,
             decode=True, slot_ids=slots, mutable=["cache"],
-            **self._paged_kwargs(paged, block_tables),
+            **self._paged_kwargs(paged, block_tables, active),
         )
 
         # Active-mask: empty slots are free compute — the step runs over
@@ -870,8 +927,8 @@ class ServeEngine:
             if key not in self._generate_fns:
                 self._note_compile("slot_decode")
                 self._generate_fns[key] = jax.jit(
-                    _named("decode_slots", self._decode_slots_apply,
-                           paged),
+                    _named("decode_slots", self._recording_paths(
+                        key, self._decode_slots_apply), paged),
                     donate_argnums=(1, 2))
             tokens_dev = last_tokens
             if not isinstance(tokens_dev, jax.Array):
@@ -882,6 +939,7 @@ class ServeEngine:
                 self.params if params is None else params, cache, counts,
                 tokens_dev, np.asarray(active, bool), bt, base, counter,
                 sampling)
+        self._count_decode_launch(key)
         self._obs["decode_step"].observe(time.perf_counter() - t0)
         return (nxt, gated) if legacy else (nxt, gated, counts)
 
@@ -1094,7 +1152,7 @@ class ServeEngine:
             logits, mutated = self.module.apply(
                 {"params": params, "cache": cache}, tok[:, None],
                 decode=True, slot_ids=slots, mutable=["cache"],
-                **self._paged_kwargs(paged, block_tables),
+                **self._paged_kwargs(paged, block_tables, alive),
             )
 
             def _gate(path, new, old):
@@ -1218,8 +1276,8 @@ class ServeEngine:
             if key not in self._generate_fns:
                 self._note_compile("slot_megastep")
                 self._generate_fns[key] = jax.jit(
-                    _named("decode_megastep", self._megastep_apply,
-                           steps, paged),
+                    _named("decode_megastep", self._recording_paths(
+                        key, self._megastep_apply), steps, paged),
                     donate_argnums=(1, 2))
             tokens_dev = last_tokens
             if not isinstance(tokens_dev, jax.Array):
@@ -1232,6 +1290,7 @@ class ServeEngine:
                     tokens_dev, np.asarray(active, bool),
                     np.asarray(horizon, np.int32), eos, bt, base, counter,
                     sampling, fresh_tokens, fresh, clock))
+        self._count_decode_launch(key)
         self._obs["megastep"].observe(time.perf_counter() - t0)
         if legacy:
             return toks, tok_final, steps_run, cache
@@ -1417,7 +1476,8 @@ class ServeEngine:
                 fn = (self._verify_chain_apply if chain
                       else self._verify_slots_apply)
                 self._generate_fns[key] = jax.jit(
-                    _named(key[0], fn, k, paged),
+                    _named(key[0], self._recording_paths(key, fn), k,
+                           paged),
                     donate_argnums=(1, 2))
             tokens_dev = jax.device_put(tokens, batch_sharding(self.mesh))
             if chain:

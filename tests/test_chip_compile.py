@@ -27,6 +27,7 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from distributed_tensorflow_tpu.cluster.topology import MESH_AXES
 
 fa = importlib.import_module("distributed_tensorflow_tpu.ops.flash_attention")
+pa = importlib.import_module("distributed_tensorflow_tpu.ops.paged_attention")
 
 
 @pytest.fixture(scope="module")
@@ -317,6 +318,18 @@ def fused_computation(hlo, line):
     return body.split("\n}\n", 1)[0]
 
 
+def assert_only_scatters_produce_pools(hlo, pool):
+    produced = pool_sized_results(hlo, pool)
+    assert any(op == "scatter" for _, op, _ in produced)
+    for name, op, line in produced:
+        if op == "fusion":
+            assert " scatter(" in fused_computation(hlo, line), (
+                f"%{name} makes a pool-sized array and is no scatter")
+        else:
+            assert op in ("parameter", "get-tuple-element", "scatter",
+                          "bitcast"), f"%{name} is a pool-sized {op}"
+
+
 @pytest.mark.parametrize("program", ["decode_megastep", "prefill_slots"])
 def test_serve_program_updates_the_kv_pools_in_place(topo, program):
     """With the pools scanned over the layer axis, or stored with the head
@@ -329,15 +342,46 @@ def test_serve_program_updates_the_kv_pools_in_place(topo, program):
     compiled = lowered.compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
     hlo = compiled.as_text()
-    produced = pool_sized_results(hlo, pool)
-    assert any(op == "scatter" for _, op, _ in produced)
-    for name, op, line in produced:
-        if op == "fusion":
-            assert " scatter(" in fused_computation(hlo, line), (
-                f"%{name} makes a pool-sized array and is no scatter")
-        else:
-            assert op in ("parameter", "get-tuple-element", "scatter",
-                          "bitcast"), f"%{name} is a pool-sized {op}"
+    assert_only_scatters_produce_pools(hlo, pool)
+
+
+def test_serve_decode_attention_reads_the_pools_where_they_lie(topo):
+    """The gather path read every slot's whole table row: a gathered
+    ``bf16[32,64,16,1024]`` a layer and pool, re-laid by XLA to
+    ``bf16[32,1024,16,64]`` with the head size padded to 128 lanes (10 of a
+    step's 29 ms at 32 slots).  The decode program now hands both pools to
+    the block-table kernel as they are: the call is there under its name,
+    nothing has a head's 64 columns in its minor dimension over a slot's
+    1,024 positions, the pools are still only produced by the scatters (a
+    layout the kernel did not share would show as a copy of 3.2 GB a call),
+    and the scratch is smaller than the gather path's 0.95 GB."""
+    slots = 32
+    with pa.record_paths() as paths:
+        lowered, pool = lower_serve_program(topo, "decode_megastep", slots)
+    assert pa.KERNEL in paths     # (the init call that sizes the cache gathers)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    calls = re.findall(
+        r"%(paged_decode_attn[\w.]*) = [^\n]*tpu_custom_call", hlo)
+    assert calls, "no tpu_custom_call named paged_decode_attn"
+    heads, head_dim = 16, 64
+    rows = slots * SERVE_TOTAL_LEN * heads
+    for dims in re.findall(r"= \w+\[([\d,]+)\]", hlo):
+        shape = [int(n) for n in dims.split(",")]
+        assert not (shape[-1] == head_dim
+                    and int(np.prod(shape[:-1])) == rows), (
+            f"an array of shape {shape}: a slot's whole row, head-minor")
+    assert_only_scatters_produce_pools(hlo, pool)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_serve_prefill_program_keeps_the_gather_path(topo):
+    """Only the decode shape takes the kernel: a prefill's queries are
+    many positions a row."""
+    with pa.record_paths() as paths:
+        lowered, _ = lower_serve_program(topo, "prefill_slots", slots=32)
+    assert set(paths) == {pa.GATHER}
+    assert "paged_decode_attn" not in lowered.as_text()
 
 
 def test_serve_decode_program_fits_one_chip_at_64_slots(topo):

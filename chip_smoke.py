@@ -182,7 +182,7 @@ def phase_serve_gpt2(requests=16):
 def _train_on_mesh(axes, devices, steps, inspect=True):
     """GPT-2 medium (dropout 0: in-kernel masks are seeded per shard) for a
     few steps on ``MeshConfig(**axes)`` over ``devices`` — the calls
-    train_lib.run and bench.py make, with an explicit mesh.  ``inspect``
+    train_lib.run makes, with an explicit mesh.  ``inspect``
     also counts what the compiled step holds (``kernel_calls``)."""
     from distributed_tensorflow_tpu import cluster as cluster_lib
     from distributed_tensorflow_tpu import train_lib

@@ -45,7 +45,7 @@ def repo_root() -> Path:
 
 def default_targets(root: Path) -> List[Path]:
     targets: List[Path] = [root / "distributed_tensorflow_tpu"]
-    for name in ("train.py", "serve.py", "bench.py"):
+    for name in ("train.py", "serve.py"):
         if (root / name).exists():
             targets.append(root / name)
     scripts = root / "scripts"

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives — one rule, every entry point.
 
-``train.py``, ``serve.py``, ``bench.py``, ``chip_smoke.py`` and the scripts
-all call ``configure()`` before their first compile:
+``train.py``, ``serve.py``, ``chip_smoke.py``, ``benchmark/run.py`` and the
+scripts all call ``configure()`` before their first compile:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; nothing is set in
   code, so whoever launches the program places the cache.

@@ -9,7 +9,7 @@ The library is built on first use from ``dtt_loader.cpp`` (tracked by git;
 the built ``_build/`` is not, so a fresh checkout compiles its own).  When a
 C++ toolchain is unavailable a numpy implementation with identical semantics
 takes over, at WARNING; ``reader_name()`` says which one is feeding, and
-``train.py --data_dir`` / ``bench.py --input=loader`` print it.
+``train.py --data_dir`` prints it.
 """
 
 from __future__ import annotations

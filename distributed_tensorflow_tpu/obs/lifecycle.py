@@ -109,7 +109,7 @@ def _stats_keys() -> List[str]:
 
 
 # The uniform stat surface when no recorder is attached: dashboards, the
-# fleet router, and the bench read one key set either way (the tier-pool
+# fleet router, and the driver read one key set either way (the tier-pool
 # zeros idiom).
 EMPTY_LIFECYCLE_STATS: Dict[str, float] = {k: 0.0 for k in _stats_keys()}
 
@@ -121,8 +121,7 @@ class LifecycleRecorder:
     hook calls; it must only ever be handed HOST values the caller
     already has (timestamps, counts, byte sizes) — never a device array.
     The fold runs inline under one lock (a dict update and a few float
-    ops), so recording is cheap enough for the decode hot loop; the
-    bench arm hard-asserts the overhead bound.
+    ops), so recording is cheap enough for the decode hot loop.
     """
 
     def __init__(
@@ -427,7 +426,7 @@ class LifecycleRecorder:
     def breakdowns(self) -> List[Dict[str, float]]:
         """Completed per-request breakdowns (seconds), most recent last.
         Each carries the six phases plus ``wall``/``rid``/``tokens`` —
-        the bench's sum-to-wall invariant checks these directly."""
+        the sum-to-wall invariant checks these directly."""
         with self._lock:
             return [dict(b) for b in self._completed]
 

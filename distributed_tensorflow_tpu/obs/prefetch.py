@@ -8,7 +8,7 @@ seconds) into the loop's metric surface at a step cadence:
 - ``prefetch_queue_depth`` near capacity + ``prefetch_consumer_wait_s``
   flat  → input is ahead of compute (healthy overlap).
 - queue depth near 0 + consumer wait growing → the loader is the
-  bottleneck (the scaling killer the bench's loader mode quantifies).
+  bottleneck (``input_wait_pct.train`` in the benchmark).
 """
 
 from __future__ import annotations

@@ -11,13 +11,13 @@ Layers:
   scheduler instead of flushing fixed buckets;
 - ``continuous``: Orca-style iteration-level decode scheduling over ONE
   resident KV cache (``ContinuousScheduler``) — admit into free slots,
-  one (num_slots, 1) step per iteration, retire mid-flight;
+  one fused decode launch per iteration, retire mid-flight;
 - ``paged``: host-side block bookkeeping for ``cache_mode="paged"``
   (``BlockAllocator``) — K/V lives in a fixed pool of blocks reached
   through per-slot block tables, with optional int8 storage
   (``models.gpt2.PagedKVConfig``);
-- ``driver``: the in-process request loop behind ``serve.py`` and
-  ``bench.py --mode=serve`` (``run_serve`` / ``ServeArgs``);
+- ``driver``: the in-process request loop behind ``serve.py`` and the
+  benchmark's serving cells (``run_serve`` / ``ServeArgs``);
 - ``fleet``: multi-replica serving — ``FleetRouter`` dispatches over N
   ``Replica`` engines by load (queue depth, occupancy, free blocks) and
   ``CheckpointWatcher`` hot-reloads new checkpoint steps without

@@ -13,12 +13,12 @@ batch every decode step instead (Yu et al., OSDI 2022 — PAPERS.md):
 - Each iteration: (a) ADMIT queued requests into free slots via slot-local
   prefill (``prefill_into_slots`` resets the slot's index rows and writes
   the prompt's K/V at that slot's rows — stale K/V from the previous
-  occupant stays masked behind the reset index); (b) run ONE
-  ``(num_slots, 1)`` decode step over all slots (``decode_slots``) with an
-  active-mask so empty slots are free compute; (c) RETIRE slots whose row
-  hit its eos token or its per-request ``max_new_tokens``, resolving that
-  request's Future immediately — no request ever waits on another's
-  horizon.
+  occupant stays masked behind the reset index); (b) run ONE decode
+  launch over all slots (``decode_megastep``: ``megastep`` fused steps,
+  one by default) with an active-mask so empty slots are free compute;
+  (c) RETIRE slots whose row hit its eos token or its per-request
+  ``max_new_tokens``, resolving that request's Future immediately — no
+  request ever waits on another's horizon.
 
 Completion is out of submission order by design.  The per-request metrics
 this unlocks — time-to-first-token (submit -> prefill token) and
@@ -34,8 +34,8 @@ Fleet extensions (``serve/fleet``):
   params tree; the loop swaps it in at the top of its next iteration.
   Requests pin the generation current at ADMISSION (``_ParamGeneration``
   refcount), in-flight decodes finish on the weights they started with
-  (the iteration groups rows by generation, one ``decode_slots`` call per
-  live generation — normally exactly one), and a superseded generation's
+  (the iteration groups rows by generation, one ``decode_megastep`` call
+  per live generation — normally exactly one), and a superseded generation's
   params are dropped when its refcount drains to zero.  Each resolved
   Future carries its ``generation`` tag.
 - PER-SHARD KV POOLS — ``per_shard_kv=True`` (paged mode) partitions the
@@ -76,8 +76,9 @@ Fleet extensions (``serve/fleet``):
   sustained short traffic can't starve an in-progress whale.
   ``prefill_budget=0`` (default) keeps the one-shot whole-prompt
   prefill.
-- MEGASTEP DECODE — ``megastep K > 1`` fuses K decode iterations into
-  ONE compiled program (``engine.decode_megastep``: a bounded
+- MEGASTEP DECODE — every plain decode launch is a megastep:
+  ``megastep K`` fuses K decode iterations (default 1) into ONE
+  compiled program (``engine.decode_megastep``: a bounded
   ``lax.while_loop`` over the inner step that ALSO exits early once
   every row is dead, so an all-eos megastep stops paying for its
   remaining masked no-op steps) so the host pays one dispatch + one
@@ -123,9 +124,9 @@ Fleet extensions (``serve/fleet``):
   sequential loop would burn (unconsumed counters are refunded after
   the launch), so sampled output stays distribution-exact — with
   single-stream traffic, token-identical spec on vs off.  Iterations
-  where NO slot has a draft fall through to the plain decode step (or
-  the megastep when ``megastep > 1``) — a degenerate k=0 verify
-  program is never built; slots without a draft in a drafting
+  where NO slot has a draft fall through to the plain megastep
+  dispatch — a degenerate k=0 verify program is never built; slots
+  without a draft in a drafting
   iteration ride the verify launch with ``draft_len 0`` and advance by
   one token, exactly a plain decode step.  Composes with chunked
   prefill (prefilling slots are inactive-masked as ever), prefix
@@ -135,12 +136,14 @@ Fleet extensions (``serve/fleet``):
   launches per generated token on repetitive/structured text —
   ``spec_emitted / spec_launches`` tokens per launch against the plain
   path's one.
-- DEEP ASYNC DECODE — ``async_decode=True`` splits every launch into
-  dispatch and fetch halves and runs a bounded LAUNCH RING
-  (``async_depth=D``, default 2 — the classic double buffer): each
-  iteration dispatches launch N, then resolves the oldest ring
-  records until at most D-1 stay in flight, so the device runs up to
-  D launches ahead of the host view and admission, prefill chunking,
+- DEEP ASYNC DECODE — every launch has a dispatch and a fetch half and
+  goes through one bounded LAUNCH RING: each iteration dispatches
+  launch N, then resolves the oldest ring records until at most D-1
+  stay in flight.  Without ``async_decode`` D is 1 — dispatch, then
+  resolve: the synchronous loop.  ``async_decode=True`` makes D
+  ``async_depth`` (default 2 — the classic double buffer), so the
+  device runs up to D launches ahead of the host view and admission,
+  prefill chunking,
   and retirement bookkeeping all overlap executing compute.  Records
   resolve strictly in launch order; a dedicated FETCH THREAD performs
   the ``jax.device_get`` half off the loop thread (a device_get is
@@ -163,7 +166,7 @@ Fleet extensions (``serve/fleet``):
   view and a chain-verify launch scores them against the
   device-resident carry, so staleness costs acceptance length, never
   a token.  Only seeded-sampling and mixed-generation iterations
-  still drain the ring and fall back to the sync order
+  still drain the ring and run at depth 1
   (``async_sync_fallbacks`` counts them).  Greedy output is
   bit-identical async on vs off at every depth; the observable win is
   ``device_idle_fraction`` (share of the window with no launch in
@@ -776,8 +779,8 @@ class ContinuousScheduler:
         # path bit-identical to the unrecorded scheduler.
         self._lifecycle = lifecycle
         if lifecycle is not None:
-            # Compile taps (rid 0) let the bench cross-check its
-            # compile_post_warmup == 0 assert against lifecycle events.
+            # Compile taps (rid 0) let a run cross-check its
+            # compile_post_warmup == 0 against lifecycle events.
             engine.set_lifecycle(lifecycle)
         self._tier_pool: Optional[HostKVPool] = None
         if self.slo_scheduling and cache_mode == "paged":
@@ -1239,7 +1242,7 @@ class ContinuousScheduler:
         # Host-KV-tier telemetry: the pool has its own lock, read it
         # before the scheduler lock (same no-lock-order-edge discipline
         # as compile_stats).  Zeros when tiering is off so dashboards,
-        # the fleet router, and the bench read one uniform key set.
+        # the fleet router, and the driver read one uniform key set.
         if self._tier_pool is not None:
             tier_stats = self._tier_pool.stats()
         else:
@@ -1336,8 +1339,8 @@ class ContinuousScheduler:
                 "async_decode": 1.0 if self.async_decode else 0.0,
                 "device_clock": float(self._device_clock),
                 "device_idle_fraction": self._idle_fraction_locked(),
-                # The launch ring: configured depth, iterations that
-                # fell back to a sync path (spec/prefill compose now, so
+                # The launch ring: configured depth, async iterations
+                # that ran at depth 1 (spec/prefill compose now, so
                 # steady-state async traffic should hold this at zero),
                 # realized ring occupancy at dispatch, and loop-thread
                 # seconds spent blocked on the fetch thread (residual
@@ -2249,19 +2252,13 @@ class ContinuousScheduler:
                     # The slot stays OUT of the decode-active set
                     # (``req.tokens`` empty) until the resolve lands its
                     # token, so no decode launch dispatches it early.
-                    rec = _InflightPrefill(
-                        req=req, dispatch_t=chunk_start,
-                        fetch_payload=tok_dev)
-                    self._enqueue_fetch(rec)
-                    self._ring.append(rec)
-                    with self._lock:
-                        self._ring_depth_hist[len(self._ring)] += 1
-                        self._obs["ring_depth"].set(len(self._ring))
                     # The depth bound applies to deferred chunks too:
                     # several slots finishing prefill in one iteration
                     # must not stack the ring past what the flag promises.
-                    while len(self._ring) >= self.async_depth:
-                        self._resolve_next()
+                    self._ring_push(
+                        _InflightPrefill(req=req, dispatch_t=chunk_start,
+                                         fetch_payload=tok_dev),
+                        self.async_depth)
                 elif final:
                     tok = int(self._fetch_host(tok_dev)[0])
                     now = _now()
@@ -2330,170 +2327,69 @@ class ContinuousScheduler:
         return {s: r for s, r in snapshot.items() if r.tokens}
 
     def _decode_once(self) -> None:
-        """One iteration: a (num_slots, 1) step over all slots, then
-        retirement of every row that hit its eos or horizon.  With
-        ``megastep > 1`` the iteration is one K-step fused program
-        instead.  With ``spec_k >= 1`` the iteration is a draft-and-
-        verify step whenever ANY slot drafted; iterations where no slot
-        has a draft fall through HERE — to the plain step or the
-        megastep — so a degenerate k=0 verify program is never built or
-        cached.
+        """One decode iteration through the launch RING, the only
+        plain-decode path: build the dispatch (one ``megastep``-step
+        fused program per live generation; K=1 is a megastep of one),
+        append its record, then resolve oldest-first until fewer than
+        ``depth`` records stay in flight.
 
-        With ``async_decode`` the iteration runs the launch RING:
-        dispatch iteration N's launch, append it, then resolve the
-        oldest record(s) until at most ``async_depth - 1`` stay in
-        flight — so the device runs up to ``async_depth`` launches
-        ahead of the host view (depth 2 = the classic double buffer;
-        depth 1 = dispatch-then-resolve).  Speculative iterations
-        dispatch a chain-verify launch drafted from the stale fetched
-        view, and deferred final prefill chunks ride the same ring, so
-        neither flushes it anymore.  Traffic the stale host view cannot
-        serve (``_needs_sync``) still falls back to the synchronous
-        paths after draining the ring."""
-        if self.async_decode and not self._needs_sync():
-            rec = None
-            if self.spec_k:
-                rec = self._spec_dispatch_async()
-            if rec is None:
-                rec = self._megastep_dispatch()
-            if rec is None:
-                # Nothing dispatchable (every live horizon is already in
-                # flight, or no row decodes yet): resolve ONE record so
-                # the loop still makes progress toward the host view.
-                if self._ring:
-                    self._resolve_next()
+        ``depth`` is ``async_depth`` with ``async_decode`` on — the
+        device runs up to that many launches ahead of the host view
+        (2 = the classic double buffer) — and 1 otherwise: dispatch,
+        then resolve, the synchronous loop.  Traffic the stale host view
+        cannot serve (``_needs_sync``) drops THIS iteration to depth 1.
+        A depth-1 iteration first drains the ring, so the host token
+        vector is authoritative when it dispatches.
+
+        The one exception is speculation (``spec_k >= 1``): an iteration
+        tries a draft-and-verify launch first — ``_spec_dispatch_async``
+        on the ring at depth > 1, the synchronous ``_decode_spec_once``
+        at depth 1 — and falls through to the plain dispatch when no
+        slot drafted, so a degenerate k=0 verify program is never built
+        or cached.  Deferred final prefill chunks ride the same ring."""
+        depth = 1
+        if self.async_decode:
+            if self._needs_sync():
+                with self._lock:
+                    self._async_fallbacks += 1
+            else:
+                depth = self.async_depth
+        rec = None
+        if depth == 1:
+            self._flush_inflight()
+            if self._fresh.any():
+                # Collapse to the sync invariant: with every launch
+                # resolved the host token vector is authoritative again.
+                self._dev_last_tok = None
+                self._fresh[:] = False
+            if self.spec_k and self._decode_spec_once():
                 return
-            self._enqueue_fetch(rec)
-            self._ring.append(rec)
-            with self._lock:
-                self._ring_depth_hist[len(self._ring)] += 1
-                self._obs["ring_depth"].set(len(self._ring))
-            while len(self._ring) >= self.async_depth:
+        elif self.spec_k:
+            rec = self._spec_dispatch_async()
+        if rec is None:
+            rec = self._megastep_dispatch()
+        if rec is None:
+            # Nothing dispatchable (every live horizon is already in
+            # flight, or no row decodes yet): resolve ONE record so the
+            # loop still makes progress toward the host view.
+            if self._ring:
                 self._resolve_next()
             return
-        if self.async_decode:
-            with self._lock:
-                self._async_fallbacks += 1
-        self._flush_inflight()
-        if self._fresh.any():
-            # Collapse to the sync invariant: with every launch resolved
-            # the host token vector is authoritative again.
-            self._dev_last_tok = None
-            self._fresh[:] = False
-        if self.spec_k and self._decode_spec_once():
-            return
-        with self._lock:
-            mega = self.megastep
-        if mega > 1 or self.megastep_auto:
-            # megastep='auto' routes K=1 through the megastep halves too:
-            # the dispatch/step timing samples autotune picks from come
-            # from there.
-            self._decode_megastep_once()
-            return
-        decoding = self._decode_snapshot()
-        active_slots = list(decoding)
-        if not active_slots:
-            return
-        iter_start = _now()
-        for slot in active_slots:
-            # The upcoming step writes each slot's position
-            # prompt + len(tokens) - 1; cross a block boundary -> allocate.
-            req = decoding[slot]
-            self._ensure_blocks(
-                req, req.base_prompt_len + len(req.tokens))
-        # Group rows by pinned weight generation: mid-reload, rows admitted
-        # before the swap keep decoding on their own params — one step per
-        # live generation, oldest first (normally exactly one group, and
-        # that single-group call is identical to the pre-reload path).  A
-        # group's step only advances ITS rows: the other generation's rows
-        # are inactive-masked, so their cache state stays frozen for their
-        # own step.
-        by_gen: Dict[int, List[int]] = {}
-        for slot in active_slots:
-            by_gen.setdefault(decoding[slot].gen.generation, []).append(slot)
-        # Issue EVERY generation's launch before fetching any tokens: the
-        # launches chain through the donated cache asynchronously, so a
-        # two-generation iteration mid-reload no longer serializes on a
-        # blocking device_get between its groups.  Each group reads the
-        # same pre-iteration token vector (device-resident when the last
-        # iteration's copy is still valid).
-        last_in = (self._dev_last_tok if self._dev_last_tok is not None
-                   else self._last_tok)
-        samp = self._sampling_vector(decoding)
-        launches: List[Tuple[List[int], Any]] = []
-        with self._tracer.span(
-                "dispatch", cat="serve",
-                args={"active_slots": len(active_slots),
-                      "generations": len(by_gen)}):
-            for generation in sorted(by_gen):
-                slots = by_gen[generation]
-                active = np.zeros((self.num_slots,), bool)
-                active[slots] = True
-                tok_dev, self._cache, self._counts = (
-                    self.engine.decode_slots(
-                        self._cache, last_in, active,
-                        sampling=samp, counts=self._counts,
-                        counter=self._next_counter(),
-                        params=decoding[slots[0]].gen.params,
-                        **self._paged_call_kwargs()))
-                launches.append((slots, tok_dev))
-        self._turnover_launched(active_slots, iter_start)
-        # Chain the device tokens into the next iteration only when ONE
-        # generation ran: the single-step program's output is not
-        # alive-gated, so with two groups each output carries garbage at
-        # the other group's rows.
-        self._dev_last_tok = launches[0][1] if len(launches) == 1 else None
-        toks_by_slot: Dict[int, int] = {}
-        for slots, tok_dev in launches:
-            toks = self._fetch_host(tok_dev)
-            for slot in slots:
-                toks_by_slot[slot] = int(toks[slot])
-        with self._lock:
-            self._iterations += 1
-            self._occupancy_sum += len(active_slots)
-            self._last_occupancy = len(active_slots)
-            self._note_dispatch_locked(iter_start)
-            self._note_fetch_done_locked(
-                self._launch_seq, _now())
-        step_done = _now()
-        gaps = []
-        lc_batch = [] if self._lifecycle is not None else None
-        to_retire = []
-        for slot in active_slots:
-            req = decoding[slot]
-            tok = toks_by_slot[slot]
-            req.tokens.append(tok)
-            self._last_tok[slot, 0] = tok
-            if req.last_token_at is not None:
-                gaps.append((step_done - req.last_token_at) * 1000.0)
-            req.last_token_at = step_done
-            self._emit_tokens(req, t=step_done, dispatch_t=iter_start,
-                              batch=lc_batch)
-            if req.done():
-                to_retire.append(req)
-        if lc_batch:
-            self._lifecycle.record_tokens_batch(
-                lc_batch, t=step_done, dispatch_t=iter_start)
-        for req in to_retire:
-            self._retire(req)
-        with self._lock:
-            self._tpot_gaps_ms.extend(gaps)
-            self._megastep_launches += len(launches)
-            self._megastep_tokens += len(active_slots)
-            for _ in launches:
-                self._obs["megastep_size"].observe(1)
-            saved = len(active_slots) - len(launches)
-            if saved > 0:
-                self._obs["megastep_amortized"].inc(saved)
+        self._ring_push(rec, depth)
 
-    def _decode_megastep_once(self) -> None:
-        """One SYNC megastep iteration: dispatch, then fetch immediately
-        — the classic blocking loop.  Async mode routes through the same
-        two halves from ``_decode_once`` with the fetch deferred one
-        iteration, so sync vs async is purely WHEN the fetch runs."""
-        rec = self._megastep_dispatch()
-        if rec is not None:
-            self._megastep_fetch(rec)
+    def _ring_push(self, rec, depth: int) -> None:
+        """Append a just-dispatched record to the launch ring, then
+        resolve oldest-first until fewer than ``depth`` stay in flight.
+        At depth 1 the record resolves here and now, so its fetch runs
+        inline; deeper rings hand it to the fetch thread."""
+        if depth > 1:
+            self._enqueue_fetch(rec)
+        self._ring.append(rec)
+        with self._lock:
+            self._ring_depth_hist[len(self._ring)] += 1
+            self._obs["ring_depth"].set(len(self._ring))
+        while len(self._ring) >= depth:
+            self._resolve_next()
 
     def _megastep_dispatch(self) -> Optional[_InflightMegastep]:
         """Dispatch half of a megastep iteration: build horizons and eos
@@ -2634,7 +2530,7 @@ class ContinuousScheduler:
         The host trims each row's fetched tokens with the same
         ``req.done()`` walk that retires it, so a row finishing at inner
         step j < K contributes exactly its first j+1 tokens —
-        bit-identical to the K=1 path — and nothing after its eos leaks
+        bit-identical to K=1 — and nothing after its eos leaks
         into ``req.tokens``.  A slot that retired at a PREVIOUS fetch
         (its eos was in flight when this launch dispatched) is skipped
         whole: its columns here are the zombie tail the donation fence
@@ -2807,8 +2703,9 @@ class ContinuousScheduler:
 
     def _needs_sync(self) -> bool:
         """Rows the ring's stale-by-up-to-D-iterations host view cannot
-        serve: multiple live generations chain grouped launches (the
-        fetch order would interleave with the next dispatch), and SEEDED
+        serve, so their iteration runs at depth 1: multiple live
+        generations chain grouped launches (the fetch order would
+        interleave with the next dispatch), and SEEDED
         sampling folds ``len(req.tokens)`` into its per-row key (a stale
         step would replay keys).  Greedy rows ignore the RNG entirely
         and unseeded sampled rows draw from the global per-launch
@@ -3320,7 +3217,7 @@ class ContinuousScheduler:
     def _next_counter(self, count: int = 1) -> int:
         """Reserve ``count`` consecutive in-step RNG counters and return
         the FIRST — the megastep folds ``counter + j`` in per inner step,
-        burning exactly the per-token counters the K=1 loop would."""
+        burning exactly the per-token counters K launches of one would."""
         with self._lock:
             self._decode_counter += count
             return self._decode_counter - count + 1

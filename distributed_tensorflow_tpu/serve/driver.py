@@ -1,6 +1,6 @@
 """In-process serve loop: synthetic clients -> batcher -> engine.
 
-The ``serve.py`` entrypoint and ``bench.py --mode=serve`` both drive this.
+The ``serve.py`` entrypoint and the benchmark's serving cells drive this.
 No HTTP/stdin surface on purpose: the subsystem under test is checkpoint
 restore + KV-cache decode + dynamic batching on the accelerator; a few
 client threads submitting through ``DynamicBatcher`` exercise the same
@@ -346,9 +346,7 @@ def run_serve(args: ServeArgs,
               engine: Optional[ServeEngine] = None) -> Dict[str, Any]:
     """Drive ``args.steps`` requests; returns the serve metrics dict.
 
-    Pass ``engine`` to reuse one restored/compiled engine across runs
-    (``bench.py --mode=serve`` compares both scheduling disciplines on the
-    same engine this way)."""
+    Pass ``engine`` to reuse one restored/compiled engine across runs."""
     own_engine = engine is None
     if own_engine:
         mesh = cluster_lib.build_mesh(cluster_lib.MeshConfig(
@@ -805,7 +803,7 @@ def _drive(args: ServeArgs, engine: ServeEngine) -> Dict[str, Any]:
 
     # Compile counter AFTER warm + batcher construction: everything the
     # timed window compiles on top of this is a warmup gap (and, under a
-    # sampling mix, a one-program-set violation the bench asserts on).
+    # sampling mix, a one-program-set violation).
     compile_warm = engine.compile_stats()["compile_total"]
     t0 = time.perf_counter()
     threads = [threading.Thread(target=client, args=(c,), daemon=True)
@@ -992,7 +990,7 @@ def _drive(args: ServeArgs, engine: ServeEngine) -> Dict[str, Any]:
         if not interrupted:
             # Submission-order digest of every generated stream: two runs
             # over the same traffic are token-identical iff these match
-            # (the prefix-cache parity oracle in bench/smoke).
+            # (the prefix-cache parity oracle).
             h = hashlib.sha256()
             for r in results:
                 h.update(np.asarray(r, np.int32).tobytes())

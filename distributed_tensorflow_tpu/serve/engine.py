@@ -97,7 +97,7 @@ def _engine_instruments(registry=None):
             "dtt_serve_compile_total",
             "Serving program compiles (program-cache misses, all kinds) "
             "since engine start — flat after warmup is the no-recompile "
-            "claim the bench A/B asserts under mixed sampling traffic"),
+            "claim under mixed sampling traffic"),
         "programs_cached": r.gauge(
             "dtt_serve_programs_cached",
             "Distinct compiled serving programs resident in the "
@@ -273,7 +273,7 @@ class ServeEngine:
     """Checkpoint-backed inference over a mesh.
 
     ``checkpoint_dir=None`` (or an empty directory) falls back to fresh
-    random init — the smoke/bench path when no training run preceded.
+    random init — the smoke/benchmark path when no training run preceded.
     """
 
     def __init__(
@@ -407,14 +407,14 @@ class ServeEngine:
 
     def set_lifecycle(self, lifecycle) -> None:
         """Attach a lifecycle recorder (``obs.lifecycle``): every
-        program-cache miss records a rid-0 COMPILE event, so a bench
+        program-cache miss records a rid-0 COMPILE event, so a run
         asserting ``compile_post_warmup == 0`` can cross-check the
         lifecycle stream instead of trusting the counter alone."""
         self._lifecycle = lifecycle
 
     def _note_compile(self, kind: str) -> None:
         """Account one program-cache miss: the per-kind labelled counter
-        plus the total the bench A/B asserts stays flat post-warmup.
+        plus the total that must stay flat post-warmup.
         Every miss inserts exactly one never-evicted program, so the
         resident-program gauge advances here too — the insert site, not
         a dict-length read, so ``compile_stats`` never has to touch the
@@ -691,7 +691,7 @@ class ServeEngine:
     def cache_hbm_bytes(cache: PyTree) -> int:
         """GLOBAL resident bytes of a KV cache tree (dense rows or paged
         pools + scales + index vectors) — the serving-capacity denominator
-        the block-pool gauges and ``bench.py --mode=serve`` report."""
+        the block-pool gauges report."""
         return int(sum(
             int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
             for leaf in jax.tree.leaves(cache)))
@@ -897,9 +897,9 @@ class ServeEngine:
         rows admitted before a weight swap keep decoding on their own
         generation — same avals/shardings, so no recompile).
 
-        ``last_tokens`` and ``block_tables`` may already be device arrays
-        (the scheduler keeps both resident between iterations); host
-        arrays are transferred as before, so the slow path still works.
+        The single-step REFERENCE the tests hold ``decode_megastep`` to:
+        the scheduler launches that program for every K, ``steps=1``
+        included.  ``last_tokens``/``block_tables``: host or device arrays.
 
         PER-REQUEST SAMPLING: ``sampling`` is a (num_slots,)-row vector
         dict and ``counts`` the resident (num_slots, vocab) emitted-token
@@ -1223,9 +1223,9 @@ class ServeEngine:
         scattering into positions past their frozen index or into the
         trash block, never into a live request's K/V.
 
-        ``steps=1`` compiles a one-iteration scan — same math as
-        ``decode_slots``, used only when callers want a uniform K
-        interface.  The scheduler routes K=1 through ``decode_slots``.
+        ``steps=1`` compiles a one-iteration loop — same math as
+        ``decode_slots``, the single-step reference — and is what the
+        scheduler launches at K=1.
 
         PER-REQUEST SAMPLING: ``sampling``/``counts`` as in
         ``decode_slots`` — ONE program per (steps, paged).  Inside the

@@ -409,7 +409,7 @@ def _score(flights: List["_Flight"], wall: float, *,
         for f in flights if f.first_token_t is not None)
     # Greedy-output fingerprint in TRACE order: two runs of the same
     # trace against bit-identical decode paths produce the same digest
-    # (the bench's recorder-on vs recorder-off parity check).
+    # (the recorder-on vs recorder-off parity check).
     h = hashlib.sha256()
     for i, f in enumerate(flights):
         if f.result_tokens is not None:

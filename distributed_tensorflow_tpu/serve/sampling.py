@@ -218,8 +218,7 @@ class MixAssigner:
     """Deterministic weighted round-robin over a sampling mix: request i
     always lands on the same config for a given spec (smooth-WRR — pick
     the entry whose realized share lags its weight most), so two runs of
-    the same traffic shape draw identical per-request configs and the
-    bench A/B stays reproducible."""
+    the same traffic shape draw identical per-request configs."""
 
     def __init__(self, mix: Sequence[Tuple[SamplingParams, float]]):
         if not mix:

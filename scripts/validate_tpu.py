@@ -127,8 +127,8 @@ def validate_kv_mask():
 
 def time_kernels():
     """Wall-clock of the bf16 causal forward, flash vs dense (includes
-    dispatch latency; scripts/profile_resnet.py has the device-time
-    methodology)."""
+    dispatch latency; device times are the benchmark's, from its
+    trace)."""
     from distributed_tensorflow_tpu.ops import flash_attention
     from distributed_tensorflow_tpu.ops.flash_attention import _dense
 

@@ -75,6 +75,33 @@ def free_port() -> int:
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def leave_in_order(grace_s: float = 1.0) -> None:
+    """Leave a worker process of a multi-process test, for its script's
+    last line.  Process 0 hosts JAX's coordination service, and a peer
+    whose error poll sees that socket close aborts with exit code 1
+    though its work is done — so process 0 must leave last.  Every other
+    process says it is going (a key in the service's store) and goes;
+    process 0 waits for all of them and a moment more.  ``os._exit``
+    skips the atexit cluster-wide shutdown barrier, which only races on
+    CPU test exits."""
+    import time
+
+    import jax
+    from jax._src import distributed
+
+    client = distributed.global_state.client
+    me, n = jax.process_index(), jax.process_count()
+    if client is not None and n > 1:
+        if me:
+            client.key_value_set(f"dtt_test_gone/{me}", "1")
+        else:
+            for peer in range(1, n):
+                client.blocking_key_value_get(
+                    f"dtt_test_gone/{peer}", 120_000)
+            time.sleep(grace_s)
+    os._exit(0)
+
+
 def spawn_worker_cluster(
     script: str,
     n: int = 2,

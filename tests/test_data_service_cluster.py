@@ -29,8 +29,8 @@ result = run(TrainArgs(
 assert result["final_step"] == 6, result
 assert np.isfinite(result["loss"]), result
 print("TRAINER_OK", jax.process_index(), flush=True)
-# skip the jax.distributed atexit shutdown barrier races on CPU test exits
-os._exit(0)
+from tests.helpers import leave_in_order
+leave_in_order()
 """
 
 

@@ -166,7 +166,8 @@ result = run(TrainArgs(model="mnist", steps=6, batch_size=64, log_every=3))
 assert result["final_step"] == 6, result
 assert np.isfinite(result["loss"]), result
 print("TRAIN_OK", jax.process_index(), flush=True)
-os._exit(0)
+from tests.helpers import leave_in_order
+leave_in_order()
 """
 
 
@@ -253,7 +254,8 @@ assert np.allclose(gathered, gathered[0]), gathered
 
 server.shutdown()
 print("HYBRID_OK", jax.process_index(), losses_h, flush=True)
-os._exit(0)
+from tests.helpers import leave_in_order
+leave_in_order()
 """
 
 
@@ -319,7 +321,8 @@ np.testing.assert_allclose(losses_gpipe, losses_1f1b, rtol=1e-4)
 
 server.shutdown()
 print("PIPE_MP_OK", jax.process_index(), losses_1f1b, flush=True)
-os._exit(0)
+from tests.helpers import leave_in_order
+leave_in_order()
 """
 
 
@@ -375,7 +378,8 @@ np.testing.assert_allclose(losses_ring, losses_flat, rtol=1e-4)
 
 server.shutdown()
 print("RING_MP_OK", jax.process_index(), losses_ring, flush=True)
-os._exit(0)
+from tests.helpers import leave_in_order
+leave_in_order()
 """
 
 
@@ -445,7 +449,8 @@ result = run(TrainArgs(model="mnist", steps=4, batch_size=32, log_every=2,
 assert result["final_step"] == 4, result
 assert np.isfinite(result["loss"]), result
 print("FILESET_TRAIN_OK", jax.process_index(), flush=True)
-os._exit(0)
+from tests.helpers import leave_in_order
+leave_in_order()
 """
 
 

@@ -183,7 +183,7 @@ def test_every_jit_in_the_serve_engine_has_a_name(engine):
                 + list(engine._cache_init_fns.values())
                 + list(engine._block_fns.values()) + [engine._predict_fn])
     names = {fn.__name__ for fn in compiled}
-    assert {"prefill_slots", "decode_slots", "decode_megastep"} <= names
+    assert {"prefill_slots", "decode_megastep"} <= names
     assert not any(n.startswith(("_", "<")) for n in names), names
 
 

@@ -15,13 +15,14 @@ identity stays stable; the control law is pinned against a stubbed
 timing source (no real clocks in the assert path).
 
 The ctor-validation and stubbed-autotune tests never launch a decode
-program and run in tier-1; everything that compiles end-to-end decode
-carries ``serve_slow`` (excluded from tier-1 alongside ``slow``).
+program; everything that compiles end-to-end decode carries
+``serve_slow`` (a selection marker — tier-1 excludes only ``slow``).
 
 ``DTT_ASYNC_DEPTH`` overrides the ring depth the async schedulers here
 run at (default 2 — the classic double buffer); ``scripts/t1.sh``'s
 opt-in ``DTT_SERVE_ASYNC=1`` pass reruns the serve_slow suites at
-depth 4."""
+depth 4.  ``async_decode=False`` is the same ring at depth 1, which
+``TestDepthOneIsSynchronous`` pins."""
 
 import os
 
@@ -154,6 +155,50 @@ class TestAsyncParity:
                 overlapped = _run_all(sched, reqs)
             for base, out in zip(baseline, overlapped):
                 np.testing.assert_array_equal(out, base)
+
+
+@pytest.mark.serve_slow
+class TestDepthOneIsSynchronous:
+    """``async_decode=False`` IS the launch ring at depth 1: the same
+    dispatch/resolve halves, the same launches, iteration for iteration.
+    Stepped by hand (``start=False``, every request queued first) so the
+    launch counts do not depend on thread timing."""
+
+    @pytest.mark.parametrize("steps", [1, 4])
+    @pytest.mark.parametrize("cache_mode", ["dense", "paged"])
+    def test_async_off_equals_ring_of_depth_one(self, gpt2_engine,
+                                                cache_mode, steps):
+        vocab = gpt2_engine.module.cfg.vocab_size
+        reqs = _mixed_requests(vocab, seed=17)
+        kwargs = dict(num_slots=8, max_total_len=32, megastep=steps)
+        if cache_mode == "paged":
+            kwargs.update(cache_mode="paged", block_size=4)
+        runs = []
+        for ring in (dict(async_decode=False),
+                     dict(async_decode=True, async_depth=1)):
+            sched = ContinuousScheduler(gpt2_engine, start=False,
+                                        **kwargs, **ring)
+            try:
+                futs = [sched.submit(p, max_new_tokens=m) for p, m in reqs]
+                n = 0
+                while not all(f.done() for f in futs) and n < 200:
+                    sched._iteration()
+                    assert not sched._ring  # depth 1 leaves nothing behind
+                    n += 1
+                outs = [np.asarray(f.result(timeout=60)) for f in futs]
+                stats = sched.stats()
+            finally:
+                sched.close(timeout=5.0)
+            assert stats["async_ring_depth_max"] == 1.0
+            assert stats["async_sync_fallbacks"] == 0.0
+            runs.append((outs, stats["megastep_launches"],
+                         stats["iterations"], stats["megastep_tokens"]))
+        (sync_outs, *sync_counts), (ring_outs, *ring_counts) = runs
+        assert sync_counts == ring_counts and sync_counts[0] > 0
+        for (prompt, horizon), a, b in zip(reqs, sync_outs, ring_outs):
+            np.testing.assert_array_equal(a, b)
+            np.testing.assert_array_equal(
+                a, _fixed_reference(gpt2_engine, prompt, horizon))
 
 
 @pytest.mark.serve_slow

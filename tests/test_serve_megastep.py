@@ -12,14 +12,18 @@ against chunked prefill, the prefix cache, and hot weight reload at a
 megastep boundary.  EOS fired at an inner scan step j < K must trim on
 host to the exact K=1 output — no post-EOS token leaks."""
 
+import collections
 import contextlib
 import time
 
+import jax
 import numpy as np
 import pytest
 
-from distributed_tensorflow_tpu.models.gpt2 import GPT2Config
+from distributed_tensorflow_tpu.models.gpt2 import GPT2Config, PagedKVConfig
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
+from distributed_tensorflow_tpu.serve import sampling as sampling_lib
+from distributed_tensorflow_tpu.serve.sampling import SamplingParams
 
 
 def _mixed_requests(vocab, seed=3):
@@ -70,6 +74,131 @@ class TestCtorValidation:
         assert stats["megastep_tokens"] == 0.0
         assert stats["megastep_effective_steps"] == 0.0
         sched.close(timeout=0.1)
+
+
+def _index_leaves(cache):
+    """The per-slot ``cache_index``/``position`` vectors of a cache tree."""
+    out = {}
+
+    def _grab(path, leaf):
+        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
+        if name in ("cache_index", "position"):
+            out[jax.tree_util.keystr(path)] = np.asarray(jax.device_get(leaf))
+        return leaf
+
+    jax.tree_util.tree_map_with_path(_grab, cache)
+    assert out
+    return out
+
+
+class TestSingleStepReference:
+    """``engine.decode_slots`` is the single-step REFERENCE: the scheduler
+    launches ``decode_megastep`` for every K, so the K=1 program it runs
+    must return what the plain step returns on the same inputs — tokens,
+    per-slot cache index and penalty counts, step after step."""
+
+    SLOTS = (0, 2, 5)
+    PER_REQUEST = (
+        None,                                            # greedy neighbour
+        SamplingParams(temperature=0.9, top_k=8),
+        SamplingParams(temperature=1.1, top_k=5, seed=7,
+                       presence_penalty=0.5),
+    )
+
+    @pytest.mark.parametrize("sampling", ["greedy", "per-request"])
+    @pytest.mark.parametrize("cache_mode", ["dense", "paged"])
+    def test_megastep_of_one_equals_decode_slots(self, gpt2_engine,
+                                                 cache_mode, sampling):
+        eng = gpt2_engine
+        vocab = eng.module.cfg.vocab_size
+        rng = np.random.default_rng(21)
+        prompts = [rng.integers(0, vocab, size=(n,), dtype=np.int32)
+                   for n in (4, 7, 5)]
+        params = (self.PER_REQUEST if sampling == "per-request"
+                  else (None,) * 3)
+        key = jax.random.key(9)
+        paged_kw = {}
+        if cache_mode == "paged":
+            pcfg = PagedKVConfig(block_size=4, num_blocks=33)
+            tables = np.zeros((8, 4), np.int32)
+            for slot in self.SLOTS:  # block 0 is the trash block
+                tables[slot] = 1 + 4 * slot + np.arange(4)
+            paged_kw = dict(paged=pcfg, block_tables=tables)
+
+        def admitted():
+            cache = (eng.init_paged_cache(8, 16, paged=paged_kw["paged"])
+                     if paged_kw else eng.init_slot_cache(8, 16))
+            counts = eng.init_slot_counts(8)
+            last = np.zeros((8,), np.int32)
+            for i, (slot, prompt) in enumerate(zip(self.SLOTS, prompts)):
+                tok, cache, counts = eng.prefill_into_slots(
+                    cache, prompt[None, :], [slot],
+                    sampling=sampling_lib.pack([params[i]], [0]),
+                    counts=counts, rng=key, counter=i, **paged_kw)
+                last[slot] = int(np.asarray(jax.device_get(tok))[0])
+            return cache, counts, last
+
+        active = np.zeros((8,), bool)
+        active[list(self.SLOTS)] = True
+        horizon = np.where(active, 8, 0).astype(np.int32)
+        row_params = [None] * 8
+        for slot, sp in zip(self.SLOTS, params):
+            row_params[slot] = sp
+        ref_cache, ref_counts, ref_last = admitted()
+        cache, counts, last = admitted()
+        np.testing.assert_array_equal(last, ref_last)
+        for step in range(1, 4):
+            steps = [step if a else 0 for a in active]  # emitted so far
+            samp = sampling_lib.pack(row_params, steps)
+            ref_tok, ref_cache, ref_counts = eng.decode_slots(
+                ref_cache, ref_last[:, None], active, sampling=samp,
+                counts=ref_counts, rng=key, counter=10 + step, **paged_kw)
+            toks, final, steps_run, _clock, cache, counts = (
+                eng.decode_megastep(
+                    cache, last, active, horizon, steps=1, sampling=samp,
+                    counts=counts, rng=key, counter=10 + step, **paged_kw))
+            ref_tok = np.asarray(jax.device_get(ref_tok))
+            toks = np.asarray(jax.device_get(toks))
+            assert toks.shape == (8, 1) and int(steps_run) == 1
+            np.testing.assert_array_equal(toks[active, 0], ref_tok[active])
+            np.testing.assert_array_equal(
+                np.asarray(jax.device_get(final))[active], ref_tok[active])
+            np.testing.assert_array_equal(
+                np.asarray(jax.device_get(counts)),
+                np.asarray(jax.device_get(ref_counts)))
+            got, want = _index_leaves(cache), _index_leaves(ref_cache)
+            assert got.keys() == want.keys()
+            for name in want:
+                np.testing.assert_array_equal(got[name], want[name])
+            ref_last = np.where(active, ref_tok, ref_last).astype(np.int32)
+            last = ref_last.copy()
+            horizon = horizon - active
+
+
+def test_default_scheduler_compiles_only_the_megastep_program(mesh_dp):
+    """One plain-decode path: with default options (``megastep=1``,
+    ``async_decode=False``) every decode program the scheduler compiled
+    is a ``slot_megastep`` — the K=1 one — and the single-step reference
+    ``decode_slots`` is never launched by it."""
+    with ServeEngine("gpt2", mesh=mesh_dp, preset="tiny") as eng:
+        reqs = _mixed_requests(eng.module.cfg.vocab_size, seed=23)
+        with ContinuousScheduler(eng, num_slots=8,
+                                 max_total_len=32) as sched:
+            outs = _run_all(sched, reqs)
+            stats = sched.stats()
+        kinds = collections.Counter(
+            k[0] for k in eng._generate_fns
+            if isinstance(k, tuple) and str(k[0]).startswith("slot_"))
+        assert set(kinds) == {"slot_prefill", "slot_megastep"}
+        assert ("slot_megastep", 1, None) in eng._generate_fns
+        assert kinds["slot_megastep"] == 1
+        names = {fn.__name__ for fn in eng._generate_fns.values()}
+        assert "decode_megastep" in names and "decode_slots" not in names
+        assert stats["megastep_launches"] == stats["iterations"] > 0
+        assert stats["megastep_effective_steps"] == stats["iterations"]
+        for (prompt, horizon), out in zip(reqs, outs):
+            np.testing.assert_array_equal(
+                out, _fixed_reference(eng, prompt, horizon))
 
 
 class TestMegastepParity:

@@ -336,8 +336,9 @@ class TestHeterogeneousScheduler:
 
     def test_mixed_configs_share_one_program_set(self, gpt2_engine):
         """THE tentpole claim: N distinct sampling configs in one batch
-        compile exactly one slot_prefill and one slot_decode program,
-        and a second wave of fresh configs compiles NOTHING."""
+        compile exactly one slot_prefill and one decode program (the
+        K=1 megastep, the scheduler's only plain-decode launch), and a
+        second wave of fresh configs compiles NOTHING."""
         vocab = gpt2_engine.module.cfg.vocab_size
         rng = np.random.default_rng(2)
         prompts = [rng.integers(0, vocab, size=(4 + i % 3,), dtype=np.int32)
@@ -359,7 +360,7 @@ class TestHeterogeneousScheduler:
             stats = sched.stats()
         keys = _slot_program_keys(gpt2_engine)
         assert keys.count(("slot_prefill", None)) == 1
-        assert keys.count(("slot_decode", None)) == 1
+        assert keys.count(("slot_megastep", 1, None)) == 1
         assert (gpt2_engine.compile_stats()["compile_total"]
                 == total_after_wave1)
         assert stats["programs_cached"] >= 2
@@ -431,8 +432,8 @@ class TestHeterogeneousScheduler:
                 f.result(timeout=300)
         np.testing.assert_array_equal(out, ref)
         keys = _slot_program_keys(gpt2_engine)
-        paged_decode = [k for k in keys if k[0] == "slot_decode"
-                        and k[1] is not None]
+        paged_decode = [k for k in keys if k[:2] == ("slot_megastep", 1)
+                        and k[2] is not None]
         assert len(paged_decode) == 1
 
     def test_seeded_stream_reproduces_across_everything(self, gpt2_engine):

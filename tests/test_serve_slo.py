@@ -2,7 +2,7 @@
 starvation aging, and host-RAM KV tiering (preempt -> swap -> resume)
 under deliberate block pressure.
 
-The preemption scenario mirrors the bench's ``slo`` arm: a low-priority
+The preemption scenario: a low-priority
 whale decodes in a pool sized so one resident whale leaves LESS than one
 short request's worth of free blocks — a high-priority short can only
 run by evicting the whale.  Greedy decode on CPU is deterministic, so

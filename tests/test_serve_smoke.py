@@ -1,7 +1,7 @@
 """Smoke tests for the serve entrypoints' driver contract: ONE parseable
-JSON line from ``serve.py`` and from ``bench.py --mode=serve``.
+JSON line from ``serve.py``.
 
-Marked ``slow`` (excluded from tier-1, like test_bench_smoke.py) — each
+Marked ``slow`` (excluded from tier-1) — each
 subprocess compiles the tiny GPT-2 prefill + decode programs cold.  The
 continuous-batching entrypoint smokes additionally carry ``serve_slow``
 (they compile one slot-prefill program per distinct prompt length on top
@@ -173,95 +173,3 @@ def test_serve_entrypoint_sampling_mix_prints_one_json_line():
     assert out["compile_post_warmup"] == 0
     assert 0 < out["programs_cached"] <= 4
     assert out["compile_total"] == out["programs_cached"]
-
-
-@pytest.mark.slow
-@pytest.mark.serve_slow
-def test_bench_serve_mode_prints_one_json_line():
-    out = _run([os.path.join(REPO, "bench.py"), "--mode=serve",
-                "--serve_requests=16"])
-    for key in ("metric", "value", "unit", "device", "preset",
-                "p50_latency_ms", "p99_latency_ms",
-                "ttft_p50_ms", "tpot_mean_ms", "slot_occupancy",
-                "fixed_tokens_per_sec", "continuous_speedup",
-                "paged_tokens_per_sec", "paged_speedup",
-                "paged_int8_tokens_per_sec", "kv_hbm_bytes",
-                "kv_hbm_ratio_paged", "kv_hbm_ratio_paged_int8",
-                "block_size", "num_blocks", "block_utilization",
-                "queue_wait_p50_ms", "queue_wait_p99_ms", "trace_events"):
-        assert key in out, f"missing {key!r} in {out}"
-    assert out["unit"] == "tokens/sec"
-    assert out["value"] > 0
-    assert out["fixed_tokens_per_sec"] > 0
-    assert out["paged_tokens_per_sec"] > 0
-    assert "serve_tokens_per_sec" in out["metric"]
-    # the trace-export smoke: the bench runs with the flight recorder on,
-    # so the continuous runs must have recorded per-request spans
-    assert out["trace_events"] > 0
-    assert out["queue_wait_p99_ms"] >= out["queue_wait_p50_ms"] >= 0
-    # the memory claim: paged <= 0.5x dense cache bytes, int8 <= 0.25x
-    assert out["kv_hbm_bytes"]["paged"] < out["kv_hbm_bytes"]["dense"]
-    assert out["kv_hbm_ratio_paged"] <= 0.5
-    assert out["kv_hbm_ratio_paged_int8"] <= 0.25
-    # the prefix-caching claim: shared-prefix traffic hits the cache and
-    # the warm run's greedy tokens are bit-identical to the cold run's
-    for key in ("prefix_hit_rate", "prefill_tokens_skipped",
-                "ttft_speedup_prefix", "prefix_parity"):
-        assert key in out, f"missing {key!r} in {out}"
-    assert out["prefix_hit_rate"] > 0
-    assert out["prefill_tokens_skipped"] > 0
-    assert out["prefix_parity"] is True
-    # the chunked-prefill claim: the skewed whale mix's inter-token gap
-    # p99 improves (or at worst matches), the whale actually chunked, and
-    # greedy output is bit-identical budget on vs off — alone and
-    # composed with the prefix cache and the per-shard pool
-    for key in ("tpot_p99_unchunked", "tpot_p99_chunked",
-                "unchunked_tokens_per_sec", "chunked_tokens_per_sec",
-                "chunked_prefill_budget"):
-        assert key in out, f"missing {key!r} in {out}"
-    assert out["tpot_p99_speedup_chunked"] >= 1.0
-    assert out["chunked_prefill_chunks"] > 0
-    assert out["chunked_parity"] is True
-    assert out["chunked_prefix_parity"] is True
-    assert out["chunked_prefix_skip_parity"] is True
-    assert out["chunked_pershard_parity"] is True
-    # the megastep claim: K fused decode steps per dispatch beat (or at
-    # worst match) the per-token launch on the same traffic, at the same
-    # greedy checksum
-    for key in ("megastep", "megastep_tokens_per_sec",
-                "megastep_base_tokens_per_sec", "megastep_launches",
-                "megastep_base_launches"):
-        assert key in out, f"missing {key!r} in {out}"
-    assert out["megastep"] == 8
-    assert out["megastep_parity"] is True
-    assert out["megastep_speedup"] >= 1.0
-    assert out["megastep_launches"] < out["megastep_base_launches"]
-    # the speculative-decoding claim: on the repetitive mix the drafter
-    # lands, the verifier emits more than one token per launch
-    # (steps-per-token speedup > 1), and greedy output stays
-    # bit-identical spec on vs off — alone and composed with chunked
-    # prefill, the megastep, and the prefix cache
-    for key in ("spec_k", "spec_steps_per_token",
-                "spec_base_steps_per_token", "spec_launches",
-                "spec_drafted", "spec_accepted"):
-        assert key in out, f"missing {key!r} in {out}"
-    assert out["spec_k"] == 4
-    assert out["spec_parity"] is True
-    assert out["spec_acceptance_rate"] > 0
-    assert out["spec_speedup"] >= 1.0
-    assert out["spec_steps_per_token"] < out["spec_base_steps_per_token"]
-    assert out["spec_chunked_parity"] is True
-    assert out["spec_megastep_parity"] is True
-    assert out["spec_prefix_parity"] is True
-    # the vectorized-sampling claim: a heterogeneous per-request mix
-    # runs on ONE compiled program set (zero post-warmup compiles),
-    # while the scalar fixed-batch path pays one program set per config
-    for key in ("sampling_mix", "sampling_configs",
-                "sampling_tokens_per_sec", "sampling_programs_cached",
-                "sampling_compile_post_warmup",
-                "sampling_scalar_program_sets"):
-        assert key in out, f"missing {key!r} in {out}"
-    assert out["sampling_configs"] == 3
-    assert out["sampling_compile_post_warmup"] == 0
-    assert out["sampling_scalar_program_sets"] == 3
-    assert out["sampling_tokens_per_sec"] > 0

@@ -37,6 +37,7 @@ from distributed_tensorflow_tpu.data.pipeline import (
 )
 from distributed_tensorflow_tpu.models import Workload
 from distributed_tensorflow_tpu.ops import flash_attention
+from distributed_tensorflow_tpu.ops.flash_attention import REMAT_POLICY
 from distributed_tensorflow_tpu.parallel.ring_attention import ring_attention
 from distributed_tensorflow_tpu.parallel.sharding import (
     P,
@@ -184,7 +185,8 @@ class BertPretrain(nn.Module):
         x = nn.Dropout(cfg.dropout, deterministic=deterministic)(x)
         x = x.astype(cfg.dtype)
         if cfg.scan_layers:
-            body = (nn.remat(EncoderLayer, prevent_cse=False)
+            body = (nn.remat(EncoderLayer, prevent_cse=False,
+                             policy=REMAT_POLICY)
                     if cfg.remat else EncoderLayer)
             Scanned = nn.scan(
                 body,

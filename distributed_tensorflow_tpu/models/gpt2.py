@@ -38,9 +38,9 @@ from distributed_tensorflow_tpu.data.pipeline import synthetic_lm
 from distributed_tensorflow_tpu.ops import flash_attention, paged_attention
 from distributed_tensorflow_tpu.parallel.ring_attention import ring_attention
 from distributed_tensorflow_tpu.models import Workload
+from distributed_tensorflow_tpu.ops.flash_attention import REMAT_POLICY
 from distributed_tensorflow_tpu.parallel.sharding import (
-    P,
-    ShardingRules,
+    P, ShardingRules,
     transformer_rules,
 )
 
@@ -66,9 +66,9 @@ class GPT2Config:
     # share of GPT-2 step time at unroll=1 in profiles that predate the
     # current chip attachment).
     scan_unroll: int = 1
-    # Rematerialize each block in backward (jax.checkpoint): trades ~30%
-    # more FLOPs for activation memory ~ O(sqrt) — the TPU-native answer to
-    # the reference's gradient-accumulation-for-memory config.
+    # Rematerialize each block in backward (jax.checkpoint), all of it but
+    # the flash kernel's output and log-sum-exp (ops.flash_attention's
+    # REMAT_POLICY): the kernel is the dearest part per byte to run twice.
     remat: bool = True
     # Pallas fused attention (ops.flash_attention).  Attention-prob dropout
     # runs in-kernel (TPU PRNG), matching the dense path's recipe.
@@ -653,8 +653,8 @@ class GPT2(nn.Module):
         elif cfg.scan_layers:
             # No remat in decode: there is no backward pass, and remat's
             # lifted scope rejects the mutable cache writes.
-            use_remat = cfg.remat and not decode
-            body = nn.remat(Block, prevent_cse=False) if use_remat else Block
+            body = Block if decode or not cfg.remat else nn.remat(
+                Block, prevent_cse=False, policy=REMAT_POLICY)
             # Paged decode CARRIES the cache through the layer loop: each
             # layer updates the stacked (L, ...) pools in place at its own
             # index.  Scanned over axis 0 they would be sliced per layer
@@ -740,7 +740,8 @@ def _pipe_stage_fn(cfg, mesh):
             return h, None
 
         if cfg.remat:
-            body = jax.checkpoint(body, prevent_cse=False)
+            body = jax.checkpoint(body, prevent_cse=False,
+                                  policy=REMAT_POLICY)
         h, _ = lax.scan(body, h, stage_params)
         return h
 

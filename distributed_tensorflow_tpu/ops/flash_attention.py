@@ -54,6 +54,24 @@ Design (round-4 schedule — FlashAttention-2 style grid streaming):
   ring attention consumes per key block.  Dropout composes exactly with
   the ring combine (l/lse always use undropped probabilities), so the
   with_lse path supports it too — each block pair seeded distinctly.
+- What a remat'd layer keeps.  A layer under ``remat`` would run the
+  forward kernel a second time in the backward pass, only to rebuild the two
+  residuals the backward kernels need of it: the output and the
+  log-sum-exp.  Per byte they are the dearest part of a layer to make again
+  (the kernel runs far under its roofline where the layer's matmuls run near
+  half the peak), so the forward rules name them (``flash_out``,
+  ``flash_lse``) and the models' remat takes ``REMAT_POLICY``, which saves
+  those names and nothing else: q, k, v and the rest of the layer are still
+  recomputed.  The dense fallback has nothing under the names and keeps
+  whole-layer remat.
+- The log-sum-exp is one f32 a query in HBM: a (1, T) row a head, which the
+  forward kernel writes and the backward kernels read by (1, block_q)
+  blocks and turn into the (block_q, 1) column a score tile needs with a
+  transpose in VMEM (``_row``/``_col``).  Broadcast over the 128 lanes, as
+  the kernels once wrote it, it was 67 MB a call for GPT-2 medium at
+  8 x 1024, four times the attention output and half of what the three
+  calls moved through HBM, and far too much to keep from one pass to the
+  other.
 - Non-TPU platforms take the dense XLA path with identical numerics (f32
   softmax); its backward is XLA autodiff.  Its dropout uses ``jax.random``
   — same distribution, different mask realization than the kernel PRNG
@@ -79,6 +97,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 logger = logging.getLogger(__name__)
@@ -91,7 +110,29 @@ logger = logging.getLogger(__name__)
 # Shorter sequences clamp to T (min below), so small models are unaffected.
 BLOCK_Q = int(os.environ.get("DTT_FLASH_BLOCK_Q", "512"))
 BLOCK_K = int(os.environ.get("DTT_FLASH_BLOCK_K", "512"))
-LANES = 128  # Mosaic minimum lane tile; LSE is broadcast across it
+LANES = 128  # Mosaic minimum lane tile
+
+# What a remat round the kernel keeps of it: the forward rules name the
+# kernel's two results, and the policy saves those names and nothing else.
+FLASH_OUT, FLASH_LSE = "flash_out", "flash_lse"
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    FLASH_OUT, FLASH_LSE)
+
+
+def _named(out, lse):
+    return checkpoint_name(out, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
+
+
+def _col(row):
+    """In a kernel: a (1, n) row of per-query statistics as the (n, 1)
+    column a (block_q, block_k) score tile is broadcast against."""
+    return jnp.transpose(jnp.broadcast_to(row, (LANES, row.shape[1])))[:, :1]
+
+
+def _row(col):
+    """In a kernel: an (n, 1) column of per-query statistics as the (1, n)
+    row it is stored as: one f32 a query in HBM, not a lane tile of 128."""
+    return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], LANES)))[:1]
 
 
 def _fit_block(T: int, want: int):
@@ -273,7 +314,7 @@ def _fwd_kernel(*refs, causal, scale, block_q, block_k, save_lse,
             # contribution (ring attention's cross-block combine).
             m = m_ref[...][:, :1]
             lse = jnp.where(l > 0, m + jnp.log(l_safe), -1e30)
-            lse_ref[0] = jnp.broadcast_to(lse, (block_q, LANES))
+            lse_ref[0] = _row(lse)
 
 
 # ---------------------------------------------------------------------------
@@ -347,7 +388,7 @@ def _fwd_kernel_resident(*refs, seq_len, causal, scale, block_q, block_k,
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
     if save_lse:
         lse = jnp.where(l > 0, m + jnp.log(l_safe), -1e30)
-        lse_ref[0] = jnp.broadcast_to(lse, (block_q, LANES))
+        lse_ref[0] = _row(lse)
 
 
 def _dq_kernel_resident(q_ref, k_ref, v_ref, o_ref, g_ref, lse_ref, *rest,
@@ -365,13 +406,13 @@ def _dq_kernel_resident(q_ref, k_ref, v_ref, o_ref, g_ref, lse_ref, *rest,
     q = q_ref[0]                              # (block_q, D), input dtype
     g = g_ref[0]                              # (block_q, D)
     o = o_ref[0]                              # (block_q, D)
-    lse = lse_ref[0][:, :1]                   # (block_q, 1)
+    lse = _col(lse_ref[0])                    # (block_q, 1)
     delta = jnp.sum(                          # Δ = rowsum(dO ∘ O), f32
         g.astype(jnp.float32) * o.astype(jnp.float32),
         axis=-1, keepdims=True,
     )
     if has_glse:
-        delta = delta - glse_ref[0][:, :1]
+        delta = delta - _col(glse_ref[0])
     D = q.shape[-1]
 
     num_k_blocks = pl.cdiv(seq_len, block_k)
@@ -435,18 +476,26 @@ def _dkv_kernel_resident(q_ref, k_ref, v_ref, o_ref, g_ref, lse_ref, *rest,
     if has_mask:
         my_mask = mask_ref[0, :, pl.ds(ki * block_k, block_k)]
 
+    def stat_rows(ref, i):
+        """Query block i's (1, block_q) of a resident (1, T) row.  A
+        sequence of one block (T <= 128) need not be lane-aligned, and a
+        slice that Mosaic cannot prove aligned is refused: take it whole."""
+        if seq_len == block_q:
+            return ref[0]
+        return ref[0, :, pl.ds(i * block_q, block_q)]
+
     def body(i, carry):
         dk_acc, dv_acc = carry
         q_blk = q_ref[0, pl.ds(i * block_q, block_q), :]
         g_blk = g_ref[0, pl.ds(i * block_q, block_q), :]
         o_blk = o_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(i * block_q, block_q), :1]
+        lse = _col(stat_rows(lse_ref, i))
         delta = jnp.sum(
             g_blk.astype(jnp.float32) * o_blk.astype(jnp.float32),
             axis=-1, keepdims=True,
         )
         if has_glse:
-            delta = delta - glse_ref[0, pl.ds(i * block_q, block_q), :1]
+            delta = delta - _col(stat_rows(glse_ref, i))
         s = jax.lax.dot_general(
             q_blk, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -503,6 +552,9 @@ def _resident_kv_bytes(T, D, itemsize):
 
 
 def _resident_dkv_bytes(T, D, itemsize, has_glse):
+    # The lse (+ g_lse) term is the (T, 128) f32 window of the measurements
+    # above; the window is a (1, T) row now, and the term stays so that the
+    # cutoff stays where it was measured.
     win = 3 * T * D * itemsize + T * LANES * 4 * (2 if has_glse else 1)
     return 2 * win  # q/o/g + lse (+ g_lse) windows, double-buffered
 
@@ -534,7 +586,7 @@ def _seed_operand(dropout_rng):
 
 def _flash_fwd_tpu(q, k, v, kv_mask, *, causal, scale, save_lse,
                    dropout_rate=0.0, seed=None):
-    """Returns out (B,T,H,D), and lse (B·H, T, LANES) f32 if save_lse."""
+    """Returns out (B,T,H,D), and lse (B, H, T) f32 if save_lse."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -557,7 +609,7 @@ def _flash_fwd_tpu(q, k, v, kv_mask, *, causal, scale, save_lse,
             pl.BlockSpec((1, T, D), lambda b, i: (b, 0, 0)),  # V resident
         ]
         mask_spec = pl.BlockSpec((1, 1, T), lambda b, i: (b // H, 0, 0))
-        lse_spec = pl.BlockSpec((1, block_q, LANES), lambda b, i: (b, i, 0))
+        lse_spec = pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i))
         kernel = functools.partial(
             _fwd_kernel_resident, seq_len=T, causal=causal, scale=scale,
             block_q=block_q, block_k=block_k, save_lse=save_lse,
@@ -576,8 +628,8 @@ def _flash_fwd_tpu(q, k, v, kv_mask, *, causal, scale, save_lse,
         ]
         mask_spec = pl.BlockSpec((1, 1, block_k),
                                  lambda b, i, j: (b // H, 0, j))
-        lse_spec = pl.BlockSpec((1, block_q, LANES),
-                                lambda b, i, j: (b, i, 0))
+        lse_spec = pl.BlockSpec((1, 1, block_q),
+                                lambda b, i, j: (b, 0, i))
         kernel = functools.partial(
             _fwd_kernel, causal=causal, scale=scale,
             block_q=block_q, block_k=block_k, save_lse=save_lse,
@@ -600,9 +652,10 @@ def _flash_fwd_tpu(q, k, v, kv_mask, *, causal, scale, save_lse,
     out_specs = [pl.BlockSpec((1, block_q, D), qmap)]
     out_shape = [jax.ShapeDtypeStruct((B * H, T, D), q.dtype)]
     if save_lse:
+        # One f32 a query, as a (1, T) row a head (the singleton keeps
+        # the block's sublane dim tileable, as for the mask).
         out_specs.append(lse_spec)
-        out_shape.append(
-            jax.ShapeDtypeStruct((B * H, T, LANES), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32))
     res = pl.pallas_call(
         kernel,
         grid=grid,
@@ -618,7 +671,7 @@ def _flash_fwd_tpu(q, k, v, kv_mask, *, causal, scale, save_lse,
     )(*operands)
     out = _from_heads(res[0], B, H)
     if save_lse:
-        return out, res[1]
+        return out, res[1].reshape(B, H, T)
     return out, None
 
 
@@ -651,14 +704,14 @@ def _bwd_dq_kernel(*refs, causal, scale, block_q, block_k,
         q = q_ref[0]                          # (block_q, D), input dtype
         g = g_ref[0]                          # (block_q, D)
         o = o_ref[0]                          # (block_q, D)
-        lse = lse_ref[0][:, :1]               # (block_q, 1)
+        lse = _col(lse_ref[0])                # (block_q, 1)
         delta = jnp.sum(                      # Δ = rowsum(dO ∘ O), f32
             g.astype(jnp.float32) * o.astype(jnp.float32),
             axis=-1, keepdims=True,
         )
         if has_glse:
             # dS gains + g_lse ∘ P (∂lse/∂s = P): fold into Δ subtraction.
-            delta = delta - glse_ref[0][:, :1]
+            delta = delta - _col(glse_ref[0])
         k_blk = k_ref[0]
         v_blk = v_ref[0]
         s = jax.lax.dot_general(
@@ -721,13 +774,13 @@ def _bwd_dkv_kernel(*refs, causal, scale, block_q, block_k,
         q_blk = q_ref[0]                      # (block_q, D)
         g_blk = g_ref[0]
         o_blk = o_ref[0]
-        lse = lse_ref[0][:, :1]
+        lse = _col(lse_ref[0])
         delta = jnp.sum(
             g_blk.astype(jnp.float32) * o_blk.astype(jnp.float32),
             axis=-1, keepdims=True,
         )
         if has_glse:
-            delta = delta - glse_ref[0][:, :1]
+            delta = delta - _col(glse_ref[0])
         s = jax.lax.dot_general(
             q_blk, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -783,6 +836,10 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
     gh, oh = _to_heads(g), _to_heads(o)
     mask_op = (kv_mask.astype(jnp.int32).reshape(B, 1, T)
                if has_mask else None)
+    # lse and its cotangent arrive (B, H, T): one (1, T) row a head.
+    lse = lse.reshape(B * H, 1, T)
+    if has_glse:
+        g_lse = g_lse.astype(jnp.float32).reshape(B * H, 1, T)
 
     common = dict(causal=causal, scale=scale,
                   block_q=block_q, block_k=block_k,
@@ -804,9 +861,10 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
             pl.BlockSpec((1, T, D), full),                   # v (resident)
             pl.BlockSpec((1, block_q, D), qmap),             # o
             pl.BlockSpec((1, block_q, D), qmap),             # g
-            pl.BlockSpec((1, block_q, LANES), qmap),         # lse
+            pl.BlockSpec((1, 1, block_q),                    # lse
+                         lambda b, i: (b, 0, i)),
         ]
-        dq_glse_spec = pl.BlockSpec((1, block_q, LANES), qmap)
+        dq_glse_spec = dq_in_specs[-1]
         dq_mask_spec = pl.BlockSpec((1, 1, T), lambda b, i: (b // H, 0, 0))
         dq_kernel = functools.partial(_dq_kernel_resident, seq_len=T,
                                       **common)
@@ -823,9 +881,10 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
             pl.BlockSpec((1, block_k, D), kmap),             # v
             pl.BlockSpec((1, block_q, D), qmap),             # o
             pl.BlockSpec((1, block_q, D), qmap),             # g
-            pl.BlockSpec((1, block_q, LANES), qmap),         # lse
+            pl.BlockSpec((1, 1, block_q),                    # lse
+                         lambda b, i, j: (b, 0, i)),
         ]
-        dq_glse_spec = pl.BlockSpec((1, block_q, LANES), qmap)
+        dq_glse_spec = dq_in_specs[-1]
         dq_mask_spec = pl.BlockSpec((1, 1, block_k),
                                     lambda b, i, j: (b // H, 0, j))
         dq_kernel = functools.partial(_bwd_dq_kernel, **common)
@@ -870,9 +929,9 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
             pl.BlockSpec((1, block_k, D), kv_self),          # v
             pl.BlockSpec((1, T, D), full),                   # o (resident)
             pl.BlockSpec((1, T, D), full),                   # g (resident)
-            pl.BlockSpec((1, T, LANES), full),               # lse (resident)
+            pl.BlockSpec((1, 1, T), full),                   # lse (resident)
         ]
-        dkv_glse_spec = pl.BlockSpec((1, T, LANES), full)
+        dkv_glse_spec = dkv_in_specs[-1]
         dkv_mask_spec = pl.BlockSpec((1, 1, T), lambda b, ki: (b // H, 0, 0))
         dkv_kernel = functools.partial(_dkv_kernel_resident, seq_len=T,
                                        **common)
@@ -892,9 +951,10 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
             pl.BlockSpec((1, block_k, D), kv_self),          # v
             pl.BlockSpec((1, block_q, D), q_stream),         # o
             pl.BlockSpec((1, block_q, D), q_stream),         # g
-            pl.BlockSpec((1, block_q, LANES), q_stream),     # lse
+            pl.BlockSpec((1, 1, block_q),                    # lse
+                         lambda b, ki, i: (b, 0, i)),
         ]
-        dkv_glse_spec = pl.BlockSpec((1, block_q, LANES), q_stream)
+        dkv_glse_spec = dkv_in_specs[-1]
         dkv_mask_spec = pl.BlockSpec((1, 1, block_k),
                                      lambda b, ki, i: (b // H, 0, ki))
         dkv_kernel = functools.partial(_bwd_dkv_kernel, **common)
@@ -1004,6 +1064,7 @@ def _flash_fwd(q, k, v, kv_mask, seed, causal, scale, dropout_rate):
         out, lse = _flash_fwd_tpu(q, k, v, kv_mask, causal=causal,
                                   scale=scale, save_lse=True,
                                   dropout_rate=dropout_rate, seed=seed)
+        out, lse = _named(out, lse)
         return out, (q, k, v, kv_mask, seed, out, lse)
     return (_dense_from_seed(q, k, v, kv_mask, seed, causal=causal,
                              scale=scale, dropout_rate=dropout_rate),
@@ -1031,37 +1092,24 @@ def _flash_bwd(causal, scale, dropout_rate, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _lse_to_bht(lse_lanes, B, H):
-    """(B·H, T, LANES) broadcast layout -> (B, H, T) value layout."""
-    BH, T, _ = lse_lanes.shape
-    return lse_lanes[:, :, 0].reshape(B, H, T)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _flash_lse(q, k, v, kv_mask, seed, causal, scale, dropout_rate):
-    out, lse = _flash_fwd_tpu(q, k, v, kv_mask, causal=causal, scale=scale,
-                              save_lse=True, dropout_rate=dropout_rate,
-                              seed=seed)
-    return out, _lse_to_bht(lse, q.shape[0], q.shape[2])
+    return _flash_fwd_tpu(q, k, v, kv_mask, causal=causal, scale=scale,
+                          save_lse=True, dropout_rate=dropout_rate, seed=seed)
 
 
 def _flash_lse_fwd(q, k, v, kv_mask, seed, causal, scale, dropout_rate):
     out, lse = _flash_fwd_tpu(q, k, v, kv_mask, causal=causal, scale=scale,
                               save_lse=True, dropout_rate=dropout_rate,
                               seed=seed)
-    return ((out, _lse_to_bht(lse, q.shape[0], q.shape[2])),
-            (q, k, v, kv_mask, seed, out, lse))
+    out, lse = _named(out, lse)
+    return (out, lse), (q, k, v, kv_mask, seed, out, lse)
 
 
 def _flash_lse_bwd(causal, scale, dropout_rate, res, cts):
     q, k, v, kv_mask, seed, o, lse = res
     g_out, g_lse = cts
-    B, T, H, D = q.shape
-    # (B, H, T) -> the kernels' (B·H, T, LANES) broadcast layout.
-    g_lse_lanes = jnp.broadcast_to(
-        g_lse.astype(jnp.float32).reshape(B * H, T, 1), (B * H, T, LANES)
-    )
-    dq, dk, dv = _flash_bwd_tpu(q, k, v, o, lse, g_out, kv_mask, g_lse_lanes,
+    dq, dk, dv = _flash_bwd_tpu(q, k, v, o, lse, g_out, kv_mask, g_lse,
                                 causal=causal, scale=scale,
                                 dropout_rate=dropout_rate, seed=seed)
     return dq, dk, dv, None, None
@@ -1100,7 +1148,7 @@ class _MeshLayout:
         b = self.batch or None
         self.qkv = P(b, None, self.head, None)   # (B, T, H, D)
         self.mask = P(b, None)                   # (B, Tk)
-        self.lse = P(b, self.head, None, None)   # (B, H, T, LANES)
+        self.lse = P(b, self.head, None)         # (B, H, T)
 
     def shard_shape(self, q) -> jax.ShapeDtypeStruct:
         """The (B, T, H, D) one shard's kernel call sees."""
@@ -1176,15 +1224,13 @@ def _split_optional(rest, lay, kv_mask, seed, q_local):
 
 
 def _sharded_fwd(lay, q, k, v, kv_mask, seed, *, save_lse, **kw):
-    """``_flash_fwd_tpu`` per shard; lse comes back as (B, H, T, LANES)."""
+    """``_flash_fwd_tpu`` per shard; lse comes back as (B, H, T)."""
 
     def local(q_, k_, v_, *rest):
         m_, s_ = _split_optional(rest, lay, kv_mask, seed, q_)
         out, lse = _flash_fwd_tpu(q_, k_, v_, m_, save_lse=save_lse,
                                   seed=s_, **kw)
-        if not save_lse:
-            return out
-        return out, lse.reshape(q_.shape[0], q_.shape[2], *lse.shape[1:])
+        return (out, lse) if save_lse else out
 
     operands, in_specs = [q, k, v], [lay.qkv] * 3
     _with_optional(operands, in_specs, lay, kv_mask, seed)
@@ -1193,11 +1239,10 @@ def _sharded_fwd(lay, q, k, v, kv_mask, seed, *, save_lse, **kw):
 
 
 def _sharded_bwd(lay, q, k, v, o, lse, g, kv_mask, seed, **kw):
-    """``_flash_bwd_tpu`` per shard (lse in the (B, H, T, LANES) layout)."""
+    """``_flash_bwd_tpu`` per shard."""
 
     def local(q_, k_, v_, o_, lse_, g_, *rest):
         m_, s_ = _split_optional(rest, lay, kv_mask, seed, q_)
-        lse_ = lse_.reshape(-1, *lse_.shape[2:])
         return _flash_bwd_tpu(q_, k_, v_, o_, lse_, g_, m_, None, seed=s_,
                               **kw)
 
@@ -1220,9 +1265,9 @@ def _flash_sharded(q, k, v, kv_mask, seed, causal, scale, dropout_rate,
 
 def _flash_sharded_fwd(q, k, v, kv_mask, seed, causal, scale, dropout_rate,
                        mesh):
-    out, lse = _sharded_fwd(_MeshLayout(mesh), q, k, v, kv_mask, seed,
-                            save_lse=True, causal=causal, scale=scale,
-                            dropout_rate=dropout_rate)
+    out, lse = _named(*_sharded_fwd(
+        _MeshLayout(mesh), q, k, v, kv_mask, seed, save_lse=True,
+        causal=causal, scale=scale, dropout_rate=dropout_rate))
     return out, (q, k, v, kv_mask, seed, out, lse)
 
 

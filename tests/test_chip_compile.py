@@ -78,8 +78,8 @@ def kernel_names(hlo):
     how a device trace tells the three apart.  The compiler names the
     instruction after the innermost scope of its ``op_name``:
     ``%flash_fwd.3`` under a module's scope, ``%jvp_flash_fwd_.1`` bare."""
-    return set(re.findall(
-        r"%\w*?(flash_(?:fwd|dq|dkv))[\w.]* = [^\n]*tpu_custom_call", hlo))
+    calls = kernel_calls(hlo)     # below, with the counts
+    return set(calls)
 
 
 def one_chip(topo, shape, dtype=jnp.bfloat16):
@@ -231,7 +231,7 @@ def test_flash_inside_pipeline_stage_compiles_on_four_chips(topo, inner):
         return y.astype(jnp.float32).sum()
 
     hlo = compiled_text(jax.grad(loss), blocks, x)
-    assert hlo.count("tpu_custom_call") >= 3
+    assert kernel_calls(hlo) == dict(flash_dkv=1, flash_dq=1, flash_fwd=1)
 
 
 # -- the server's programs: the paged KV pools are updated in place ----------
@@ -391,3 +391,68 @@ def test_serve_decode_program_fits_one_chip_at_64_slots(topo):
     memory = lowered.compile().memory_analysis()
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < V5E_HBM_BYTES
+
+
+# -- a remat'd stack runs each of the three kernels once ---------------------
+
+def kernel_calls(hlo):
+    """How many instructions carry each kernel's name."""
+    calls = re.findall(
+        r"%\w*?(flash_(?:fwd|dq|dkv))[\w.]* = [^\n]*tpu_custom_call", hlo)
+    return {name: calls.count(name) for name in sorted(set(calls))}
+
+
+def remat_stack_gpt2(mesh, sharded):
+    """Two layers of GPT-2 medium's width, scanned and remat'd by the model
+    itself (a small vocabulary: the head is not what is looked at)."""
+    from distributed_tensorflow_tpu.models import gpt2
+
+    cfg = dataclasses.replace(
+        gpt2.GPT2Config.medium(dropout=0.0, use_flash_attention=True),
+        n_layer=2, scan_unroll=1, vocab_size=1024)
+    model = gpt2.GPT2(cfg, mesh=mesh)
+    batch = {"tokens": np.zeros((8, 1024), np.int32)}
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), batch["tokens"]))["params"]
+    return (lambda p, b: gpt2._loss_fn(model, True, p, b, None)[0],
+            sharded(params, P()), sharded(batch, P("data")))
+
+
+def remat_stack_bert(mesh, sharded):
+    """The same for BERT base's encoder layer, with a key mask."""
+    from distributed_tensorflow_tpu.data.pipeline import synthetic_mlm
+    from distributed_tensorflow_tpu.models import bert
+
+    cfg = dataclasses.replace(
+        bert.BertConfig.base(dropout=0.0, use_flash_attention=True),
+        n_layer=2, scan_unroll=1, vocab_size=1024)
+    model = bert.BertPretrain(cfg, mesh=mesh)
+    batch = next(synthetic_mlm(batch_size=32, seq_len=512, vocab_size=1024))
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), batch))["params"]
+    return (lambda p, b: bert._loss_fn(model, True, p, b, None)[0],
+            sharded(params, P()), sharded(batch, P("data")))
+
+
+@pytest.mark.parametrize("axes", [{}, {"data": 2, "tensor": 2}],
+                         ids=["one-chip", "data2xtensor2"])
+@pytest.mark.parametrize("stack", [remat_stack_gpt2, remat_stack_bert],
+                         ids=["gpt2", "bert-mask"])
+def test_remat_stack_runs_each_flash_kernel_once(topo, stack, axes):
+    """Whole-block remat ran the forward kernel a second time in the
+    backward loop, only to rebuild the two arrays ``_flash_bwd`` needs; the
+    layers' remat now keeps them (``fa.REMAT_POLICY``).  Forward and backward
+    of the stack as the model builds it: one instruction a kernel, in the
+    one program and inside the four-chip ``shard_map``."""
+    mesh = described_mesh(topo, **axes)
+
+    def sharded(tree, spec):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype,
+                sharding=NamedSharding(mesh, P(*spec[:len(a.shape)]))),
+            tree)
+
+    loss, params, batch = stack(mesh if axes else None, sharded)
+    hlo = compiled_text(jax.grad(loss), params, batch)
+    assert kernel_calls(hlo) == dict(flash_dkv=1, flash_dq=1, flash_fwd=1)

@@ -70,6 +70,17 @@ class Workload:
     # randomness each step from the step rng.  Zero host cost; never
     # applied at eval.  Signature: (batch_dict, rng) -> batch_dict.
     augment_fn: Optional[Callable[[Dict[str, Any], Any], Dict[str, Any]]] = None
+    # Serving (decoder families; ``serve/engine.py`` and the continuous
+    # scheduler read these, no model by name):
+    # ``cache_rules(per_shard_pools) -> ShardingRules`` for the "cache"
+    # collection the module's ``decode=True`` calls carry.
+    cache_rules: Optional[Callable[..., ShardingRules]] = None
+    # ``cache_geometry(paged) -> dict``: what a token costs in the paged
+    # pool (values and bytes a token and layer, pool width, padding).
+    cache_geometry: Optional[Callable[[Any], Dict[str, Any]]] = None
+    # Scheduler features the family cannot serve yet -> the reason; the
+    # scheduler refuses each at construction instead of falling back.
+    serve_refusals: Dict[str, str] = dataclasses.field(default_factory=dict)
 
 
 _REGISTRY = {
@@ -77,6 +88,7 @@ _REGISTRY = {
     "resnet50": "distributed_tensorflow_tpu.models.resnet",
     "bert": "distributed_tensorflow_tpu.models.bert",
     "gpt2": "distributed_tensorflow_tpu.models.gpt2",
+    "glm4_moe_lite": "distributed_tensorflow_tpu.models.glm4_moe_lite",
     "wide_deep": "distributed_tensorflow_tpu.models.wide_deep",
 }
 

@@ -1174,4 +1174,22 @@ def make_workload(
         warmup_steps=200,
         example_key="tokens",
         init_key="tokens",
+        cache_rules=gpt2_cache_rules,
+        cache_geometry=functools.partial(_cache_geometry, cfg),
     )
+
+
+def _cache_geometry(cfg: GPT2Config, paged: PagedKVConfig) -> Dict[str, Any]:
+    """What a token costs in the paged K and V pools."""
+    itemsize = jnp.dtype(paged.storage_dtype(cfg.dtype)).itemsize
+    return {
+        "kind": "key_value",
+        "pools_per_layer": 2,
+        "values_per_token_layer": 2 * cfg.d_model,
+        "pool_width": cfg.d_model,
+        "padding_values": 0,
+        "bytes_per_token_layer": 2 * cfg.d_model * itemsize,
+        "bytes_per_token": cfg.n_layer * 2 * cfg.d_model * itemsize,
+        "pool_bytes": (cfg.n_layer * 2 * paged.num_blocks * paged.block_size
+                       * cfg.d_model * itemsize),
+    }

@@ -82,9 +82,9 @@ def note_path(path: str) -> None:
 
 
 def one_path(paths) -> str:
-    """The path of a program from its calls': the kernel's only if every
-    call took it."""
-    return KERNEL if set(paths) == {KERNEL} else GATHER
+    """The path of a program from its calls': the one every call took (the
+    kernel's only if all did), else the gather's."""
+    return next(iter(paths)) if len(set(paths)) == 1 else GATHER
 
 
 def supported(*, query_len: int, block_size: int, width: int, pool_dtype,

@@ -287,6 +287,13 @@ def _continuous_instruments(registry=None):
             "dtt_serve_megastep_size",
             "Inner decode steps fused per compiled decode launch",
             buckets=(1, 2, 4, 8, 16, 32, 64)),
+        "moe_assignments": r.counter(
+            "dtt_serve_moe_assignments_total",
+            "Router choices made in decode launches, by whether the chosen "
+            "expert is held on this device ('here') or on another chip of "
+            "the expert-parallel deployment ('absent'); counted on the "
+            "device by the expert layers and fetched with the tokens",
+            labelnames=("held",)),
         "megastep_amortized": r.counter(
             "dtt_serve_megastep_launches_amortized_total",
             "Tokens fetched beyond one per decode launch (host "
@@ -485,7 +492,8 @@ class _InflightMegastep:
     seq: int                         # _launch_seq at dispatch
     clock_dev: Any = None            # on-device iteration clock output
     # Device handles the fetch thread resolves (set at construction):
-    # (launches, clock_dev) — one ``jax.device_get`` over the pytree.
+    # (launches, clock_dev, expert counts) — one ``jax.device_get`` over
+    # the pytree.
     fetch_payload: Any = None
     # True once handed to the fetch thread; resolution then reads
     # ``fetched`` instead of fetching inline.
@@ -624,6 +632,21 @@ class ContinuousScheduler:
             raise ValueError(
                 f"prefill_budget must be >= 0 (0 = unchunked one-shot "
                 f"prefill), got {prefill_budget}")
+        # What this model's family cannot serve yet is refused here, with
+        # the family's own reason, never fallen back from in silence.
+        asked = {
+            "dense_cache": cache_mode == "dense",
+            "kv_dtype": kv_dtype is not None,
+            "per_shard_kv": bool(per_shard_kv),
+            "slo_scheduling": bool(slo_scheduling),
+            "spec_k": bool(spec_k),
+            "prefix_cache": bool(prefix_cache),
+        }
+        for feature, reason in engine.workload.serve_refusals.items():
+            if asked.get(feature):
+                raise ValueError(
+                    f"model {engine.model!r} cannot be served with "
+                    f"{feature}: {reason}")
         self.megastep_auto = False
         if isinstance(megastep, str):
             if megastep != "auto":
@@ -902,6 +925,10 @@ class ContinuousScheduler:
         # Megastep early exit: inner steps the while_loop actually ran
         # (vs launches * K had every megastep ridden out its full span).
         self._megastep_effective_steps = 0
+        # Expert layers that count the router's choices on the device
+        # (under _lock): the decode launches' ``moe_counts`` rows summed,
+        # (expert layers, experts held + 3); None until a launch brings one.
+        self._moe_counts: Optional[np.ndarray] = None
         # Speculative decoding (under _lock): verify launches, draft
         # tokens proposed / accepted, and tokens emitted by the verify
         # path (accepted drafts + the per-slot bonus/correction token).
@@ -912,6 +939,10 @@ class ContinuousScheduler:
         self._iterations = 0
         self._decode_counter = 0  # fold_in counter for the in-step RNG
         self._occupancy_sum = 0
+        # Cached positions (prompt + generated) the rows of each plain
+        # decode launch held at its dispatch, summed: what the launch's
+        # attention has to read at the least.
+        self._live_positions_sum = 0
         self._last_occupancy = 0
         self._latencies_ms: collections.deque = collections.deque(maxlen=1024)
         self._ttft_ms: collections.deque = collections.deque(maxlen=1024)
@@ -1287,6 +1318,11 @@ class ContinuousScheduler:
                     self._occupancy_sum / (iters * self.num_slots)
                     if iters else 0.0),
                 "last_occupancy": float(self._last_occupancy),
+                # Mean cached positions a decode launch's rows held at
+                # dispatch (plain decode launches; per iteration, like
+                # slot_occupancy): the least its attention reads.
+                "decode_live_positions": (
+                    self._live_positions_sum / iters if iters else 0.0),
                 "admissions_per_iter": (
                     self._admitted / iters if iters else 0.0),
                 "retirements_per_iter": (
@@ -1378,6 +1414,7 @@ class ContinuousScheduler:
                 "decode_attention_kernel_share": (
                     attention["kernel"] / attention_launches
                     if attention_launches else 0.0),
+                **self._moe_stats_locked(),
                 # SLO scheduling: preempt/resume traffic, parked
                 # requests, host-KV-tier bytes, and TTFT-deadline
                 # goodput (fraction of deadline-carrying completions
@@ -2430,6 +2467,7 @@ class ContinuousScheduler:
         horizon = np.zeros((self.num_slots,), np.int32)
         eos_rows = np.full((self.num_slots,), -1, np.int32)
         active_slots: List[int] = []
+        live_positions = 0      # cached positions the launched rows hold
         pending: Dict[int, int] = {}
         for slot in sorted(decoding):
             req = decoding[slot]
@@ -2438,6 +2476,8 @@ class ContinuousScheduler:
             if left <= 0:
                 continue  # the rest of the horizon is already in flight
             active_slots.append(slot)
+            live_positions += (req.base_prompt_len + len(req.tokens)
+                               + inflight)
             pending[slot] = min(K, left)
             horizon[slot] = left
             if req.eos_token is not None:
@@ -2476,12 +2516,13 @@ class ContinuousScheduler:
                     clock = np.int32(self._device_clock)
             samp = self._sampling_vector(decoding)
             launches: List[Tuple[List[int], Any, Any]] = []
+            moe_devs: List[Any] = []    # one a launch, where the model counts
             for generation in sorted(by_gen):
                 slots = by_gen[generation]
                 active = np.zeros((self.num_slots,), bool)
                 active[slots] = True
                 (toks_dev, carry, steps_dev, clock, self._cache,
-                 self._counts) = (
+                 self._counts, *moe_dev) = (
                     self.engine.decode_megastep(
                         self._cache, carry, active, horizon, steps=K,
                         eos_rows=eos_rows,
@@ -2492,12 +2533,14 @@ class ContinuousScheduler:
                         **self._paged_call_kwargs()))
                 fresh = fresh_tokens = None  # the first launch merged them
                 launches.append((slots, toks_dev, steps_dev))
+                moe_devs.extend(moe_dev)
             self._dev_last_tok = carry
             self._dev_clock = clock
             self._fresh[:] = False
             with self._lock:
                 self._iterations += 1
                 self._occupancy_sum += len(active_slots)
+                self._live_positions_sum += live_positions
                 self._last_occupancy = len(active_slots)
                 self._note_dispatch_locked(dispatch_t)
                 seq = self._launch_seq
@@ -2520,7 +2563,7 @@ class ContinuousScheduler:
             # (fetched lists round-trip as unhashable 0-d arrays).
             fetch_payload=([(toks_dev, steps_dev)
                             for _, toks_dev, steps_dev in launches],
-                           clock))
+                           clock, moe_devs))
 
     def _megastep_fetch(self, rec: _InflightMegastep) -> None:
         """Fetch half: resolve a dispatched megastep — ONE (num_slots, K)
@@ -2543,7 +2586,8 @@ class ContinuousScheduler:
         per inner step, not an equal share of the host's observation
         gap (which, async, includes a whole iteration of host work)."""
         K = rec.steps
-        (outs_host, clock_host), fetch_done, waited = self._rec_result(rec)
+        (outs_host, clock_host, moe_host), fetch_done, waited = (
+            self._rec_result(rec))
         fetched = [(slots, toks, int(steps))
                    for (slots, _, _), (toks, steps)
                    in zip(rec.launches, outs_host)]
@@ -2609,8 +2653,43 @@ class ContinuousScheduler:
             saved = appended - len(rec.launches)
             if saved > 0:
                 self._obs["megastep_amortized"].inc(saved)
+            for rows in moe_host:
+                self._note_moe_counts_locked(np.asarray(rows, np.int64))
             self._note_fetch_done_locked(rec.seq, fetch_done)
             self._obs["device_idle"].set(self._idle_fraction_locked())
+
+    def _note_moe_counts_locked(self, rows: np.ndarray) -> None:
+        """One decode launch's ``moe_counts`` rows: (expert layers, experts
+        held + 3), the last three columns the assignments to experts held
+        elsewhere, the held experts that got a token (summed over steps)
+        and the steps counted."""
+        if self._moe_counts is None:
+            self._moe_counts = np.zeros_like(rows)
+        self._moe_counts += rows
+        self._obs["moe_assignments"].labels(held="here").inc(
+            int(rows[:, :-3].sum()))
+        self._obs["moe_assignments"].labels(held="absent").inc(
+            int(rows[:, -3].sum()))
+
+    def _moe_stats_locked(self) -> Dict[str, float]:
+        """The expert layers' load as the decode launches counted it; all
+        zero for a model that counts nothing."""
+        keys = ("moe_experts_held", "moe_assignments_here",
+                "moe_assignments_absent", "moe_active_experts_per_step",
+                "moe_layer_steps", "moe_load_max_over_mean")
+        if self._moe_counts is None:
+            return dict.fromkeys(keys, 0.0)
+        tokens = self._moe_counts[:, :-3].astype(np.float64)
+        absent, active, steps = (self._moe_counts[:, -3:].sum(axis=0)
+                                 .astype(np.float64))
+        loaded = tokens[tokens.sum(axis=1) > 0]
+        return dict(zip(keys, (
+            float(tokens.shape[1]), float(tokens.sum()), float(absent),
+            float(active / steps) if steps else 0.0, float(steps),
+            # Per layer, the busiest held expert's tokens over the held
+            # experts' mean; the layers' mean.
+            float(np.mean(loaded.max(axis=1) / loaded.mean(axis=1)))
+            if len(loaded) else 0.0)))
 
     def _fetch_host(self, value):
         """THE loop thread's host-fetch point for launch outputs: one

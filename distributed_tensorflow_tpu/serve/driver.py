@@ -216,18 +216,29 @@ class ServeArgs:
     lifecycle_log: str = ""
 
 
+# Models served through the KV-cache decode path (generate and the slot
+# programs), each with its presets where no --preset is given: (the CPU
+# smoke's config, the chip's).  For glm4_moe_lite the chip's is one chip's
+# share of an 8-chip deployment (the whole model is 60 GB in bfloat16).
+# Every other model is batched classification.
+_AUTO_PRESETS = {"gpt2": ("tiny", "medium"),
+                 "glm4_moe_lite": ("tiny", "v5e8_share")}
+DECODER_MODELS = tuple(_AUTO_PRESETS)
+
+
 def _auto_preset(args: ServeArgs) -> Optional[str]:
     if args.preset:
         return args.preset
-    if args.model != "gpt2":
+    if args.model not in _AUTO_PRESETS:
         return None
     # CPU smoke serves the test config; real TPUs serve the paper's model.
     # The choice is logged and the JSON line carries ``preset`` and
     # ``device``, so a tiny CPU run can never be read as the chip.
     platform = cluster_lib.device_summary()["platform"]
-    preset = "medium" if platform == "tpu" else "tiny"
-    logger.info("no --preset given: serving gpt2 %r on platform %r",
-                preset, platform)
+    on_cpu, on_chip = _AUTO_PRESETS[args.model]
+    preset = on_chip if platform == "tpu" else on_cpu
+    logger.info("no --preset given: serving %s %r on platform %r",
+                args.model, preset, platform)
     return preset
 
 
@@ -294,7 +305,7 @@ def _make_requests(args: ServeArgs, engine: ServeEngine,
     max_new_tokens) tuples — both paths serve the SAME mixed traffic.
     ``sampling_mix`` upgrades them to dicts carrying each request's own
     ``SamplingParams`` (same prompts, same horizons)."""
-    if args.model == "gpt2":
+    if args.model in DECODER_MODELS:
         vocab = engine.module.cfg.vocab_size
         lens = _prompt_lengths(args)
         horizons = _horizons(args)
@@ -386,7 +397,7 @@ def _make_batcher(args: ServeArgs, engine: ServeEngine,
                   lifecycle=None) -> DynamicBatcher:
     """The scheduling discipline behind one run: fixed buckets or
     iteration-level streaming into a continuous scheduler."""
-    if args.model != "gpt2":
+    if args.model not in DECODER_MODELS:
         return DynamicBatcher(
             engine.classify_batch,
             max_batch_size=args.max_batch_size,
@@ -503,7 +514,7 @@ def _resolve_megastep(args: ServeArgs, engine: ServeEngine,
     must not move because K was chosen dynamically."""
     if args.megastep != "auto":
         return int(args.megastep)
-    if args.model != "gpt2" or not args.continuous:
+    if args.model not in DECODER_MODELS or not args.continuous:
         raise ValueError(
             "--megastep=auto autotunes the continuous gpt2 decode loop "
             "(--continuous); fixed-batch decode has no megastep")
@@ -549,7 +560,7 @@ def _warm(args: ServeArgs, engine: ServeEngine, payloads) -> None:
     """Compile outside the timed window: the fixed path warms the padded
     full-batch prefill+decode programs; the continuous path warms the
     slot prefill (per prompt length) and the (num_slots, 1) step."""
-    if args.model != "gpt2":
+    if args.model not in DECODER_MODELS:
         engine.classify_batch(payloads[: min(len(payloads),
                                              args.max_batch_size)])
         return
@@ -709,12 +720,18 @@ def _drive_loadgen(args: ServeArgs, engine: ServeEngine, batcher,
 
 
 def _drive(args: ServeArgs, engine: ServeEngine) -> Dict[str, Any]:
-    if args.sampling_mix and not (args.model == "gpt2" and args.continuous):
+    no_dense = engine.workload.serve_refusals.get("dense_cache")
+    if no_dense and not (args.continuous and args.cache_mode == "paged"):
+        raise ValueError(
+            f"model {args.model!r} is served with --continuous "
+            f"--cache_mode=paged only: {no_dense}")
+    if args.sampling_mix and not (args.model in DECODER_MODELS
+                                  and args.continuous):
         raise ValueError(
             "--sampling_mix requires the continuous gpt2 path "
             "(--continuous); per-request sampling rides the slot "
             "programs' runtime vectors")
-    if args.slo_scheduling and not (args.model == "gpt2"
+    if args.slo_scheduling and not (args.model in DECODER_MODELS
                                     and args.continuous):
         raise ValueError(
             "--slo_scheduling requires the continuous gpt2 path "
@@ -722,7 +739,7 @@ def _drive(args: ServeArgs, engine: ServeEngine) -> Dict[str, Any]:
             "ranking or preemption")
     lifecycle = None
     if args.loadgen_trace or args.lifecycle_log:
-        if not (args.model == "gpt2" and args.continuous
+        if not (args.model in DECODER_MODELS and args.continuous
                 and args.num_replicas == 1):
             raise ValueError(
                 "--loadgen_trace / --lifecycle_log require the "
@@ -743,7 +760,7 @@ def _drive(args: ServeArgs, engine: ServeEngine) -> Dict[str, Any]:
         # sees a dynamic K.
         args = dataclasses.replace(
             args, megastep=_resolve_megastep(args, engine, payloads))
-    is_lm = args.model == "gpt2"
+    is_lm = args.model in DECODER_MODELS
     fleet = is_lm and args.continuous and args.num_replicas > 1
     if args.num_replicas > 1 and not fleet:
         raise ValueError(

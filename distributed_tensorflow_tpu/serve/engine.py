@@ -475,7 +475,7 @@ class ServeEngine:
         """Paged decode launches so far by the launched program's
         attention path (the process-wide counter, like ``compile_stats``)."""
         return {path: self._obs["decode_attention"].labels(path=path).value
-                for path in (paged_attention.KERNEL, paged_attention.GATHER)}
+                for path in _DECODE_PATHS}
 
     def _count_decode_launch(self, key) -> None:
         traced = self._attention_paths.get(key)
@@ -510,7 +510,7 @@ class ServeEngine:
     def init_cache(self, batch: int, total_len: int) -> PyTree:
         """Preallocated, sharded KV cache for ``batch`` rows of up to
         ``total_len`` (prompt + generated) tokens."""
-        from distributed_tensorflow_tpu.models.gpt2 import gpt2_cache_rules
+        cache_rules = self._cache_rules()  # the workload's, by no model's name
 
         key = (batch, total_len)
         if key not in self._cache_init_fns:
@@ -523,7 +523,7 @@ class ServeEngine:
                 return vs["cache"]
 
             shapes = jax.eval_shape(mk)
-            shardings = gpt2_cache_rules().shardings_for(self.mesh, shapes)
+            shardings = cache_rules().shardings_for(self.mesh, shapes)
             self._cache_init_fns[key] = jax.jit(
                 _named("cache_init", lambda: jax.tree.map(
                     lambda s: jnp.zeros(s.shape, s.dtype), shapes)),
@@ -539,7 +539,7 @@ class ServeEngine:
         ``(num_slots,)`` ``cache_index``/``position`` vectors (the model's
         ``slot_ids`` path), sharded exactly like the fixed-batch cache
         (slots over the data axes, heads over ``tensor``)."""
-        from distributed_tensorflow_tpu.models.gpt2 import gpt2_cache_rules
+        cache_rules = self._cache_rules()  # the workload's, by no model's name
 
         dp = max(1, self.data_parallelism)
         if num_slots < 1 or num_slots % dp:
@@ -564,7 +564,7 @@ class ServeEngine:
                 return vs["cache"]
 
             shapes = jax.eval_shape(mk)
-            shardings = gpt2_cache_rules().shardings_for(self.mesh, shapes)
+            shardings = cache_rules().shardings_for(self.mesh, shapes)
             self._cache_init_fns[key] = jax.jit(
                 _named("slot_cache_init", lambda: jax.tree.map(
                     lambda s: jnp.zeros(s.shape, s.dtype), shapes)),
@@ -611,7 +611,7 @@ class ServeEngine:
                 f"{shard_note} "
                 f"(block_size {paged.block_size} x max_total_len "
                 f"{total_len}) plus the reserved trash block")
-        from distributed_tensorflow_tpu.models.gpt2 import gpt2_cache_rules
+        cache_rules = self._cache_rules()  # the workload's, by no model's name
 
         key = ("paged", num_slots, total_len, paged)
         if key not in self._cache_init_fns:
@@ -629,7 +629,7 @@ class ServeEngine:
                 return vs["cache"]
 
             shapes = jax.eval_shape(mk)
-            shardings = gpt2_cache_rules(
+            shardings = cache_rules(
                 per_shard_pools=paged.data_shards > 1,
             ).shardings_for(self.mesh, shapes)
             self._cache_init_fns[key] = jax.jit(
@@ -1242,7 +1242,14 @@ class ServeEngine:
         last_tokens)`` on device), and ``clock`` chains the on-device
         iteration counter — pass the previous launch's ``clock_out``
         handle to keep the chain pure device-side.  All three default
-        to no-ops (no fresh rows, clock 0)."""
+        to no-ops (no fresh rows, clock 0).
+
+        EXPERT COUNTS: where the cache carries a ``moe_counts`` leaf (a
+        family whose expert layers count the router's choices on the
+        device), the per-request return grows by one: what THIS launch
+        added to that leaf, ``(expert layers, experts held + 3)`` int32,
+        an output of the same program (nothing is launched or fetched
+        for it beyond what the tokens' fetch brings)."""
         if (paged is None) != (block_tables is None):
             raise ValueError("paged and block_tables go together")
         steps = int(steps)
@@ -1275,26 +1282,28 @@ class ServeEngine:
         with _launch_lock:
             if key not in self._generate_fns:
                 self._note_compile("slot_megastep")
+                counting = moe_counts_of(cache) is not None
                 self._generate_fns[key] = jax.jit(
                     _named("decode_megastep", self._recording_paths(
-                        key, self._megastep_apply), steps, paged),
+                        key, self._megastep_counting_apply if counting
+                        else self._megastep_apply), steps, paged),
                     donate_argnums=(1, 2))
             tokens_dev = last_tokens
             if not isinstance(tokens_dev, jax.Array):
                 tokens_dev = jax.device_put(
                     np.asarray(tokens_dev, np.int32).reshape(-1),
                     batch_sharding(self.mesh))
-            toks, tok_final, steps_run, clock_out, cache, counts = (
-                self._generate_fns[key](
-                    self.params if params is None else params, cache, counts,
-                    tokens_dev, np.asarray(active, bool),
-                    np.asarray(horizon, np.int32), eos, bt, base, counter,
-                    sampling, fresh_tokens, fresh, clock))
+            out = self._generate_fns[key](
+                self.params if params is None else params, cache, counts,
+                tokens_dev, np.asarray(active, bool),
+                np.asarray(horizon, np.int32), eos, bt, base, counter,
+                sampling, fresh_tokens, fresh, clock)
+        toks, tok_final, steps_run, clock_out, cache, counts = out[:6]
         self._count_decode_launch(key)
         self._obs["megastep"].observe(time.perf_counter() - t0)
         if legacy:
             return toks, tok_final, steps_run, cache
-        return toks, tok_final, steps_run, clock_out, cache, counts
+        return (toks, tok_final, steps_run, clock_out, cache, counts) + out[6:]
 
     def _verify_slots_apply(self, k, paged, params, cache, counts, tokens,
                             active, draft_lens, block_tables, rng, counter,
@@ -1670,3 +1679,47 @@ class ServeEngine:
     def __exit__(self, exc_type, exc, tb):
         self.close()
         return False
+
+    # -- what a decoder family tells the engine (no model by name) ------------
+
+    def _cache_rules(self):
+        """The workload's sharding rules for the "cache" collection."""
+        rules = self.workload.cache_rules
+        if rules is None:
+            raise ValueError(
+                f"model {self.model!r} declares no decode cache "
+                f"(Workload.cache_rules): only decoder families serve "
+                f"through generate and the slot programs")
+        return rules
+
+    def cache_geometry(self, paged) -> Dict[str, Any]:
+        """What a token costs in the paged pool this family's cache is, as
+        the workload reckons it: values and bytes a token and layer, the
+        pool's width and its padding, the pool's bytes under ``paged``."""
+        geometry = self.workload.cache_geometry
+        if geometry is None:
+            raise ValueError(
+                f"model {self.model!r} declares no cache geometry "
+                f"(Workload.cache_geometry)")
+        return geometry(paged)
+
+    def _megastep_counting_apply(self, steps, paged, params, cache, *rest):
+        """``_megastep_apply`` and, as one more output, what its steps
+        added to the cache's ``moe_counts``."""
+        before = moe_counts_of(cache)
+        out = self._megastep_apply(steps, paged, params, cache, *rest)
+        return out + (moe_counts_of(out[4]) - before,)
+
+
+def moe_counts_of(cache: PyTree):
+    """The cache tree's ``moe_counts`` leaf (expert layers that count the
+    router's choices on the device carry one), or None."""
+    found = [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
+             if getattr(path[-1], "key", None) == "moe_counts"]
+    return found[0] if found else None
+
+
+# Attention paths a traced program may have on record: the paged kernel's
+# two (``ops.paged_attention``) and the latent attention's two.
+_DECODE_PATHS = (paged_attention.KERNEL, paged_attention.GATHER,
+                 "latent_absorbed", "latent_expanded")

@@ -10,6 +10,8 @@ Examples:
     python serve.py --model=gpt2 --checkpoint_dir=/tmp/ckpt --max_batch_size=8
     python serve.py --model=mnist --steps=64                 # classify path
     python serve.py --model=gpt2 --tensor=2                  # TP decode
+    python serve.py --model=glm4_moe_lite --continuous --cache_mode=paged \
+        --megastep=4 --async_decode     # latent (MLA) cache, sparse experts
     python serve.py --model=gpt2 --continuous --num_slots=8 \
         --prompt_lens=8,16,24 --min_new_tokens=4             # continuous batching
     python serve.py --model=gpt2 --continuous --cache_mode=paged \
@@ -69,8 +71,13 @@ def parse_args(argv=None):
     defaults = ServeArgs()
     p = argparse.ArgumentParser(description="TPU-native batched serving")
     p.add_argument("--model", default=defaults.model,
-                   help="gpt2 (KV-cache decode) or mnist|resnet50|bert "
-                        "(batched classify)")
+                   help="gpt2 or glm4_moe_lite (KV-cache decode) or "
+                        "mnist|resnet50|bert (batched classify).  "
+                        "glm4_moe_lite (latent attention, sparse experts) "
+                        "serves with --continuous --cache_mode=paged only "
+                        "and refuses, with the reason, --kv_dtype, "
+                        "--per_shard_kv, --prefix_cache, --spec_k, "
+                        "--slo_scheduling and a --tensor mesh")
     p.add_argument("--checkpoint_dir", default=None,
                    help="restore params from here (fresh random init when "
                         "unset or empty — the smoke path)")
@@ -237,8 +244,12 @@ def parse_args(argv=None):
                         "config batches together in ONE compiled program "
                         "set ('' = uniform --temperature/--top_k)")
     p.add_argument("--preset", default=None,
-                   help="gpt2 config preset (tiny|small|medium); default "
-                        "tiny on CPU, medium on TPU")
+                   help="config preset: gpt2 tiny|small|medium (default "
+                        "tiny on CPU, medium on TPU); glm4_moe_lite "
+                        "tiny|v5e8_share|flash (default tiny on CPU, "
+                        "v5e8_share, one chip's share of an 8-chip "
+                        "expert-parallel host, on TPU; flash is the whole "
+                        "60 GB model)")
     for axis in ("data", "fsdp", "tensor"):
         p.add_argument(f"--{axis}", type=int,
                        default=getattr(defaults, axis),

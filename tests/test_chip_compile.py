@@ -242,17 +242,20 @@ SERVE_TOTAL_LEN, SERVE_BLOCK, SERVE_MEGASTEP, SERVE_PROMPT = 1024, 16, 4, 128
 V5E_HBM_BYTES = 15.75e9
 
 
-def lower_serve_program(topo, program, slots):
+def lower_serve_program(topo, program, slots, module=None,
+                        prompt=SERVE_PROMPT):
     """The engine's own ``decode_megastep`` or ``prefill_slots`` program for
-    GPT-2 medium, lowered from shapes alone.  ``ServeEngine()`` places real
-    weights, which a described device cannot hold, so the two ``_apply``
-    methods run on a bare instance that has only the module they read."""
+    GPT-2 medium (or ``module``), lowered from shapes alone.
+    ``ServeEngine()`` places real weights, which a described device cannot
+    hold, so the two ``_apply`` methods run on a bare instance that has
+    only the module they read."""
     from distributed_tensorflow_tpu.models.gpt2 import (
         GPT2, GPT2Config, PagedKVConfig)
     from distributed_tensorflow_tpu.serve import sampling as sampling_lib
+    from distributed_tensorflow_tpu.serve import engine as engine_lib
     from distributed_tensorflow_tpu.serve.engine import ServeEngine
 
-    module = GPT2(GPT2Config.medium(dropout=0.0))
+    module = module or GPT2(GPT2Config.medium(dropout=0.0))
     engine = object.__new__(ServeEngine)
     engine.module = module
     max_blocks = SERVE_TOTAL_LEN // SERVE_BLOCK
@@ -279,8 +282,13 @@ def lower_serve_program(topo, program, slots):
             sampling_lib.uniform(rows, 0.0, 0))
 
     if program == "decode_megastep":
+        # As ``decode_megastep`` picks it: a cache that counts the router's
+        # choices gets the program that returns what a launch added.
+        apply = (engine._megastep_apply
+                 if engine_lib.moe_counts_of(cache) is None
+                 else engine._megastep_counting_apply)
         fn = jax.jit(
-            lambda *a: engine._megastep_apply(SERVE_MEGASTEP, paged, *a),
+            lambda *a: apply(SERVE_MEGASTEP, paged, *a),
             donate_argnums=(1, 2))
         lowered = fn.lower(
             params, cache, counts, arg((slots,)), arg((slots,), jnp.bool_),
@@ -292,9 +300,10 @@ def lower_serve_program(topo, program, slots):
             lambda *a: engine._prefill_slots_apply(paged, *a),
             donate_argnums=(1, 2))
         lowered = fn.lower(
-            params, cache, counts, arg((1, SERVE_PROMPT)), arg((1,)), tables,
+            params, cache, counts, arg((1, prompt)), arg((1,)), tables,
             rng, arg(()), arg((1,)), sampling(1), arg((1,), jnp.bool_))
-    pool = cache["blocks"]["cached_key_pool"].shape
+    pool = (cache["latent_pool"] if "latent_pool" in cache
+            else cache["blocks"]["cached_key_pool"]).shape
     return lowered, pool
 
 
@@ -391,6 +400,45 @@ def test_serve_decode_program_fits_one_chip_at_64_slots(topo):
     memory = lowered.compile().memory_analysis()
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < V5E_HBM_BYTES
+
+
+# -- the latent-attention, sparse-expert family at its cell's shapes ----------
+
+def glm_cell():
+    """``serve.glm-4.7-flash.reason-saturated``: its module and scheduler."""
+    from benchmark.harness import program, spec
+    from distributed_tensorflow_tpu.models.glm4_moe_lite import Glm4MoeLite
+
+    cell = spec.load_cell("serve.glm-4.7-flash.reason-saturated")
+    sched = cell.cell["scheduler"]
+    assert (sched["max_total_len"], sched["block_size"], sched["megastep"]) \
+        == (SERVE_TOTAL_LEN, SERVE_BLOCK, SERVE_MEGASTEP)
+    return Glm4MoeLite(program.program_config(cell.config)), cell
+
+
+@pytest.mark.parametrize("program", ["decode_megastep", "prefill_slots"])
+def test_latent_serve_programs_fit_one_chip_at_the_cells_shapes(topo, program):
+    """One chip's share of the expert-parallel deployment, 1 dense + 20
+    expert layers at the published widths: 4.60 GB of bfloat16 weights and
+    a latent pool of 16 slots x 1,024 positions x 21 layers x 640 values
+    (0.44 GB), updated in place; the decode program (4 fused steps, with
+    the router's counts as one more output) adds 0.43 GB of scratch, the
+    longest prefill (384 positions) 0.04 GB.  PERF.md section 4 quotes
+    these figures."""
+    module, cell = glm_cell()
+    slots = int(cell.cell["scheduler"]["num_slots"])
+    longest = max(cell.traffic["prompt_tokens"]["round_up_to"])
+    lowered, pool = lower_serve_program(topo, program, slots, module=module,
+                                        prompt=longest)
+    assert pool == (21, slots * 64 + 1, 16, 640)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert 5.0e9 < memory.argument_size_in_bytes < 5.1e9
+    assert memory.temp_size_in_bytes < (0.5e9 if program == "decode_megastep"
+                                        else 0.06e9)
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < V5E_HBM_BYTES
+    assert_only_scatters_produce_pools(compiled.as_text(), pool)
 
 
 # -- a remat'd stack runs each of the three kernels once ---------------------

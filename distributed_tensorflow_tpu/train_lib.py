@@ -25,6 +25,7 @@ from distributed_tensorflow_tpu import compile_cache
 from distributed_tensorflow_tpu.checkpoint import CheckpointManager
 from distributed_tensorflow_tpu.data import DevicePrefetchIterator
 from distributed_tensorflow_tpu.models import Workload, available_models, get_workload
+from distributed_tensorflow_tpu.obs.trace import default_tracer
 from distributed_tensorflow_tpu.parallel.sharding import batch_sharding
 from distributed_tensorflow_tpu.training import (
     BF16,
@@ -36,9 +37,9 @@ from distributed_tensorflow_tpu.training import (
     ProfilerHook,
     TrainLoop,
     TrainState,
+    carry_step_marks,
     make_eval_step,
     make_train_step,
-    mark_in_step_rng,
 )
 
 logger = logging.getLogger(__name__)
@@ -270,15 +271,24 @@ def build_step(
         # Async-loop contract: the step folds state.step into a constant
         # base key on device, so the loop never splits keys host-side.
         in_step_rng=True,
+        # Where the mesh has a `data` axis to defer over, each replica sums
+        # its own microbatches' gradients and the step reduces them once.
+        mesh=mesh,
+        state_shardings=state_shardings,
+        batch_rows=workload.batch_size,
     )
+    default_tracer().add_instant(
+        "grad_reduce", cat="train",
+        args={"where": raw_step.grad_reduce,
+              "data": mesh.shape.get("data", 1), "accum": grad_accum_steps})
     bsh = batch_sharding(mesh)
     batch_shardings = {k: bsh for k in workload.init_batch}
-    train_step = mark_in_step_rng(jax.jit(
+    train_step = carry_step_marks(raw_step, jax.jit(
         raw_step,
         in_shardings=(state_shardings, batch_shardings, NamedSharding(mesh, P())),
         out_shardings=(state_shardings, None),
         donate_argnums=(0,),
-    ), True)
+    ))
     return init, abstract_state, state_shardings, train_step, batch_shardings
 
 
@@ -401,6 +411,10 @@ def run(args: TrainArgs) -> Dict[str, Any]:
     grad_accum = args.grad_accum_steps or workload.grad_accum_steps
     precision = BF16 if args.precision == "bf16" else FP32
 
+    if args.trace_out:
+        # Before the step is built: ``build_step`` records how it reduces
+        # gradients (``dtt/train/grad_reduce``).
+        default_tracer().enable()
     state, state_shardings, train_step, batch_shardings = build_state_and_step(
         workload,
         mesh,
@@ -537,10 +551,6 @@ def run(args: TrainArgs) -> Dict[str, Any]:
         from distributed_tensorflow_tpu.obs import MetricsServer
 
         metrics_server = MetricsServer(port=args.metrics_port)
-    if args.trace_out:
-        from distributed_tensorflow_tpu.obs import default_tracer
-
-        default_tracer().enable()
     loop = TrainLoop(
         train_step,
         state,

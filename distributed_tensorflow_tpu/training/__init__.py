@@ -11,6 +11,8 @@ from distributed_tensorflow_tpu.training.loop import (
 )
 from distributed_tensorflow_tpu.training.metrics import RunningMean, ThroughputMeter
 from distributed_tensorflow_tpu.training.step import (
+    carry_step_marks,
+    grad_reduce_site,
     make_eval_step,
     make_train_step,
     mark_in_step_rng,
@@ -37,6 +39,8 @@ __all__ = [
     "ThroughputMeter",
     "TrainLoop",
     "TrainState",
+    "carry_step_marks",
+    "grad_reduce_site",
     "make_eval_step",
     "make_train_step",
     "mark_in_step_rng",

@@ -504,3 +504,131 @@ def test_remat_stack_runs_each_flash_kernel_once(topo, stack, axes):
     loss, params, batch = stack(mesh if axes else None, sharded)
     hlo = compiled_text(jax.grad(loss), params, batch)
     assert kernel_calls(hlo) == dict(flash_dkv=1, flash_dq=1, flash_fwd=1)
+
+
+# -- the accumulating step reduces its gradients over `data` once -------------
+
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute")
+
+
+def hlo_computations(hlo):
+    """name -> text of every computation of a compiled module."""
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)^\}", hlo, re.M | re.S)}
+
+
+def inside_loops(comps):
+    """The computations that run inside some ``while``: the loops' bodies
+    and conditions and whatever those call (the layer loop inside the
+    microbatch loop, fusions, reducers)."""
+    def called(text):
+        names = re.findall(r"(?:body|condition|calls|to_apply)=%([\w.\-]+)",
+                           text)
+        for branches in re.findall(r"branch_computations=\{([^}]*)\}", text):
+            names += re.findall(r"%([\w.\-]+)", branches)
+        return names
+
+    todo = [name for text in comps.values()
+            for name in re.findall(r"(?:body|condition)=%([\w.\-]+)", text)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in comps and name not in seen:
+            seen.add(name)
+            todo += called(comps[name])
+    return seen
+
+
+def device_groups(line):
+    """A collective's groups of devices, from either form of
+    ``replica_groups`` (listed, or an iota ``[groups,size]<=[dims]T(perm)``)
+    or from a permute's ``source_target_pairs``."""
+    m = re.search(r"(?:replica_groups|source_target_pairs)=\{(\{[\d,{}]*\})\}",
+                  line)
+    if m:
+        return [[int(i) for i in group.split(",")]
+                for group in re.findall(r"\{([\d,]+)\}", m.group(1))]
+    m = re.search(
+        r"replica_groups=\[(\d+),(\d+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?",
+        line)
+    assert m, f"no device groups in: {line[:200]}"
+    ids = np.arange(int(m.group(1)) * int(m.group(2))).reshape(
+        [int(d) for d in m.group(3).split(",")])
+    if m.group(4):
+        ids = ids.transpose([int(d) for d in m.group(4).split(",")])
+    return ids.reshape(int(m.group(1)), int(m.group(2))).tolist()
+
+
+def collectives_over(text, coordinate):
+    """(instruction, opcode, result bytes) of each collective in ``text``
+    with a group whose devices differ in ``coordinate(device)``."""
+    sizes = dict(bf16=2, f32=4, s32=4, u32=4, pred=1, s8=1, u8=1)
+    found = []
+    for line in text.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) (" + "|".join(COLLECTIVES)
+            + r")(?:-start)?\(", line)
+        if m and any(len({coordinate(d) for d in group}) > 1
+                     for group in device_groups(line)):
+            nbytes = sum(
+                sizes[t] * int(np.prod([int(d) for d in dims.split(",") if d]
+                                       or [1]))
+                for t, dims in re.findall(
+                    r"\b(" + "|".join(sizes) + r")\[([\d,]*)\]", m.group(2)))
+            found.append((m.group(1), m.group(3), nbytes))
+    return found
+
+
+def test_accumulating_step_reduces_over_data_once(topo):
+    """``train.gpt2-large.d2t2``'s own step (``data=2 x tensor=2``, 64 x
+    1024 a step, accumulation 8), whole: the scanned stack compiles in the
+    time 4 layers take.  Left to GSPMD the accumulator is replicated over
+    ``data`` and every layer's gradient crosses it in every microbatch
+    (6.7 GB a chip and step where one reduction moves 1.68).  Each replica
+    now sums its own microbatches: no collective inside any ``while`` body
+    spans ``data``, the reduction stands once after the loop in f32, the
+    three kernels are still called, and the program fits the chip."""
+    from benchmark.harness import spec, train
+
+    cell = spec.load_cell("train.gpt2-large.d2t2")
+    workload, _, abstract, shardings, step, batch_sh = train.build_step(
+        cell, list(topo.devices)[:cell.chips])
+    assert step.grad_reduce == "after_scan"
+    mesh = batch_sh["tokens"].mesh
+    assert dict(mesh.shape)["data"] == 2 and dict(mesh.shape)["tensor"] == 2
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        abstract, shardings)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (int(cell.traffic["batch_size"]), int(cell.traffic["seq_len"])),
+        jnp.int32, sharding=batch_sh["tokens"])}
+    rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=NamedSharding(mesh, P()))
+    compiled = step.lower(state, batch, rng).compile()
+    hlo = compiled.as_text()
+
+    # A device's number in the compiled program is its place in the mesh,
+    # ``tensor`` minor: its ``data`` coordinate is the quotient.
+    data_of = lambda device: device // mesh.shape["tensor"]  # noqa: E731
+    comps = hlo_computations(hlo)
+    loops = inside_loops(comps)
+    assert loops, "no while loop: the layer and microbatch scans are gone"
+    in_loops = [c for name in loops
+                for c in collectives_over(comps[name], data_of)]
+    assert in_loops == [], f"collectives over `data` inside a loop: {in_loops}"
+    after = [c for name in set(comps) - loops
+             for c in collectives_over(comps[name], data_of)]
+    assert {op for _, op, _ in after} == {"all-reduce"}
+    grads = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(abstract.params))
+    # Each chip's part of the f32 accumulator, once (`tensor` halves all but
+    # the embeddings and the vectors), and the loss and aux scalars.
+    assert grads * 4 / 2 < sum(n for _, _, n in after) <= grads * 4 + 64
+    # ... while the `tensor` axis still works inside the loops.
+    tensor_of = lambda device: device % mesh.shape["tensor"]  # noqa: E731
+    assert any(collectives_over(comps[name], tensor_of) for name in loops)
+
+    assert kernel_names(hlo) == {"flash_fwd", "flash_dq", "flash_dkv"}
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < V5E_HBM_BYTES

@@ -13,7 +13,7 @@ scanned stack of expert layers.  Two mechanisms are its own:
   qk_rope_head_dim`` values a token and layer, whatever the head count, in a
   lane-dense paged pool ``(layers, num_blocks, block_size, pool_width)``
   addressed through the scheduler's block tables (``PagedKVConfig``, the
-  geometry ``models/gpt2.py`` defines; only the row's content differs).
+  geometry every family shares; only the row's content differs).
   Attention over the pool runs one of two ways, chosen by the call's shape
   and recorded like the paged kernel's choice (``ops.paged_attention.
   note_path``): a decode step (one query a row) **absorbs** ``kv_b`` into
@@ -71,8 +71,7 @@ from jax import lax
 from jax.sharding import Mesh
 
 from distributed_tensorflow_tpu.data.pipeline import synthetic_lm
-from distributed_tensorflow_tpu.models import Workload
-from distributed_tensorflow_tpu.models.gpt2 import PagedKVConfig
+from distributed_tensorflow_tpu.models import PagedKVConfig, Workload
 from distributed_tensorflow_tpu.ops import paged_attention
 from distributed_tensorflow_tpu.parallel.sharding import ShardingRules
 
@@ -121,6 +120,8 @@ class Glm4MoeLiteConfig:
     experts_held: Optional[int] = None
     first_expert: int = 0
     dtype: Any = jnp.bfloat16               # products' operands, parameters
+
+    router = "sigmoid_bias"                 # ``route``'s kind; no field
 
     def __post_init__(self):
         held = self.held
@@ -327,15 +328,20 @@ def rms_norm(x, scale, eps):
     return y * scale.astype(jnp.float32)
 
 
-def rope(x, positions, theta):
+def rope(x, positions, theta, inv_freq=None, scale=None):
     """Rotary positions over the whole last dimension, dimension ``i`` paired
     with ``i + half`` (the rotate-half convention).  ``x`` is ``(B, T, ...,
-    D)``, ``positions`` ``(B, T)``; float32 out."""
+    D)``, ``positions`` ``(B, T)``; float32 out.  ``inv_freq`` ``(half,)``
+    replaces ``theta``'s plain table and ``scale`` multiplies cos and sin
+    (a scaled table, such as YaRN's, is its caller's to compute)."""
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    freq = (theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+            if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
     angle = positions.astype(jnp.float32)[..., None] * freq      # (B, T, half)
     angle = angle.reshape(angle.shape[:2] + (1,) * (x.ndim - 3) + (half,))
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     xf = x.astype(jnp.float32)
     a, b = xf[..., :half], xf[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
@@ -403,24 +409,35 @@ def mla_attend(cfg, p, q_n, q_r, latent, k_r, mask, absorb: bool):
 
 def route(cfg, p, x):
     """The router, in float32 whatever the compute type: -> the chosen
-    experts' indices ``(N, k)`` and their weights ``(N, k)``."""
-    scores = jax.nn.sigmoid(jnp.einsum(
+    experts' indices ``(N, k)`` and their weights ``(N, k)``.  Its kind is
+    the config's to say (``cfg.router``): ``"sigmoid_bias"`` scores by a
+    sigmoid, chooses by score + correction bias and scales the normalized
+    weights; ``"softmax"`` scores by a softmax over all the experts and
+    chooses by score, with no bias and no scale."""
+    logits = jnp.einsum(
         "nd,de->ne", x.astype(jnp.float32),
         p["kernel"].astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
-    _, chosen = lax.top_k(scores + p["bias"].astype(jnp.float32),
-                          cfg.num_experts_per_tok)
+        precision=lax.Precision.HIGHEST)
+    if cfg.router == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        order, scaling = scores, 1.0
+    else:
+        scores = jax.nn.sigmoid(logits)
+        order = scores + p["bias"].astype(jnp.float32)
+        scaling = cfg.routed_scaling_factor
+    _, chosen = lax.top_k(order, cfg.num_experts_per_tok)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if cfg.norm_topk_prob:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
-    return chosen, weights * cfg.routed_scaling_factor
+    return chosen, weights * scaling
 
 
 def expert_layer(cfg, p, x, live=None):
-    """Held experts' part of the routed result plus the shared expert, for
-    ``x`` ``(N, d)`` float32 (the router reads it unrounded; the experts'
-    products take it in the compute type), float32 out; and the layer's row
-    of ``moe_counts``.  ``live`` ``(N,)`` masks the tokens that count."""
+    """Held experts' part of the routed result plus the shared expert (where
+    the layer has one), for ``x`` ``(N, d)`` float32 (the router reads it
+    unrounded; the experts' products take it in the compute type), float32
+    out; and the layer's row of ``moe_counts``.  ``live`` ``(N,)`` masks the
+    tokens that count."""
     dt = cfg.dtype
     chosen, weights = route(cfg, p["router"], x)
     held = cfg.first_expert + jnp.arange(cfg.held, dtype=chosen.dtype)
@@ -434,7 +451,7 @@ def expert_layer(cfg, p, x, live=None):
                 ex["down"]["kernel"])
     # The gates weigh float32 results in float32: no product, a sum of 8.
     routed = jnp.sum(gates.T[:, :, None] * each, axis=0)
-    y = routed + gated_mlp(p["shared"], xd, dt)
+    y = routed + gated_mlp(p["shared"], xd, dt) if "shared" in p else routed
 
     counted = (jnp.ones(x.shape[:1], jnp.int32) if live is None
                else live.astype(jnp.int32))

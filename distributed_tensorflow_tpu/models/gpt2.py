@@ -37,7 +37,10 @@ from jax.sharding import Mesh
 from distributed_tensorflow_tpu.data.pipeline import synthetic_lm
 from distributed_tensorflow_tpu.ops import flash_attention, paged_attention
 from distributed_tensorflow_tpu.parallel.ring_attention import ring_attention
-from distributed_tensorflow_tpu.models import Workload
+from distributed_tensorflow_tpu.models import (  # noqa: F401
+    PagedKVConfig,  # defined beside Workload; re-exported for its callers
+    Workload,
+)
 from distributed_tensorflow_tpu.ops.flash_attention import REMAT_POLICY
 from distributed_tensorflow_tpu.parallel.sharding import (
     P, ShardingRules,
@@ -119,152 +122,6 @@ class GPT2Config:
         # serve a bench run in seconds.
         return cls(vocab_size=256, n_positions=512, d_model=256, n_layer=4,
                    n_head=8, dropout=0.0, **kw)
-
-
-@dataclasses.dataclass(frozen=True)
-class PagedKVConfig:
-    """Geometry of the block-table (paged) KV cache — vLLM-style
-    (Kwon et al., SOSP 2023; PAPERS.md).
-
-    Instead of one dense ``(num_slots, max_total_len)`` K/V row per slot,
-    K/V live in a ``(num_blocks, block_size, heads * head_dim)`` pool per
-    layer — ``(layers, num_blocks, block_size, heads * head_dim)`` under
-    the scanned stack — and each slot maps its logical positions to
-    physical blocks through a host-managed ``(num_slots,
-    max_blocks_per_slot)`` int32 block table passed into every decode
-    call.  A request only pins the blocks its current length actually
-    covers, so a 30-token request no longer reserves a full worst-case
-    row.  Heads are merged into the minor dimension (head h owns columns
-    ``[h * head_dim, (h + 1) * head_dim)``) so a block's rows are a
-    multiple of the TPU's 128 lanes wide: a head size of 64 in the minor
-    dimension would be padded to 128 and every program would re-lay the
-    pool first.
-
-    Physical block 0 is the TRASH block: never allocated to a request,
-    it absorbs the garbage K/V that inactive decode rows write (their
-    table rows are reset to all-zeros at retirement), so a freed-and-
-    reused block can never be corrupted by a stale slot.
-
-    ``kv_dtype`` selects the pool storage dtype: ``None`` stores the
-    model's compute dtype (bit-identical to the dense cache), any dtype
-    name (e.g. ``"bfloat16"``) casts on write, and ``"int8"`` stores
-    symmetric per-token-quantized K/V plus f32 scale tables of shape
-    ``(num_blocks, block_size)`` (one scale per written token position,
-    shared across heads) that dequantize in the attention gather.
-
-    Frozen + hashable on purpose: the engine keys its jitted program cache
-    by this config, and the model treats every field as compile-time
-    static.
-    """
-
-    block_size: int = 16
-    num_blocks: int = 64
-    kv_dtype: Optional[str] = None  # None | "int8" | a jnp dtype name
-    # Per-shard pools (fleet serving): partition the pool's block dimension
-    # over the data axis — shard s owns blocks [s*per, (s+1)*per) with its
-    # own trash block at s*per, and a slot's table only ever indexes its
-    # shard.  1 keeps today's data-axis-replicated pool.
-    data_shards: int = 1
-
-    def __post_init__(self):
-        if self.block_size < 1:
-            raise ValueError(f"block_size must be >= 1, got {self.block_size}")
-        if self.num_blocks < 2:
-            raise ValueError(
-                f"num_blocks must be >= 2 (block 0 is the reserved trash "
-                f"block), got {self.num_blocks}")
-        if self.data_shards < 1:
-            raise ValueError(
-                f"data_shards must be >= 1, got {self.data_shards}")
-        if self.num_blocks % self.data_shards:
-            raise ValueError(
-                f"num_blocks {self.num_blocks} must divide evenly over "
-                f"data_shards {self.data_shards} per-shard pools")
-        if self.num_blocks // self.data_shards < 2:
-            raise ValueError(
-                f"num_blocks {self.num_blocks} leaves fewer than 2 blocks "
-                f"per shard across data_shards {self.data_shards} (each "
-                f"shard reserves its own trash block)")
-        if self.kv_dtype is not None:
-            jnp.dtype(self.kv_dtype)  # fail fast on typos
-
-    @property
-    def quantized(self) -> bool:
-        return self.kv_dtype == "int8"
-
-    def storage_dtype(self, compute_dtype):
-        if self.kv_dtype is None:
-            return compute_dtype
-        return jnp.dtype(self.kv_dtype)
-
-    def blocks_for(self, tokens: int) -> int:
-        """Physical blocks covering ``tokens`` logical positions."""
-        return -(-max(0, tokens) // self.block_size)
-
-    def prefix_blocks(self, prompt_len: int) -> int:
-        """Most leading blocks of a ``prompt_len``-token prompt that
-        prefix caching may map from cache: full blocks only, and never
-        the whole prompt — prefill must compute at least the final
-        position to emit the first sampled token, so a block-aligned
-        prompt re-computes its last block into a private (copy-on-write)
-        block instead of mapping it."""
-        return max(0, int(prompt_len) - 1) // self.block_size
-
-    def max_blocks_per_slot(self, total_len: int) -> int:
-        return self.blocks_for(total_len)
-
-    def blocks_for_megastep(self, prompt_len: int, generated: int,
-                            steps: int, max_new_tokens: int) -> int:
-        """Physical blocks a ``steps``-iteration fused decode (megastep)
-        needs mapped BEFORE it launches.  The scan applies the cache
-        ``steps`` times inside one program, so the scatter targets for
-        every inner position must already resolve through the block
-        table — there is no host boundary mid-scan to allocate at.
-        Coverage clamps to the admission reservation
-        (``prompt_len + max_new_tokens - 1``): a row whose horizon ends
-        mid-megastep is alive-gated on device (its ``cache_index`` row
-        freezes), so the positions past its horizon are only ever
-        written as masked garbage — behind the frozen index, where the
-        causal mask never admits them — and need no block of their own.
-        """
-        if steps < 1:
-            raise ValueError(f"steps must be >= 1, got {steps}")
-        covered = min(prompt_len + generated + steps - 1,
-                      prompt_len + max_new_tokens - 1)
-        return self.blocks_for(covered)
-
-    def blocks_for_spec(self, prompt_len: int, generated: int,
-                        draft_len: int, max_new_tokens: int) -> int:
-        """Physical blocks a speculative verify launch needs mapped
-        BEFORE it runs: the (1 + draft_len)-token forward scatters K/V
-        for the last emitted token plus every draft position in ONE
-        program, so all of them must already resolve through the block
-        table — exactly the megastep precondition with
-        ``steps = draft_len + 1``, including the clamp to the admission
-        reservation (positions past the horizon are only ever written as
-        masked garbage behind the rolled-back index)."""
-        if draft_len < 0:
-            raise ValueError(f"draft_len must be >= 0, got {draft_len}")
-        return self.blocks_for_megastep(
-            prompt_len, generated, draft_len + 1, max_new_tokens)
-
-    @property
-    def usable_blocks(self) -> int:
-        """Blocks available to requests (pool minus the trash blocks)."""
-        return self.num_blocks - self.data_shards
-
-    @property
-    def blocks_per_shard(self) -> int:
-        return self.num_blocks // self.data_shards
-
-    @property
-    def usable_blocks_per_shard(self) -> int:
-        """Blocks one data shard can hand to requests — the admission
-        bound in per-shard mode (a shard cannot borrow a peer's blocks)."""
-        return self.blocks_per_shard - 1
-
-    def trash_block(self, shard: int = 0) -> int:
-        return shard * self.blocks_per_shard
 
 
 def _quantize_kv_int8(x):

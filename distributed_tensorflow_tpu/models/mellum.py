@@ -1,0 +1,607 @@
+"""A grouped-query decoder whose layers are window and full attention by
+turns, every MLP a layer of sparse experts (``model_type`` ``mellum``), for
+the serving path.
+
+The third decoder family beside ``models/gpt2.py`` and
+``models/glm4_moe_lite.py``.  RMS norm, rotate-half rotary positions, the
+stacked parameter leaves, the float32 router and the expert layer that is
+told which experts it holds are ``glm4_moe_lite``'s, imported; what is this
+family's own:
+
+- **Grouped-query attention.**  ``num_attention_heads`` query heads over
+  ``num_key_value_heads`` K/V heads of ``head_dim``: K/V head ``g`` serves
+  query heads ``g * G .. g * G + G - 1`` (``G`` their ratio).  The cache
+  holds K and V at the K/V head count, side by side in one row of
+  ``2 * num_key_value_heads * head_dim`` values a token and layer (whole
+  128-lane tiles at the published sizes, no padding).
+- **Two kinds of layer, two kinds of cache.**  ``layer_types[l]`` is
+  ``sliding_attention`` (a query at position ``i`` reads keys ``j <= i``
+  with ``i - j < sliding_window``: the window counts the query itself) or
+  ``full_attention`` (causal).  The scanned stack's body is one period of
+  the pattern (three window layers and one full, as published).  Full
+  layers keep their K/V in a paged pool ``(full layers, num_blocks,
+  block_size, row)`` addressed through the slot's block-table row, as the
+  other families do.  Window layers keep theirs in a second pool
+  ``(window layers, window_blocks, block_size, row)`` in which a slot owns
+  a RING of ``window_ring`` blocks whatever its length
+  (``PagedKVConfig``): position ``p`` is written to ring cell ``p %
+  (window_ring * block_size)``, over whatever slid out of the window, and
+  a call reads the whole ring back with each cell's position worked out
+  from the row's length.  A call of ``T`` positions needs the ``sliding_
+  window - 1`` before its first still in the ring, so ``T + sliding_window
+  - 1`` may not pass the ring's capacity (refused at trace time).
+- **Two rotary tables.**  Window layers rotate by the plain table
+  ``rope_theta ** (-2i / head_dim)``; full layers by YaRN's
+  (``yarn_inv_freq``), with ``attention_factor`` on cos and sin.
+- **A softmax router**: softmax over all ``num_experts`` in float32, the
+  top ``num_experts_per_tok`` by score, their weights renormalized; no
+  bias, no scale, no shared expert (``route``'s ``"softmax"`` kind).
+
+Both attention kinds gather their pool rows and attend densely under the
+mask (``GATHER_WINDOW`` / ``GATHER_FULL`` in ``attention_paths()``); the
+paged decode kernel (``ops/paged_attention.py``) is built for a pool as
+wide as the query and is not used.
+
+Precision as ``glm4_moe_lite``: parameters and products' operands in
+``dtype`` (bfloat16), float32 accumulation; the residual stream, norms,
+rotations, router, softmax and logits float32.  The multi-token head the
+family's description mentions has no key in ``config.json`` and the main
+model's logits do not depend on it: not built.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+from flax import linen as nn
+from jax import lax
+from jax.sharding import Mesh
+
+from distributed_tensorflow_tpu.data.pipeline import synthetic_lm
+from distributed_tensorflow_tpu.models import PagedKVConfig, Workload
+from distributed_tensorflow_tpu.models.glm4_moe_lite import (
+    COUNT_EXTRA, _declare, _dot, _mlp_spec, _stacked, expert_layer, rms_norm,
+    rope)
+from distributed_tensorflow_tpu.ops import paged_attention
+from distributed_tensorflow_tpu.parallel.sharding import ShardingRules
+
+# The two attention paths, as ``attention_paths()`` names them.
+GATHER_WINDOW, GATHER_FULL = "gqa_gather_window", "gqa_gather_full"
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class MellumConfig:
+    """Published keys of ``config.json`` under their own names (the nested
+    ``rope_parameters`` group may be given whole: its numbers land in the
+    flat fields), plus the share of the expert layer this device holds."""
+
+    vocab_size: int = 98304
+    hidden_size: int = 2304
+    moe_intermediate_size: int = 896
+    num_hidden_layers: int = 28
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    num_experts: int = 64                   # the router's width, as published
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    sliding_window: int = 1024
+    # None: ``full_attention`` where ``l % 4 == 3``, as published.  A longer
+    # list than ``num_hidden_layers`` is cut to it (a cut in depth keeps the
+    # published list).
+    layer_types: Optional[Tuple[str, ...]] = None
+    rope_theta: float = 500000.0
+    # YaRN, full layers only (``rope_parameters["full_attention"]``).
+    rope_factor: float = 16.0
+    original_max_position_embeddings: int = 8192
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.2772588722239782
+    max_position_embeddings: int = 131072
+    rope_parameters: Any = None             # the published group; consumed
+    # This device's share: ``experts_held`` consecutive experts starting at
+    # ``first_expert``.  None holds them all (the uncut layer).
+    experts_held: Optional[int] = None
+    first_expert: int = 0
+    dtype: Any = jnp.bfloat16               # products' operands, parameters
+
+    router = "softmax"                      # ``glm4_moe_lite.route``'s kind
+
+    def __post_init__(self):
+        put = lambda name, value: object.__setattr__(self, name, value)
+        if self.rope_parameters is not None:
+            full = dict(self.rope_parameters[FULL])
+            plain = dict(self.rope_parameters[SLIDING])
+            if (full.get("rope_type") != "yarn"
+                    or plain.get("rope_type") != "default"
+                    or full["rope_theta"] != plain["rope_theta"]):
+                raise ValueError(
+                    "rope_parameters: full layers 'yarn' and window layers "
+                    "'default' over one rope_theta, got "
+                    f"{self.rope_parameters}")
+            put("rope_theta", float(full["rope_theta"]))
+            put("rope_factor", float(full["factor"]))
+            put("original_max_position_embeddings",
+                int(full["original_max_position_embeddings"]))
+            put("beta_fast", float(full["beta_fast"]))
+            put("beta_slow", float(full["beta_slow"]))
+            put("attention_factor", float(full["attention_factor"]))
+            put("rope_parameters", None)
+        types = self.layer_types
+        if types is None:
+            types = tuple(FULL if l % 4 == 3 else SLIDING
+                          for l in range(self.num_hidden_layers))
+        types = tuple(types)[:self.num_hidden_layers]
+        if (len(types) != self.num_hidden_layers
+                or set(types) - {SLIDING, FULL}):
+            raise ValueError(
+                f"layer_types must name {self.num_hidden_layers} layers, "
+                f"each {SLIDING!r} or {FULL!r}, got {types}")
+        put("layer_types", types)
+        held = self.held
+        if not 1 <= held <= self.num_experts:
+            raise ValueError(
+                f"experts_held {held} must be in 1..num_experts "
+                f"{self.num_experts}")
+        if not 0 <= self.first_expert <= self.num_experts - held:
+            raise ValueError(
+                f"first_expert {self.first_expert} + experts_held {held} "
+                f"passes num_experts {self.num_experts}")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"num_attention_heads {self.num_attention_heads} must be a "
+                f"multiple of num_key_value_heads {self.num_key_value_heads}")
+        if self.head_dim % 2:
+            raise ValueError("head_dim must be even (rotary pairs)")
+        if self.sliding_window < 1:
+            raise ValueError("sliding_window must be >= 1")
+
+    @property
+    def held(self) -> int:
+        return (self.num_experts if self.experts_held is None
+                else int(self.experts_held))
+
+    @property
+    def period(self) -> int:
+        """Layers in the scanned body: the shortest pattern ``layer_types``
+        repeats (all of them where it repeats none)."""
+        n, types = self.num_hidden_layers, self.layer_types
+        return next(p for p in range(1, n + 1)
+                    if n % p == 0 and types == types[:p] * (n // p))
+
+    @property
+    def n_window_layers(self) -> int:
+        return sum(t == SLIDING for t in self.layer_types)
+
+    @property
+    def n_full_layers(self) -> int:
+        return self.num_hidden_layers - self.n_window_layers
+
+    @property
+    def kv_row(self) -> int:
+        """Values cached a token and layer: K and V of every K/V head."""
+        return 2 * self.num_key_value_heads * self.head_dim
+
+    @property
+    def n_positions(self) -> int:
+        """What the engine checks a slot's length against."""
+        return self.max_position_embeddings
+
+    @classmethod
+    def published(cls, **kw):
+        """Mellum2-12B-A2.5B-Instruct's sizes, every expert held."""
+        return cls(**kw)
+
+    @classmethod
+    def v5e4_share(cls, **kw):
+        """One chip's share of a v5e-4 host on which 4 chips share each
+        layer (16 experts a layer, 1/4 of the vocabulary's rows), at the
+        depth one chip serves beside its float32 reference (four whole
+        periods): the sizes of ``benchmark/configs/mellum2-12b-a2.5b.json``."""
+        base = dict(num_hidden_layers=16, vocab_size=24576, experts_held=16)
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
+    def tiny(cls, **kw):  # tests
+        base = dict(
+            vocab_size=256, hidden_size=64, moe_intermediate_size=32,
+            num_hidden_layers=4, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, num_experts=8,
+            num_experts_per_tok=2, sliding_window=24,
+            original_max_position_embeddings=32,
+            max_position_embeddings=512)
+        base.update(kw)
+        return cls(**base)
+
+
+def yarn_inv_freq(cfg: MellumConfig) -> np.ndarray:
+    """The full layers' rotary table ``(head_dim / 2,)``: pair ``i``'s plain
+    frequency ``theta ** (-2i / D)`` where it turns more than ``beta_fast``
+    times inside the original context, that frequency over ``rope_factor``
+    where it turns fewer than ``beta_slow`` times, and a linear ramp
+    between the two pairs those counts fall on."""
+    dim, half = cfg.head_dim, cfg.head_dim // 2
+    extra = cfg.rope_theta ** (-2.0 * np.arange(half) / dim)
+    inter = extra / cfg.rope_factor
+
+    def pair_of(turns):
+        return (dim * math.log(cfg.original_max_position_embeddings
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = max(math.floor(pair_of(cfg.beta_fast)), 0)
+    high = min(math.ceil(pair_of(cfg.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def plain_inv_freq(cfg: MellumConfig) -> np.ndarray:
+    half = cfg.head_dim // 2
+    return (cfg.rope_theta ** (-2.0 * np.arange(half) / cfg.head_dim)
+            ).astype(np.float32)
+
+
+def attention_mask(q_pos, k_pos, window: Optional[int]):
+    """``(B, T)`` query and ``(B, S)`` key positions -> ``(B, T, S)``, True
+    where the key may be read: a position that exists (``>= 0``), not after
+    the query, and with ``window`` fewer than that many places before it
+    (the query's own place counted)."""
+    q, k = q_pos[:, :, None], k_pos[:, None, :]
+    ok = (k >= 0) & (k <= q)
+    return ok if window is None else ok & (q - k < window)
+
+
+# -- parameters ----------------------------------------------------------------
+
+def _layer_spec(cfg):
+    d, hd = cfg.hidden_size, cfg.head_dim
+    return (
+        ("input_norm", (("scale", (d,)),)),
+        ("attn", (
+            ("q", (("kernel", (d, cfg.num_attention_heads * hd)),)),
+            ("k", (("kernel", (d, cfg.num_key_value_heads * hd)),)),
+            ("v", (("kernel", (d, cfg.num_key_value_heads * hd)),)),
+            ("o", (("kernel", (cfg.num_attention_heads * hd, d)),)),
+        )),
+        ("post_norm", (("scale", (d,)),)),
+        ("router", (("kernel", (d, cfg.num_experts)),)),
+        ("experts", _mlp_spec(d, cfg.moe_intermediate_size,
+                              lead=(cfg.held,))),
+    )
+
+
+def param_spec(cfg):
+    d = cfg.hidden_size
+    return (
+        ("embed", (cfg.vocab_size, d)),
+        ("layers", _stacked(_layer_spec(cfg), cfg.num_hidden_layers)),
+        ("final_norm", (("scale", (d,)),)),
+        ("head", (("kernel", (d, cfg.vocab_size)),)),
+    )
+
+
+# -- the layer's mathematics ---------------------------------------------------
+
+def gqa_project(cfg, p, xn, positions, full: bool):
+    """``xn`` (normalized, compute type) -> rotated ``q`` ``(B, T, Hkv, G,
+    D)`` and ``k`` ``(B, T, Hkv, D)``, and ``v``, each rounded once."""
+    B, T, _ = xn.shape
+    hkv, hd = cfg.num_key_value_heads, cfg.head_dim
+    g = cfg.num_attention_heads // hkv
+    q = _dot("btd,df->btf", xn, p["q"]["kernel"]).reshape(B, T, hkv, g, hd)
+    k = _dot("btd,df->btf", xn, p["k"]["kernel"]).reshape(B, T, hkv, hd)
+    v = _dot("btd,df->btf", xn, p["v"]["kernel"], cfg.dtype).reshape(
+        B, T, hkv, hd)
+    table = dict(inv_freq=yarn_inv_freq(cfg), scale=cfg.attention_factor) \
+        if full else dict(inv_freq=plain_inv_freq(cfg))
+    q = rope(q, positions, cfg.rope_theta, **table).astype(cfg.dtype)
+    k = rope(k, positions, cfg.rope_theta, **table).astype(cfg.dtype)
+    return q, k, v
+
+
+def gqa_attend(cfg, q, k, v, mask):
+    """``q`` ``(B, T, Hkv, G, D)`` over ``k``, ``v`` ``(B, S, Hkv, D)``
+    under ``mask`` ``(B, T, S)``; softmax in float32 -> ``(B, T, H * D)``."""
+    B, T = q.shape[:2]
+    scores = _dot("btkgd,bskd->bkgts", q, k) / np.sqrt(cfg.head_dim)
+    scores = jnp.where(mask[:, None, None], scores,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+    out = _dot("bkgts,bskd->btkgd", probs, v, cfg.dtype)
+    return out.reshape(B, T, cfg.num_attention_heads * cfg.head_dim)
+
+
+# -- the module ----------------------------------------------------------------
+
+class Mellum(nn.Module):
+    cfg: MellumConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, tokens, *, deterministic: bool = True,
+                 decode: bool = False, slot_ids=None,
+                 paged: Optional[PagedKVConfig] = None, block_tables=None,
+                 live=None):
+        cfg = self.cfg
+        B, T = tokens.shape
+        if decode and (paged is None or slot_ids is None
+                       or block_tables is None):
+            raise ValueError(
+                "the two K/V pools are paged only: decode=True needs "
+                "slot_ids, paged=PagedKVConfig(...) and block_tables (the "
+                "continuous scheduler's cache_mode='paged'); there is no "
+                "dense-row or fixed-batch cache of this family")
+        if not decode and (paged is not None or slot_ids is not None
+                           or block_tables is not None or live is not None):
+            raise ValueError(
+                "slot_ids, paged, block_tables and live only apply to "
+                "decode=True calls")
+        if paged is not None:
+            if paged.quantized or paged.kv_dtype is not None:
+                raise ValueError(
+                    f"kv_dtype {paged.kv_dtype!r}: "
+                    f"{SERVE_REFUSALS['kv_dtype']}")
+            if paged.data_shards != 1:
+                raise ValueError(SERVE_REFUSALS["per_shard_kv"])
+            if cfg.n_window_layers and not paged.window_ring:
+                raise ValueError(
+                    "window layers need the window pool: paged.window_ring "
+                    "and paged.window_blocks (PagedKVConfig), which the "
+                    "continuous scheduler sizes from cache_geometry()")
+            # A ring as long as the row's own table never wraps; the
+            # engine's shape-only init call is as long as the row.
+            wraps = (0 < paged.window_ring
+                     < block_tables.shape[1] - paged.window_ring)
+            if (wraps and not self.is_initializing()
+                    and T + cfg.sliding_window - 1 > paged.window_capacity):
+                raise ValueError(
+                    f"a call of {T} positions needs the {cfg.sliding_window}"
+                    f" - 1 before its first still in the window ring: "
+                    f"{T + cfg.sliding_window - 1} positions, and the ring "
+                    f"holds {paged.window_capacity} ({paged.window_ring} "
+                    f"blocks of {paged.block_size}); prefill in chunks "
+                    f"(prefill_budget) or size the ring for the call")
+        params = _declare(self, param_spec(cfg), cfg)
+        # Float32 from here to the head, as glm4_moe_lite keeps it: the
+        # router reads the stream's norm unrounded.
+        x = params["embed"][tokens].astype(jnp.float32)
+
+        n_win, n_full = cfg.n_window_layers, cfg.n_full_layers
+        if decode:
+            bs = paged.block_size
+            row = (bs, cfg.kv_row)
+            full_pool = self.variable(
+                "cache", "full_pool", lambda: jnp.zeros(
+                    (n_full, paged.num_blocks) + row, cfg.dtype))
+            window_pool = self.variable(
+                "cache", "window_pool", lambda: jnp.zeros(
+                    (n_win, max(paged.window_blocks, 1)) + row, cfg.dtype))
+            index = self.variable(
+                "cache", "cache_index", lambda: jnp.zeros((B,), jnp.int32))
+            counts = self.variable(
+                "cache", "moe_counts", lambda: jnp.zeros(
+                    (cfg.num_hidden_layers, cfg.held + COUNT_EXTRA),
+                    jnp.int32))
+            start = index.value[slot_ids]                         # (B,)
+            positions = start[:, None] + jnp.arange(T)[None, :]   # (B, T)
+            full_bt, ring_bt = paged.split_tables(
+                jnp.maximum(block_tables, 0)[slot_ids])
+            flat = lambda a: a.reshape(-1)
+            # Full layers: position p in the row's p // bs-th block; the
+            # whole table row is read back, in position order.
+            full_cells = (flat(jnp.take_along_axis(
+                full_bt, positions // bs, axis=1)), flat(positions % bs))
+            full_mask = attention_mask(
+                positions, jnp.broadcast_to(
+                    jnp.arange(full_bt.shape[1] * bs)[None],
+                    (B, full_bt.shape[1] * bs)), None)
+            win_cells = win_mask = None
+            if n_win:
+                # Window layers: position p in ring cell p % cap.  After
+                # this call's writes, cell c holds the newest position
+                # <= last with that remainder (below 0: never written).
+                cap = paged.window_capacity
+                ring_pos = positions % cap
+                win_cells = (flat(jnp.take_along_axis(
+                    ring_bt, ring_pos // bs, axis=1)), flat(ring_pos % bs))
+                last = positions[:, -1:]                          # (B, 1)
+                held_pos = last - (last - jnp.arange(cap)[None]) % cap
+                win_mask = attention_mask(positions, held_pos,
+                                          cfg.sliding_window)
+            index.value = index.value.at[slot_ids].set(start + T)
+            pools = (window_pool.value, full_pool.value)
+        else:
+            positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+            full_mask = attention_mask(positions, positions, None)
+            win_mask = attention_mask(positions, positions,
+                                      cfg.sliding_window)
+            pools, full_bt, ring_bt = (None, None), None, None
+            full_cells = win_cells = None
+        token_live = None if live is None else jnp.repeat(live, T)
+
+        def attention(p, x, pool, layer, full: bool):
+            xn = rms_norm(x, p["input_norm"]["scale"],
+                          cfg.rms_norm_eps).astype(cfg.dtype)
+            q, k, v = gqa_project(cfg, p["attn"], xn, positions, full)
+            if pool is not None:
+                cells, table = ((full_cells, full_bt) if full
+                                else (win_cells, ring_bt))
+                paged_attention.note_path(
+                    GATHER_FULL if full else GATHER_WINDOW)
+                half = cfg.kv_row // 2
+                pool = pool.at[(layer,) + cells].set(jnp.concatenate(
+                    [k.reshape(B * T, half), v.reshape(B * T, half)],
+                    axis=-1))
+                rows = pool[layer, table].reshape(B, -1, cfg.kv_row)
+                shape = (B, rows.shape[1], cfg.num_key_value_heads,
+                         cfg.head_dim)
+                k = rows[..., :half].reshape(shape)
+                v = rows[..., half:].reshape(shape)
+            ctx = gqa_attend(cfg, q, k, v, full_mask if full else win_mask)
+            return x + _dot("btf,fd->btd", ctx, p["attn"]["o"]["kernel"]), pool
+
+        period = cfg.period
+        kinds = [t == FULL for t in cfg.layer_types[:period]]
+        win_per = period - sum(kinds)
+
+        def one_period(carry, n):
+            x, win_pool, full_pool_v = carry
+            rows = []
+            seen = [0, 0]                   # window, full layers so far
+            for i, full in enumerate(kinds):
+                # One layer's leaves, taken from the stack where a product
+                # reads them: a period's leaves sliced out together are
+                # copied, 0.8 GB of expert stacks a period and step.
+                p = jax.tree.map(
+                    lambda w: lax.dynamic_index_in_dim(
+                        w, n * period + i, keepdims=False), params["layers"])
+                if full:
+                    layer = n * (period - win_per) + seen[1]
+                    h, full_pool_v = attention(p, x, full_pool_v, layer, True)
+                else:
+                    layer = n * win_per + seen[0]
+                    h, win_pool = attention(p, x, win_pool, layer, False)
+                seen[full] += 1
+                hn = rms_norm(h, p["post_norm"]["scale"], cfg.rms_norm_eps)
+                y, row = expert_layer(
+                    cfg, p, hn.reshape(B * T, cfg.hidden_size), token_live)
+                x = h + y.reshape(h.shape)
+                rows.append(row)
+            return (x, win_pool, full_pool_v), jnp.stack(rows)
+
+        n_periods = cfg.num_hidden_layers // period
+        (x, win_pool, full_pool_v), rows = lax.scan(
+            one_period, (x,) + pools,
+            jnp.arange(n_periods, dtype=jnp.int32))
+        if decode:
+            window_pool.value, full_pool.value = win_pool, full_pool_v
+            counts.value = counts.value + rows.reshape(counts.value.shape)
+        x = rms_norm(x, params["final_norm"]["scale"],
+                     cfg.rms_norm_eps).astype(cfg.dtype)
+        return _dot("btd,dv->btv", x, params["head"]["kernel"])
+
+
+# -- what the engine and the scheduler ask of a decoder family -----------------
+
+def cache_rules(per_shard_pools: bool = False) -> ShardingRules:
+    """The cache collection is replicated (no ``tensor`` rule; the workload
+    refuses such a mesh)."""
+    del per_shard_pools
+    return ShardingRules()
+
+
+def cache_geometry(cfg: MellumConfig, paged: PagedKVConfig) -> Dict[str, Any]:
+    """Both kinds of pool.  ``window_positions`` and the layer counts are
+    what the scheduler sizes the ring from before a ``paged`` with a window
+    pool exists; the ``window`` group is what that pool then costs."""
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    per_layer = cfg.kv_row * itemsize
+    block = paged.block_size * per_layer
+    out = {
+        "kind": "key_value_grouped",
+        "pools_per_layer": 1,
+        "values_per_token_layer": cfg.kv_row,
+        "pool_width": cfg.kv_row,
+        "padding_values": 0,
+        "bytes_per_token_layer": per_layer,
+        # A token's bytes while it is inside the window, and after.
+        "bytes_per_token": cfg.num_hidden_layers * per_layer,
+        "bytes_per_token_past_window": cfg.n_full_layers * per_layer,
+        "full_layers": cfg.n_full_layers,
+        "window_layers": cfg.n_window_layers,
+        "window_positions": cfg.sliding_window if cfg.n_window_layers else 0,
+        "full_block_bytes": cfg.n_full_layers * block,
+        "window_block_bytes": cfg.n_window_layers * block,
+        "full_pool_bytes": cfg.n_full_layers * paged.num_blocks * block,
+        "window_ring_blocks": paged.window_ring,
+        "window_ring_positions": paged.window_capacity,
+        "window_pool_bytes": (cfg.n_window_layers * paged.window_blocks
+                              * block),
+    }
+    out["pool_bytes"] = out["full_pool_bytes"] + out["window_pool_bytes"]
+    return out
+
+
+SERVE_REFUSALS = {
+    "dense_cache": (
+        "window and full layers keep their K/V in two paged pools "
+        "(cache_mode='paged'): there is no dense-row layout of them"),
+    "kv_dtype": (
+        "the two pools are stored in the compute type: an int8 or cast "
+        "K/V needs scale tables for both pools and a dequantizing read"),
+    "per_shard_kv": (
+        "the pools are replicated: per-shard pools are not built for the "
+        "window ring"),
+    "slo_scheduling": (
+        "host tiering swaps one pool's blocks and does not know the window "
+        "ring, whose blocks hold a row's latest positions and not its "
+        "first; preempting would lose a victim's cache"),
+    "spec_k": (
+        "a verify launch rolls rejected positions back, and in the ring "
+        "they have already overwritten the positions a window behind"),
+    "prefix_cache": (
+        "a shared prefix block of a window layer may already be "
+        "overwritten by the request that registered it"),
+    "tensor_mesh": (
+        "four K/V heads and the expert stack have no tensor rule: serve on "
+        "a mesh without a 'tensor' axis"),
+}
+
+
+def _loss_fn(module, params, batch, rng):
+    tokens = batch["tokens"]
+    logits = module.apply({"params": params}, tokens)
+    loss = jnp.mean(optax.softmax_cross_entropy_with_integer_labels(
+        logits[:, :-1], tokens[:, 1:]))
+    return loss, {"perplexity": jnp.exp(jnp.minimum(loss, 20.0))}
+
+
+def make_workload(
+    *,
+    preset: str = "published",
+    batch_size: int = 8,
+    seq_len: Optional[int] = None,
+    config: Optional[MellumConfig] = None,
+    mesh: Optional[Mesh] = None,
+    **_unused,
+) -> Workload:
+    cfg = config or getattr(MellumConfig, preset)()
+    if mesh is not None and mesh.shape.get("tensor", 1) > 1:
+        raise ValueError(
+            f"mellum on a mesh with tensor={mesh.shape['tensor']}: "
+            f"{SERVE_REFUSALS['tensor_mesh']}")
+    seq = seq_len or min(cfg.max_position_embeddings, 128)
+    module = Mellum(cfg, mesh=mesh)
+    data = functools.partial(synthetic_lm, seq_len=seq,
+                             vocab_size=cfg.vocab_size)
+    return Workload(
+        name="mellum",
+        module=module,
+        loss_fn=functools.partial(_loss_fn, module),
+        init_batch={"tokens": np.zeros((2, seq), np.int32)},
+        data_fn=lambda per_host_bs: data(batch_size=per_host_bs),
+        eval_data_fn=lambda per_host_bs: data(batch_size=per_host_bs,
+                                              holdout=True),
+        rules=ShardingRules(),
+        batch_size=batch_size,
+        clip_grad_norm=1.0,
+        learning_rate=3e-4,
+        example_key="tokens",
+        init_key="tokens",
+        cache_rules=cache_rules,
+        cache_geometry=functools.partial(cache_geometry, cfg),
+        serve_refusals=dict(SERVE_REFUSALS),
+    )

@@ -2,7 +2,7 @@
 
 The serving path keeps K and V in a lane-dense pool of blocks,
 ``(layers, num_blocks, block_size, heads * head_dim)``, and a slot finds its
-positions through its block-table row (``models/gpt2.py: PagedKVConfig``).
+positions through its block-table row (``models/__init__.py: PagedKVConfig``).
 The plain way to attend over that is to gather every slot's whole table row
 into a contiguous ``(B, max_total_len, heads, head_dim)`` view and contract
 it: XLA then re-lays the gathered rows with the head size padded to 128
@@ -85,6 +85,18 @@ def one_path(paths) -> str:
     """The path of a program from its calls': the one every call took (the
     kernel's only if all did), else the gather's."""
     return next(iter(paths)) if len(set(paths)) == 1 else GATHER
+
+
+def program_paths(paths):
+    """The paths a program is on record with: ``one_path`` of the kernel's
+    two, and where its calls name paths of their own (a family whose
+    layers are of two kinds and attend two ways), each of them."""
+    kinds = set(paths)
+    if not kinds:
+        return ()
+    if kinds <= {KERNEL, GATHER}:
+        return (one_path(paths),)
+    return tuple(sorted(kinds))
 
 
 def supported(*, query_len: int, block_size: int, width: int, pool_dtype,

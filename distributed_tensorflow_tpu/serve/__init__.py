@@ -15,7 +15,7 @@ Layers:
 - ``paged``: host-side block bookkeeping for ``cache_mode="paged"``
   (``BlockAllocator``) — K/V lives in a fixed pool of blocks reached
   through per-slot block tables, with optional int8 storage
-  (``models.gpt2.PagedKVConfig``);
+  (``models.PagedKVConfig``);
 - ``driver``: the in-process request loop behind ``serve.py`` and the
   benchmark's serving cells (``run_serve`` / ``ServeArgs``);
 - ``fleet``: multi-replica serving — ``FleetRouter`` dispatches over N

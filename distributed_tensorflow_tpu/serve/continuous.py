@@ -187,6 +187,7 @@ from typing import Any, Dict, List, Optional, Tuple, Union
 import jax
 import numpy as np
 
+from distributed_tensorflow_tpu.models import PagedKVConfig
 from distributed_tensorflow_tpu.obs import metrics as obs_metrics
 from distributed_tensorflow_tpu.obs.lifecycle import EMPTY_LIFECYCLE_STATS
 from distributed_tensorflow_tpu.obs.trace import default_tracer, now as _now
@@ -294,6 +295,16 @@ def _continuous_instruments(registry=None):
             "the expert-parallel deployment ('absent'); counted on the "
             "device by the expert layers and fetched with the tokens",
             labelnames=("held",)),
+        "kv_blocks_held": r.gauge(
+            "dtt_serve_kv_blocks_held",
+            "Physical K/V blocks live requests hold, by the kind of pool: "
+            "'full' (the block-table pool, grows with the row) or 'window' "
+            "(a family's window layers: at most the slot's ring)",
+            labelnames=("kind",)),
+        "window_recycled": r.counter(
+            "dtt_serve_window_blocks_recycled_total",
+            "Blocks of a row's positions written over a ring entry whose "
+            "positions had slid out of the window (no block was taken)"),
         "megastep_amortized": r.counter(
             "dtt_serve_megastep_launches_amortized_total",
             "Tokens fetched beyond one per decode launch (host "
@@ -716,8 +727,6 @@ class ContinuousScheduler:
         self.block_size = int(block_size)
         shards = 1
         if cache_mode == "paged":
-            from distributed_tensorflow_tpu.models.gpt2 import PagedKVConfig
-
             if per_shard_kv:
                 shards = max(1, engine.data_parallelism)
             # spec_k tail slack: the verify program's width is fixed at
@@ -737,9 +746,27 @@ class ContinuousScheduler:
                 # hand-picked pool UP to the next multiple of the shard
                 # count rather than rejecting it.
                 num_blocks = -(-int(num_blocks) // shards) * shards
-            self.paged: Optional["PagedKVConfig"] = PagedKVConfig(
+            # A family with window layers (``cache_geometry`` says how
+            # many positions they read) keeps those layers' K/V in a second
+            # pool where a slot owns a ring, whatever its length: the
+            # window, the longest call's positions (a prefill chunk must
+            # still see the window before its first) and a megastep, in
+            # whole blocks and one more; never more than the row itself.
+            geometry = engine.workload.cache_geometry
+            self._kv_geometry = (geometry(PagedKVConfig(
+                block_size=self.block_size, num_blocks=int(num_blocks)))
+                if geometry is not None else {})
+            self._window = int(self._kv_geometry.get("window_positions", 0))
+            ring = 0
+            if self._window:
+                chunk = self.prefill_budget or self.max_total_len
+                ring = min(per_slot, -(-(self._window + chunk + self.megastep)
+                                       // self.block_size) + 1)
+            self.paged: Optional[PagedKVConfig] = PagedKVConfig(
                 block_size=self.block_size, num_blocks=int(num_blocks),
-                kv_dtype=kv_dtype, data_shards=shards)
+                kv_dtype=kv_dtype, data_shards=shards,
+                window_blocks=self.num_slots * ring + 1 if ring else 0,
+                window_ring=ring)
             self._cache = engine.init_paged_cache(
                 self.num_slots, self.max_total_len, paged=self.paged)
             self._allocator: Optional[BlockAllocator] = BlockAllocator(
@@ -754,15 +781,25 @@ class ContinuousScheduler:
             # (and entries past a slot's allocation) point at the slot's
             # shard's trash block (block 0 in single-shard mode).  Passed
             # into every prefill/decode call.
+            # With a window pool the slot's ring entries follow the full
+            # layers' in the same row (``PagedKVConfig.split_tables``);
+            # slot s's e-th ring entry is always window block 1 + s * ring
+            # + e, mapped when the row first reaches it and pointed back
+            # at the window pool's trash block (0) at retirement.
+            self._full_cols = per_slot
             self._block_tables = np.zeros(
-                (self.num_slots, per_slot), np.int32)
+                (self.num_slots, per_slot + ring), np.int32)
             for s in range(self.num_slots):
-                self._block_tables[s, :] = self._allocator.trash_block(
-                    self._slot_shard[s])
+                self._block_tables[s, :per_slot] = (
+                    self._allocator.trash_block(self._slot_shard[s]))
+            # Blocks of positions each slot's row has covered so far (the
+            # ring holds the last ``ring`` of them).  Loop thread.
+            self._window_covered = [0] * self.num_slots
             self._slot_blocks: Dict[int, List[int]] = {
                 s: [] for s in range(self.num_slots)}
         else:
             self.paged = None
+            self._window = 0
             self._allocator = None
             self._block_tables = None
             self._slot_blocks = {}
@@ -943,6 +980,10 @@ class ContinuousScheduler:
         # decode launch held at its dispatch, summed: what the launch's
         # attention has to read at the least.
         self._live_positions_sum = 0
+        # The same, each row counted up to the window (what a window
+        # layer's attention reads), and ring entries written over.
+        self._live_window_positions_sum = 0
+        self._window_recycled = 0
         self._last_occupancy = 0
         self._latencies_ms: collections.deque = collections.deque(maxlen=1024)
         self._ttft_ms: collections.deque = collections.deque(maxlen=1024)
@@ -1323,6 +1364,7 @@ class ContinuousScheduler:
                 # slot_occupancy): the least its attention reads.
                 "decode_live_positions": (
                     self._live_positions_sum / iters if iters else 0.0),
+                **self._two_pool_stats_locked(),
                 "admissions_per_iter": (
                     self._admitted / iters if iters else 0.0),
                 "retirements_per_iter": (
@@ -2029,6 +2071,7 @@ class ContinuousScheduler:
             return
         blocks = self._slot_blocks[req.slot]
         needed = self.paged.blocks_for(tokens_written)
+        self._cover_window_ring(req.slot, needed)
         if needed <= len(blocks):
             return
         shard = self._slot_shard[req.slot]
@@ -2041,6 +2084,73 @@ class ContinuousScheduler:
             release = min(req.reserved_blocks, len(fresh))
             req.reserved_blocks -= release
             self._reserved[shard] -= release
+
+    def _cover_window_ring(self, slot: int, covered: int) -> None:
+        """The window pool's side of ``_ensure_blocks``: the row now covers
+        ``covered`` blocks of positions.  Ring entries are mapped as the
+        row first reaches them and no further; past the ring a block's
+        positions go over the entry that slid out of the window, counted
+        as recycled, and nothing is taken or copied."""
+        ring = self.paged.window_ring
+        if not ring:
+            return
+        with self._lock:
+            seen = self._window_covered[slot]
+        if covered <= seen:
+            return
+        held, want = min(seen, ring), min(covered, ring)
+        if want > held:
+            first = 1 + slot * ring
+            self._block_tables[
+                slot, self._full_cols + held:self._full_cols + want] = (
+                np.arange(first + held, first + want))
+            self._dev_block_tables = None  # host table grew
+        recycled = max(0, covered - max(seen, ring))
+        self._note_window_covered(slot, covered, recycled)
+        if recycled:
+            self._obs["window_recycled"].inc(recycled)
+
+    def _note_window_covered(self, slot: int, covered: int,
+                             recycled: int = 0) -> None:
+        """``stats()`` reads the rows' coverage from other threads: it
+        changes under the lock, and the gauges follow it."""
+        with self._lock:
+            self._window_covered[slot] = covered
+            self._window_recycled += recycled
+            window = self._window_blocks_held_locked()
+        self._obs["kv_blocks_held"].labels(kind="full").set(
+            self._allocator.used_count)
+        self._obs["kv_blocks_held"].labels(kind="window").set(window)
+
+    def _window_blocks_held_locked(self) -> int:
+        """Call under ``_lock``."""
+        ring = self.paged.window_ring
+        return sum(min(c, ring) for c in self._window_covered)
+
+    def _two_pool_stats_locked(self) -> Dict[str, float]:
+        """What the rows hold in each kind of pool (call under ``_lock``);
+        nothing for a family with one.  ``kv_bytes_held_uniform`` is what
+        the same rows would hold if every layer kept a block wherever the
+        full layers do (one geometry for all)."""
+        if self.paged is None or not self.paged.window_ring:
+            return {}
+        g = self._kv_geometry
+        full, window = self._allocator.used_count, self._window_blocks_held_locked()
+        iters = self._iterations
+        return {
+            "kv_blocks_held_full": float(full),
+            "kv_blocks_held_window": float(window),
+            "kv_bytes_held": float(full * g["full_block_bytes"]
+                                   + window * g["window_block_bytes"]),
+            "kv_bytes_held_uniform": float(full * (
+                g["full_block_bytes"] + g["window_block_bytes"])),
+            "window_ring_blocks": float(self.paged.window_ring),
+            "window_blocks_recycled": float(self._window_recycled),
+            # The full layers read ``decode_live_positions``; the window
+            # layers the same rows, each counted up to the window.
+            "decode_live_positions_window": (
+                self._live_window_positions_sum / iters if iters else 0.0),
+        }
 
     def _paged_call_kwargs(self) -> Dict[str, Any]:
         """Paged kwargs for the slot programs, with the block tables kept
@@ -2468,6 +2578,7 @@ class ContinuousScheduler:
         eos_rows = np.full((self.num_slots,), -1, np.int32)
         active_slots: List[int] = []
         live_positions = 0      # cached positions the launched rows hold
+        live_window = 0         # ... each row counted up to the window
         pending: Dict[int, int] = {}
         for slot in sorted(decoding):
             req = decoding[slot]
@@ -2476,8 +2587,9 @@ class ContinuousScheduler:
             if left <= 0:
                 continue  # the rest of the horizon is already in flight
             active_slots.append(slot)
-            live_positions += (req.base_prompt_len + len(req.tokens)
-                               + inflight)
+            held = req.base_prompt_len + len(req.tokens) + inflight
+            live_positions += held
+            live_window += min(held, self._window)
             pending[slot] = min(K, left)
             horizon[slot] = left
             if req.eos_token is not None:
@@ -2541,6 +2653,7 @@ class ContinuousScheduler:
                 self._iterations += 1
                 self._occupancy_sum += len(active_slots)
                 self._live_positions_sum += live_positions
+                self._live_window_positions_sum += live_window
                 self._last_occupancy = len(active_slots)
                 self._note_dispatch_locked(dispatch_t)
                 seq = self._launch_seq
@@ -3386,6 +3499,8 @@ class ContinuousScheduler:
             self._block_tables[req.slot, :] = self._allocator.trash_block(
                 self._slot_shard[req.slot])
             self._dev_block_tables = None  # host table reset
+            if self.paged.window_ring:      # the ring's entries too
+                self._note_window_covered(req.slot, 0)
         else:
             used = self.paged_equivalent_blocks
         with self._lock:

@@ -450,8 +450,7 @@ class ServeEngine:
         def body(*args):
             with paged_attention.record_paths() as paths:
                 out = fn(*args)
-            if paths:
-                path = paged_attention.one_path(paths)
+            for path in paged_attention.program_paths(paths):
                 traced = self._attention_paths.setdefault(key, {})
                 traced[path] = traced.get(path, 0) + 1
             return out
@@ -478,10 +477,9 @@ class ServeEngine:
                 for path in _DECODE_PATHS}
 
     def _count_decode_launch(self, key) -> None:
-        traced = self._attention_paths.get(key)
-        if traced:
-            self._obs["decode_attention"].labels(
-                path=paged_attention.one_path(traced)).inc()
+        for path in paged_attention.program_paths(
+                self._attention_paths.get(key, ())):
+            self._obs["decode_attention"].labels(path=path).inc()
 
     def _decode_step_fn(self, temperature: float, top_k: int) -> Callable:
         """Jitted fixed-batch decode step for one sampling config.  The
@@ -582,7 +580,7 @@ class ServeEngine:
         part of this tree — the caller owns it host-side and passes it
         into every prefill/decode call.
 
-        ``paged`` is a ``models.gpt2.PagedKVConfig``; the pool must hold at
+        ``paged`` is a ``models.PagedKVConfig``; the pool must hold at
         least one maximum-length request plus the reserved trash block.
         """
         dp = max(1, self.data_parallelism)
@@ -624,8 +622,9 @@ class ServeEngine:
                     decode=True,
                     slot_ids=jnp.arange(num_slots, dtype=jnp.int32),
                     paged=paged,
-                    block_tables=jnp.zeros((num_slots, max_blocks),
-                                           jnp.int32))
+                    block_tables=jnp.zeros(
+                        (num_slots, paged.table_width(total_len)),
+                        jnp.int32))
                 return vs["cache"]
 
             shapes = jax.eval_shape(mk)
@@ -1720,6 +1719,8 @@ def moe_counts_of(cache: PyTree):
 
 
 # Attention paths a traced program may have on record: the paged kernel's
-# two (``ops.paged_attention``) and the latent attention's two.
+# two (``ops.paged_attention``), the latent attention's two and the
+# grouped-query family's two (a program of its has both).
 _DECODE_PATHS = (paged_attention.KERNEL, paged_attention.GATHER,
-                 "latent_absorbed", "latent_expanded")
+                 "latent_absorbed", "latent_expanded",
+                 "gqa_gather_window", "gqa_gather_full")

@@ -12,6 +12,8 @@ Examples:
     python serve.py --model=gpt2 --tensor=2                  # TP decode
     python serve.py --model=glm4_moe_lite --continuous --cache_mode=paged \
         --megastep=4 --async_decode     # latent (MLA) cache, sparse experts
+    python serve.py --model=mellum --continuous --cache_mode=paged \
+        --prefill_budget=64 --megastep=4  # window + full layers, two K/V pools
     python serve.py --model=gpt2 --continuous --num_slots=8 \
         --prompt_lens=8,16,24 --min_new_tokens=4             # continuous batching
     python serve.py --model=gpt2 --continuous --cache_mode=paged \
@@ -71,13 +73,16 @@ def parse_args(argv=None):
     defaults = ServeArgs()
     p = argparse.ArgumentParser(description="TPU-native batched serving")
     p.add_argument("--model", default=defaults.model,
-                   help="gpt2 or glm4_moe_lite (KV-cache decode) or "
+                   help="gpt2, glm4_moe_lite or mellum (KV-cache decode) or "
                         "mnist|resnet50|bert (batched classify).  "
                         "glm4_moe_lite (latent attention, sparse experts) "
-                        "serves with --continuous --cache_mode=paged only "
-                        "and refuses, with the reason, --kv_dtype, "
-                        "--per_shard_kv, --prefix_cache, --spec_k, "
-                        "--slo_scheduling and a --tensor mesh")
+                        "and mellum (grouped-query attention, window and "
+                        "full layers over two paged K/V pools, sparse "
+                        "experts) serve with --continuous "
+                        "--cache_mode=paged only and refuse, with the "
+                        "reason, --kv_dtype, --per_shard_kv, "
+                        "--prefix_cache, --spec_k, --slo_scheduling and a "
+                        "--tensor mesh")
     p.add_argument("--checkpoint_dir", default=None,
                    help="restore params from here (fresh random init when "
                         "unset or empty — the smoke path)")
@@ -249,7 +254,9 @@ def parse_args(argv=None):
                         "tiny|v5e8_share|flash (default tiny on CPU, "
                         "v5e8_share, one chip's share of an 8-chip "
                         "expert-parallel host, on TPU; flash is the whole "
-                        "60 GB model)")
+                        "60 GB model); mellum tiny|v5e4_share|published "
+                        "(default tiny on CPU, v5e4_share, one chip's "
+                        "share of a 4-chip host, on TPU)")
     for axis in ("data", "fsdp", "tensor"):
         p.add_argument(f"--{axis}", type=int,
                        default=getattr(defaults, axis),

@@ -632,3 +632,99 @@ def test_accumulating_step_reduces_over_data_once(topo):
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < V5E_HBM_BYTES
+
+
+# -- the grouped-query, window-and-full family at its cell's shapes -----------
+
+def lower_two_pool_program(topo, program):
+    """``serve.mellum2-12b-a2.5b.code-mixed-saturated``'s decode or prefill
+    program, lowered from shapes as ``lower_serve_program`` does, with the
+    scheduler's two tables a slot (the full layers' entries, then the
+    window ring's) and the cell's own lengths."""
+    from benchmark.harness import program as program_lib, spec
+    from distributed_tensorflow_tpu.models import PagedKVConfig
+    from distributed_tensorflow_tpu.models.mellum import Mellum
+    from distributed_tensorflow_tpu.serve import sampling as sampling_lib
+    from distributed_tensorflow_tpu.serve.engine import ServeEngine
+
+    cell = spec.load_cell("serve.mellum2-12b-a2.5b.code-mixed-saturated")
+    sched = cell.cell["scheduler"]
+    slots, total, block = (sched["num_slots"], sched["max_total_len"],
+                           sched["block_size"])
+    chunk, steps = sched["prefill_budget"], sched["megastep"]
+    module = Mellum(program_lib.program_config(cell.config))
+    engine = object.__new__(ServeEngine)
+    engine.module = module
+    per_slot = total // block
+    ring = -(-(module.cfg.sliding_window + chunk + steps) // block) + 1
+    paged = PagedKVConfig(
+        block_size=block, num_blocks=slots * per_slot + 1,
+        window_blocks=slots * ring + 1, window_ring=ring)
+    width = paged.table_width(total)
+
+    def arg(shape, dtype=jnp.int32):
+        return one_chip(topo, shape, dtype)
+
+    variables = jax.eval_shape(lambda: module.init(
+        jax.random.key(0), jnp.zeros((slots, total), jnp.int32),
+        decode=True, slot_ids=jnp.arange(slots, dtype=jnp.int32),
+        paged=paged, block_tables=jnp.zeros((slots, width), jnp.int32)))
+    params, cache = jax.tree.map(
+        lambda s: arg(s.shape, s.dtype),
+        (variables["params"], variables["cache"]))
+    counts = arg((slots, module.cfg.vocab_size))
+    tables = arg((slots, width))
+    rng = arg((), jax.random.key(0).dtype)
+    sampling = lambda rows: jax.tree.map(
+        lambda a: arg(np.shape(a), np.asarray(a).dtype),
+        sampling_lib.uniform(rows, 0.0, 0))
+    if program == "decode_megastep":
+        fn = jax.jit(
+            lambda *a: engine._megastep_counting_apply(steps, paged, *a),
+            donate_argnums=(1, 2))
+        lowered = fn.lower(
+            params, cache, counts, arg((slots,)), arg((slots,), jnp.bool_),
+            arg((slots,)), arg((slots,)), tables, rng, arg(()),
+            sampling(slots), arg((slots,)), arg((slots,), jnp.bool_),
+            arg(()))
+    else:
+        fn = jax.jit(
+            lambda *a: engine._prefill_slots_apply(paged, *a),
+            donate_argnums=(1, 2))
+        lowered = fn.lower(
+            params, cache, counts, arg((1, chunk)), arg((1,)), tables,
+            rng, arg(()), arg((1,)), sampling(1), arg((1,), jnp.bool_))
+    return lowered, cache, slots
+
+
+@pytest.mark.parametrize("program", ["decode_megastep", "prefill_slots"])
+def test_two_pool_serve_programs_fit_one_chip_at_the_cells_shapes(topo,
+                                                                  program):
+    """One chip's share of the 4-chip deployment, 16 layers at the
+    published widths: 4.08 GB of bfloat16 weights, the 4 full layers' pool
+    of ``slots x 256 + 1`` blocks and the 12 window layers' of ``slots x
+    98 + 1`` (a ring a slot, whatever the row's length), both updated in
+    place; at 16 slots 5.24 GB of arguments, and 0.58 GB of scratch for the
+    decode program (4 fused steps; the gathered rows and rings and their
+    float32 scores; 0.41 GB at 8 slots), 0.63 GB for a prefill chunk of 512
+    (one slot's, whatever the slots).  A scanned body that slices a whole
+    period's leaves out of the layer stack copies them (0.8 GB of expert
+    stacks: 1.28 and 1.51 GB of scratch at 8 slots, and half of a decode
+    step's time on the chip; PERF.md Findings, PR 35): the scratch bound
+    below is what catches it.  The cell's ``num_slots_arithmetic`` and
+    PERF.md section 4 quote these figures."""
+    lowered, cache, slots = lower_two_pool_program(topo, program)
+    full, window = cache["full_pool"].shape, cache["window_pool"].shape
+    assert full == (4, slots * 256 + 1, 16, 1024)
+    assert window == (12, slots * 98 + 1, 16, 1024)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    pools = 2 * (np.prod(full) + np.prod(window))
+    assert 4.07e9 + pools < memory.argument_size_in_bytes < 4.10e9 + pools
+    assert memory.temp_size_in_bytes < 0.8e9     # the slabs alone are 0.8
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < V5E_HBM_BYTES
+    hlo = compiled.as_text()
+    assert_only_scatters_produce_pools(hlo, full)
+    assert_only_scatters_produce_pools(hlo, window)
+    assert not kernel_calls(hlo)        # the gather path: no Pallas call

@@ -26,10 +26,11 @@ family's own:
   a RING of ``window_ring`` blocks whatever its length
   (``PagedKVConfig``): position ``p`` is written to ring cell ``p %
   (window_ring * block_size)``, over whatever slid out of the window, and
-  a call reads the whole ring back with each cell's position worked out
-  from the row's length.  A call of ``T`` positions needs the ``sliding_
-  window - 1`` before its first still in the ring, so ``T + sliding_window
-  - 1`` may not pass the ring's capacity (refused at trace time).
+  a gathering call reads the whole ring back with each cell's position
+  worked out from the row's length.  A call of ``T`` positions needs the
+  ``sliding_window - 1`` before its first still in the ring, so ``T +
+  sliding_window - 1`` may not pass the ring's capacity (refused at trace
+  time).
 - **Two rotary tables.**  Window layers rotate by the plain table
   ``rope_theta ** (-2i / head_dim)``; full layers by YaRN's
   (``yarn_inv_freq``), with ``attention_factor`` on cos and sin.
@@ -37,10 +38,14 @@ family's own:
   top ``num_experts_per_tok`` by score, their weights renormalized; no
   bias, no scale, no shared expert (``route``'s ``"softmax"`` kind).
 
-Both attention kinds gather their pool rows and attend densely under the
-mask (``GATHER_WINDOW`` / ``GATHER_FULL`` in ``attention_paths()``); the
-paged decode kernel (``ops/paged_attention.py``) is built for a pool as
-wide as the query and is not used.
+A decode step (one query position a row) reads both pools where they lie,
+through the block-table kernel (``ops/paged_attention.py``: a full layer
+the row's own blocks, a window layer the ring's blocks from the window's
+first position on; ``KERNEL_WINDOW`` / ``KERNEL_FULL`` in
+``attention_paths()``) wherever ``paged_attention.supported`` says it runs.
+Every other call (a prefill chunk, the CPU without the interpreter) gathers
+its pool rows and attends densely under the mask (``GATHER_WINDOW`` /
+``GATHER_FULL``), which is also what the kernel is tested against.
 
 Precision as ``glm4_moe_lite``: parameters and products' operands in
 ``dtype`` (bfloat16), float32 accumulation; the residual stream, norms,
@@ -72,8 +77,10 @@ from distributed_tensorflow_tpu.models.glm4_moe_lite import (
 from distributed_tensorflow_tpu.ops import paged_attention
 from distributed_tensorflow_tpu.parallel.sharding import ShardingRules
 
-# The two attention paths, as ``attention_paths()`` names them.
+# The attention paths, as ``attention_paths()`` names them: each kind of layer
+# by the gather or by the block-table kernel.
 GATHER_WINDOW, GATHER_FULL = "gqa_gather_window", "gqa_gather_full"
+KERNEL_WINDOW, KERNEL_FULL = paged_attention.GQA_KERNEL_PATHS
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -423,34 +430,59 @@ class Mellum(nn.Module):
                                           cfg.sliding_window)
             index.value = index.value.at[slot_ids].set(start + T)
             pools = (window_pool.value, full_pool.value)
+            # A decode step reads the pools where they lie; a row that is
+            # not live reads nothing.
+            kernel = paged_attention.supported(
+                query_len=T, block_size=bs, width=cfg.kv_row // 2,
+                pool_dtype=cfg.dtype, compute_dtype=cfg.dtype,
+                mesh=self.mesh, data_shards=paged.data_shards,
+                groups=cfg.num_attention_heads // cfg.num_key_value_heads)
+            lengths = start + T if live is None else jnp.where(
+                live, start + T, 0)
         else:
             positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
             full_mask = attention_mask(positions, positions, None)
             win_mask = attention_mask(positions, positions,
                                       cfg.sliding_window)
             pools, full_bt, ring_bt = (None, None), None, None
-            full_cells = win_cells = None
+            full_cells = win_cells = lengths = None
+            kernel = False
         token_live = None if live is None else jnp.repeat(live, T)
 
         def attention(p, x, pool, layer, full: bool):
             xn = rms_norm(x, p["input_norm"]["scale"],
                           cfg.rms_norm_eps).astype(cfg.dtype)
             q, k, v = gqa_project(cfg, p["attn"], xn, positions, full)
+            ctx = None
             if pool is not None:
                 cells, table = ((full_cells, full_bt) if full
                                 else (win_cells, ring_bt))
-                paged_attention.note_path(
-                    GATHER_FULL if full else GATHER_WINDOW)
                 half = cfg.kv_row // 2
                 pool = pool.at[(layer,) + cells].set(jnp.concatenate(
                     [k.reshape(B * T, half), v.reshape(B * T, half)],
                     axis=-1))
-                rows = pool[layer, table].reshape(B, -1, cfg.kv_row)
-                shape = (B, rows.shape[1], cfg.num_key_value_heads,
-                         cfg.head_dim)
-                k = rows[..., :half].reshape(shape)
-                v = rows[..., half:].reshape(shape)
-            ctx = gqa_attend(cfg, q, k, v, full_mask if full else win_mask)
+                if kernel:
+                    # The block-table kernel: the row's own blocks, from
+                    # the window's first position on in a window layer,
+                    # whose table is its ring.
+                    paged_attention.note_path(
+                        KERNEL_FULL if full else KERNEL_WINDOW)
+                    ctx = paged_attention.paged_decode_attention(
+                        q, pool, None, table, lengths, layer=layer,
+                        firsts=None if full else jnp.maximum(
+                            lengths - cfg.sliding_window, 0),
+                    ).reshape(B, T, cfg.num_attention_heads * cfg.head_dim)
+                else:
+                    paged_attention.note_path(
+                        GATHER_FULL if full else GATHER_WINDOW)
+                    rows = pool[layer, table].reshape(B, -1, cfg.kv_row)
+                    shape = (B, rows.shape[1], cfg.num_key_value_heads,
+                             cfg.head_dim)
+                    k = rows[..., :half].reshape(shape)
+                    v = rows[..., half:].reshape(shape)
+            if ctx is None:
+                ctx = gqa_attend(cfg, q, k, v,
+                                 full_mask if full else win_mask)
             return x + _dot("btf,fd->btd", ctx, p["attn"]["o"]["kernel"]), pool
 
         period = cfg.period

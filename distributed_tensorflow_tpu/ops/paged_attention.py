@@ -27,11 +27,28 @@ Sixteen-fold redundant arithmetic on the MXU buys what matters here: the
 step is bound by the bytes of K and V, and no array with ``head_dim`` in the
 minor dimension exists anywhere.
 
+The same walk serves the grouped-query family (``models/mellum.py``), by
+what the call's shapes and one more argument say:
+
+- **grouped heads**: ``q`` ``(B, 1, Hkv, G, D)``.  The ``G`` query heads of a
+  K/V head share its columns, so the kernel's query is ``(G, Hkv * D)`` (row
+  i holds query head ``g * G + i`` in K/V head g's columns), tiled ``Hkv``
+  times down the rows and masked to the block diagonal ``(H, Hkv * D)``:
+  fourfold redundant where the form above is sixteenfold;
+- **one pool**: the row is ``2 * Hkv * D`` wide, K in the first half and V
+  in the second (``v_pool=None``), so one DMA a block brings both;
+- **a first position and a ring**: with ``firsts`` a row reads positions
+  ``firsts[row] .. lengths[row] - 1`` (a window layer's window).  Logical
+  block ``j`` is table entry ``j % max_blocks``, so a table shorter than the
+  row is a ring (position ``p`` in ring cell ``p % capacity``); the walk
+  starts at the chunk that holds the first position, fetches only the
+  blocks that hold one of the row's, and masks both ends.
+
 One algorithm, two implementations: ``supported`` says whether the kernel
 runs for a call, from what the call can observe (decode shape, the pool's
 storage type, the platform, one device); everything else keeps the gather
-path in ``models/gpt2.py``, which is also the reference the kernel is tested
-against (``tests/test_paged_attention.py``).
+paths in ``models/gpt2.py`` and ``models/mellum.py``, which are also the
+references the kernel is tested against (``tests/test_paged_attention.py``).
 """
 
 from __future__ import annotations
@@ -54,9 +71,23 @@ _fa = importlib.import_module("distributed_tensorflow_tpu.ops.flash_attention")
 # (16 positions, 32 KB a pool for GPT-2 medium) is too small to be a step of
 # its own, so a step takes CHUNK // block_size blocks, each by its own DMA.
 CHUNK = 128
+# The grouped form's: its one copy a block is half the two pools' copies a
+# position, and a step of 512 positions reads a full layer's rows at 611
+# GB/s where one of 128 reads 383 (PERF.md Findings, PR 36).
+GROUPED_CHUNK = 512
+# A step's copies stand one by one in the program's text up to this many
+# blocks (the two pools' 8); beyond, a loop goes over groups of this many
+# (the grouped form's 32 blocks in 4 turns: within 5% of all 32 written out
+# on the chip, at half the time to trace and lower, which every process
+# start pays; one block a turn is 25% slower on the chip).
+UNROLLED_PAGES = 8
 _MASKED = -1e30  # finite: exp(_MASKED - m) is exactly 0, no inf - inf
 
 KERNEL, GATHER = "kernel", "gather"
+# The grouped form's calls are on record by the kind of layer they serve
+# (``models/mellum.py``); every path that reads a pool through the kernel:
+GQA_KERNEL_PATHS = ("gqa_kernel_window", "gqa_kernel_full")
+KERNEL_PATHS = (KERNEL,) + GQA_KERNEL_PATHS
 
 _trace = threading.local()
 
@@ -100,83 +131,118 @@ def program_paths(paths):
 
 
 def supported(*, query_len: int, block_size: int, width: int, pool_dtype,
-              compute_dtype, mesh=None, data_shards: int = 1) -> bool:
+              compute_dtype, mesh=None, data_shards: int = 1,
+              groups: Optional[int] = None) -> bool:
     """Whether the kernel runs for this call: a decode step, a pool stored
     in the compute type (int8 and cast-on-write pools dequantize or convert
     in the gather), one device, and a TPU (or the interpreter).  On the TPU
-    a block must also be whole tiles of the pool's type."""
+    a block must also be whole tiles of the pool's type: ``width``, the
+    columns of K (and as many of V) in a pool row, whole lane tiles, and in
+    the grouped form (``groups`` given) the query heads of a K/V head whole
+    float32 sublane tiles where there is more than one."""
     if query_len != 1 or jnp.dtype(pool_dtype) != jnp.dtype(compute_dtype):
         return False
     if data_shards != 1 or (mesh is not None and mesh.size != 1):
         return False
-    if CHUNK % block_size:
+    if (CHUNK if groups is None else GROUPED_CHUNK) % block_size:
         return False
     if _fa._interpret():
         return True
     if _fa._platform() != "tpu":
         return False
     sublanes = 8 * 4 // jnp.dtype(pool_dtype).itemsize
-    return block_size % sublanes == 0 and width % 128 == 0
+    return (block_size % sublanes == 0 and width % 128 == 0
+            and (groups in (None, 1) or groups % 8 == 0))
 
 
-def _kernel(layer_ref, lengths_ref, tables_ref,        # scalar prefetch
-            q_ref, k_hbm, v_hbm,                       # inputs
-            o_ref,                                     # output
-            k_buf, v_buf, m_ref, l_ref, acc_ref, state, k_sem, v_sem,
-            *, scale, block_size, max_blocks, heads):
+def _kernel(*refs, scale, chunk_size, block_size, max_blocks, heads, groups,
+            pools, windowed):
+    """One row of the batch a grid step.  ``refs``: the scalars (layer,
+    lengths, tables, and where ``windowed`` the rows' first positions), the
+    query, the ``pools`` pools in HBM, the output, then the scratch: a
+    fetch buffer a pool, the softmax's running maximum, sum and
+    accumulator, the hand-over state, a DMA semaphore pair a pool."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    n = 3 + windowed
+    layer_ref, lengths_ref, tables_ref = refs[:3]
+    firsts_ref = refs[3] if windowed else None
+    q_ref, hbm, o_ref = refs[n], refs[n + 1:n + 1 + pools], refs[n + 1 + pools]
+    scratch = refs[n + 2 + pools:]
+    bufs, (m_ref, l_ref, acc_ref, state), sems = (
+        scratch[:pools], scratch[pools:pools + 4], scratch[pools + 4:])
+
     b, rows = pl.program_id(0), pl.num_programs(0)
-    pages = CHUNK // block_size
+    pages = chunk_size // block_size
     width = q_ref.shape[-1]
     layer = layer_ref[0]
     length = lengths_ref[b]
-    chunks = (length + CHUNK - 1) // CHUNK
+    chunks = (length + chunk_size - 1) // chunk_size
 
-    def copies(row, chunk, slot):
-        """The (predicate, K copy, V copy) of each block of one chunk: only
-        the blocks below the row's length are ever fetched."""
+    def first_chunk(row):
+        """The chunk that holds the row's first position."""
+        return firsts_ref[row] // chunk_size if windowed else 0
+
+    def each_copy(row, chunk, slot, act):
+        """``act`` on the copy, from each pool, of every block of one chunk
+        that holds one of the row's positions: only such blocks are ever
+        fetched.  Logical block ``j`` is table entry ``j % max_blocks``: a
+        table shorter than the row is a ring.  The blocks stand one by one
+        in the program's text up to ``UNROLLED_PAGES`` of them; beyond, a
+        loop goes over groups of that many."""
         blocks = (lengths_ref[row] + block_size - 1) // block_size
-        out = []
-        for p in range(pages):
+        if windowed:
+            first_block = firsts_ref[row] // block_size
+
+        def copies(p):
             page = chunk * pages + p
-            # Clamped so that the table read stays in bounds where the
-            # predicate is false.
-            block = tables_ref[row * max_blocks
-                               + jnp.minimum(page, max_blocks - 1)]
-            dst = pl.ds(p * block_size, block_size)
-            out.append((
-                page < blocks,
-                pltpu.make_async_copy(k_hbm.at[layer, block],
-                                      k_buf.at[slot, dst], k_sem.at[slot]),
-                pltpu.make_async_copy(v_hbm.at[layer, block],
-                                      v_buf.at[slot, dst], v_sem.at[slot]),
-            ))
-        return out
+            # Kept in bounds where the predicate is false, too.
+            block = tables_ref[row * max_blocks + (
+                lax.rem(page, max_blocks) if windowed
+                else jnp.minimum(page, max_blocks - 1))]
+            wanted = page < blocks
+            if windowed:
+                wanted &= page >= first_block
+            offset = p * block_size
+            if not isinstance(p, int):
+                offset = pl.multiple_of(offset, block_size)
+            dst = pl.ds(offset, block_size)
+            return (wanted,) + tuple(
+                pltpu.make_async_copy(pool.at[layer, block],
+                                      buf.at[slot, dst], sem.at[slot])
+                for pool, buf, sem in zip(hbm, bufs, sems))
+
+        def group(first, size):
+            for wanted, *block_copies in [copies(first + p)
+                                          for p in range(size)]:
+                @pl.when(wanted)
+                def _():
+                    for copy in block_copies:
+                        act(copy)
+
+        if pages <= UNROLLED_PAGES:
+            group(0, pages)
+        else:
+            assert pages % UNROLLED_PAGES == 0, (chunk_size, block_size)
+            lax.fori_loop(0, pages // UNROLLED_PAGES, lambda g, _: group(
+                g * UNROLLED_PAGES, UNROLLED_PAGES) or 0, 0)
 
     def start(row, chunk, slot):
-        for wanted, k_copy, v_copy in copies(row, chunk, slot):
-            @pl.when(wanted)
-            def _():
-                k_copy.start()
-                v_copy.start()
+        each_copy(row, chunk, slot, lambda copy: copy.start())
 
     def wait(row, chunk, slot):
-        for wanted, k_copy, v_copy in copies(row, chunk, slot):
-            @pl.when(wanted)
-            def _():
-                k_copy.wait()
-                v_copy.wait()
+        each_copy(row, chunk, slot, lambda copy: copy.wait())
 
     @pl.when(b == 0)
     def _():
         state[0] = 0  # the buffer the next chunk to compute lands in
         state[1] = 0  # whether that chunk's fetch has been started
-        # Probabilities of positions past a row's length are exactly 0, but
+        # Probabilities of positions outside a row's are exactly 0, but
         # 0 x what VMEM held before this call may be NaN: blocks that are
         # not fetched must read as finite.  After this only pool data lands
         # here.
+        v_buf = bufs[-1]
         v_buf[...] = jnp.zeros_like(v_buf)
 
     @pl.when(length == 0)
@@ -187,7 +253,7 @@ def _kernel(layer_ref, lengths_ref, tables_ref,        # scalar prefetch
     def _():
         @pl.when(state[1] == 0)
         def _():
-            start(b, 0, state[0])
+            start(b, first_chunk(b), state[0])
             state[1] = 1
 
         # The next row that has anything to fetch: its first chunk is
@@ -198,12 +264,17 @@ def _kernel(layer_ref, lengths_ref, tables_ref,        # scalar prefetch
                 (found == rows) & (lengths_ref[r] > 0), r, found),
             rows)
 
+        # Row ``h`` of the block-diagonal query keeps the columns of its own
+        # K/V head, ``h // groups``.
         head_of_column = lax.broadcasted_iota(
-            jnp.int32, (heads, width), 1) // (width // heads)
-        own = head_of_column == lax.broadcasted_iota(
-            jnp.int32, (heads, width), 0)
-        q = q_ref[...].astype(jnp.float32)                   # (1, width)
-        q_heads = jnp.where(own, jnp.broadcast_to(q, (heads, width)), 0.0)
+            jnp.int32, (heads, width), 1) // (width * groups // heads)
+        head_of_row = lax.broadcasted_iota(jnp.int32, (heads, width), 0)
+        own = head_of_column == (
+            head_of_row if groups == 1 else head_of_row // groups)
+        q = q_ref[...].astype(jnp.float32)                   # (groups, width)
+        q = (jnp.broadcast_to(q, (heads, width)) if groups == 1
+             else jnp.concatenate([q] * (heads // groups), axis=0))
+        q_heads = jnp.where(own, q, 0.0)
         q_heads = q_heads.astype(q_ref.dtype)                # (heads, width)
 
         m_ref[...] = jnp.full_like(m_ref, _MASKED)
@@ -220,16 +291,22 @@ def _kernel(layer_ref, lengths_ref, tables_ref,        # scalar prefetch
 
             @pl.when((i + 1 == chunks) & (following < rows))
             def _():
-                start(following, 0, other)
+                start(following, first_chunk(following), other)
 
             wait(b, i, slot)
-            k = k_buf[slot]                                  # (CHUNK, width)
+            if pools == 2:
+                k = bufs[0][slot]                            # (chunk, width)
+            else:                      # K and V side by side in one row
+                k = bufs[0][slot, :, pl.ds(0, width)]
             s = lax.dot_general(
                 q_heads, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (heads, CHUNK)
-            position = i * CHUNK + lax.broadcasted_iota(
+                preferred_element_type=jnp.float32) * scale  # (heads, chunk)
+            position = i * chunk_size + lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
-            s = jnp.where(position < length, s, _MASKED)
+            inside = position < length
+            if windowed:
+                inside &= position >= firsts_ref[b]
+            s = jnp.where(inside, s, _MASKED)
             m_prev = m_ref[...]                              # (heads, 1)
             m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_next)
@@ -237,78 +314,114 @@ def _kernel(layer_ref, lengths_ref, tables_ref,        # scalar prefetch
             l_ref[...] = alpha * l_ref[...] + jnp.sum(
                 p, axis=1, keepdims=True)
             m_ref[...] = m_next
-            v = v_buf[slot]
+            if pools == 2:
+                v = bufs[1][slot]
+            else:
+                v = bufs[0][slot, :, pl.ds(width, width)]
             acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
                 p.astype(v.dtype), v, preferred_element_type=jnp.float32)
             state[0] = other
             return 0
 
-        lax.fori_loop(0, chunks, fold, 0)
+        lax.fori_loop(first_chunk(b), chunks, fold, 0)
         out = acc_ref[...] / l_ref[...]                      # (heads, width)
-        o_ref[...] = jnp.sum(jnp.where(own, out, 0.0), axis=0,
-                             keepdims=True).astype(o_ref.dtype)
+        out = jnp.where(own, out, 0.0)
+        if groups == 1:
+            out = jnp.sum(out, axis=0, keepdims=True)
+        else:
+            out = sum(out[g * groups:(g + 1) * groups]
+                      for g in range(heads // groups))
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, lengths, *,
-                           layer=None, scale: Optional[float] = None):
+                           layer=None, scale: Optional[float] = None,
+                           firsts=None):
     """Exact softmax attention of one query position a row over the row's
-    first ``lengths[row]`` cached positions.
+    cached positions ``firsts[row] .. lengths[row] - 1`` (from 0 where
+    ``firsts`` is None).
 
-    ``q`` is ``(B, 1, H, D)``; the pools are ``(layers, num_blocks,
-    block_size, H * D)`` with ``layer`` the (traced) index of this layer, or
-    one layer's own ``(num_blocks, block_size, H * D)`` with ``layer=None``;
-    ``tables`` ``(B, max_blocks)`` int32 maps a row's logical blocks to
-    physical ones; ``lengths`` ``(B,)`` int32.  A row of length 0 fetches
-    nothing and returns zeros.  Returns ``(B, 1, H, D)`` in ``q``'s type.
+    ``q`` is ``(B, 1, H, D)`` over pools as wide as the query, ``(layers,
+    num_blocks, block_size, H * D)`` each, or grouped, ``(B, 1, Hkv, G,
+    D)``, over ONE pool whose row holds K then V, ``(layers, num_blocks,
+    block_size, 2 * Hkv * D)``, with ``v_pool`` None.  ``layer`` is the
+    (traced) index of this layer, or None over one layer's own pools
+    without the leading dimension.  ``tables`` ``(B, max_blocks)`` int32
+    maps a row's logical block ``j`` to the physical one in entry ``j %
+    max_blocks``: a row longer than its table is a ring, which must still
+    hold every block from ``firsts[row]`` on.  ``lengths`` and ``firsts``
+    are ``(B,)`` int32.  A row of length 0 fetches nothing and returns
+    zeros.  Returns ``q``'s shape and type.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, T, H, D = q.shape
+    grouped = q.ndim == 5
+    if grouped:
+        B, T, kv_heads, groups, D = q.shape
+    else:
+        (B, T, kv_heads, D), groups = q.shape, 1
     if T != 1:
         raise ValueError(f"decode attention takes one query position, got {T}")
+    if grouped != (v_pool is None):
+        raise ValueError(
+            "grouped queries (B, 1, Hkv, G, D) read one pool of K and V "
+            "side by side (v_pool=None); queries (B, 1, H, D) read two")
+    sources = [k_pool] if grouped else [k_pool, v_pool]
     if layer is None:
-        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
-    _, _, block_size, width = k_pool.shape
-    if width != H * D:
-        raise ValueError(f"pool width {width} is not heads x head_dim "
-                         f"({H} x {D})")
+        sources, layer = [pool[None] for pool in sources], 0
+    _, _, block_size, row_width = sources[0].shape
+    width = kv_heads * D
+    if row_width != width * (1 + grouped):
+        raise ValueError(
+            f"pool width {row_width} is not {1 + grouped} x heads x "
+            f"head_dim ({kv_heads} x {D})")
     if scale is None:
         scale = 1.0 / float(D) ** 0.5
     max_blocks = tables.shape[1]
+    heads = kv_heads * groups
+    chunk_size = GROUPED_CHUNK if grouped else CHUNK
     kernel = functools.partial(
-        _kernel, scale=scale, block_size=block_size, max_blocks=max_blocks,
-        heads=H)
-    row = pl.BlockSpec((None, 1, width), lambda b, *_: (b, 0, 0))
+        _kernel, scale=scale, chunk_size=chunk_size, block_size=block_size,
+        max_blocks=max_blocks, heads=heads, groups=groups,
+        pools=len(sources), windowed=firsts is not None)
+    scalars = [jnp.asarray(layer, jnp.int32).reshape(1),
+               lengths.astype(jnp.int32),
+               tables.astype(jnp.int32).reshape(-1)]
+    if firsts is not None:
+        scalars.append(firsts.astype(jnp.int32))
+    # The kernel's rows: query head ``g * G + i`` in row ``i``, in K/V head
+    # ``g``'s columns.
+    rows = q.reshape(B, 1, width) if groups == 1 else q.reshape(
+        B, kv_heads, groups, D).swapaxes(1, 2).reshape(B, groups, width)
+    row = pl.BlockSpec((None, groups, width), lambda b, *_: (b, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(scalars),
             grid=(B,),
-            in_specs=[row, pool, pool],
+            in_specs=[row] + [pool] * len(sources),
             out_specs=row,
             scratch_shapes=[
-                pltpu.VMEM((2, CHUNK, width), k_pool.dtype),
-                pltpu.VMEM((2, CHUNK, width), v_pool.dtype),
-                pltpu.VMEM((H, 1), jnp.float32),        # running maximum
-                pltpu.VMEM((H, 1), jnp.float32),        # running sum
-                pltpu.VMEM((H, width), jnp.float32),    # accumulator
+                pltpu.VMEM((2, chunk_size, row_width), source.dtype)
+                for source in sources
+            ] + [
+                pltpu.VMEM((heads, 1), jnp.float32),      # running maximum
+                pltpu.VMEM((heads, 1), jnp.float32),      # running sum
+                pltpu.VMEM((heads, width), jnp.float32),  # accumulator
                 pltpu.SMEM((2,), jnp.int32),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
+            ] + [pltpu.SemaphoreType.DMA((2,)) for _ in sources],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, 1, width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(rows.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             # Rows share the fetch buffers and hand the next row's first
             # chunk on: they run in order.
             dimension_semantics=("arbitrary",),
         ),
         interpret=_fa._interpret(),
-        name="paged_decode_attn",
-    )(jnp.asarray(layer, jnp.int32).reshape(1),
-      lengths.astype(jnp.int32),
-      tables.astype(jnp.int32).reshape(-1),
-      q.reshape(B, 1, width), k_pool, v_pool)
-    return out.reshape(B, 1, H, D)
+        name="paged_decode_attn_gqa" if grouped else "paged_decode_attn",
+    )(*scalars, rows, *sources)
+    if groups > 1:
+        out = out.reshape(B, groups, kv_heads, D).swapaxes(1, 2)
+    return out.reshape(q.shape)

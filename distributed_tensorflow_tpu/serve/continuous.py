@@ -191,6 +191,7 @@ from distributed_tensorflow_tpu.models import PagedKVConfig
 from distributed_tensorflow_tpu.obs import metrics as obs_metrics
 from distributed_tensorflow_tpu.obs.lifecycle import EMPTY_LIFECYCLE_STATS
 from distributed_tensorflow_tpu.obs.trace import default_tracer, now as _now
+from distributed_tensorflow_tpu.ops.paged_attention import KERNEL_PATHS
 from distributed_tensorflow_tpu.serve.batcher import (
     ServeOverloadedError,
     _percentile,
@@ -1454,8 +1455,8 @@ class ContinuousScheduler:
                 # share of paged decode launches whose program was traced
                 # with it (0 with no such launch yet, and on the CPU).
                 "decode_attention_kernel_share": (
-                    attention["kernel"] / attention_launches
-                    if attention_launches else 0.0),
+                    sum(attention[path] for path in KERNEL_PATHS)
+                    / attention_launches if attention_launches else 0.0),
                 **self._moe_stats_locked(),
                 # SLO scheduling: preempt/resume traffic, parked
                 # requests, host-KV-tier bytes, and TTFT-deadline

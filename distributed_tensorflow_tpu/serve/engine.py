@@ -120,7 +120,8 @@ def _engine_instruments(registry=None):
             "dtt_serve_decode_attention_launches_total",
             "Paged decode launches (decode_slots, decode_megastep) by the "
             "attention path the launched program was traced with: the "
-            "block-table kernel, or the whole-row gather",
+            "block-table kernel (the grouped-query family's by kind of "
+            "layer), or the whole-row gather",
             labelnames=("path",)),
     }
 
@@ -1720,7 +1721,9 @@ def moe_counts_of(cache: PyTree):
 
 # Attention paths a traced program may have on record: the paged kernel's
 # two (``ops.paged_attention``), the latent attention's two and the
-# grouped-query family's two (a program of its has both).
+# grouped-query family's four (a program of its has a window and a full
+# path, both by the gather or both by the kernel).
 _DECODE_PATHS = (paged_attention.KERNEL, paged_attention.GATHER,
                  "latent_absorbed", "latent_expanded",
-                 "gqa_gather_window", "gqa_gather_full")
+                 "gqa_gather_window", "gqa_gather_full",
+                 ) + paged_attention.GQA_KERNEL_PATHS

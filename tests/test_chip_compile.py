@@ -704,15 +704,23 @@ def test_two_pool_serve_programs_fit_one_chip_at_the_cells_shapes(topo,
     published widths: 4.08 GB of bfloat16 weights, the 4 full layers' pool
     of ``slots x 256 + 1`` blocks and the 12 window layers' of ``slots x
     98 + 1`` (a ring a slot, whatever the row's length), both updated in
-    place; at 16 slots 5.24 GB of arguments, and 0.58 GB of scratch for the
-    decode program (4 fused steps; the gathered rows and rings and their
-    float32 scores; 0.41 GB at 8 slots), 0.63 GB for a prefill chunk of 512
-    (one slot's, whatever the slots).  A scanned body that slices a whole
+    place; at 16 slots 5.24 GB of arguments, and 0.36 GB of scratch for the
+    decode program (4 fused steps), 0.63 GB for a prefill chunk of 512 (one
+    slot's, whatever the slots).  A scanned body that slices a whole
     period's leaves out of the layer stack copies them (0.8 GB of expert
     stacks: 1.28 and 1.51 GB of scratch at 8 slots, and half of a decode
     step's time on the chip; PERF.md Findings, PR 35): the scratch bound
     below is what catches it.  The cell's ``num_slots_arithmetic`` and
-    PERF.md section 4 quote these figures."""
+    PERF.md section 4 quote these figures.
+
+    The decode program reads both pools where they lie: one call of the
+    grouped block-table kernel a layer of the scanned period's body (the
+    fused steps are a loop round it), and none of what the gather path made
+    of a full layer's table rows (0.58 GB of scratch; PERF.md Findings,
+    PR 36): the gathered ``bf16[4096,16,1024]`` (16 slots x 256 blocks), K
+    and V split out of it and re-laid as ``bf16[16,4096,512]``, nor the
+    window layers' rings (16 x 98 blocks).  A prefill chunk is many
+    positions a row and keeps the gather path."""
     lowered, cache, slots = lower_two_pool_program(topo, program)
     full, window = cache["full_pool"].shape, cache["window_pool"].shape
     assert full == (4, slots * 256 + 1, 16, 1024)
@@ -727,4 +735,20 @@ def test_two_pool_serve_programs_fit_one_chip_at_the_cells_shapes(topo,
     hlo = compiled.as_text()
     assert_only_scatters_produce_pools(hlo, full)
     assert_only_scatters_produce_pools(hlo, window)
-    assert not kernel_calls(hlo)        # the gather path: no Pallas call
+    assert not kernel_calls(hlo)        # no flash kernel in a serving step
+    calls = re.findall(
+        r"%(paged_decode_attn[\w.]*) = [^\n]*tpu_custom_call", hlo)
+    if program == "prefill_slots":
+        assert not calls                # the gather path: no Pallas call
+        return
+    period = 4                          # three window layers and one full
+    assert len(calls) == period
+    assert all(name.startswith("paged_decode_attn_gqa") for name in calls)
+    assert memory.temp_size_in_bytes < 0.45e9    # the gather path: 0.58
+    gathered = {(slots * 256, 16, 1024), (slots, 256 * 16, 512),
+                (slots * 98, 16, 1024), (slots, 98 * 16, 512)}
+    for dims in re.findall(r"= bf16\[([\d,]+)\]", hlo):
+        shape = tuple(int(n) for n in dims.split(","))
+        assert shape not in gathered, (
+            f"a bf16{list(shape)}: every slot's table rows or rings, "
+            f"gathered or split")

@@ -243,13 +243,31 @@ def paged_for(cfg, *, slots, total, block, chunk, megastep=1):
     return paged, jnp.asarray(tables)
 
 
+@pytest.fixture(params=[False, True], ids=["gather", "kernel"])
+def kernel(request, monkeypatch):
+    """Whether a decode step's attention runs the block-table kernel (in
+    the Pallas interpreter) or, as the CPU does without it, the gather."""
+    if request.param:
+        monkeypatch.setenv("DTT_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("DTT_PALLAS_INTERPRET", raising=False)
+    return request.param
+
+
+GATHERS = {mellum.GATHER_WINDOW, mellum.GATHER_FULL}
+KERNELS = {mellum.KERNEL_WINDOW, mellum.KERNEL_FULL}
+
+
 @pytest.mark.parametrize("block,chunk", [(8, 16), (16, 16), (8, 40)])
-def test_chunked_prefill_then_decode_gives_the_reference_logits(block, chunk):
+def test_chunked_prefill_then_decode_gives_the_reference_logits(block, chunk,
+                                                                kernel):
     """A row 3 x (window + chunk) long: every position's logits, prefilled
     ``chunk`` at a time and then decoded one by one, against the
     reference's full forward pass.  The ring (window + chunk + 1 positions
     in whole blocks and one block more) wraps, most cells twice; a chunk's
-    first query still sees the window's 23 positions before it."""
+    first query still sees the window's 23 positions before it.  Under the
+    interpreter the chunks still gather and the single positions read both
+    pools through the kernel, from the window's first position on."""
     cfg = tiny(experts_held=4, first_expert=2)
     total = 3 * (cfg.sliding_window + chunk)
     params = drawn_params(cfg)
@@ -267,12 +285,16 @@ def test_chunked_prefill_then_decode_gives_the_reference_logits(block, chunk):
         {"params": params, "cache": c}, t, mutable=["cache"], **call))
     got, at = [], 0
     prefilled = 2 * (cfg.sliding_window + chunk) // chunk * chunk
+    paths = {chunk: set(), 1: set()}
     while at < total:
         n = chunk if at < prefilled else 1
-        out, mutated = step(cache, tokens[:, at:at + n])
+        with mellum.paged_attention.record_paths() as traced:
+            out, mutated = step(cache, tokens[:, at:at + n])
+        paths[n].update(traced)         # what the call's first trace took
         cache = mutated["cache"]
         got.append(np.asarray(out))
         at += n
+    assert paths == {chunk: GATHERS, 1: KERNELS if kernel else GATHERS}
     want = reference_logits(cfg, params, tokens)
     np.testing.assert_allclose(np.concatenate(got, axis=1), want, atol=5e-5)
     assert cache["cache_index"].tolist() == [total, total]
@@ -396,12 +418,26 @@ SERVED = tiny(experts_held=4, first_expert=2)
 CHUNK = 16
 
 
-@pytest.fixture(scope="module")
-def engine():
-    eng = ServeEngine("mellum", config=SERVED)
+def _engine(**kw):
+    eng = ServeEngine("mellum", config=SERVED, **kw)
     eng.install_params(eng.shard_params(drawn_params(SERVED)))
     yield eng
     eng.close()
+
+
+@pytest.fixture(scope="module")
+def engine():
+    yield from _engine()
+
+
+@pytest.fixture(scope="module")
+def kernel_engine():
+    """An engine keeps the programs it traced, so the one whose decode
+    programs are traced under the interpreter is its own; on one device, as
+    the kernel asks."""
+    from distributed_tensorflow_tpu import cluster as cluster_lib
+    yield from _engine(mesh=cluster_lib.build_mesh(
+        cluster_lib.MeshConfig(), devices=jax.devices()[:1]))
 
 
 def scheduler(engine, **kw):
@@ -421,10 +457,15 @@ def _gap_to_reference_best(engine, prompt, answer):
 
 @pytest.mark.parametrize("async_decode", [False, True])
 @pytest.mark.parametrize("megastep", [1, 4])
-def test_scheduler_serves_the_reference_best_tokens(engine, megastep,
+def test_scheduler_serves_the_reference_best_tokens(request, monkeypatch,
+                                                    kernel, megastep,
                                                     async_decode):
     """Greedy answers through both pools, rows longer than the window and
-    than the ring, every token the reference's own first choice."""
+    than the ring, every token the reference's own first choice: with the
+    decode programs on the gather paths, and on the kernel's (every prefill
+    program on the gather paths either way)."""
+    engine = request.getfixturevalue("kernel_engine" if kernel else "engine")
+    before = engine.decode_attention_launches()
     rng = np.random.default_rng(0)
     requests = [(rng.integers(0, SERVED.vocab_size, n, dtype=np.int32), new)
                 for n, new in ((48, 100), (32, 30), (80, 150), (16, 8))]
@@ -434,7 +475,16 @@ def test_scheduler_serves_the_reference_best_tokens(engine, megastep,
         assert ring == -(-(24 + CHUNK + megastep) // 8) + 1
         futures = [sched.submit(p, max_new_tokens=n) for p, n in requests]
         answers = [np.asarray(f.result(timeout=600)) for f in futures]
-        stats = sched.stats()
+        # The launch counter is the process's: this scheduler's share of
+        # it is what it added.
+        total = engine.decode_attention_launches
+        with monkeypatch.context() as patch:
+            patch.setattr(
+                engine, "decode_attention_launches",
+                lambda: {path: n - before[path]
+                         for path, n in total().items()})
+            stats = sched.stats()
+    assert stats["decode_attention_kernel_share"] == float(kernel)
     assert stats["moe_layer_steps"] > 0 and stats["moe_experts_held"] == 4
     assert stats["window_blocks_recycled"] > 0
     assert (stats["decode_live_positions_window"]
@@ -443,11 +493,13 @@ def test_scheduler_serves_the_reference_best_tokens(engine, megastep,
         assert len(answer) == new
         assert _gap_to_reference_best(engine, prompt, answer).max() <= 1e-4
     paths = engine.attention_paths()
-    both = {mellum.GATHER_WINDOW, mellum.GATHER_FULL}
-    assert set(paths["slot_prefill"]) == both
-    assert set(paths["slot_megastep"]) == both
-    launches = engine.decode_attention_launches()
-    assert launches[mellum.GATHER_WINDOW] == launches[mellum.GATHER_FULL] > 0
+    decode = KERNELS if kernel else GATHERS
+    assert set(paths["slot_prefill"]) == GATHERS
+    assert set(paths["slot_megastep"]) == decode
+    launches = {path: n - before[path] for path, n
+                in engine.decode_attention_launches().items()}
+    assert {path for path, n in launches.items() if n} == decode
+    assert len({launches[path] for path in decode}) == 1
 
 
 def _held(sched):

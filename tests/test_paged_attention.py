@@ -1,6 +1,8 @@
 """The block-table decode attention kernel (``ops/paged_attention.py``) in
 the Pallas interpreter, held to the gather path of
-``models/gpt2.py: _paged_cached_attention`` within bf16 rounding.
+``models/gpt2.py: _paged_cached_attention`` within bf16 rounding, and its
+grouped form (one pool of K and V side by side, a first position, a table
+that is a ring) to the gather path of ``models/mellum.py``.
 
 The interpreter shows the kernel's logic: which blocks a row fetches, the
 online softmax over chunks, the masked tail, rows that fetch nothing.  What
@@ -16,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_tensorflow_tpu.models import mellum
 from distributed_tensorflow_tpu.models.gpt2 import (
     GPT2, GPT2Config, PagedKVConfig)
 
@@ -76,11 +79,14 @@ def queries(rng, rows):
 def assert_within_bf16_rounding(got, want, rows):
     """Outputs are of order 1 (averages of unit normals), so one bf16 step
     is 2**-8 at most; the gather path itself sits two or three steps from
-    exact arithmetic (its scores and probabilities are rounded)."""
+    exact arithmetic (its scores and probabilities are rounded).  In
+    float32 only the order of the sums differs: one position too many or
+    too few of a window of 40 moves an output by a fortieth."""
+    atol = 1e-5 if got.dtype == jnp.float32 else 3 * 2.0 ** -8
     got = np.asarray(got, np.float32)[rows]
     want = np.asarray(want, np.float32)[rows]
     assert np.isfinite(got).all()
-    np.testing.assert_allclose(got, want, rtol=0.0, atol=3 * 2.0 ** -8)
+    np.testing.assert_allclose(got, want, rtol=0.0, atol=atol)
 
 
 EDGE_LENGTHS = [1, 15, 16, 17, 250, 1023, 1024]
@@ -139,9 +145,80 @@ def case_unrolled_layout(rng):
                 pools=(k_pool[0], v_pool[0]), layer=None)
 
 
+def gqa_gather_path(q, pool, tables, lengths, window, layer):
+    """``models/mellum.py``'s gather path after its scatter, with its own
+    mask and product: the whole table row (a window layer's whole ring,
+    each cell's position worked out from the row's length) gathered, K and
+    V split out of it, attended under the mask."""
+    B, _, kv_heads, groups, head_dim = q.shape
+    cfg = mellum.MellumConfig.tiny(
+        num_attention_heads=kv_heads * groups, num_key_value_heads=kv_heads,
+        head_dim=head_dim, dtype=q.dtype)
+    rows = pool[layer, tables].reshape(B, -1, pool.shape[-1])
+    cells = rows.shape[1]
+    shape = (B, cells, kv_heads, head_dim)
+    k = rows[..., :kv_heads * head_dim].reshape(shape)
+    v = rows[..., kv_heads * head_dim:].reshape(shape)
+    last = lengths[:, None] - 1
+    held = jnp.broadcast_to(jnp.arange(cells)[None], (B, cells))
+    if window is not None:
+        held = last - (last - held) % cells
+    mask = mellum.attention_mask(last, held, window)
+    return mellum.gqa_attend(cfg, q, k, v, mask).reshape(q.shape)
+
+
+def grouped_case(name, lengths, *, kv_heads, groups, head_dim, window=None,
+                 ring=None, dtype=jnp.float32):
+    """A case of the grouped form: ``lengths`` rows over one pool of K and
+    V side by side.  ``window`` None is a full layer (the table as long as
+    the longest row); else a window layer whose table is a ring of ``ring``
+    blocks, every cell written (a row shorter than the ring reads only its
+    own positions out of it)."""
+    def make(rng):
+        n = np.array(lengths)
+        entries = ring or -(-int(n.max()) // BLOCK)
+        num_blocks = len(n) * entries + 1
+        tables = 1 + rng.permutation(len(n) * entries).reshape(
+            len(n), entries).astype(np.int32)
+        pool = jnp.asarray(rng.normal(size=(
+            2, num_blocks, BLOCK, 2 * kv_heads * head_dim)), dtype)
+        return dict(lengths=n, tables=tables, pools=(pool,), layer=1,
+                    zero_rows=n == 0, window=window,
+                    grouped=(kv_heads, groups, head_dim))
+    make.__name__ = "case_" + name
+    return make
+
+
+# A window of 40 over blocks of 16 in a ring of 5 (80 cells): rows shorter
+# than the window, exactly it and one more; the first position in the middle
+# of a block (all but 168 and the chunk's own), in the middle of a chunk
+# (1000: 960 of 512..1023), on a chunk's first position, and in the chunk
+# before the last position's; past the ring's capacity once (100) and
+# several times; dead rows between live ones.
+WINDOW_LENGTHS = [7, 39, 40, 41, 0, 100, 168, pa.GROUPED_CHUNK + 40, 0, 0,
+                  pa.GROUPED_CHUNK + 18, 1000, 1]
+GROUPED_CASES = [
+    grouped_case("gqa_full_g2_d16", [0, 1, 16, 17, 100, 129, 0, 300, 256],
+                 kv_heads=2, groups=2, head_dim=16),
+    grouped_case("gqa_full_g8_d128", [250, 0, 1023, 1],
+                 kv_heads=4, groups=8, head_dim=128, dtype=jnp.bfloat16),
+    grouped_case("gqa_window_g1_d128", WINDOW_LENGTHS, kv_heads=2, groups=1,
+                 head_dim=128, window=40, ring=5),
+    grouped_case("gqa_window_g2_d16", WINDOW_LENGTHS, kv_heads=2, groups=2,
+                 head_dim=16, window=40, ring=5),
+    grouped_case("gqa_window_g8_d16", WINDOW_LENGTHS, kv_heads=2, groups=8,
+                 head_dim=16, window=40, ring=5, dtype=jnp.bfloat16),
+    # The published heads, window and ring: a row inside the window, at it,
+    # one past it, at the ring's capacity, one past it, wrapped twice over.
+    grouped_case("gqa_window_published", [1000, 1024, 1025, 0, 1568, 1569,
+                                          4000],
+                 kv_heads=4, groups=8, head_dim=128, window=1024, ring=98,
+                 dtype=jnp.bfloat16),
+]
+
 CASES = [case_edge_lengths, case_empty_and_dead_rows,
          case_trash_block_garbage, case_shared_prefix_blocks,
-         case_unrolled_layout]
+         case_unrolled_layout] + GROUPED_CASES
 
 
 @pytest.mark.parametrize("make", CASES, ids=lambda f: f.__name__[5:])
@@ -150,14 +227,25 @@ def test_kernel_matches_the_gather_path(interpreter, make):
     case = make(rng)
     lengths = jnp.asarray(case["lengths"], jnp.int32)
     tables = jnp.asarray(case["tables"])
-    k_pool, v_pool = case["pools"]
     layer = case["layer"]
-    q = queries(rng, len(lengths))
-    got = jax.jit(lambda *a: pa.paged_decode_attention(
-        *a, layer=None if layer is None else jnp.int32(layer)))(
-            q, k_pool, v_pool, tables, lengths)
+    if "grouped" in case:
+        (pool,), window = case["pools"], case["window"]
+        q = jnp.asarray(rng.normal(size=(len(lengths), 1) + case["grouped"]),
+                        pool.dtype)
+        firsts = (None if window is None
+                  else jnp.maximum(lengths - window, 0))
+        got = jax.jit(lambda *a: pa.paged_decode_attention(
+            a[0], a[1], None, *a[2:], layer=jnp.int32(layer),
+            firsts=firsts))(q, pool, tables, lengths)
+        want = gqa_gather_path(q, pool, tables, lengths, window, layer)
+    else:
+        k_pool, v_pool = case["pools"]
+        q = queries(rng, len(lengths))
+        got = jax.jit(lambda *a: pa.paged_decode_attention(
+            *a, layer=None if layer is None else jnp.int32(layer)))(
+                q, k_pool, v_pool, tables, lengths)
+        want = gather_path(q, k_pool, v_pool, tables, lengths, layer)
     assert got.shape == q.shape and got.dtype == q.dtype
-    want = gather_path(q, k_pool, v_pool, tables, lengths, layer)
     zero_rows = case.get("zero_rows", np.zeros(len(lengths), bool))
     assert_within_bf16_rounding(got, want, ~zero_rows)
     assert not np.asarray(got, np.float32)[zero_rows].any()
@@ -177,15 +265,62 @@ SELECTION = [
 ]
 
 
+# The grouped-query family's own call: the same fields, then the heads
+# (query heads, K/V heads, head size).  Its pools are stored in the compute
+# type or refused, so there is no ``kv_dtype`` to choose by.
+GQA_KERNEL = {mellum.KERNEL_WINDOW, mellum.KERNEL_FULL}
+GQA_GATHER = {mellum.GATHER_WINDOW, mellum.GATHER_FULL}
+GQA_SELECTION = [
+    ("gqa-decode-tpu", 1, None, None, False, "tpu", GQA_KERNEL, (16, 2, 64)),
+    ("gqa-decode-interpreter", 1, None, None, True, "cpu", GQA_KERNEL,
+     (4, 2, 16)),
+    ("gqa-decode-one-device-mesh", 1, None, 1, True, "cpu", GQA_KERNEL,
+     (4, 2, 16)),
+    ("gqa-decode-cpu", 1, None, None, False, "cpu", GQA_GATHER, (16, 2, 64)),
+    ("gqa-prefill-chunk", 8, None, None, True, "cpu", GQA_GATHER, (4, 2, 16)),
+    ("gqa-decode-on-a-mesh", 1, None, 2, True, "cpu", GQA_GATHER, (4, 2, 16)),
+    # On the chip a K/V half of a pool row must be whole lane tiles, and a
+    # group of query heads whole sublane tiles.
+    ("gqa-decode-tpu-half-a-lane-tile", 1, None, None, False, "tpu",
+     GQA_GATHER, (16, 2, 32)),
+    ("gqa-decode-tpu-group-of-two", 1, None, None, False, "tpu", GQA_GATHER,
+     (4, 2, 64)),
+]
+
+
+def gqa_paths(mesh, query_len, heads):
+    """The paths ``Mellum``'s decode call of ``query_len`` positions a row
+    takes, traced from shapes."""
+    slots, total = 4, 64
+    n_heads, kv_heads, head_dim = heads
+    cfg = mellum.MellumConfig.tiny(
+        num_attention_heads=n_heads, num_key_value_heads=kv_heads,
+        head_dim=head_dim)
+    model = mellum.Mellum(cfg, mesh=mesh)
+    paged = PagedKVConfig(block_size=16, num_blocks=slots * 4 + 1,
+                          window_blocks=slots * 4 + 1, window_ring=4)
+    call = dict(decode=True, slot_ids=jnp.arange(slots, dtype=jnp.int32),
+                paged=paged, block_tables=jnp.zeros((slots, 8), jnp.int32))
+    variables = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((slots, total), jnp.int32), **call))
+    with pa.record_paths() as paths:
+        jax.eval_shape(
+            lambda v, t: model.apply(v, t, mutable=["cache"], **call),
+            variables, jax.ShapeDtypeStruct((slots, query_len), jnp.int32))
+    return set(paths)
+
+
 @pytest.mark.parametrize(
-    "query_len,kv_dtype,mesh_devices,interpret,platform,expected",
-    [c[1:] for c in SELECTION], ids=[c[0] for c in SELECTION])
+    "query_len,kv_dtype,mesh_devices,interpret,platform,expected,gqa_heads",
+    [c[1:] + (None,) for c in SELECTION] + [c[1:] for c in GQA_SELECTION],
+    ids=[c[0] for c in SELECTION + GQA_SELECTION])
 def test_the_path_is_chosen_by_what_the_call_can_observe(
         monkeypatch, query_len, kv_dtype, mesh_devices, interpret, platform,
-        expected):
+        expected, gqa_heads):
     """The model's own call, traced from shapes: which implementation
-    ``_paged_cached_attention`` takes (the CPU cannot lower the kernel for
-    a TPU, so nothing is compiled)."""
+    ``_paged_cached_attention`` (or the grouped-query family's attention)
+    takes (the CPU cannot lower the kernel for a TPU, so nothing is
+    compiled)."""
     fa = importlib.import_module(
         "distributed_tensorflow_tpu.ops.flash_attention")
     monkeypatch.setattr(fa, "_platform", lambda: platform)
@@ -197,6 +332,9 @@ def test_the_path_is_chosen_by_what_the_call_can_observe(
     if mesh_devices is not None:
         from jax.sharding import Mesh
         mesh = Mesh(np.array(jax.devices()[:mesh_devices]), ("tensor",))
+    if gqa_heads is not None:
+        assert gqa_paths(mesh, query_len, gqa_heads) == expected
+        return
     slots, total = 4, 32
     cfg = dataclasses.replace(GPT2Config.tiny(), d_model=256)  # whole lanes
     model = GPT2(cfg, mesh=mesh)

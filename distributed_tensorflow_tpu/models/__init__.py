@@ -292,11 +292,19 @@ def available_models() -> Tuple[str, ...]:
 def get_workload(name: str, **overrides) -> Workload:
     if name not in _REGISTRY:
         raise ValueError(f"Unknown model {name!r}; available: {available_models()}")
-    try:
-        mod = importlib.import_module(_REGISTRY[name])
-    except ModuleNotFoundError as e:
-        raise NotImplementedError(
-            f"Model family {name!r} is registered but its module "
-            f"{_REGISTRY[name]} is not implemented yet"
-        ) from e
-    return mod.make_workload(**overrides)
+    # Lazy: ``obs`` imports the training loop, which must not import back
+    # into a half-made ``models``.
+    from distributed_tensorflow_tpu.obs.trace import default_tracer
+
+    # The family's module is imported inside the span: what it pulls in is
+    # part of what making a workload costs a process.
+    with default_tracer().span("workload", cat="startup",
+                               args={"model": name}):
+        try:
+            mod = importlib.import_module(_REGISTRY[name])
+        except ModuleNotFoundError as e:
+            raise NotImplementedError(
+                f"Model family {name!r} is registered but its module "
+                f"{_REGISTRY[name]} is not implemented yet"
+            ) from e
+        return mod.make_workload(**overrides)

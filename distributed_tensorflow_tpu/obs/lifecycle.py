@@ -49,12 +49,11 @@ __all__ = [
 ]
 
 # The typed event vocabulary.  SUBMIT..RETIRED are per-request (rid > 0);
-# MEGASTEP_DISPATCH/FETCH and COMPILE are loop/engine-level (rid == 0).
+# MEGASTEP_DISPATCH/FETCH are loop-level (rid == 0).
 EVENTS = frozenset({
     "SUBMIT", "QUEUED", "ADMITTED", "PREFILL_CHUNK", "FIRST_TOKEN",
     "MEGASTEP_DISPATCH", "MEGASTEP_FETCH", "PREEMPTED", "SWAPPED_OUT",
     "SWAPPED_IN", "RESUMED", "TOKEN_STREAMED", "CANCELLED", "RETIRED",
-    "COMPILE",
 })
 
 # The breakdown phases, in presentation order.

@@ -15,7 +15,15 @@ OR a JAX profiler session is open (``jax.profiler.start_trace``,
 ``TraceAnnotation.is_enabled()`` is the switch, so no flag has to be
 threaded to the code that is profiled.  With neither, a span costs two
 clock reads and a small object (about half a microsecond), and nothing is
-appended.
+appended.  **Two categories are the exception and are always recorded:**
+``startup`` (the phases between process start and the loop: a workload
+made, a step built, an engine and a scheduler constructed, a program's
+first launch) and ``compile`` (``compile_cache.py``'s listener: every
+trace, lowering and compile-or-cache-read JAX reports, by program).  They
+are once-a-process or once-a-compile work, a few dozen appends in a run
+and none of them inside a decode launch or a training step, and set-up is
+over before anybody could have asked for it to be recorded:
+``Tracer.spans(cat="startup")`` reads them.
 
 **One clock.**  Every timestamp is :func:`now` (``time.perf_counter``)
 seconds.  Call sites that stamp a span's ends themselves (``add_span``)
@@ -28,7 +36,9 @@ clocks agree.
 session is open, so the loop's phases lie on the ``/host:CPU`` lines of
 the same xplane file as the device's lines, on the profiler's own clock.
 Spans recorded after the fact (``add_span`` with explicit times: a
-request's ``queue_wait``, ``prefill``, ``decode``) stay ring-only.
+request's ``queue_wait``, ``prefill``, ``decode``; a compile's stages)
+stay ring-only, and carry as ``parent`` the span that was open on the
+calling thread when they were recorded.
 
 The ring buffer bounds memory: a long-running server keeps only the most
 recent ``capacity`` events — a flight recorder, not an archive.
@@ -37,6 +47,7 @@ recent ``capacity`` events — a flight recorder, not an archive.
 from __future__ import annotations
 
 import collections
+import functools
 import itertools
 import json
 import threading
@@ -45,13 +56,18 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["Tracer", "default_tracer", "now", "span_name"]
+__all__ = ["ALWAYS_RECORDED", "Tracer", "default_tracer", "now", "span_name",
+           "spanned"]
 
 #: The tracer's clock, in seconds.  ``benchmark/harness/spans.py`` stamps
 #: its spans with the same function.
 now = time.perf_counter
 
 _profiler_on = TraceAnnotation.is_enabled
+
+#: Categories that land in the ring whether or not anything records (the
+#: module docstring says why these two).
+ALWAYS_RECORDED = frozenset({"startup", "compile"})
 
 Span = Tuple[str, float, float, int, Dict[str, Any]]
 
@@ -98,7 +114,7 @@ class _OpenSpan:
         # The annotation first and the clock second, as the benchmark's
         # own spans do: the two stamps of one instant lie some
         # microseconds apart, in the same order everywhere.
-        self._record = tracer._enabled
+        self._record = tracer._enabled or self._cat in ALWAYS_RECORDED
         if _profiler_on():
             self._record = True
             self._annotation = TraceAnnotation(
@@ -243,10 +259,16 @@ class Tracer:
         args: Optional[Dict[str, Any]] = None,
     ) -> None:
         """Record a completed span; ``start``/``end`` are ``now()`` times.
-        Ring only: it never enters the profiler's trace."""
-        if self.recording:
-            self._record_span(name, start, end, cat, tid,
-                              dict(args) if args else None)
+        Ring only: it never enters the profiler's trace.  Its ``args``
+        carry ``parent``, the ``span_id`` of the innermost span open on
+        the calling thread, where there is one: a span recorded after the
+        fact says which of the program's spans it fell in."""
+        if self.recording or cat in ALWAYS_RECORDED:
+            args = dict(args) if args else {}
+            stack = getattr(self._local, "stack", None)
+            if stack:
+                args["parent"] = stack[-1]
+            self._record_span(name, start, end, cat, tid, args)
 
     def add_instant(
         self,
@@ -368,3 +390,19 @@ def default_tracer() -> Tracer:
     """Process-global tracer; entrypoints enable it under ``--trace_out``,
     and it records under any profiler session without that."""
     return _default_tracer
+
+
+def spanned(name: str, cat: str):
+    """Decorator: each call runs inside ``default_tracer().span(name,
+    cat)``.  For a body too long to indent under a ``with`` (a
+    constructor that is a set-up phase)."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inside(*args, **kwargs):
+            with _default_tracer.span(name, cat):
+                return fn(*args, **kwargs)
+
+        return inside
+
+    return wrap

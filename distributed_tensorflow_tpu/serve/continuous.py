@@ -190,7 +190,11 @@ import numpy as np
 from distributed_tensorflow_tpu.models import PagedKVConfig
 from distributed_tensorflow_tpu.obs import metrics as obs_metrics
 from distributed_tensorflow_tpu.obs.lifecycle import EMPTY_LIFECYCLE_STATS
-from distributed_tensorflow_tpu.obs.trace import default_tracer, now as _now
+from distributed_tensorflow_tpu.obs.trace import (
+    default_tracer,
+    now as _now,
+    spanned,
+)
 from distributed_tensorflow_tpu.ops.paged_attention import KERNEL_PATHS
 from distributed_tensorflow_tpu.serve.batcher import (
     ServeOverloadedError,
@@ -591,6 +595,7 @@ class ContinuousScheduler:
     output is bit-identical budget on vs off.
     """
 
+    @spanned("scheduler_init", "startup")   # up to the loop thread running
     def __init__(
         self,
         engine,
@@ -768,8 +773,9 @@ class ContinuousScheduler:
                 kv_dtype=kv_dtype, data_shards=shards,
                 window_blocks=self.num_slots * ring + 1 if ring else 0,
                 window_ring=ring)
-            self._cache = engine.init_paged_cache(
-                self.num_slots, self.max_total_len, paged=self.paged)
+            with default_tracer().span("cache_init", cat="startup"):
+                self._cache = engine.init_paged_cache(
+                    self.num_slots, self.max_total_len, paged=self.paged)
             self._allocator: Optional[BlockAllocator] = BlockAllocator(
                 self.paged.num_blocks, self.block_size, num_shards=shards)
             # Slot -> data shard: contiguous ranges, matching how
@@ -811,8 +817,9 @@ class ContinuousScheduler:
             # overwriting the last real K/V rows (caught as an
             # end-of-stream parity break when max_total_len is sized
             # exactly to prompt + max_new_tokens).
-            self._cache = engine.init_slot_cache(
-                self.num_slots, self.max_total_len + self.spec_k)
+            with default_tracer().span("cache_init", cat="startup"):
+                self._cache = engine.init_slot_cache(
+                    self.num_slots, self.max_total_len + self.spec_k)
         # Per-slot emitted-token counts (presence/frequency penalties):
         # resident device state beside the KV cache, donated through every
         # slot launch and rebound from its return — same chaining idiom
@@ -839,10 +846,6 @@ class ContinuousScheduler:
         # sizes), never a device array.  None (default) keeps every
         # path bit-identical to the unrecorded scheduler.
         self._lifecycle = lifecycle
-        if lifecycle is not None:
-            # Compile taps (rid 0) let a run cross-check its
-            # compile_post_warmup == 0 against lifecycle events.
-            engine.set_lifecycle(lifecycle)
         self._tier_pool: Optional[HostKVPool] = None
         if self.slo_scheduling and cache_mode == "paged":
             self._tier_pool = HostKVPool(

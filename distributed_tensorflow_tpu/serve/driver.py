@@ -41,7 +41,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from distributed_tensorflow_tpu import cluster as cluster_lib
-from distributed_tensorflow_tpu.obs import ServeMonitorHook
+from distributed_tensorflow_tpu.obs import ServeMonitorHook, startup
 from distributed_tensorflow_tpu.serve.batcher import (
     DynamicBatcher,
     ServeOverloadedError,
@@ -789,6 +789,9 @@ def _drive(args: ServeArgs, engine: ServeEngine) -> Dict[str, Any]:
             "priority_headroom=%d)",
             gateway.host, gateway.port, args.max_inflight,
             args.priority_headroom)
+    # Ready: engine made, programs warmed, front door open.  One
+    # ``startup`` line says what each phase and each program's compile took.
+    startup.report()
     monitor = ServeMonitorHook(batcher, every_steps=args.log_every)
     if args.loadgen_trace:
         try:

@@ -22,6 +22,7 @@ batch split.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import inspect
 import logging
@@ -38,6 +39,7 @@ from distributed_tensorflow_tpu import cluster as cluster_lib
 from distributed_tensorflow_tpu.checkpoint import CheckpointManager
 from distributed_tensorflow_tpu.models import Workload, get_workload
 from distributed_tensorflow_tpu.obs import metrics as obs_metrics
+from distributed_tensorflow_tpu.obs.trace import default_tracer, spanned
 from distributed_tensorflow_tpu.ops import paged_attention
 from distributed_tensorflow_tpu.parallel.sharding import (
     apply_shardings,
@@ -68,6 +70,10 @@ PyTree = Any
 # trees); it only ever touches launch outputs.
 _launch_lock = threading.Lock()
 
+# What a launch of a program the caches already hold runs inside, where a
+# program's first launch runs inside ``dtt/startup/program_first_launch``.
+_WARM = contextlib.nullcontext()
+
 
 def _named(name: str, fn: Callable, *bound) -> Callable:
     """``fn`` (with ``bound`` leading arguments) under a ``__name__`` of
@@ -82,17 +88,15 @@ def _named(name: str, fn: Callable, *bound) -> Callable:
 
 
 def _engine_instruments(registry=None):
-    """Engine-side families: one compile-event counter per program kind
-    (a burst after warmup is normal; compiles during steady-state serving
-    are the shape-bucketing bug the label surfaces), and host-side
-    dispatch timing for the slot programs.  Instrumentation is entirely
-    host-side — it never enters the jitted programs, so the greedy decode
-    programs stay bit-identical."""
+    """Engine-side families: the program caches' misses (what XLA then
+    compiled or read, by program and cache outcome, is
+    ``dtt_compiles_total`` of ``compile_cache.py``: a compile during
+    steady-state serving is the shape-bucketing bug it surfaces), and
+    host-side dispatch timing for the fused launches.  Instrumentation is
+    entirely host-side — it never enters the jitted programs, so the
+    greedy decode programs stay bit-identical."""
     r = registry or obs_metrics.default_registry()
     return {
-        "compiles": r.counter(
-            "dtt_serve_compile_events_total",
-            "Program-cache misses by program kind", labelnames=("kind",)),
         "compile_total": r.counter(
             "dtt_serve_compile_total",
             "Serving program compiles (program-cache misses, all kinds) "
@@ -103,12 +107,6 @@ def _engine_instruments(registry=None):
             "Distinct compiled serving programs resident in the "
             "program caches — ONE set per (family, paged, K/k) "
             "regardless of the sampling parameter mix"),
-        "prefill": r.histogram(
-            "dtt_serve_prefill_seconds",
-            "Host-side slot-prefill dispatch duration"),
-        "decode_step": r.histogram(
-            "dtt_serve_decode_step_seconds",
-            "Host-side slot-decode dispatch duration"),
         "megastep": r.histogram(
             "dtt_serve_megastep_seconds",
             "Host-side megastep dispatch duration (K fused decode steps)"),
@@ -277,6 +275,7 @@ class ServeEngine:
     random init — the smoke/benchmark path when no training run preceded.
     """
 
+    @spanned("engine_init", "startup")
     def __init__(
         self,
         model: str = "gpt2",
@@ -287,6 +286,7 @@ class ServeEngine:
         seed: int = 0,
         **workload_overrides,
     ):
+        self._tracer = default_tracer()
         self.mesh = mesh if mesh is not None else cluster_lib.build_mesh(
             cluster_lib.MeshConfig())
         self.workload: Workload = get_workload(
@@ -334,8 +334,10 @@ class ServeEngine:
             )
             return dict(self.module.init(jax.random.key(seed), init_input))
 
-        abstract = jax.eval_shape(init_fn)
-        shardings = self.workload.rules.shardings_for(self.mesh, abstract)
+        with self._tracer.span("abstract_params", cat="startup"):
+            abstract = jax.eval_shape(init_fn)
+        with self._tracer.span("shardings", cat="startup"):
+            shardings = self.workload.rules.shardings_for(self.mesh, abstract)
         restored = None
         if checkpoint_dir:
             self._manager = CheckpointManager(checkpoint_dir)
@@ -353,10 +355,14 @@ class ServeEngine:
                 logger.warning(
                     "no checkpoint under %s — serving FRESH-INIT params",
                     checkpoint_dir)
-        if restored is not None:
-            variables = apply_shardings(restored, shardings)
-        else:
-            variables = jax.jit(init_fn, out_shardings=shardings)()
+        # Parameters placed: a checkpoint's laid out over the mesh, or a
+        # fresh draw made there (its program's compile falls inside).
+        with self._tracer.span("params_placed", cat="startup",
+                               args={"restored": restored is not None}):
+            if restored is not None:
+                variables = apply_shardings(restored, shardings)
+            else:
+                variables = jax.jit(init_fn, out_shardings=shardings)()
         self.params = variables.pop("params")
         self.model_state = variables  # e.g. {"batch_stats": ...} for resnet
         self._predict_fn = jax.jit(_named("predict", self._predict_apply))
@@ -406,26 +412,26 @@ class ServeEngine:
             return (0.0, 0)
         return (float(temperature), max(0, int(top_k)))
 
-    def set_lifecycle(self, lifecycle) -> None:
-        """Attach a lifecycle recorder (``obs.lifecycle``): every
-        program-cache miss records a rid-0 COMPILE event, so a run
-        asserting ``compile_post_warmup == 0`` can cross-check the
-        lifecycle stream instead of trusting the counter alone."""
-        self._lifecycle = lifecycle
-
-    def _note_compile(self, kind: str) -> None:
-        """Account one program-cache miss: the per-kind labelled counter
-        plus the total that must stay flat post-warmup.
-        Every miss inserts exactly one never-evicted program, so the
-        resident-program gauge advances here too — the insert site, not
-        a dict-length read, so ``compile_stats`` never has to touch the
-        caches themselves."""
-        self._obs["compiles"].labels(kind=kind).inc()
+    def _note_miss(self) -> None:
+        """Account one program-cache miss: the total that must stay flat
+        post-warmup, and the resident-program gauge (every miss inserts
+        exactly one never-evicted program, so it advances here, at the
+        insert site, and ``compile_stats`` never has to touch the caches
+        themselves).  What XLA then compiles or reads is on record by
+        program, stage and cache outcome (``compile_cache.py``)."""
         self._obs["compile_total"].inc()
         self._obs["programs_cached"].inc()
-        lifecycle = getattr(self, "_lifecycle", None)
-        if lifecycle is not None:
-            lifecycle.record(0, "COMPILE", program=kind)
+
+    def _first_launch(self, kind: str):
+        """A program-cache miss whose site also makes the new program's
+        first call: accounted (``_note_miss``), and the span that call runs
+        inside until it returns, ``dtt/startup/program_first_launch``: the
+        trace, the lowering and the compile or cache read of the shape the
+        program is first met with fall inside it (``dtt/compile/*``), and
+        what is left is the first dispatch."""
+        self._note_miss()
+        return self._tracer.span("program_first_launch", cat="startup",
+                                 args={"kind": kind})
 
     def compile_stats(self) -> Dict[str, float]:
         """Compile/program-cache telemetry snapshot.  Reads
@@ -492,14 +498,14 @@ class ServeEngine:
         with _launch_lock:
             if temperature <= 0.0:
                 if "step" not in self._generate_fns:
-                    self._note_compile("decode_step")
+                    self._note_miss()   # generate() makes the first call
                     self._generate_fns["step"] = jax.jit(
                         _named("decode_step", self._decode_apply),
                         donate_argnums=(1,))
                 return self._generate_fns["step"]
             key = ("step", temperature, top_k)
             if key not in self._generate_fns:
-                self._note_compile("decode_step")
+                self._note_miss()
                 self._generate_fns[key] = jax.jit(
                     _named("sampled_decode", self._sampled_decode_apply,
                            temperature, top_k),
@@ -512,8 +518,8 @@ class ServeEngine:
         cache_rules = self._cache_rules()  # the workload's, by no model's name
 
         key = (batch, total_len)
-        if key not in self._cache_init_fns:
-            self._note_compile("cache_init")
+        missed = key not in self._cache_init_fns
+        if missed:
 
             def mk():
                 vs = self.module.init(
@@ -528,7 +534,8 @@ class ServeEngine:
                     lambda s: jnp.zeros(s.shape, s.dtype), shapes)),
                 out_shardings=shardings,
             )
-        return self._cache_init_fns[key]()
+        with self._first_launch("cache_init") if missed else _WARM:
+            return self._cache_init_fns[key]()
 
     # -- resident slot cache (continuous batching) ---------------------------
 
@@ -551,8 +558,8 @@ class ServeEngine:
                 f"max_total_len {total_len} exceeds n_positions "
                 f"{cfg.n_positions}")
         key = ("slots", num_slots, total_len)
-        if key not in self._cache_init_fns:
-            self._note_compile("slot_cache_init")
+        missed = key not in self._cache_init_fns
+        if missed:
 
             def mk():
                 vs = self.module.init(
@@ -569,7 +576,8 @@ class ServeEngine:
                     lambda s: jnp.zeros(s.shape, s.dtype), shapes)),
                 out_shardings=shardings,
             )
-        return self._cache_init_fns[key]()
+        with self._first_launch("slot_cache_init") if missed else _WARM:
+            return self._cache_init_fns[key]()
 
     def init_paged_cache(self, num_slots: int, total_len: int, *,
                          paged) -> PyTree:
@@ -613,8 +621,8 @@ class ServeEngine:
         cache_rules = self._cache_rules()  # the workload's, by no model's name
 
         key = ("paged", num_slots, total_len, paged)
-        if key not in self._cache_init_fns:
-            self._note_compile("paged_cache_init")
+        missed = key not in self._cache_init_fns
+        if missed:
 
             def mk():
                 vs = self.module.init(
@@ -637,7 +645,8 @@ class ServeEngine:
                     lambda s: jnp.zeros(s.shape, s.dtype), shapes)),
                 out_shardings=shardings,
             )
-        return self._cache_init_fns[key]()
+        with self._first_launch("paged_cache_init") if missed else _WARM:
+            return self._cache_init_fns[key]()
 
     def init_slot_counts(self, num_slots: int) -> jax.Array:
         """Device-resident ``(num_slots, vocab)`` int32 emitted-token
@@ -831,19 +840,18 @@ class ServeEngine:
         bt = block_tables
         if bt is not None and not isinstance(bt, jax.Array):
             bt = np.asarray(bt, np.int32)
-        t0 = time.perf_counter()
         with _launch_lock:
-            if key not in self._generate_fns:
-                self._note_compile("slot_prefill")
+            missed = key not in self._generate_fns
+            if missed:
                 self._generate_fns[key] = jax.jit(
                     _named("prefill_slots", self._recording_paths(
                         key, self._prefill_slots_apply), paged),
                     donate_argnums=(1, 2))
-            nxt, cache, counts = self._generate_fns[key](
-                self.params if params is None else params, cache, counts,
-                prompts, np.asarray(slot_ids, np.int32), bt, base, counter,
-                starts, sampling, commit_mask)
-        self._obs["prefill"].observe(time.perf_counter() - t0)
+            with self._first_launch("slot_prefill") if missed else _WARM:
+                nxt, cache, counts = self._generate_fns[key](
+                    self.params if params is None else params, cache,
+                    counts, prompts, np.asarray(slot_ids, np.int32), bt,
+                    base, counter, starts, sampling, commit_mask)
         return (nxt, cache) if legacy else (nxt, cache, counts)
 
     def _decode_slots_apply(self, paged, params, cache, counts, tokens,
@@ -922,10 +930,9 @@ class ServeEngine:
         bt = block_tables
         if bt is not None and not isinstance(bt, jax.Array):
             bt = np.asarray(bt, np.int32)
-        t0 = time.perf_counter()
         with _launch_lock:
-            if key not in self._generate_fns:
-                self._note_compile("slot_decode")
+            missed = key not in self._generate_fns
+            if missed:
                 self._generate_fns[key] = jax.jit(
                     _named("decode_slots", self._recording_paths(
                         key, self._decode_slots_apply), paged),
@@ -935,12 +942,12 @@ class ServeEngine:
                 tokens_dev = jax.device_put(
                     np.asarray(tokens_dev, np.int32),
                     batch_sharding(self.mesh))
-            nxt, gated, counts = self._generate_fns[key](
-                self.params if params is None else params, cache, counts,
-                tokens_dev, np.asarray(active, bool), bt, base, counter,
-                sampling)
+            with self._first_launch("slot_decode") if missed else _WARM:
+                nxt, gated, counts = self._generate_fns[key](
+                    self.params if params is None else params, cache,
+                    counts, tokens_dev, np.asarray(active, bool), bt, base,
+                    counter, sampling)
         self._count_decode_launch(key)
-        self._obs["decode_step"].observe(time.perf_counter() - t0)
         return (nxt, gated) if legacy else (nxt, gated, counts)
 
     def put_replicated(self, arr) -> jax.Array:
@@ -1043,11 +1050,12 @@ class ServeEngine:
         int8 pool round-trips bit-exactly."""
         key = ("block_gather", paged)
         with _launch_lock:
-            if key not in self._block_fns:
-                self._note_compile("block_gather")
+            missed = key not in self._block_fns
+            if missed:
                 self._block_fns[key] = jax.jit(
                     _named("block_gather", self._gather_block_apply))
-            slices = self._block_fns[key](cache, np.int32(block))
+            with self._first_launch("block_gather") if missed else _WARM:
+                slices = self._block_fns[key](cache, np.int32(block))
             return jax.device_get(slices)
 
     def scatter_kv_block(self, cache: PyTree, block: int, payload: list,
@@ -1058,12 +1066,14 @@ class ServeEngine:
         cache, ...)``), exactly the donated-cache chaining discipline."""
         key = ("block_scatter", paged)
         with _launch_lock:
-            if key not in self._block_fns:
-                self._note_compile("block_scatter")
+            missed = key not in self._block_fns
+            if missed:
                 self._block_fns[key] = jax.jit(
                     _named("block_scatter", self._scatter_block_apply),
                     donate_argnums=(0,))
-            return self._block_fns[key](cache, np.int32(block), payload)
+            with self._first_launch("block_scatter") if missed else _WARM:
+                return self._block_fns[key](
+                    cache, np.int32(block), payload)
 
     def bind_slot_rows(self, cache: PyTree, slot_ids, starts) -> PyTree:
         """Set ``cache_index``/``position`` rows for ``slot_ids`` to
@@ -1073,14 +1083,15 @@ class ServeEngine:
         The cache is donated; callers rebind."""
         key = ("slot_bind",)
         with _launch_lock:
-            if key not in self._block_fns:
-                self._note_compile("slot_bind")
+            missed = key not in self._block_fns
+            if missed:
                 self._block_fns[key] = jax.jit(
                     _named("slot_bind", self._bind_rows_apply),
                     donate_argnums=(0,))
-            return self._block_fns[key](
-                cache, np.asarray(slot_ids, np.int32),
-                np.asarray(starts, np.int32))
+            with self._first_launch("slot_bind") if missed else _WARM:
+                return self._block_fns[key](
+                    cache, np.asarray(slot_ids, np.int32),
+                    np.asarray(starts, np.int32))
 
     def gather_counts_row(self, counts: jax.Array, slot: int) -> np.ndarray:
         """One slot's emitted-token count row to host — swapped out with
@@ -1088,11 +1099,12 @@ class ServeEngine:
         preempt/resume round-trip bit-exactly."""
         key = ("counts_gather",)
         with _launch_lock:
-            if key not in self._block_fns:
-                self._note_compile("counts_gather")
+            missed = key not in self._block_fns
+            if missed:
                 self._block_fns[key] = jax.jit(
                     _named("counts_gather", self._counts_row_apply))
-            row = self._block_fns[key](counts, np.int32(slot))
+            with self._first_launch("counts_gather") if missed else _WARM:
+                row = self._block_fns[key](counts, np.int32(slot))
             return np.asarray(jax.device_get(row))
 
     def scatter_counts_row(self, counts: jax.Array, slot: int,
@@ -1100,13 +1112,14 @@ class ServeEngine:
         """Restore a saved count row into ``slot``; counts donated."""
         key = ("counts_bind",)
         with _launch_lock:
-            if key not in self._block_fns:
-                self._note_compile("counts_bind")
+            missed = key not in self._block_fns
+            if missed:
                 self._block_fns[key] = jax.jit(
                     _named("counts_bind", self._counts_bind_apply),
                     donate_argnums=(0,))
-            return self._block_fns[key](
-                counts, np.int32(slot), np.asarray(row, np.int32))
+            with self._first_launch("counts_bind") if missed else _WARM:
+                return self._block_fns[key](
+                    counts, np.int32(slot), np.asarray(row, np.int32))
 
     def _megastep_apply(self, steps, paged, params, cache, counts, tokens,
                         active, horizon, eos_rows, block_tables, rng,
@@ -1280,8 +1293,8 @@ class ServeEngine:
             clock = np.int32(0)
         t0 = time.perf_counter()
         with _launch_lock:
-            if key not in self._generate_fns:
-                self._note_compile("slot_megastep")
+            missed = key not in self._generate_fns
+            if missed:
                 counting = moe_counts_of(cache) is not None
                 self._generate_fns[key] = jax.jit(
                     _named("decode_megastep", self._recording_paths(
@@ -1293,11 +1306,12 @@ class ServeEngine:
                 tokens_dev = jax.device_put(
                     np.asarray(tokens_dev, np.int32).reshape(-1),
                     batch_sharding(self.mesh))
-            out = self._generate_fns[key](
-                self.params if params is None else params, cache, counts,
-                tokens_dev, np.asarray(active, bool),
-                np.asarray(horizon, np.int32), eos, bt, base, counter,
-                sampling, fresh_tokens, fresh, clock)
+            with self._first_launch("slot_megastep") if missed else _WARM:
+                out = self._generate_fns[key](
+                    self.params if params is None else params, cache,
+                    counts, tokens_dev, np.asarray(active, bool),
+                    np.asarray(horizon, np.int32), eos, bt, base, counter,
+                    sampling, fresh_tokens, fresh, clock)
         toks, tok_final, steps_run, clock_out, cache, counts = out[:6]
         self._count_decode_launch(key)
         self._obs["megastep"].observe(time.perf_counter() - t0)
@@ -1480,43 +1494,44 @@ class ServeEngine:
             bt = np.asarray(bt, np.int32)
         t0 = time.perf_counter()
         with _launch_lock:
-            if key not in self._generate_fns:
-                self._note_compile(key[0])
+            missed = key not in self._generate_fns
+            if missed:
                 fn = (self._verify_chain_apply if chain
                       else self._verify_slots_apply)
                 self._generate_fns[key] = jax.jit(
                     _named(key[0], self._recording_paths(key, fn), k,
                            paged),
                     donate_argnums=(1, 2))
-            tokens_dev = jax.device_put(tokens, batch_sharding(self.mesh))
-            if chain:
-                n = tokens.shape[0]
-                carry_dev = carry
-                if not isinstance(carry_dev, jax.Array):
-                    carry_dev = jax.device_put(
-                        np.asarray(carry_dev, np.int32).reshape(-1),
-                        batch_sharding(self.mesh))
-                if fresh_tokens is None:
-                    fresh_tokens = np.zeros((n,), np.int32)
-                elif not isinstance(fresh_tokens, jax.Array):
-                    fresh_tokens = np.asarray(
-                        fresh_tokens, np.int32).reshape(-1)
-                fresh = (np.zeros((n,), bool) if fresh is None
-                         else np.asarray(fresh, bool))
-                if clock is None:
-                    clock = np.int32(0)
-                (targets, accepted, carry_out, clock_out, gated,
-                 counts) = self._generate_fns[key](
-                    self.params if params is None else params, cache,
-                    counts, tokens_dev, np.asarray(active, bool),
-                    np.asarray(draft_lens, np.int32), bt, base, counter,
-                    sampling, carry_dev, fresh_tokens, fresh, clock)
-            else:
-                targets, accepted, gated, counts = self._generate_fns[key](
-                    self.params if params is None else params, cache, counts,
-                    tokens_dev, np.asarray(active, bool),
-                    np.asarray(draft_lens, np.int32), bt, base, counter,
-                    sampling)
+            with self._first_launch(key[0]) if missed else _WARM:
+                tokens_dev = jax.device_put(tokens, batch_sharding(self.mesh))
+                if chain:
+                    n = tokens.shape[0]
+                    carry_dev = carry
+                    if not isinstance(carry_dev, jax.Array):
+                        carry_dev = jax.device_put(
+                            np.asarray(carry_dev, np.int32).reshape(-1),
+                            batch_sharding(self.mesh))
+                    if fresh_tokens is None:
+                        fresh_tokens = np.zeros((n,), np.int32)
+                    elif not isinstance(fresh_tokens, jax.Array):
+                        fresh_tokens = np.asarray(
+                            fresh_tokens, np.int32).reshape(-1)
+                    fresh = (np.zeros((n,), bool) if fresh is None
+                             else np.asarray(fresh, bool))
+                    if clock is None:
+                        clock = np.int32(0)
+                    (targets, accepted, carry_out, clock_out, gated,
+                     counts) = self._generate_fns[key](
+                        self.params if params is None else params, cache,
+                        counts, tokens_dev, np.asarray(active, bool),
+                        np.asarray(draft_lens, np.int32), bt, base, counter,
+                        sampling, carry_dev, fresh_tokens, fresh, clock)
+                else:
+                    targets, accepted, gated, counts = self._generate_fns[key](
+                        self.params if params is None else params, cache, counts,
+                        tokens_dev, np.asarray(active, bool),
+                        np.asarray(draft_lens, np.int32), bt, base, counter,
+                        sampling)
         self._obs["verify"].observe(time.perf_counter() - t0)
         if chain:
             return targets, accepted, carry_out, clock_out, gated, counts

@@ -215,81 +215,93 @@ def build_step(
     alone (``init.lower()``, ``train_step.lower(abstract_state, ...)``) —
     how the tests ask what program a full-width mesh gets without running
     it."""
-    lr = learning_rate if learning_rate is not None else workload.learning_rate
-    schedule = optax.warmup_cosine_decay_schedule(
-        init_value=0.0,
-        peak_value=lr,
-        warmup_steps=min(workload.warmup_steps, max(1, total_steps // 10)),
-        decay_steps=max(2, total_steps),
-    )
-    if workload.make_optimizer is not None:
-        tx = workload.make_optimizer(schedule)
-    else:
-        tx = optax.adamw(schedule, weight_decay=1e-4)
-
-    rng = jax.random.key(seed)
-
-    def init_fn():
-        init_input = (
-            workload.init_batch if workload.init_key is None
-            else workload.init_batch[workload.init_key]
+    tracer = default_tracer()
+    with tracer.span("build_step", cat="startup") as phase:
+        lr = learning_rate if learning_rate is not None else workload.learning_rate
+        schedule = optax.warmup_cosine_decay_schedule(
+            init_value=0.0,
+            peak_value=lr,
+            warmup_steps=min(workload.warmup_steps, max(1, total_steps // 10)),
+            decay_steps=max(2, total_steps),
         )
-        variables = dict(workload.module.init(rng, init_input))
-        params = variables.pop("params")
-        return TrainState.create(
-            apply_fn=workload.module.apply, params=params, tx=tx,
-            model_state=variables,
-        )
+        if workload.make_optimizer is not None:
+            tx = workload.make_optimizer(schedule)
+        else:
+            tx = optax.adamw(schedule, weight_decay=1e-4)
 
-    abstract_state = jax.eval_shape(init_fn)
-    # One rule table shards params AND optimizer moments: regex paths match
-    # both "params/.../kernel" and "opt_state/.../mu/.../kernel".
-    state_shardings = workload.rules.shardings_for(mesh, abstract_state)
-    init = jax.jit(init_fn, out_shardings=state_shardings)
+        rng = jax.random.key(seed)
 
-    # shard_map paths (ring attention over `context`, GPipe over `pipe`)
-    # need static per-shard shapes: every microbatch must divide the batch
-    # axes exactly.  Plain GSPMD paths tolerate uneven sharding, so only
-    # enforce where the cryptic shard_map divisibility error would hit.
-    if mesh.shape.get("context", 1) > 1 or mesh.shape.get("pipe", 1) > 1:
-        batch_par = mesh.shape.get("data", 1) * mesh.shape.get("fsdp", 1)
-        micro = workload.batch_size // max(1, grad_accum_steps)
-        if micro % max(1, batch_par):
-            raise ValueError(
-                f"microbatch {micro} (= batch {workload.batch_size} / "
-                f"grad_accum {grad_accum_steps}) does not divide the batch "
-                f"axes data*fsdp={batch_par}; raise --batch_size or lower "
-                "--grad_accum_steps"
+        def init_fn():
+            init_input = (
+                workload.init_batch if workload.init_key is None
+                else workload.init_batch[workload.init_key]
             )
-    raw_step = make_train_step(
-        _wrap_from_record(workload, workload.loss_fn, train=True),
-        grad_accum_steps=grad_accum_steps,
-        precision=precision,
-        clip_grad_norm=workload.clip_grad_norm,
-        jit=False,
-        stateful=workload.stateful,
-        # Async-loop contract: the step folds state.step into a constant
-        # base key on device, so the loop never splits keys host-side.
-        in_step_rng=True,
-        # Where the mesh has a `data` axis to defer over, each replica sums
-        # its own microbatches' gradients and the step reduces them once.
-        mesh=mesh,
-        state_shardings=state_shardings,
-        batch_rows=workload.batch_size,
-    )
-    default_tracer().add_instant(
-        "grad_reduce", cat="train",
-        args={"where": raw_step.grad_reduce,
-              "data": mesh.shape.get("data", 1), "accum": grad_accum_steps})
-    bsh = batch_sharding(mesh)
-    batch_shardings = {k: bsh for k in workload.init_batch}
-    train_step = carry_step_marks(raw_step, jax.jit(
-        raw_step,
-        in_shardings=(state_shardings, batch_shardings, NamedSharding(mesh, P())),
-        out_shardings=(state_shardings, None),
-        donate_argnums=(0,),
-    ))
-    return init, abstract_state, state_shardings, train_step, batch_shardings
+            variables = dict(workload.module.init(rng, init_input))
+            params = variables.pop("params")
+            return TrainState.create(
+                apply_fn=workload.module.apply, params=params, tx=tx,
+                model_state=variables,
+            )
+
+        with tracer.span("abstract_state", cat="startup"):
+            abstract_state = jax.eval_shape(init_fn)
+        # One rule table shards params AND optimizer moments: regex paths match
+        # both "params/.../kernel" and "opt_state/.../mu/.../kernel".
+        with tracer.span("shardings", cat="startup"):
+            state_shardings = workload.rules.shardings_for(mesh, abstract_state)
+        jitted_init = jax.jit(init_fn, out_shardings=state_shardings)
+
+        def init():
+            with tracer.span("state_init", cat="startup"):
+                return jitted_init()
+
+        # Lowerable from shapes alone, as the jitted function is.
+        init.lower = jitted_init.lower
+
+        # shard_map paths (ring attention over `context`, GPipe over `pipe`)
+        # need static per-shard shapes: every microbatch must divide the batch
+        # axes exactly.  Plain GSPMD paths tolerate uneven sharding, so only
+        # enforce where the cryptic shard_map divisibility error would hit.
+        if mesh.shape.get("context", 1) > 1 or mesh.shape.get("pipe", 1) > 1:
+            batch_par = mesh.shape.get("data", 1) * mesh.shape.get("fsdp", 1)
+            micro = workload.batch_size // max(1, grad_accum_steps)
+            if micro % max(1, batch_par):
+                raise ValueError(
+                    f"microbatch {micro} (= batch {workload.batch_size} / "
+                    f"grad_accum {grad_accum_steps}) does not divide the batch "
+                    f"axes data*fsdp={batch_par}; raise --batch_size or lower "
+                    "--grad_accum_steps"
+                )
+        with tracer.span("make_step", cat="startup"):
+            raw_step = make_train_step(
+                _wrap_from_record(workload, workload.loss_fn, train=True),
+                grad_accum_steps=grad_accum_steps,
+                precision=precision,
+                clip_grad_norm=workload.clip_grad_norm,
+                jit=False,
+                stateful=workload.stateful,
+                # Async-loop contract: the step folds state.step into a constant
+                # base key on device, so the loop never splits keys host-side.
+                in_step_rng=True,
+                # Where the mesh has a `data` axis to defer over, each replica sums
+                # its own microbatches' gradients and the step reduces them once.
+                mesh=mesh,
+                state_shardings=state_shardings,
+                batch_rows=workload.batch_size,
+            )
+        # Where the step sums gradients over ``data``: the build-time fact the
+        # span carries (``train_step.grad_reduce`` is the same word).
+        phase.set(grad_reduce=raw_step.grad_reduce,
+                  data=mesh.shape.get("data", 1), accum=grad_accum_steps)
+        bsh = batch_sharding(mesh)
+        batch_shardings = {k: bsh for k in workload.init_batch}
+        train_step = carry_step_marks(raw_step, jax.jit(
+            raw_step,
+            in_shardings=(state_shardings, batch_shardings, NamedSharding(mesh, P())),
+            out_shardings=(state_shardings, None),
+            donate_argnums=(0,),
+        ))
+        return init, abstract_state, state_shardings, train_step, batch_shardings
 
 
 def build_state_and_step(workload: Workload, mesh, **kwargs):
@@ -412,8 +424,6 @@ def run(args: TrainArgs) -> Dict[str, Any]:
     precision = BF16 if args.precision == "bf16" else FP32
 
     if args.trace_out:
-        # Before the step is built: ``build_step`` records how it reduces
-        # gradients (``dtt/train/grad_reduce``).
         default_tracer().enable()
     state, state_shardings, train_step, batch_shardings = build_state_and_step(
         workload,
@@ -487,11 +497,14 @@ def run(args: TrainArgs) -> Dict[str, Any]:
 
     # 5. Hooks.
     from distributed_tensorflow_tpu.obs import PrefetchMonitorHook
+    from distributed_tensorflow_tpu.obs.startup import StartupReportHook
 
     hooks = [
         LoggingHook(every_steps=args.log_every),
         NanHook(),
         PrefetchMonitorHook(data_iter, every_steps=max(args.log_every, 1)),
+        # One ``startup`` line when the first loss has landed.
+        StartupReportHook(),
     ]
     if jax.process_count() > 1:
         # Peer-liveness fail-fast (MWMS check-health equivalent, SURVEY

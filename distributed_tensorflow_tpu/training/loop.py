@@ -263,6 +263,11 @@ class TrainLoop:
         # (step, device metrics pytree) whose host copy is in flight.
         self._pending_metrics: Optional[tuple] = None
         self._stop = False
+        # ``dtt/startup/first_step``: from the dispatch of the first step
+        # this loop ever runs (its trace, lowering and compile or cache
+        # read fall inside) until the first loss is fetched.
+        self._first_step_at: Optional[float] = None
+        self._first_step_recorded = False
         # Lazy import: obs.__init__ pulls in the hook modules, which import
         # THIS module — importing obs.metrics at the top here would re-enter
         # the partially-initialized obs package whenever training.loop is
@@ -313,6 +318,11 @@ class TrainLoop:
         self._pending_metrics = None
         with self._tracer.span("metrics_fetch", cat="train"):
             host_tree = jax.device_get(tree)
+        if not self._first_step_recorded:
+            self._first_step_recorded = True
+            self._tracer.add_span(
+                "first_step", cat="startup", start=self._first_step_at,
+                end=time.perf_counter(), args={"metrics_step": step})
         host = {k: float(np.asarray(v)) for k, v in host_tree.items()}
         self._obs_flushes.inc()
         return step, host
@@ -373,6 +383,8 @@ class TrainLoop:
             self.last_step_metrics = None
             return completed_steps
         t0 = time.perf_counter()
+        if self._first_step_at is None:
+            self._first_step_at = t0
         rng = self._step_rng(fn)
         with self._tracer.span("dispatch", cat="train"):
             self.state, metrics = fn(self.state, batch, rng)

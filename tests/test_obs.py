@@ -329,6 +329,72 @@ class TestTracer:
         assert len(t) == 0
         assert 8 * per_span < 20e-6, f"{per_span * 1e9:.0f} ns a span"
 
+    @pytest.mark.parametrize("cat", ["startup", "compile"])
+    def test_two_categories_are_recorded_whatever_records(self, cat):
+        """Set-up's phases and the compiles land in the ring with the
+        tracer off and no profiler session open, by either way of emitting
+        a span; the loop's categories still do not."""
+        t = Tracer()
+        assert not t.recording
+        with t.span("phase", cat=cat, args={"model": "gpt2"}):
+            with t.span("iteration", cat="serve"):
+                pass
+            with t.span("step", cat="train"):
+                pass
+        t.add_span("backend", cat=cat, start=1.0, end=3.0)
+        t.add_span("queue_wait", cat="serve", start=1.0, end=3.0)
+        assert [s[0] for s in t.spans()] == [
+            f"dtt/{cat}/phase", f"dtt/{cat}/backend"]
+        assert t.spans(cat=cat)[0][4]["model"] == "gpt2"
+        assert t.spans(cat="serve") == [] and t.spans(cat="train") == []
+
+    def test_add_span_names_the_span_it_fell_in(self):
+        """A span recorded after the fact carries as ``parent`` the span
+        open on the calling thread (recorded or not), and none outside one
+        or from a thread that has none open."""
+        t = Tracer()
+        t.add_span("backend", cat="compile", start=0.0, end=1.0)
+        with t.span("iteration", cat="serve"):       # not recorded: off
+            with t.span("program_first_launch", cat="startup") as _:
+                t.add_span("backend", cat="compile", start=1.0, end=2.0,
+                           args={"program": "jit_step"})
+                other = threading.Thread(target=lambda: t.add_span(
+                    "backend", cat="compile", start=2.0, end=3.0))
+                other.start()
+                other.join(timeout=10)
+            t.add_span("lower", cat="compile", start=3.0, end=4.0)
+        outside, inside, elsewhere = t.spans(name="dtt/compile/backend")
+        (launch,) = t.spans(name="dtt/startup/program_first_launch")
+        assert "parent" not in outside[4] and "parent" not in elsewhere[4]
+        assert inside[4] == {"program": "jit_step",
+                             "parent": launch[4]["span_id"]}
+        # The unrecorded loop span's id: a child still says it had one.
+        (lower,) = t.spans(name="dtt/compile/lower")
+        assert lower[4]["parent"] == launch[4]["parent"]
+
+    def test_spanned_runs_each_call_inside_a_span(self, monkeypatch):
+        from distributed_tensorflow_tpu.obs import trace as obs_trace
+
+        t = Tracer()
+        monkeypatch.setattr(obs_trace, "_default_tracer", t)
+
+        class Engine:
+            @obs_trace.spanned("engine_init", "startup")
+            def __init__(self, width, *, depth=2):
+                """Doc kept."""
+                self.size = width * depth
+                with t.span("params_placed", cat="startup"):
+                    pass
+
+        assert Engine(3, depth=4).size == 12
+        assert Engine.__init__.__doc__ == "Doc kept."
+        child, parent = t.spans(cat="startup")
+        assert parent[0] == "dtt/startup/engine_init"
+        assert child[4]["parent"] == parent[4]["span_id"]
+        with pytest.raises(TypeError):
+            Engine()                                  # the span still closes
+        assert not t._local.stack
+
     def test_ring_buffer_bounds_memory(self):
         t = Tracer(capacity=4, enabled=True)
         for i in range(10):
@@ -371,6 +437,72 @@ CONTINUOUS_STATS = {
     "retirements_per_iter": 0.3, "ttft_p50_ms": 20.0, "ttft_p99_ms": 50.0,
     "tpot_mean_ms": 1.5, "p50_latency_ms": 30.0, "p99_latency_ms": 80.0,
 }
+
+
+class TestStartupReport:
+    """``obs/startup.py``: the ``startup`` line and its gauge, from spans
+    the ring holds."""
+
+    def _filled(self):
+        t = Tracer()
+        with t.span("build_step", cat="startup") as phase:
+            with t.span("abstract_state", cat="startup"):
+                t.add_span("trace", cat="compile", start=now() - 0.5,
+                           end=now(), args={"program": "jit_init_fn"})
+            phase.set(grad_reduce="none")
+        for kind in ("slot_prefill", "slot_prefill", "slot_megastep"):
+            with t.span("program_first_launch", cat="startup",
+                        args={"kind": kind}):
+                pass
+        t.add_span("backend", cat="compile", start=10.0, end=12.0,
+                   args={"program": "jit_step", "cache": "miss"})
+        t.add_span("backend", cat="compile", start=20.0, end=21.0,
+                   args={"program": "jit_step", "cache": "hit"})
+        t.add_span("lower", cat="compile", start=9.0, end=10.0,
+                   args={"program": "jit_step"})
+        return t
+
+    def test_summary_names_children_and_programs(self):
+        from distributed_tensorflow_tpu.obs import startup
+
+        said = startup.summary(self._filled())
+        assert set(said["phases"]) == {
+            "build_step", "build_step/abstract_state",
+            "program_first_launch[slot_prefill]",
+            "program_first_launch[slot_megastep]"}
+        assert said["programs"]["jit_step"] == {
+            "backend_s": pytest.approx(3.0), "lower_s": pytest.approx(1.0),
+            "cache": {"miss": 1, "hit": 1}}
+        assert said["programs"]["jit_init_fn"] == {
+            "trace_s": pytest.approx(0.5, abs=0.01)}
+        # The union: 9-12 and 20-21 for jit_step, and the half second of
+        # the trace, which the phases round its end do not add to.
+        assert said["covered_s"] == pytest.approx(4.5, abs=0.01)
+        assert startup.union_seconds(
+            [(0, 2), (1, 3), (5, 6), (5.5, 5.8)]) == pytest.approx(4.0)
+
+    def test_report_logs_one_line_and_sets_the_gauge(self, caplog):
+        from distributed_tensorflow_tpu.obs import startup
+
+        reg = Registry()
+        with caplog.at_level(logging.INFO,
+                             logger="distributed_tensorflow_tpu.obs.startup"):
+            said = startup.report(self._filled(), reg)
+        (line,) = [r.getMessage() for r in caplog.records
+                   if r.getMessage().startswith("startup ")]
+        assert json.loads(line[len("startup "):]) == json.loads(
+            json.dumps(said))
+        text = render_prometheus(reg)
+        assert 'dtt_startup_seconds{phase="build_step/abstract_state"}' in text
+        assert 'phase="program_first_launch[slot_prefill]"' in text
+
+    def test_hook_reports_once_when_the_first_loss_lands(self, monkeypatch):
+        from distributed_tensorflow_tpu.obs import startup
+
+        calls = []
+        monkeypatch.setattr(startup, "report", lambda: calls.append(1) or {})
+        run_loop([startup.StartupReportHook()], steps=8)
+        assert calls == [1]
 
 
 class TestHookLogCompat:

@@ -228,7 +228,6 @@ class TestEngineSmoke:
         finally:
             sched.close()
             rec.close()
-            gpt2_engine.set_lifecycle(None)
         assert report["completed"] == 6 and report["shed"] == 0
         assert report["tokens_emitted"] == 6 * 4
         lc = report["lifecycle"]

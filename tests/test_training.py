@@ -515,25 +515,21 @@ class TestDeferredGradReduce:
         ({"data": 1}, 4, "none"),
     ], ids=["cell4-mesh", "cell1-mesh"])
     def test_build_step_records_the_choice(self, devices8, axes, accum, word):
-        """One ``dtt/train/grad_reduce`` instant, in the repo's tracer."""
+        """One ``dtt/startup/build_step`` span, in the repo's tracer, whose
+        arguments say where the step sums gradients; recorded with the
+        tracer off (the category always is)."""
         from distributed_tensorflow_tpu.obs.trace import default_tracer
 
         tracer = default_tracer()
-        was = tracer.enabled
-        tracer.enable()
-        try:
-            before = len(tracer.events())
-            _, step, _ = _tiny_step(axes, devices8, site="after_scan",
-                                         accum=accum)
-            marks = [e for e in tracer.events()[before:]
-                     if e["name"] == "grad_reduce"]
-        finally:
-            tracer.enabled = was
+        assert not tracer.enabled
+        before = len(tracer.spans(name="dtt/startup/build_step"))
+        _, step, _ = _tiny_step(axes, devices8, site="after_scan",
+                                accum=accum)
         assert step.grad_reduce == word
-        (mark,) = marks
-        assert mark["ph"] == "i" and mark["cat"] == "train"
-        assert mark["args"] == {"where": word, "data": axes["data"],
-                                "accum": accum}
+        (mark,) = tracer.spans(name="dtt/startup/build_step")[before:]
+        args = mark[4]
+        assert {k: args[k] for k in ("grad_reduce", "data", "accum")} == {
+            "grad_reduce": word, "data": axes["data"], "accum": accum}
 
 
 class TestTrainLib:

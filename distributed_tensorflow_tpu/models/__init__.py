@@ -83,6 +83,12 @@ class Workload:
     # Scheduler features the family cannot serve yet -> the reason; the
     # scheduler refuses each at construction instead of falling back.
     serve_refusals: Dict[str, str] = dataclasses.field(default_factory=dict)
+    # ``served_dtypes(params) -> tree of dtypes``, leaf for leaf over a
+    # parameter tree (arrays or shapes): the compute type for a leaf that
+    # every served program reads only through a cast to it, the leaf's own
+    # for the rest.  The engine holds its weights so (``ServeEngine``,
+    # "The served weights").  None: every leaf as the checkpoint has it.
+    served_dtypes: Optional[Callable[[PyTree], PyTree]] = None
 
 
 @dataclasses.dataclass(frozen=True)

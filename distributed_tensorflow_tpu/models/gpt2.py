@@ -43,7 +43,7 @@ from distributed_tensorflow_tpu.models import (  # noqa: F401
 )
 from distributed_tensorflow_tpu.ops.flash_attention import REMAT_POLICY
 from distributed_tensorflow_tpu.parallel.sharding import (
-    P, ShardingRules,
+    P, ShardingRules, _path_str,
     transformer_rules,
 )
 
@@ -507,37 +507,28 @@ class GPT2(nn.Module):
                     "make_workload does this automatically"
                 )
             x = self._pipelined_blocks(x)
+        elif cfg.scan_layers and paged is not None and (
+                not self.is_initializing()):
+            x = self._paged_blocks(x, slot_ids, block_tables, live, paged)
         elif cfg.scan_layers:
             # No remat in decode: there is no backward pass, and remat's
-            # lifted scope rejects the mutable cache writes.
+            # lifted scope rejects the mutable cache writes.  (The paged
+            # serve path has its own loop above; its init, which only
+            # fixes the shapes, stacks per-layer variables here.)
             body = Block if decode or not cfg.remat else nn.remat(
                 Block, prevent_cse=False, policy=REMAT_POLICY)
-            # Paged decode CARRIES the cache through the layer loop: each
-            # layer updates the stacked (L, ...) pools in place at its own
-            # index.  Scanned over axis 0 they would be sliced per layer
-            # and re-stacked, a copy of every pool each token step.  Init
-            # (which only fixes the shapes) stacks per-layer variables.
-            if paged is not None and not self.is_initializing():
-                cache_vars = dict(
-                    variable_axes={"params": 0}, variable_carry="cache",
-                    in_axes=(nn.broadcast, nn.broadcast, nn.broadcast, 0))
-                more = (live, jnp.arange(cfg.n_layer, dtype=jnp.int32))
-            else:
-                cache_vars = dict(
-                    variable_axes={"params": 0, "cache": 0},
-                    in_axes=nn.broadcast)
-                more = ()
             Scanned = nn.scan(
                 body,
+                variable_axes={"params": 0, "cache": 0},
                 split_rngs={"params": True, "dropout": True},
+                in_axes=nn.broadcast,  # slot_ids/tables: every layer's
                 length=cfg.n_layer,
                 unroll=cfg.scan_unroll,
-                **cache_vars,  # slot_ids/tables are shared by every layer
             )
             x, _ = Scanned(
                 cfg, mesh=self.mesh, deterministic=deterministic,
                 decode=decode, paged=paged, name="blocks",
-            )(x, slot_ids, block_tables, *more)
+            )(x, slot_ids, block_tables)
         else:
             for i in range(cfg.n_layer):
                 x, _ = Block(
@@ -559,6 +550,41 @@ class GPT2(nn.Module):
             preferred_element_type=jnp.float32,
         )
         return logits
+
+    def _paged_blocks(self, x, slot_ids, block_tables, live, paged):
+        """The scanned stack over the paged cache (the served path): a loop
+        of its own, for two things ``nn.scan`` over axis 0 would copy.
+
+        The cache is CARRIED through the layer loop: each layer updates the
+        stacked (L, ...) pools in place at its own index.  Scanned over
+        axis 0 they would be sliced per layer and re-stacked, a copy of
+        every pool each token step.  And a layer's leaves are taken from
+        the (L, ...) parameter stack by that index, where a product reads
+        them: scanned over axis 0 with the stack's unroll, four layers'
+        kernels are sliced out together, a copy of all the weights each
+        pass (into fast memory while four layers fit there, through HBM
+        where they do not) before any product reads them."""
+        cfg = self.cfg
+        block = Block(cfg, mesh=self.mesh, deterministic=True, decode=True,
+                      paged=paged)
+        stack = self.scope.get_variable("params", "blocks")
+
+        def body(carry, layer):
+            h, cache = carry
+            leaves = jax.tree.map(
+                lambda w: lax.dynamic_index_in_dim(w, layer, keepdims=False),
+                stack)
+            (h, _), mutated = block.apply(
+                {"params": leaves, "cache": cache}, h, slot_ids,
+                block_tables, live, layer, mutable=["cache"])
+            return (h, mutated["cache"]), None
+
+        (x, cache), _ = lax.scan(
+            body, (x, self.scope.get_variable("cache", "blocks")),
+            jnp.arange(cfg.n_layer, dtype=jnp.int32),
+            unroll=cfg.scan_unroll)
+        self.scope.put_variable("cache", "blocks", cache)
+        return x
 
     def _pipelined_blocks(self, x):
         """Apply the scanned block stack through the GPipe schedule.
@@ -1033,6 +1059,7 @@ def make_workload(
         init_key="tokens",
         cache_rules=gpt2_cache_rules,
         cache_geometry=functools.partial(_cache_geometry, cfg),
+        served_dtypes=functools.partial(_served_dtypes, cfg),
     )
 
 
@@ -1050,3 +1077,27 @@ def _cache_geometry(cfg: GPT2Config, paged: PagedKVConfig) -> Dict[str, Any]:
         "pool_bytes": (cfg.n_layer * 2 * paged.num_blocks * paged.block_size
                        * cfg.d_model * itemsize),
     }
+
+
+# The leaves every served program reads only through a cast to
+# ``cfg.dtype``: a ``Dense`` promotes its kernel and bias to its ``dtype``
+# at each use, and the embedding, the positions and the tied head read
+# ``wte`` and ``wpe`` through ``.astype(cfg.dtype)``.  Not the layer norms'
+# scale and bias (``ln_1``, ``ln_2``, ``ln_f``), which are read in float32.
+_CAST_AT_USE = ("wte", "wpe")
+_DENSE = ("c_attn", "c_proj", "mlp_c_fc", "mlp_c_proj")
+
+
+def _served_dtypes(cfg: GPT2Config, params) -> Any:
+    """The type a server holds each parameter in
+    (``Workload.served_dtypes``): rounding such a leaf once, where the
+    weights enter the engine, gives the bits that rounding it in every
+    launch gave."""
+
+    def one(path, leaf):
+        *_, module, name = [None] + _path_str(path).split("/")
+        cast = name in _CAST_AT_USE or (
+            module in _DENSE and name in ("kernel", "bias"))
+        return jnp.dtype(cfg.dtype) if cast else leaf.dtype
+
+    return jax.tree_util.tree_map_with_path(one, params)

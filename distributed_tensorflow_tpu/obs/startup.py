@@ -9,7 +9,8 @@ trace, lowering and compile or cache read).  This module holds no span; it
 reads them and reports:
 
 - :func:`summary` (:func:`summarize` over the ring): every phase's seconds
-  (a child under its parent's name, ``build_step/abstract_state``), every
+  (a child under its parent's name, ``build_step/abstract_state``) and what
+  it said of itself (``params_placed/params_cast``: ``leaves_cast``), every
   program's three stages with how often the persistent cache said hit,
   miss or off, and the seconds all of it covers (the union: nested spans
   are not counted twice);
@@ -36,6 +37,9 @@ from distributed_tensorflow_tpu.training.loop import Hook
 logger = logging.getLogger(__name__)
 
 _PREFIX = "dtt/startup/"
+# Arguments that place a span among the others; the rest are what the
+# phase says of itself (``grad_reduce``, ``restored``, ``leaves_cast``).
+_STRUCTURAL = ("span_id", "parent", "kind")
 
 
 def union_seconds(intervals: Iterable[Tuple[float, float]]) -> float:
@@ -51,6 +55,7 @@ def union_seconds(intervals: Iterable[Tuple[float, float]]) -> float:
 def summarize(phases: List[Span], compiles: List[Span]) -> Dict[str, Any]:
     """``startup`` and ``compile`` spans (as ``Tracer.spans`` gives them)
     reduced to ``phases`` (seconds by name, a child under its parent's
+    name), ``phase_args`` (what a phase said of itself, under the same
     name), ``programs`` (seconds by stage and the cache's outcomes) and
     ``covered_s`` (the union of them all)."""
     by_id = {args["span_id"]: (name, args) for name, _, _, _, args in phases
@@ -64,9 +69,13 @@ def summarize(phases: List[Span], compiles: List[Span]) -> Dict[str, Any]:
         return f"{path(*parent)}/{short}" if parent else short
 
     seconds: Dict[str, float] = {}
+    said: Dict[str, Dict[str, Any]] = {}
     for name, start, end, _tid, args in phases:
         key = path(name, args)
         seconds[key] = seconds.get(key, 0.0) + (end - start)
+        own = {k: v for k, v in args.items() if k not in _STRUCTURAL}
+        if own:
+            said.setdefault(key, {}).update(own)
     programs: Dict[str, Dict[str, Any]] = {}
     for name, start, end, _tid, args in compiles:
         row = programs.setdefault(args.get("program", "unknown"), {})
@@ -77,6 +86,7 @@ def summarize(phases: List[Span], compiles: List[Span]) -> Dict[str, Any]:
             cache[args["cache"]] = cache.get(args["cache"], 0) + 1
     return {
         "phases": seconds,
+        "phase_args": said,
         "programs": programs,
         "covered_s": union_seconds(
             (start, end) for _, start, end, _, _ in phases + compiles),

@@ -39,7 +39,7 @@ from distributed_tensorflow_tpu import cluster as cluster_lib
 from distributed_tensorflow_tpu.checkpoint import CheckpointManager
 from distributed_tensorflow_tpu.models import Workload, get_workload
 from distributed_tensorflow_tpu.obs import metrics as obs_metrics
-from distributed_tensorflow_tpu.obs.trace import default_tracer, spanned
+from distributed_tensorflow_tpu.obs.trace import default_tracer, now, spanned
 from distributed_tensorflow_tpu.ops import paged_attention
 from distributed_tensorflow_tpu.parallel.sharding import (
     apply_shardings,
@@ -121,6 +121,11 @@ def _engine_instruments(registry=None):
             "block-table kernel (the grouped-query family's by kind of "
             "layer), or the whole-row gather",
             labelnames=("path",)),
+        "params_bytes": r.gauge(
+            "dtt_serve_params_bytes",
+            "Bytes of the served parameter tree by leaf type, as the engine "
+            "last placed or installed it",
+            labelnames=("dtype",)),
     }
 
 
@@ -273,6 +278,20 @@ class ServeEngine:
 
     ``checkpoint_dir=None`` (or an empty directory) falls back to fresh
     random init — the smoke/benchmark path when no training run preceded.
+
+    THE SERVED WEIGHTS.  ``self.params`` is the one parameter tree: what
+    every program takes and what the scheduler's generations pin.  It has
+    the checkpoint's paths and shapes, and each leaf in the type the
+    programs read it in: a leaf the family says every served program reads
+    only through a cast to the compute type (``Workload.served_dtypes``;
+    GPT-2's ``Dense`` kernels and biases, ``wte``, ``wpe``) is rounded to
+    that type once, where weights enter the engine (the fresh draw or the
+    restore here, ``shard_params``, ``install_params``), which gives the
+    bits that rounding it in every launch gave and saves every launch the
+    pass over all the layers.  Everything else (the layer norms, a family
+    that names no leaf, a leaf already in the compute type, a float32
+    compute type) stays as it came.  No float32 twin is kept on the device;
+    a checkpoint stays float32 on disk and in the trainer.
     """
 
     @spanned("engine_init", "startup")
@@ -327,15 +346,20 @@ class ServeEngine:
         # the compiled step, never split on the host per token).
         self._sample_rng = jax.random.fold_in(jax.random.key(seed), 0x53)
 
-        def init_fn():
+        def draw():
             init_input = (
                 self.workload.init_batch if self.workload.init_key is None
                 else self.workload.init_batch[self.workload.init_key]
             )
             return dict(self.module.init(jax.random.key(seed), init_input))
 
+        def init_fn():
+            variables = draw()
+            variables["params"] = self._in_served_types(variables["params"])
+            return variables
+
         with self._tracer.span("abstract_params", cat="startup"):
-            abstract = jax.eval_shape(init_fn)
+            abstract = jax.eval_shape(draw)
         with self._tracer.span("shardings", cat="startup"):
             shardings = self.workload.rules.shardings_for(self.mesh, abstract)
         restored = None
@@ -360,10 +384,17 @@ class ServeEngine:
         with self._tracer.span("params_placed", cat="startup",
                                args={"restored": restored is not None}):
             if restored is not None:
+                restored["params"] = self._served(
+                    restored["params"], always=True)
                 variables = apply_shardings(restored, shardings)
             else:
+                # The cast is the init program's own output types: no
+                # program and no time of its own, so the span is a count.
                 variables = jax.jit(init_fn, out_shardings=shardings)()
+                self._note_cast(abstract["params"], variables["params"],
+                                now(), always=True)
         self.params = variables.pop("params")
+        self._note_params_bytes()
         self.model_state = variables  # e.g. {"batch_stats": ...} for resnet
         self._predict_fn = jax.jit(_named("predict", self._predict_apply))
 
@@ -1660,25 +1691,84 @@ class ServeEngine:
         return [int(np.argmax(logits[i], axis=-1))
                 for i in range(len(examples))]
 
-    # -- hot weight reload ----------------------------------------------------
+    # -- the served weights (placing, hot reload) ------------------------------
+
+    def _in_served_types(self, params: PyTree) -> PyTree:
+        """``params`` with each leaf in the type the programs read it in
+        (class docstring, "The served weights").  A leaf is rounded where
+        it lies: a host array on the host, a device array on the device,
+        a traced one in the program being traced."""
+        typed = self.workload.served_dtypes
+        if typed is None:
+            return params
+        return jax.tree.map(
+            lambda leaf, dtype: (
+                leaf if leaf.dtype == dtype else leaf.astype(dtype)),
+            params, typed(params))
+
+    def _note_cast(self, before: PyTree, after: PyTree, start: float,
+                   always: bool = False) -> None:
+        """Puts a cast on record as ``dtt/startup/params_cast`` (args
+        ``leaves_cast``, ``bytes_before``, ``bytes_after``; a child of the
+        span open on this thread): when a leaf was cast, and from the
+        constructor (``always``) when none was, which is the reading that
+        says a family bypasses the mechanism."""
+        pairs = list(zip(jax.tree.leaves(before), jax.tree.leaves(after)))
+        cast = sum(b.dtype != a.dtype for b, a in pairs)
+        if cast or always:
+            nbytes = lambda leaf: leaf.size * leaf.dtype.itemsize
+            self._tracer.add_span(
+                "params_cast", start=start, end=now(), cat="startup",
+                args={"leaves_cast": cast,
+                      "bytes_before": sum(nbytes(b) for b, _ in pairs),
+                      "bytes_after": sum(nbytes(a) for _, a in pairs)})
+
+    def _served(self, params: PyTree, always: bool = False) -> PyTree:
+        """A host or device tree in the served types, the cast on record."""
+        start = now()
+        served = self._in_served_types(params)
+        self._note_cast(params, served, start, always)
+        return served
+
+    def _note_params_bytes(self) -> None:
+        held: Dict[str, int] = {}
+        for leaf in jax.tree.leaves(self.params):
+            held[leaf.dtype.name] = held.get(leaf.dtype.name, 0) + leaf.nbytes
+        gauge = self._obs["params_bytes"]
+        for (name,), child in gauge.samples():
+            child.set(float(held.pop(name, 0)))
+        for name, nbytes in held.items():
+            gauge.labels(dtype=name).set(float(nbytes))
+
+    def params_bytes(self) -> Dict[str, float]:
+        """Bytes of the served tree by leaf type (``compile_stats``'s
+        sibling: it reads the process-wide gauge
+        ``dtt_serve_params_bytes{dtype}`` and takes no lock)."""
+        return {name: child.value
+                for (name,), child in self._obs["params_bytes"].samples()}
 
     def shard_params(self, params: PyTree) -> PyTree:
-        """Device-put a HOST params tree through the workload's sharding
-        rules — the fleet checkpoint watcher's reload path.  The result has
-        the same avals/shardings as ``self.params``, so passing it as the
+        """Device-put a HOST params tree (a checkpoint's float32 or the
+        served types) through the workload's sharding rules — the fleet
+        checkpoint watcher's reload path.  The result has the same
+        avals/shardings as ``self.params``, so passing it as the
         ``params=`` override of the slot programs never recompiles."""
+        params = self._served(params)   # rounded on the host: no launch
         shardings = self.workload.rules.shardings_for(
             self.mesh, {"params": params})
         with _launch_lock:
             return apply_shardings({"params": params}, shardings)["params"]
 
     def install_params(self, params: PyTree) -> None:
-        """Swap the live weights (hot reload).  The assignment runs under
-        the launch lock, so every launch path that reads ``self.params``
-        inside the lock sees either the old or the new tree — never a
-        swap interleaved with a dispatch."""
+        """Swap the live weights (hot reload): a tree in the served types
+        (``shard_params``'s, a draw made over ``self.params``'s avals) or in
+        the checkpoint's, which is cast here and not kept.  The cast and
+        the assignment run under the launch lock, so every launch path
+        that reads ``self.params`` inside the lock sees either the old or
+        the new tree — never a swap interleaved with a dispatch."""
         with _launch_lock:
-            self.params = params
+            self.params = self._served(params)
+            self._note_params_bytes()
 
     # -- lifecycle -----------------------------------------------------------
 

@@ -243,19 +243,27 @@ V5E_HBM_BYTES = 15.75e9
 
 
 def lower_serve_program(topo, program, slots, module=None,
-                        prompt=SERVE_PROMPT):
+                        prompt=SERVE_PROMPT, served=True):
     """The engine's own ``decode_megastep`` or ``prefill_slots`` program for
     GPT-2 medium (or ``module``), lowered from shapes alone.
     ``ServeEngine()`` places real weights, which a described device cannot
     hold, so the two ``_apply`` methods run on a bare instance that has
-    only the module they read."""
+    only the module they read.  The parameters are given in the types the
+    engine holds them in (GPT-2's by the family's ``served_dtypes``; the
+    other families' as declared); ``served=False`` gives the checkpoint's
+    float32, what every program took up to PR 38."""
+    from distributed_tensorflow_tpu.models import get_workload
     from distributed_tensorflow_tpu.models.gpt2 import (
         GPT2, GPT2Config, PagedKVConfig)
     from distributed_tensorflow_tpu.serve import sampling as sampling_lib
     from distributed_tensorflow_tpu.serve import engine as engine_lib
     from distributed_tensorflow_tpu.serve.engine import ServeEngine
 
-    module = module or GPT2(GPT2Config.medium(dropout=0.0))
+    typed = None
+    if module is None:
+        module = GPT2(GPT2Config.medium(dropout=0.0))
+        if served:
+            typed = get_workload("gpt2", config=module.cfg).served_dtypes
     engine = object.__new__(ServeEngine)
     engine.module = module
     max_blocks = SERVE_TOTAL_LEN // SERVE_BLOCK
@@ -269,9 +277,12 @@ def lower_serve_program(topo, program, slots, module=None,
         jax.random.key(0), jnp.zeros((slots, SERVE_TOTAL_LEN), jnp.int32),
         decode=True, slot_ids=jnp.arange(slots, dtype=jnp.int32),
         paged=paged, block_tables=jnp.zeros((slots, max_blocks), jnp.int32)))
+    declared = jax.tree.map(lambda s: s.dtype, variables["params"])
     params, cache = jax.tree.map(
-        lambda s: arg(s.shape, s.dtype),
-        (variables["params"], variables["cache"]))
+        lambda s, dtype: arg(s.shape, dtype),
+        (variables["params"], variables["cache"]),
+        (typed(variables["params"]) if typed else declared,
+         jax.tree.map(lambda s: s.dtype, variables["cache"])))
     counts = arg((slots, module.cfg.vocab_size))
     tables = arg((slots, max_blocks))
     rng = arg((), jax.random.key(0).dtype)
@@ -400,6 +411,43 @@ def test_serve_decode_program_fits_one_chip_at_64_slots(topo):
     memory = lowered.compile().memory_analysis()
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < V5E_HBM_BYTES
+
+
+# A GPT-2 medium layer's four kernels, as the HLO's shapes spell them.
+LAYER_KERNELS = "1024,3072|1024,1024|1024,4096|4096,1024"
+
+
+@pytest.mark.parametrize("program", ["decode_megastep", "prefill_slots"])
+def test_serve_programs_read_the_weights_once_in_the_compute_type(topo,
+                                                                  program):
+    """Up to PR 38 every launch converted all 24 layers' float32 kernels
+    and the embedding to bfloat16 (1.41 GB read, 0.71 GB written, 0.78 GB
+    of the decode program's scratch) and then took four layers' kernels at
+    a time out of the converted stack.  With the arguments as the engine
+    holds them, at the cell's 64 slots: no float32 value of a weight's
+    shape is left in the program and nothing converts to one; **no
+    four-layer slice of the kernels exists, in any memory space** (while
+    the stack's scan sliced them, the decode program staged them in fast
+    memory, ``S(1)``, and the prefill program, whose fast memory the
+    prefetched embedding fills, copied them in HBM: 0.6 GB a launch); each
+    layer's kernel is sliced by the layer's index inside the fusion of the
+    product that reads it; and the scratch is what the activations need."""
+    lowered, _ = lower_serve_program(topo, program, slots=64)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert not re.search(
+        rf"f32\[(?:\d+,)*(?:{LAYER_KERNELS})\]|f32\[50257,1024\]", hlo)
+    assert not re.search(
+        rf"= bf16\[(?:\d+,)*(?:{LAYER_KERNELS}|50257,1024)\]\S* convert\(",
+        hlo)
+    slabs = re.findall(rf"= (bf16\[4,(?:{LAYER_KERNELS})\]\S*) ", hlo)
+    assert slabs == [], f"four layers' kernels sliced out together: {slabs}"
+    for kernel in LAYER_KERNELS.split("|"):
+        assert re.search(
+            rf"= bf16\[1,{kernel}\]\S* dynamic-slice\(", hlo), kernel
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 7.2e9    # 7.88e9 in float32
+    assert memory.temp_size_in_bytes < 0.1e9        # 0.78e9 and 0.61e9
 
 
 # -- the latent-attention, sparse-expert family at its cell's shapes ----------

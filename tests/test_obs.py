@@ -481,6 +481,21 @@ class TestStartupReport:
         assert startup.union_seconds(
             [(0, 2), (1, 3), (5, 6), (5.5, 5.8)]) == pytest.approx(4.0)
 
+    def test_summary_keeps_what_a_phase_said_of_itself(self):
+        from distributed_tensorflow_tpu.obs import startup
+
+        t = self._filled()
+        with t.span("engine_init", cat="startup"):
+            with t.span("params_placed", cat="startup",
+                        args={"restored": False}):
+                t.add_span("params_cast", cat="startup", start=now(),
+                           end=now(), args={"leaves_cast": 10})
+        # Not ``kind`` (it is in the phase's name), ``span_id``, ``parent``.
+        assert startup.summary(t)["phase_args"] == {
+            "build_step": {"grad_reduce": "none"},
+            "engine_init/params_placed": {"restored": False},
+            "engine_init/params_placed/params_cast": {"leaves_cast": 10}}
+
     def test_report_logs_one_line_and_sets_the_gauge(self, caplog):
         from distributed_tensorflow_tpu.obs import startup
 

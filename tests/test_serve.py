@@ -427,8 +427,11 @@ class TestServeEngine:
         assert all(0 <= p < 10 for p in preds)
 
     def test_restore_roundtrip(self, mesh_dp, tmp_path):
-        """Train-side save -> serve-side restore_params -> identical params
-        and a working generate — the checkpoint_dir acceptance path."""
+        """Train-side save -> serve-side restore_params -> the saved
+        params, each in the type the server holds it in (the float32 leaves
+        the programs cast at every use rounded once, the layer norms as
+        saved; tests/test_serve_weights.py), and a working generate — the
+        checkpoint_dir acceptance path."""
         from distributed_tensorflow_tpu.checkpoint import CheckpointManager
         from distributed_tensorflow_tpu.models import get_workload
         from distributed_tensorflow_tpu.train_lib import build_state_and_step
@@ -443,10 +446,13 @@ class TestServeEngine:
         with ServeEngine("gpt2", mesh=mesh_dp, checkpoint_dir=ckdir,
                          preset="tiny") as eng:
             assert eng.restored_step == 0
+            served = jax.device_get(eng.params)
             jax.tree.map(
                 lambda a, b: np.testing.assert_array_equal(
-                    np.asarray(a), np.asarray(b)),
-                jax.device_get(eng.params), saved_params)
+                    np.asarray(a), np.asarray(b).astype(a.dtype)),
+                served, saved_params)
+            assert served["wte"].dtype == jnp.bfloat16
+            assert served["ln_f"]["scale"].dtype == np.float32
             out = eng.generate(np.zeros((8, 4), np.int32), 2)
         assert out.shape == (8, 2)
 

@@ -287,6 +287,7 @@ _REGISTRY = {
     "gpt2": "distributed_tensorflow_tpu.models.gpt2",
     "glm4_moe_lite": "distributed_tensorflow_tpu.models.glm4_moe_lite",
     "mellum": "distributed_tensorflow_tpu.models.mellum",
+    "glm_moe_dsa": "distributed_tensorflow_tpu.models.glm_moe_dsa",
     "wide_deep": "distributed_tensorflow_tpu.models.wide_deep",
 }
 

@@ -356,16 +356,24 @@ def gated_mlp(p, x, dtype):
                 p["down"]["kernel"])
 
 
-def mla_project(cfg, p, xn, positions):
+def mla_query_latent(cfg, p, xn):
+    """The query's normalized low-rank latent ``c_q``, in the compute type
+    (a learned indexer projects its own queries from it)."""
+    return rms_norm(_dot("btd,dr->btr", xn, p["q_a"]["kernel"]),
+                    p["q_a_norm"]["scale"], cfg.rms_norm_eps).astype(cfg.dtype)
+
+
+def mla_project(cfg, p, xn, positions, cq=None):
     """``xn`` (the normalized input, in the compute type) -> the query's
     two parts, and what is cached of the keys and values: the normalized
     latent and the rotary key.  Norms and rotations are taken in float32 on
     the products' float32 results; each output is rounded once, to the
-    compute type."""
+    compute type.  ``cq`` is ``mla_query_latent``'s result where the caller
+    has it already."""
     B, T, _ = xn.shape
     dt = cfg.dtype
-    cq = rms_norm(_dot("btd,dr->btr", xn, p["q_a"]["kernel"]),
-                  p["q_a_norm"]["scale"], cfg.rms_norm_eps).astype(dt)
+    if cq is None:
+        cq = mla_query_latent(cfg, p, xn)
     q = _dot("btr,rf->btf", cq, p["q_b"]["kernel"]).reshape(
         B, T, cfg.num_attention_heads, cfg.qk_head_dim)
     q_n, q_r = q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]

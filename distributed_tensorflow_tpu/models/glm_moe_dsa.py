@@ -1,0 +1,699 @@
+"""A latent-attention, sparse-expert decoder whose attention reads only the
+positions a learned indexer selects (``model_type`` ``glm_moe_dsa``), for the
+serving path.
+
+The fourth decoder family.  RMS norm, rotate-half rotary positions, the
+parameters' declaration, the latent projections (``mla_project``), the
+absorbed and the expanded latent attention (``mla_attend``), the float32
+sigmoid router, the expert layer that is told which experts it holds, the
+replicated cache's rules and the loss are ``glm4_moe_lite``'s, imported;
+what is this family's own:
+
+- **The indexer** (a ``full`` layer).  From the query's low-rank latent
+  ``c_q``: ``q^I = c_q W^I_q``, ``index_n_heads`` heads of
+  ``index_head_dim``, the first ``qk_rope_head_dim`` of each rotated; from
+  the normalized input ``xn``: one key a position ``k^I = layer_norm(xn
+  W^I_k)`` (rotated the same way) and head weights ``w = xn W^I_w *
+  index_n_heads^-1/2 * index_head_dim^-1/2``.  A position's score for a
+  query is ``I[t, s] = sum_h w[t, h] relu(q^I[t, h] . k^I[s])``, float32,
+  and the query reads ``S_t``: the ``min(t + 1, index_topk)`` positions ``s
+  <= t`` of largest score, the lower position first on a tie.
+- **Shared indices.**  ``indexer_types[l]`` is ``full`` or ``shared``; a
+  ``shared`` layer has no indexer and no index keys and reads the set of
+  the nearest ``full`` layer before it, handed down the layer loop.
+- **Attention over the selection alone**: softmax over ``s in S_t``.  Up to
+  ``index_topk`` positions of context that is plain causal attention.
+- **Two paged pools under one block table.**  The latent pool ``(layers,
+  num_blocks, block_size, pool_width)`` as ``glm4_moe_lite`` keeps it, and
+  beside it the index keys' ``(full layers, num_blocks, block_size,
+  index_head_dim)``: physical block ``b`` of the one is block ``b`` of the
+  other, so the scheduler's allocator, its tables and its trash block serve
+  both and a block is taken, freed and counted once.
+
+A cached call runs one of two ways, on record like the other families'
+(``ops.paged_attention.note_path``):
+
+- ``SELECTED`` (a decode step, one query a row): a ``full`` layer scores the
+  row's cached index keys chunk by chunk through the block table, as far as
+  the longest live row reaches and no further, takes the top ``index_topk``
+  (``select_top``) and turns them into pool cells through the table once;
+  every layer down to the next ``full`` one gathers those cells' latent
+  rows, ``index_topk`` a row and layer at most, and attends absorbed.  No
+  table row is gathered whole.
+- ``MASKED`` (a prefill chunk): a ``full`` layer scores every query of the
+  chunk against the context's index keys and marks each query's selection
+  in a mask ``(B, T, context)`` (the ``index_topk``-th largest score found
+  by bisection on the scores' bits: a mask needs no sort); every layer
+  attends expanded, context chunk by context chunk under an online softmax,
+  as far as the call's last position reaches.  A per-query gather of
+  ``index_topk`` latent rows would move ``T`` times the bytes of the
+  context it selects from; the MXU does the unselected pairs for less.
+
+An uncached call (``decode=False``: the tests' full forward, a loss) takes
+the same scores and the same mask over its own positions.
+
+``mlp_layer_types[l]`` is ``dense`` or ``sparse``.  The layers are four
+kinds (``dense_full``, ``sparse_shared``...) in no fixed period, so each is
+a group of leaves of its own (``layer_0``...) and the layer loop is written
+out, not scanned: a chip of the deployment holds one pipeline stage's ten
+or so layers, never the 78.
+
+Precision as ``glm4_moe_lite``; index keys are cached in the compute type
+and index scores are float32 sums of bfloat16 products.  Not built: the
+multi-token-prediction layer (the main logits do not depend on it), the
+published indexer's float8 keys and its Hadamard rotation (an orthogonal
+map on both sides leaves ``q^I . k^I`` as it is).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from flax import linen as nn
+from jax import lax
+from jax.sharding import Mesh
+
+from distributed_tensorflow_tpu.data.pipeline import synthetic_lm
+from distributed_tensorflow_tpu.models import PagedKVConfig, Workload
+from distributed_tensorflow_tpu.models.glm4_moe_lite import (
+    COUNT_EXTRA, _attention_spec, _declare, _dot, _loss_fn, _mlp_spec,
+    cache_rules, expert_layer, gated_mlp, mla_attend, mla_project,
+    mla_query_latent, rms_norm, rope)
+from distributed_tensorflow_tpu.ops import paged_attention
+from distributed_tensorflow_tpu.parallel.sharding import ShardingRules
+
+# The cached attention's two implementations, as ``attention_paths()`` names
+# them.
+SELECTED, MASKED = "latent_sparse_selected", "latent_sparse_masked"
+
+FULL, SHARED = "full", "shared"
+DENSE, SPARSE = "dense", "sparse"
+
+# Context positions a step of the chunked walks takes (whole blocks).
+CONTEXT_CHUNK = 1024
+_MASKED = -1e30  # finite: exp(_MASKED - m) is exactly 0, no inf - inf
+
+
+@dataclasses.dataclass(frozen=True)
+class GlmMoeDsaConfig:
+    """Published keys of ``config.json`` under their own names, plus the
+    share of the expert layer this device holds."""
+
+    vocab_size: int = 154880
+    hidden_size: int = 6144
+    intermediate_size: int = 12288          # a dense layer's MLP
+    moe_intermediate_size: int = 2048       # one expert, routed or shared
+    num_hidden_layers: int = 78
+    first_k_dense_replace: int = 3
+    num_attention_heads: int = 64
+    q_lora_rank: int = 2048
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    index_n_heads: int = 32
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    # None: ``full`` on the leading dense layers and then on every fourth
+    # layer (``shared, shared, shared, full``), as published.
+    indexer_types: Optional[Tuple[str, ...]] = None
+    # None: ``dense`` on the first ``first_k_dense_replace`` layers.
+    mlp_layer_types: Optional[Tuple[str, ...]] = None
+    n_routed_experts: int = 256             # the router's width, as published
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-5
+    index_norm_eps: float = 1e-6            # the index key's layer norm
+    rope_theta: float = 8e6
+    max_position_embeddings: int = 1048576
+    # This device's share: ``experts_held`` consecutive experts starting at
+    # ``first_expert``.  None holds them all (the uncut layer).
+    experts_held: Optional[int] = None
+    first_expert: int = 0
+    dtype: Any = jnp.bfloat16               # products' operands, parameters
+
+    router = "sigmoid_bias"                 # ``glm4_moe_lite.route``'s kind
+
+    def __post_init__(self):
+        put = lambda name, value: object.__setattr__(self, name, value)
+        n, k = self.num_hidden_layers, self.first_k_dense_replace
+        indexers = self.indexer_types
+        if indexers is None:
+            indexers = tuple(
+                FULL if l < k or (l - k) % 4 == 3 else SHARED
+                for l in range(n))
+        mlps = self.mlp_layer_types
+        if mlps is None:
+            mlps = tuple(DENSE if l < k else SPARSE for l in range(n))
+        indexers, mlps = tuple(indexers), tuple(mlps)
+        if len(indexers) != n or set(indexers) - {FULL, SHARED}:
+            raise ValueError(
+                f"indexer_types must name {n} layers, each {FULL!r} or "
+                f"{SHARED!r}, got {indexers}")
+        if indexers[0] != FULL:
+            raise ValueError(
+                "indexer_types must start with a 'full' layer: a 'shared' "
+                "layer reads the selection of the 'full' layer before it")
+        if len(mlps) != n or set(mlps) - {DENSE, SPARSE}:
+            raise ValueError(
+                f"mlp_layer_types must name {n} layers, each {DENSE!r} or "
+                f"{SPARSE!r}, got {mlps}")
+        put("indexer_types", indexers)
+        put("mlp_layer_types", mlps)
+        held = self.held
+        if not 1 <= held <= self.n_routed_experts:
+            raise ValueError(
+                f"experts_held {held} must be in 1..n_routed_experts "
+                f"{self.n_routed_experts}")
+        if not 0 <= self.first_expert <= self.n_routed_experts - held:
+            raise ValueError(
+                f"first_expert {self.first_expert} + experts_held {held} "
+                f"passes n_routed_experts {self.n_routed_experts}")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
+        if self.qk_rope_head_dim > self.index_head_dim:
+            raise ValueError(
+                f"index_head_dim {self.index_head_dim} must hold the "
+                f"{self.qk_rope_head_dim} rotated dimensions")
+        if self.index_topk < 1:
+            raise ValueError("index_topk must be >= 1")
+
+    @property
+    def held(self) -> int:
+        return (self.n_routed_experts if self.experts_held is None
+                else int(self.experts_held))
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """Each layer's stack: its MLP's kind and its indexer's."""
+        return tuple(f"{mlp}_{indexer}" for mlp, indexer in zip(
+            self.mlp_layer_types, self.indexer_types))
+
+    @property
+    def n_full_layers(self) -> int:
+        return sum(t == FULL for t in self.indexer_types)
+
+    @property
+    def n_moe_layers(self) -> int:
+        return sum(t == SPARSE for t in self.mlp_layer_types)
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """Values cached a token and layer: the latent and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def pool_width(self) -> int:
+        """``latent_width`` rounded up to whole lane tiles."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def n_positions(self) -> int:
+        """What the engine checks a slot's length against."""
+        return self.max_position_embeddings
+
+    @classmethod
+    def published(cls, **kw):
+        """GLM-5.2's sizes, every expert held."""
+        return cls(**kw)
+
+    @classmethod
+    def v5e256_share(cls, **kw):
+        """One chip's share of a v5e-256 on which 32 chips share each layer
+        (8 experts a layer, 1/8 of the vocabulary's rows), at the depth one
+        chip serves beside its float32 reference: published layers 2-6 (a
+        dense layer and one whole period, ``full`` at both ends), the
+        sizes of ``benchmark/configs/glm-5.2.json``."""
+        base = dict(num_hidden_layers=5, first_k_dense_replace=1,
+                    indexer_types=(FULL, SHARED, SHARED, SHARED, FULL),
+                    vocab_size=19360, experts_held=8)
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
+    def tiny(cls, **kw):  # tests
+        base = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=128,
+            moe_intermediate_size=32, num_hidden_layers=5,
+            first_k_dense_replace=1,
+            indexer_types=(FULL, SHARED, SHARED, SHARED, FULL),
+            num_attention_heads=4, q_lora_rank=32, kv_lora_rank=64,
+            qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=32,
+            index_n_heads=4, index_head_dim=32, index_topk=24,
+            n_routed_experts=8, num_experts_per_tok=2,
+            max_position_embeddings=512)
+        base.update(kw)
+        return cls(**base)
+
+
+# -- parameters ----------------------------------------------------------------
+
+def _indexer_spec(cfg):
+    d, hi, di = cfg.hidden_size, cfg.index_n_heads, cfg.index_head_dim
+    return (
+        ("wq_b", (("kernel", (cfg.q_lora_rank, hi * di)),)),
+        ("wk", (("kernel", (d, di)),)),
+        ("k_norm", (("scale", (di,)), ("bias", (di,)))),
+        ("weights_proj", (("kernel", (d, hi)),)),
+    )
+
+
+def _layer_spec(cfg, kind: str):
+    mlp, indexer = kind.split("_")
+    d = cfg.hidden_size
+    spec = (("input_norm", (("scale", (d,)),)),
+            ("attn", _attention_spec(cfg)))
+    if indexer == FULL:
+        spec += (("indexer", _indexer_spec(cfg)),)
+    spec += (("post_norm", (("scale", (d,)),)),)
+    if mlp == DENSE:
+        return spec + (("mlp", _mlp_spec(d, cfg.intermediate_size)),)
+    shared = cfg.n_shared_experts * cfg.moe_intermediate_size
+    return spec + (
+        ("router", (("kernel", (d, cfg.n_routed_experts)),
+                    ("bias", (cfg.n_routed_experts,)))),
+        ("shared", _mlp_spec(d, shared)),
+        ("experts", _mlp_spec(d, cfg.moe_intermediate_size,
+                              lead=(cfg.held,))),
+    )
+
+
+def param_spec(cfg):
+    """``embed``, a group a layer (``layer_0``...; what a group holds is
+    its layer's kind's to say), ``final_norm``, ``head``."""
+    d = cfg.hidden_size
+    layers = tuple((f"layer_{l}", _layer_spec(cfg, kind))
+                   for l, kind in enumerate(cfg.layer_kinds))
+    return ((("embed", (cfg.vocab_size, d)),) + layers + (
+        ("final_norm", (("scale", (d,)),)),
+        ("head", (("kernel", (d, cfg.vocab_size)),)),
+    ))
+
+
+# -- the indexer and the selection ---------------------------------------------
+
+def _rope_first(cfg, x, positions):
+    """The first ``qk_rope_head_dim`` values of the last dimension rotated."""
+    r = cfg.qk_rope_head_dim
+    return jnp.concatenate(
+        [rope(x[..., :r], positions, cfg.rope_theta),
+         x[..., r:].astype(jnp.float32)], axis=-1)
+
+
+def indexer_project(cfg, p, xn, cq, positions):
+    """``xn`` (normalized input) and ``cq`` (the query's latent), both in
+    the compute type -> the index queries ``(B, T, Hi, Di)`` and the index
+    key ``(B, T, Di)``, each rounded once, and the heads' weights ``(B, T,
+    Hi)`` float32, both scales folded in."""
+    B, T, _ = xn.shape
+    hi, di = cfg.index_n_heads, cfg.index_head_dim
+    q = _dot("btr,rf->btf", cq, p["wq_b"]["kernel"]).reshape(B, T, hi, di)
+    k = _dot("btd,df->btf", xn, p["wk"]["kernel"])
+    mean = jnp.mean(k, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(k - mean), axis=-1, keepdims=True)
+    k = ((k - mean) * lax.rsqrt(var + cfg.index_norm_eps)
+         * p["k_norm"]["scale"].astype(jnp.float32)
+         + p["k_norm"]["bias"].astype(jnp.float32))
+    w = _dot("btd,dh->bth", xn, p["weights_proj"]["kernel"]) * (
+        hi ** -0.5 * di ** -0.5)
+    return (_rope_first(cfg, q, positions).astype(cfg.dtype),
+            _rope_first(cfg, k, positions).astype(cfg.dtype), w)
+
+
+def index_scores(q_i, w, k_i):
+    """``I[t, s]`` of ``(B, T, Hi, Di)`` queries with weights ``(B, T, Hi)``
+    over ``(B, S, Di)`` keys -> ``(B, T, S)`` float32."""
+    s = jax.nn.relu(_dot("bthd,bsd->bths", q_i, k_i))
+    return jnp.sum(s * w[..., None], axis=2)
+
+
+def select_mask(scores, k: int):
+    """``(..., S)`` float32 scores -> a mask of the ``k`` largest of each
+    row, the lower position first on a tie (all of a row shorter than
+    ``k``).  The ``k``-th largest is found by bisection on the scores'
+    bits, which order as the scores do: 32 counts, and no sort."""
+    if scores.shape[-1] <= k:
+        return jnp.ones(scores.shape, bool)
+    bits = lax.bitcast_convert_type(scores, jnp.uint32)
+    keys = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def raise_bit(i, floor):
+        tried = floor | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        enough = jnp.sum(keys >= tried[..., None], axis=-1,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, tried, floor)
+
+    kth = lax.fori_loop(0, 32, raise_bit,
+                        jnp.zeros(scores.shape[:-1], jnp.uint32))[..., None]
+    above, ties = keys > kth, keys == kth
+    room = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    return above | (ties & (jnp.cumsum(ties, axis=-1, dtype=jnp.int32)
+                            <= room))
+
+
+def select_top(scores, k: int):
+    """``(B, S)`` scores -> the positions ``(B, k)`` of the ``k`` largest of
+    each row, the lower position first on a tie: a decode step's selection,
+    which is gathered and so needs the positions themselves."""
+    return lax.top_k(scores, k)[1]
+
+
+def _expanded_scores(cfg, p, q_n, q_r, latent, k_r):
+    """The expanded form's scaled scores ``(B, H, T, S)`` float32 and values
+    ``(B, S, H, v_head_dim)`` of ``(B, S, .)`` latents and rotary keys."""
+    H, dt = cfg.num_attention_heads, cfg.dtype
+    w = p["kv_b"]["kernel"].reshape(
+        cfg.kv_lora_rank, H, cfg.qk_nope_head_dim + cfg.v_head_dim)
+    w_k, w_v = w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+    k_n = _dot("bsc,chd->bshd", latent, w_k, dt)
+    scores = (_dot("bthd,bshd->bhts", q_n, k_n)
+              + _dot("bthr,bsr->bhts", q_r, k_r)) / np.sqrt(cfg.qk_head_dim)
+    return scores, _dot("bsc,chv->bshv", latent, w_v, dt)
+
+
+# -- the module ----------------------------------------------------------------
+
+class GlmMoeDsa(nn.Module):
+    cfg: GlmMoeDsaConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, tokens, *, deterministic: bool = True,
+                 decode: bool = False, slot_ids=None,
+                 paged: Optional[PagedKVConfig] = None, block_tables=None,
+                 live=None):
+        cfg = self.cfg
+        B, T = tokens.shape
+        if decode and (paged is None or slot_ids is None
+                       or block_tables is None):
+            raise ValueError(
+                "the latent and index-key pools are paged only: "
+                "decode=True needs slot_ids, paged=PagedKVConfig(...) and "
+                "block_tables (the continuous scheduler's "
+                "cache_mode='paged'); there is no dense-row or fixed-batch "
+                "cache of this family")
+        if not decode and (paged is not None or slot_ids is not None
+                           or block_tables is not None or live is not None):
+            raise ValueError(
+                "slot_ids, paged, block_tables and live only apply to "
+                "decode=True calls")
+        if paged is not None:
+            if paged.quantized or paged.kv_dtype is not None:
+                raise ValueError(
+                    f"kv_dtype {paged.kv_dtype!r}: "
+                    f"{SERVE_REFUSALS['kv_dtype']}")
+            if paged.data_shards != 1:
+                raise ValueError(SERVE_REFUSALS["per_shard_kv"])
+        params = _declare(self, param_spec(cfg), cfg)
+        dt, rank, lw = cfg.dtype, cfg.kv_lora_rank, cfg.latent_width
+        # Float32 from here to the head, as glm4_moe_lite keeps it.
+        x = params["embed"][tokens].astype(jnp.float32)
+
+        if decode:
+            bs = paged.block_size
+            latent_pool = self.variable(
+                "cache", "latent_pool", lambda: jnp.zeros(
+                    (cfg.num_hidden_layers, paged.num_blocks, bs,
+                     cfg.pool_width), dt))
+            index_pool = self.variable(
+                "cache", "index_pool", lambda: jnp.zeros(
+                    (cfg.n_full_layers, paged.num_blocks, bs,
+                     cfg.index_head_dim), dt))
+            index = self.variable(
+                "cache", "cache_index", lambda: jnp.zeros((B,), jnp.int32))
+            counts = self.variable(
+                "cache", "moe_counts", lambda: jnp.zeros(
+                    (cfg.n_moe_layers, cfg.held + COUNT_EXTRA), jnp.int32))
+            start = index.value[slot_ids]                         # (B,)
+            positions = start[:, None] + jnp.arange(T)[None, :]   # (B, T)
+            tables = jnp.maximum(block_tables, 0)[slot_ids]
+            cells = (jnp.take_along_axis(
+                tables, positions // bs, axis=1).reshape(-1),
+                (positions % bs).reshape(-1))
+            # The context is walked ``pages`` blocks a step, as far as the
+            # longest row that counts reaches; the table is padded with the
+            # trash block to whole steps.
+            pages = min(CONTEXT_CHUNK // bs, tables.shape[1])
+            chunk = pages * bs
+            steps_max = -(-tables.shape[1] // pages)
+            tables = jnp.pad(tables, (
+                (0, 0), (0, steps_max * pages - tables.shape[1])))
+            span = steps_max * chunk
+            reach = jnp.max(start + T if live is None
+                            else jnp.where(live, start + T, 0))
+            steps = jnp.minimum((reach + chunk - 1) // chunk, steps_max)
+            causal = (jnp.arange(span)[None, None, :]
+                      <= positions[:, :, None])                   # (B, T, S)
+            paged_attention.note_path(SELECTED if T == 1 else MASKED)
+            index.value = index.value.at[slot_ids].set(start + T)
+            pools = (latent_pool.value, index_pool.value)
+        else:
+            positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+            causal = jnp.broadcast_to(
+                jnp.tril(jnp.ones((T, T), bool))[None], (B, T, T))
+            pools = (None, None)
+        token_live = None if live is None else jnp.repeat(live, T)
+
+        def context(pool, layer, j):
+            """Positions ``j * chunk .. (j + 1) * chunk - 1`` of every row,
+            through the table: ``(B, chunk, width)``."""
+            blocks = lax.dynamic_slice_in_dim(tables, j * pages, pages, 1)
+            return pool[layer, blocks].reshape(B, chunk, pool.shape[-1])
+
+        def cached_scores(pool, layer, q_i, w):
+            """``I[t, s]`` over the rows' cached index keys, -inf where
+            ``s`` is after ``t`` (and past the walk)."""
+            def one(j, scores):
+                return lax.dynamic_update_slice_in_dim(
+                    scores, index_scores(q_i, w, context(pool, layer, j)),
+                    j * chunk, axis=2)
+
+            scores = lax.fori_loop(
+                0, steps, one, jnp.full((B, T, span), -jnp.inf, jnp.float32))
+            return jnp.where(causal, scores, -jnp.inf)
+
+        def masked_attention(p, pool, layer, q_n, q_r, mask):
+            """Expanded attention under ``mask`` over the cached context, a
+            chunk a step under an online softmax -> ``(B, T, H * v)``."""
+            H, vd = cfg.num_attention_heads, cfg.v_head_dim
+
+            def one(j, carry):
+                m, l, acc = carry
+                rows = context(pool, layer, j)
+                s, v = _expanded_scores(cfg, p, q_n, q_r, rows[..., :rank],
+                                        rows[..., rank:lw])
+                s = jnp.where(lax.dynamic_slice_in_dim(
+                    mask, j * chunk, chunk, 2)[:, None], s, _MASKED)
+                m_next = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                alpha, pr = jnp.exp(m - m_next), jnp.exp(s - m_next)
+                l = alpha * l + jnp.sum(pr, axis=-1, keepdims=True)
+                acc = alpha * acc + _dot("bhts,bshv->bhtv", pr.astype(dt), v)
+                return m_next, l, acc
+
+            _, l, acc = lax.fori_loop(0, steps, one, (
+                jnp.full((B, H, T, 1), _MASKED, jnp.float32),
+                jnp.zeros((B, H, T, 1), jnp.float32),
+                jnp.zeros((B, H, T, vd), jnp.float32)))
+            return (acc / l).astype(dt).transpose(0, 2, 1, 3).reshape(
+                B, T, H * vd)
+
+        def attention(p, x, pools, layer, full_layer, selection):
+            """One layer's attention; ``full_layer`` is the layer's place
+            among the ``full`` ones (None on a ``shared`` layer, which
+            reads ``selection`` as the last ``full`` layer left it)."""
+            latent_pool_v, index_pool_v = pools
+            xn = rms_norm(x, p["input_norm"]["scale"],
+                          cfg.rms_norm_eps).astype(dt)
+            cq = mla_query_latent(cfg, p["attn"], xn)
+            q_n, q_r, latent, k_r = mla_project(
+                cfg, p["attn"], xn, positions, cq=cq)
+            if full_layer is not None:
+                q_i, k_i, w = indexer_project(
+                    cfg, p["indexer"], xn, cq, positions)
+            if latent_pool_v is None:
+                if full_layer is not None:
+                    selection = causal & select_mask(jnp.where(
+                        causal, index_scores(q_i, w, k_i), -jnp.inf),
+                        cfg.index_topk)
+                ctx = mla_attend(cfg, p["attn"], q_n, q_r, latent, k_r,
+                                 selection, False)
+            else:
+                row = jnp.concatenate(
+                    [latent, k_r, jnp.zeros(
+                        (B, T, cfg.pool_width - lw), dt)], axis=-1)
+                latent_pool_v = latent_pool_v.at[(layer,) + cells].set(
+                    row.reshape(B * T, cfg.pool_width))
+                if full_layer is not None:
+                    index_pool_v = index_pool_v.at[
+                        (full_layer,) + cells].set(
+                        k_i.reshape(B * T, cfg.index_head_dim))
+                    scores = cached_scores(index_pool_v, full_layer, q_i, w)
+                    if T == 1:
+                        # The selection as pool cells, through the table
+                        # once for every layer that shares it.
+                        chosen = select_top(
+                            scores[:, 0], min(cfg.index_topk, span))
+                        selection = (
+                            jnp.take_along_axis(tables, chosen // bs, 1),
+                            chosen % bs, chosen <= positions)
+                    else:
+                        selection = causal & select_mask(
+                            scores, cfg.index_topk)
+                if T == 1:
+                    blocks, offsets, valid = selection
+                    rows = latent_pool_v[layer, blocks, offsets]
+                    ctx = mla_attend(
+                        cfg, p["attn"], q_n, q_r, rows[..., :rank],
+                        rows[..., rank:lw], valid[:, None, :], True)
+                else:
+                    ctx = masked_attention(p["attn"], latent_pool_v, layer,
+                                           q_n, q_r, selection)
+            out = _dot("btf,fd->btd", ctx, p["attn"]["o"]["kernel"])
+            return x + out, (latent_pool_v, index_pool_v), selection
+
+        full_layers, selection, count_rows = 0, None, []
+        for layer, kind in enumerate(cfg.layer_kinds):
+            mlp, indexer = kind.split("_")
+            p = params[f"layer_{layer}"]
+            full_layer = None
+            if indexer == FULL:
+                full_layer, full_layers = full_layers, full_layers + 1
+            h, pools, selection = attention(
+                p, x, pools, layer, full_layer, selection)
+            # What the layer's attention read, for who asks (``mutable=
+            # ["intermediates"]``; nothing in a served program): the mask
+            # ``(B, T, context)``, or a decode step's pool cells.
+            self.sow("intermediates", f"selection_{layer}", selection)
+            hn = rms_norm(h, p["post_norm"]["scale"], cfg.rms_norm_eps)
+            if mlp == DENSE:
+                x = h + gated_mlp(p["mlp"], hn.astype(dt), dt)
+            else:
+                y, row = expert_layer(
+                    cfg, p, hn.reshape(B * T, cfg.hidden_size), token_live)
+                x = h + y.reshape(h.shape)
+                count_rows.append(row)
+        if decode:
+            latent_pool.value, index_pool.value = pools
+            if count_rows:
+                counts.value = counts.value + jnp.stack(count_rows)
+        x = rms_norm(x, params["final_norm"]["scale"],
+                     cfg.rms_norm_eps).astype(dt)
+        return _dot("btd,dv->btv", x, params["head"]["kernel"])
+
+
+# -- what the engine and the scheduler ask of a decoder family -----------------
+
+def cache_geometry(cfg: GlmMoeDsaConfig, paged: PagedKVConfig
+                   ) -> Dict[str, Any]:
+    """Both pools.  A block of the table is one block of each, so
+    ``block_bytes`` is what the allocator's one block holds;
+    ``selected_positions`` is the most latent rows a layer's attention
+    reads of a row (the scheduler counts ``decode_selected_positions`` by
+    it)."""
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    latent = cfg.pool_width * itemsize
+    index = cfg.index_head_dim * itemsize
+    layers, fulls = cfg.num_hidden_layers, cfg.n_full_layers
+    cells = paged.num_blocks * paged.block_size
+    out = {
+        "kind": "latent_indexed",
+        "pools_per_layer": 1,
+        "values_per_token_layer": cfg.latent_width,
+        "pool_width": cfg.pool_width,
+        "padding_values": cfg.pool_width - cfg.latent_width,
+        "bytes_per_token_layer": latent,
+        "index_layers": fulls,
+        "index_values_per_token_layer": cfg.index_head_dim,
+        "index_bytes_per_token_layer": index,
+        "bytes_per_token": layers * latent + fulls * index,
+        "selected_positions": cfg.index_topk,
+        "latent_block_bytes": layers * paged.block_size * latent,
+        "index_block_bytes": fulls * paged.block_size * index,
+        "latent_pool_bytes": layers * cells * latent,
+        "index_pool_bytes": fulls * cells * index,
+    }
+    out["block_bytes"] = out["latent_block_bytes"] + out["index_block_bytes"]
+    out["pool_bytes"] = out["latent_pool_bytes"] + out["index_pool_bytes"]
+    return out
+
+
+# Scheduler features this family cannot serve yet, each with its reason: the
+# scheduler refuses them at construction (``ContinuousScheduler``), and
+# ``make_workload`` the ``tensor`` mesh before an engine exists.
+SERVE_REFUSALS = {
+    "dense_cache": (
+        "the latent and the index keys live in two paged pools under one "
+        "block table (cache_mode='paged'): there is no dense-row layout of "
+        "them"),
+    "kv_dtype": (
+        "both pools are stored in the compute type: the published float8 "
+        "index keys need a scale a block and a dequantizing score, an int8 "
+        "latent its own scale layout"),
+    "per_shard_kv": (
+        "the pools are replicated: per-shard pools are not built for them"),
+    "slo_scheduling": (
+        "host tiering swaps the K and V pools block by block and knows "
+        "neither the latent pool's leaf nor the index keys'; preempting "
+        "would lose a victim's cache"),
+    "spec_k": (
+        "a verify launch is k+1 queries a row, each with its own "
+        "selection, and rolls rejected positions back in both pools: not "
+        "built or tested (nor is a drafter from the model's own "
+        "multi-token-prediction layer)"),
+    "prefix_cache": (
+        "a shared prefix block would share its index keys too, and a "
+        "suffix prefill would have to select over them: not tested yet"),
+    "tensor_mesh": (
+        "the latent and the index key are shared by all heads and the "
+        "expert stack has no tensor rule: serve on a mesh without a "
+        "'tensor' axis"),
+}
+
+
+def make_workload(
+    *,
+    preset: str = "published",
+    batch_size: int = 8,
+    seq_len: Optional[int] = None,
+    config: Optional[GlmMoeDsaConfig] = None,
+    mesh: Optional[Mesh] = None,
+    **_unused,
+) -> Workload:
+    cfg = config or getattr(GlmMoeDsaConfig, preset)()
+    if mesh is not None and mesh.shape.get("tensor", 1) > 1:
+        raise ValueError(
+            f"glm_moe_dsa on a mesh with tensor={mesh.shape['tensor']}: "
+            f"{SERVE_REFUSALS['tensor_mesh']}")
+    seq = seq_len or min(cfg.max_position_embeddings, 128)
+    module = GlmMoeDsa(cfg, mesh=mesh)
+    data = functools.partial(synthetic_lm, seq_len=seq,
+                             vocab_size=cfg.vocab_size)
+    return Workload(
+        name="glm_moe_dsa",
+        module=module,
+        loss_fn=functools.partial(_loss_fn, module),
+        init_batch={"tokens": np.zeros((2, seq), np.int32)},
+        data_fn=lambda per_host_bs: data(batch_size=per_host_bs),
+        eval_data_fn=lambda per_host_bs: data(batch_size=per_host_bs,
+                                              holdout=True),
+        rules=ShardingRules(),
+        batch_size=batch_size,
+        clip_grad_norm=1.0,
+        learning_rate=3e-4,
+        example_key="tokens",
+        init_key="tokens",
+        cache_rules=cache_rules,
+        cache_geometry=functools.partial(cache_geometry, cfg),
+        serve_refusals=dict(SERVE_REFUSALS),
+    )

@@ -304,7 +304,10 @@ def _continuous_instruments(registry=None):
             "dtt_serve_kv_blocks_held",
             "Physical K/V blocks live requests hold, by the kind of pool: "
             "'full' (the block-table pool, grows with the row) or 'window' "
-            "(a family's window layers: at most the slot's ring)",
+            "(a family's window layers: at most the slot's ring); "
+            "'latent' and 'index' where a family keeps index keys in a "
+            "second pool under the latent pool's block table (a block of "
+            "the table is one of each)",
             labelnames=("kind",)),
         "window_recycled": r.counter(
             "dtt_serve_window_blocks_recycled_total",
@@ -763,6 +766,10 @@ class ContinuousScheduler:
                 block_size=self.block_size, num_blocks=int(num_blocks)))
                 if geometry is not None else {})
             self._window = int(self._kv_geometry.get("window_positions", 0))
+            # A family whose attention reads a learned selection says how
+            # many of a row's positions a layer reads at the most.
+            self._selected = int(
+                self._kv_geometry.get("selected_positions", 0))
             ring = 0
             if self._window:
                 chunk = self.prefill_budget or self.max_total_len
@@ -807,6 +814,7 @@ class ContinuousScheduler:
         else:
             self.paged = None
             self._window = 0
+            self._selected = 0
             self._allocator = None
             self._block_tables = None
             self._slot_blocks = {}
@@ -988,6 +996,9 @@ class ContinuousScheduler:
         # layer's attention reads), and ring entries written over.
         self._live_window_positions_sum = 0
         self._window_recycled = 0
+        # The same, each row counted up to the selection (the latent rows a
+        # layer's attention reads where a learned indexer selects them).
+        self._live_selected_positions_sum = 0
         self._last_occupancy = 0
         self._latencies_ms: collections.deque = collections.deque(maxlen=1024)
         self._ttft_ms: collections.deque = collections.deque(maxlen=1024)
@@ -2084,6 +2095,7 @@ class ContinuousScheduler:
         self._block_tables[req.slot, len(blocks):needed] = fresh
         self._dev_block_tables = None  # host table grew
         blocks.extend(fresh)
+        self._note_index_blocks_held()
         with self._lock:
             release = min(req.reserved_blocks, len(fresh))
             req.reserved_blocks -= release
@@ -2126,6 +2138,15 @@ class ContinuousScheduler:
             self._allocator.used_count)
         self._obs["kv_blocks_held"].labels(kind="window").set(window)
 
+    def _note_index_blocks_held(self) -> None:
+        """Where index keys lie in a second pool under the latent pool's
+        table, a block the allocator hands out is one block of each: the
+        gauge says so by kind."""
+        if self._kv_geometry.get("index_block_bytes"):
+            for kind in ("latent", "index"):
+                self._obs["kv_blocks_held"].labels(kind=kind).set(
+                    self._allocator.used_count)
+
     def _window_blocks_held_locked(self) -> int:
         """Call under ``_lock``."""
         ring = self.paged.window_ring
@@ -2136,11 +2157,29 @@ class ContinuousScheduler:
         nothing for a family with one.  ``kv_bytes_held_uniform`` is what
         the same rows would hold if every layer kept a block wherever the
         full layers do (one geometry for all)."""
-        if self.paged is None or not self.paged.window_ring:
+        if self.paged is None:
             return {}
         g = self._kv_geometry
-        full, window = self._allocator.used_count, self._window_blocks_held_locked()
         iters = self._iterations
+        if g.get("index_block_bytes"):
+            # Index keys beside the latent pool, under one table: a block
+            # held is one of each.
+            held = self._allocator.used_count
+            return {
+                "kv_blocks_held_latent": float(held),
+                "kv_blocks_held_index": float(held),
+                "kv_bytes_held": float(held * g["block_bytes"]),
+                "kv_bytes_held_index": float(held * g["index_block_bytes"]),
+                # The full indexer layers score ``decode_live_positions``;
+                # every layer's attention reads the same rows' latents,
+                # each row counted up to the selection.
+                "decode_selected_positions": (
+                    self._live_selected_positions_sum / iters
+                    if iters else 0.0),
+            }
+        if not self.paged.window_ring:
+            return {}
+        full, window = self._allocator.used_count, self._window_blocks_held_locked()
         return {
             "kv_blocks_held_full": float(full),
             "kv_blocks_held_window": float(window),
@@ -2368,6 +2407,8 @@ class ContinuousScheduler:
                     "prefill_chunk", cat="serve",
                     args={"request_id": req.rid, "slot": req.slot,
                           "offset": int(off), "chunk_tokens": int(chunk),
+                          # Positions the chunk's attention ran against.
+                          "context_tokens": int(off + chunk),
                           "chunk_index": int(req.prefill_chunks),
                           "final": bool(final)}):
                 self._ensure_blocks(req, off + chunk)
@@ -2583,6 +2624,7 @@ class ContinuousScheduler:
         active_slots: List[int] = []
         live_positions = 0      # cached positions the launched rows hold
         live_window = 0         # ... each row counted up to the window
+        live_selected = 0       # ... each row counted up to the selection
         pending: Dict[int, int] = {}
         for slot in sorted(decoding):
             req = decoding[slot]
@@ -2594,6 +2636,7 @@ class ContinuousScheduler:
             held = req.base_prompt_len + len(req.tokens) + inflight
             live_positions += held
             live_window += min(held, self._window)
+            live_selected += min(held, self._selected)
             pending[slot] = min(K, left)
             horizon[slot] = left
             if req.eos_token is not None:
@@ -2658,6 +2701,7 @@ class ContinuousScheduler:
                 self._occupancy_sum += len(active_slots)
                 self._live_positions_sum += live_positions
                 self._live_window_positions_sum += live_window
+                self._live_selected_positions_sum += live_selected
                 self._last_occupancy = len(active_slots)
                 self._note_dispatch_locked(dispatch_t)
                 seq = self._launch_seq
@@ -3505,6 +3549,7 @@ class ContinuousScheduler:
             self._dev_block_tables = None  # host table reset
             if self.paged.window_ring:      # the ring's entries too
                 self._note_window_covered(req.slot, 0)
+            self._note_index_blocks_held()
         else:
             used = self.paged_equivalent_blocks
         with self._lock:

@@ -220,11 +220,13 @@ class ServeArgs:
 # programs), each with its presets where no --preset is given: (the CPU
 # smoke's config, the chip's).  For glm4_moe_lite the chip's is one chip's
 # share of an 8-chip deployment (the whole model is 60 GB in bfloat16), for
-# mellum one chip's share of a 4-chip host (24 GB whole).
+# mellum one chip's share of a 4-chip host (24 GB whole), for glm_moe_dsa
+# one chip's share of a v5e-256 (32 chips a layer, 5 of a stage's layers).
 # Every other model is batched classification.
 _AUTO_PRESETS = {"gpt2": ("tiny", "medium"),
                  "glm4_moe_lite": ("tiny", "v5e8_share"),
-                 "mellum": ("tiny", "v5e4_share")}
+                 "mellum": ("tiny", "v5e4_share"),
+                 "glm_moe_dsa": ("tiny", "v5e256_share")}
 DECODER_MODELS = tuple(_AUTO_PRESETS)
 
 

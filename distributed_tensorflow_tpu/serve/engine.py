@@ -1827,8 +1827,11 @@ def moe_counts_of(cache: PyTree):
 # Attention paths a traced program may have on record: the paged kernel's
 # two (``ops.paged_attention``), the latent attention's two and the
 # grouped-query family's four (a program of its has a window and a full
-# path, both by the gather or both by the kernel).
+# path, both by the gather or both by the kernel), the learned sparse
+# attention's two (a decode step over the selected rows, a chunk under the
+# selection's mask).
 _DECODE_PATHS = (paged_attention.KERNEL, paged_attention.GATHER,
                  "latent_absorbed", "latent_expanded",
                  "gqa_gather_window", "gqa_gather_full",
-                 ) + paged_attention.GQA_KERNEL_PATHS
+                 ) + paged_attention.GQA_KERNEL_PATHS + (
+                 "latent_sparse_selected", "latent_sparse_masked")

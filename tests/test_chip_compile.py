@@ -800,3 +800,120 @@ def test_two_pool_serve_programs_fit_one_chip_at_the_cells_shapes(topo,
         assert shape not in gathered, (
             f"a bf16{list(shape)}: every slot's table rows or rings, "
             f"gathered or split")
+
+
+# -- the learned-sparse-attention family at its cell's shapes ------------------
+
+def lower_sparse_latent_program(topo, program):
+    """``serve.glm-5.2.longdoc-saturated``'s decode or prefill program,
+    lowered from shapes as ``lower_serve_program`` does, at the cell's own
+    slots, lengths and chunk: one table a slot for both pools."""
+    from benchmark.harness import program as program_lib, spec
+    from distributed_tensorflow_tpu.models import PagedKVConfig
+    from distributed_tensorflow_tpu.models.glm_moe_dsa import GlmMoeDsa
+    from distributed_tensorflow_tpu.serve import sampling as sampling_lib
+    from distributed_tensorflow_tpu.serve.engine import ServeEngine
+
+    cell = spec.load_cell("serve.glm-5.2.longdoc-saturated")
+    sched = cell.cell["scheduler"]
+    slots, total, block = (sched["num_slots"], sched["max_total_len"],
+                           sched["block_size"])
+    chunk, steps = sched["prefill_budget"], sched["megastep"]
+    module = GlmMoeDsa(program_lib.program_config(cell.config))
+    engine = object.__new__(ServeEngine)
+    engine.module = module
+    per_slot = total // block
+    paged = PagedKVConfig(block_size=block, num_blocks=slots * per_slot + 1)
+
+    def arg(shape, dtype=jnp.int32):
+        return one_chip(topo, shape, dtype)
+
+    variables = jax.eval_shape(lambda: module.init(
+        jax.random.key(0), jnp.zeros((slots, total), jnp.int32),
+        decode=True, slot_ids=jnp.arange(slots, dtype=jnp.int32),
+        paged=paged, block_tables=jnp.zeros((slots, per_slot), jnp.int32)))
+    params, cache = jax.tree.map(
+        lambda s: arg(s.shape, s.dtype),
+        (variables["params"], variables["cache"]))
+    counts = arg((slots, module.cfg.vocab_size))
+    tables = arg((slots, per_slot))
+    rng = arg((), jax.random.key(0).dtype)
+    sampling = lambda rows: jax.tree.map(
+        lambda a: arg(np.shape(a), np.asarray(a).dtype),
+        sampling_lib.uniform(rows, 0.0, 0))
+    if program == "decode_megastep":
+        fn = jax.jit(
+            lambda *a: engine._megastep_counting_apply(steps, paged, *a),
+            donate_argnums=(1, 2))
+        lowered = fn.lower(
+            params, cache, counts, arg((slots,)), arg((slots,), jnp.bool_),
+            arg((slots,)), arg((slots,)), tables, rng, arg(()),
+            sampling(slots), arg((slots,)), arg((slots,), jnp.bool_),
+            arg(()))
+    else:
+        fn = jax.jit(
+            lambda *a: engine._prefill_slots_apply(paged, *a),
+            donate_argnums=(1, 2))
+        lowered = fn.lower(
+            params, cache, counts, arg((1, chunk)), arg((1,)), tables,
+            rng, arg(()), arg((1,)), sampling(1), arg((1,), jnp.bool_))
+    return lowered, cache, slots, chunk
+
+
+@pytest.mark.parametrize("program", ["decode_megastep", "prefill_slots"])
+def test_sparse_latent_serve_programs_fit_one_chip_at_the_cells_shapes(
+        topo, program):
+    """One chip's share of the v5e-256 deployment, published layers 2-6 at
+    the published widths: 5.35 GB of bfloat16 weights, the five layers'
+    latent pool of ``slots x 512 + 1`` blocks (0.84 GB at 16 slots) and the
+    two ``full`` layers' index keys under the same block numbers (0.07
+    GB), both updated in place: 6.25 GB of arguments; 0.54 GB of scratch
+    for the decode program (4 fused steps, the router's counts as one more
+    output) and 0.41 GB for a prefill chunk of 1,024 (one slot's).  Here
+    the decode program compiled in 22 s and the chunk in 31 s (PERF.md
+    section 4 and the cell's ``num_slots_arithmetic`` quote these).
+
+    A decode step gathers the selected rows and no table row: the only
+    ``(slots, ., 640)`` arrays are ``index_topk`` long, and what it reads
+    of the index keys is a chunk of the context a turn of the walk, never
+    the 512 blocks of a row at once."""
+    import time
+
+    lowered, cache, slots, chunk = lower_sparse_latent_program(topo, program)
+    latent, index = cache["latent_pool"].shape, cache["index_pool"].shape
+    assert latent == (5, slots * 512 + 1, 16, 640)
+    assert index == (2, slots * 512 + 1, 16, 128)
+    started = time.perf_counter()
+    compiled = lowered.compile()
+    assert time.perf_counter() - started < 240      # a cold start pays it
+    memory = compiled.memory_analysis()
+    pools = 2 * (np.prod(latent) + np.prod(index))
+    assert 5.34e9 + pools < memory.argument_size_in_bytes < 5.36e9 + pools
+    assert memory.temp_size_in_bytes < (
+        0.65e9 if program == "decode_megastep" else 0.5e9)
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < V5E_HBM_BYTES
+    hlo = compiled.as_text()
+    # In place: nothing but a scatter makes a pool-sized array (a chunk's
+    # scatter is written over the pool's rows flattened and shows here as
+    # a bitcast of its fusion).
+    assert " scatter(" in hlo
+    for pool in (latent, index):
+        for name, op, line in pool_sized_results(hlo, pool):
+            assert op in ("parameter", "get-tuple-element", "bitcast",
+                          "scatter") or (
+                op == "fusion" and " scatter(" in fused_computation(
+                    hlo, line)), f"%{name} is a pool-sized {op}"
+    assert not kernel_calls(hlo)        # no flash kernel in a serving step
+    shapes = {tuple(int(n) for n in dims.split(","))
+              for dims in re.findall(r"= bf16\[([\d,]+)\]", hlo)}
+    whole_rows = {(slots * 512, 16, 640), (slots, 8192, 640),
+                  (slots * 512, 16, 128), (slots, 8192, 128),
+                  (512, 16, 640), (1, 8192, 640), (1, 8192, 128)}
+    assert not shapes & whole_rows, shapes & whole_rows
+    if program == "decode_megastep":
+        assert (slots, 2048, 640) in shapes      # the selected rows
+        assert (slots, 1024, 128) in shapes      # a turn's index keys
+    else:
+        assert (1, 1024, 640) in shapes          # a turn's latents
+        assert chunk in (512, 1024)

@@ -72,7 +72,7 @@ from jax.sharding import Mesh
 
 from distributed_tensorflow_tpu.data.pipeline import synthetic_lm
 from distributed_tensorflow_tpu.models import PagedKVConfig, Workload
-from distributed_tensorflow_tpu.ops import paged_attention
+from distributed_tensorflow_tpu.ops import grouped_matmul, paged_attention
 from distributed_tensorflow_tpu.parallel.sharding import ShardingRules
 
 # The module, not the function ``ops`` re-exports: the platform is read
@@ -440,25 +440,85 @@ def route(cfg, p, x):
     return chosen, weights * scaling
 
 
-def expert_layer(cfg, p, x, live=None):
+def expert_form(n: int, k: int, experts: int) -> str:
+    """Which form the routed product of a call takes, from the call's
+    static shape: ``n`` tokens, ``k`` of ``experts`` a token (how many are
+    held here changes neither form's cost an expert).  Every held expert
+    over every token reads each stack once and is bound by that read while
+    the tokens are fewer than the chip's operations a byte (240 on a v5e):
+    nothing is faster where nearly every held expert gets a row anyway, an
+    expert's chance of one being ``1 - (1 - k / experts) ** n``.  (On the chip, PERF.md Findings, PR 41: 128
+    tokens of 4-of-64 over 8 held, 0.227 ms dense and 0.238 grouped; 384,
+    0.375 and 0.266; 16 tokens of 8-of-64 over 16 held, a chance of 0.88,
+    0.281 and 0.260.)  Otherwise each assignment is computed once over rows
+    grouped by expert, and an expert without a row is not read."""
+    every_expert_live = 1.0 - (1.0 - k / experts) ** n > 0.95
+    return (grouped_matmul.DENSE if every_expert_live and n < 256
+            else grouped_matmul.GROUPED)
+
+
+def _routed_dense(cfg, ex, xd, gates):
+    """Every held expert over every token, weighed by ``gates`` ``(N,
+    held)``, zero where the router chose otherwise."""
+    g, u = jnp.split(
+        _dot("nd,egd->eng", xd, ex["gate_up"]["kernel"]), 2, axis=-1)
+    each = _dot("enf,efd->end", (jax.nn.silu(g) * u).astype(cfg.dtype),
+                ex["down"]["kernel"])
+    # The gates weigh float32 results in float32: no product, a sum of 8.
+    return jnp.sum(gates.T[:, :, None] * each, axis=0)
+
+
+def _routed_grouped(cfg, ex, xd, gates, mine, layer, kernels: bool):
+    """Each (token, held expert) assignment once: the rows of ``xd`` that
+    ``mine`` ``(N, held)`` assigns, grouped by expert, and each token's
+    results weighed by ``gates`` and summed in float32
+    (``ops/grouped_matmul.py``).  An expert without a row is not read."""
+    lay = grouped_matmul.layout(mine, cfg.num_experts_per_tok)
+    grouped_matmul.note_form(grouped_matmul.GROUPED, xd.shape[0], lay.rows)
+    product = (grouped_matmul.gated_mlp if kernels
+               else grouped_matmul.gated_mlp_reference)
+    return product(xd, ex["gate_up"]["kernel"], ex["down"]["kernel"], lay,
+                   gates, layer=layer)
+
+
+def expert_layer(cfg, p, x, live=None, *, layer=None, mesh=None):
     """Held experts' part of the routed result plus the shared expert (where
     the layer has one), for ``x`` ``(N, d)`` float32 (the router reads it
     unrounded; the experts' products take it in the compute type), float32
     out; and the layer's row of ``moe_counts``.  ``live`` ``(N,)`` masks the
-    tokens that count."""
+    tokens that count: the grouped form gives the others no row, and their
+    routed result is zero.  With ``layer`` given, ``p["experts"]`` is the
+    stack of all the model's expert layers and ``layer`` the (traced) index
+    of this one: a kernel reads the layer's blocks where they lie, and a
+    slice handed to it would be copied first.  ``mesh`` is the model's:
+    the kernels run on one device."""
     dt = cfg.dtype
     chosen, weights = route(cfg, p["router"], x)
     held = cfg.first_expert + jnp.arange(cfg.held, dtype=chosen.dtype)
     hit = chosen[:, :, None] == held[None, None, :]            # (N, k, held)
-    gates = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), axis=1)
     ex = p["experts"]
     xd = x.astype(dt)
-    g, u = jnp.split(
-        _dot("nd,egd->eng", xd, ex["gate_up"]["kernel"]), 2, axis=-1)
-    each = _dot("enf,efd->end", (jax.nn.silu(g) * u).astype(dt),
-                ex["down"]["kernel"])
-    # The gates weigh float32 results in float32: no product, a sum of 8.
-    routed = jnp.sum(gates.T[:, :, None] * each, axis=0)
+    form = expert_form(x.shape[0], cfg.num_experts_per_tok,
+                       p["router"]["kernel"].shape[-1])
+    kernels = grouped_matmul.supported(
+        n=x.shape[0], d=x.shape[-1], f=ex["down"]["kernel"].shape[-2],
+        dtype=dt, mesh=mesh)
+    gates = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), axis=1)
+    # Off the TPU the grouped form is plain ``jnp`` at a toy's sizes; on a
+    # TPU where the kernels do not run (more devices than one, widths that
+    # are not whole tiles) the dense form is the only one.
+    if form == grouped_matmul.GROUPED and (
+            kernels or _fa._platform() != "tpu"):
+        mine = hit.any(axis=1)
+        if live is not None:
+            mine = mine & live.astype(bool)[:, None]
+        routed = _routed_grouped(cfg, ex, xd, gates, mine, layer, kernels)
+    else:
+        grouped_matmul.note_form(grouped_matmul.DENSE, x.shape[0])
+        if layer is not None:
+            ex = jax.tree.map(lambda w: lax.dynamic_index_in_dim(
+                w, layer, keepdims=False), ex)
+        routed = _routed_dense(cfg, ex, xd, gates)
     y = routed + gated_mlp(p["shared"], xd, dt) if "shared" in p else routed
 
     counted = (jnp.ones(x.shape[:1], jnp.int32) if live is None
@@ -579,8 +639,11 @@ class Glm4MoeLite(nn.Module):
             p, layer = xs
             h, pool_value = attention(p, x, pool_value, layer)
             hn = rms_norm(h, p["post_norm"]["scale"], cfg.rms_norm_eps)
+            # The experts' stacks whole, and which layer's to read.
             y, row = expert_layer(
-                cfg, p, hn.reshape(B * T, cfg.hidden_size), token_live)
+                cfg, dict(p, experts=params["moe_layers"]["experts"]),
+                hn.reshape(B * T, cfg.hidden_size), token_live,
+                layer=layer - nd, mesh=self.mesh)
             return (h + y.reshape(h.shape), pool_value), row
 
         nd = cfg.n_dense_layers
@@ -590,7 +653,8 @@ class Glm4MoeLite(nn.Module):
             (params["dense_layers"], jnp.arange(nd, dtype=jnp.int32)))
         carry, rows = lax.scan(
             moe_layer, carry,
-            (params["moe_layers"],
+            ({name: leaves for name, leaves in params["moe_layers"].items()
+              if name != "experts"},
              nd + jnp.arange(cfg.n_moe_layers, dtype=jnp.int32)))
         x, pool_value = carry
         if decode:

@@ -580,7 +580,8 @@ class GlmMoeDsa(nn.Module):
                 x = h + gated_mlp(p["mlp"], hn.astype(dt), dt)
             else:
                 y, row = expert_layer(
-                    cfg, p, hn.reshape(B * T, cfg.hidden_size), token_live)
+                    cfg, p, hn.reshape(B * T, cfg.hidden_size), token_live,
+                    mesh=self.mesh)
                 x = h + y.reshape(h.shape)
                 count_rows.append(row)
         if decode:

@@ -509,7 +509,9 @@ class Mellum(nn.Module):
                 seen[full] += 1
                 hn = rms_norm(h, p["post_norm"]["scale"], cfg.rms_norm_eps)
                 y, row = expert_layer(
-                    cfg, p, hn.reshape(B * T, cfg.hidden_size), token_live)
+                    cfg, dict(p, experts=params["layers"]["experts"]),
+                    hn.reshape(B * T, cfg.hidden_size), token_live,
+                    layer=n * period + i, mesh=self.mesh)
                 x = h + y.reshape(h.shape)
                 rows.append(row)
             return (x, win_pool, full_pool_v), jnp.stack(rows)

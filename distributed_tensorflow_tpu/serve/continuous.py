@@ -195,6 +195,7 @@ from distributed_tensorflow_tpu.obs.trace import (
     now as _now,
     spanned,
 )
+from distributed_tensorflow_tpu.ops import grouped_matmul
 from distributed_tensorflow_tpu.ops.paged_attention import KERNEL_PATHS
 from distributed_tensorflow_tpu.serve.batcher import (
     ServeOverloadedError,
@@ -978,6 +979,10 @@ class ContinuousScheduler:
         # (under _lock): the decode launches' ``moe_counts`` rows summed,
         # (expert layers, experts held + 3); None until a launch brings one.
         self._moe_counts: Optional[np.ndarray] = None
+        # The engine's record of the form each traced program's expert
+        # layers took: a launch that traces its program writes it
+        # (``grouped_matmul.record_forms`` round the launch).
+        self._moe_forms = engine.expert_forms_record()
         # Speculative decoding (under _lock): verify launches, draft
         # tokens proposed / accepted, and tokens emitted by the verify
         # path (accepted drafts + the per-slot bonus/correction token).
@@ -1326,6 +1331,7 @@ class ContinuousScheduler:
         compile_stats = self.engine.compile_stats()
         attention = self.engine.decode_attention_launches()
         attention_launches = sum(attention.values())
+        moe_forms = dict(self._moe_forms).items()
         # Host-KV-tier telemetry: the pool has its own lock, read it
         # before the scheduler lock (same no-lock-order-edge discipline
         # as compile_stats).  Zeros when tiering is off so dashboards,
@@ -1472,6 +1478,14 @@ class ContinuousScheduler:
                     sum(attention[path] for path in KERNEL_PATHS)
                     / attention_launches if attention_launches else 0.0),
                 **self._moe_stats_locked(),
+                # What each traced program's expert layers took: every
+                # assignment once over rows grouped by expert, or every
+                # held expert over every token; and the grouped buffer's
+                # static rows.  Empty for a model without expert layers.
+                "moe_expert_form": {
+                    program: form for program, (form, _) in moe_forms},
+                "moe_grouped_rows": {
+                    program: rows for program, (_, rows) in moe_forms},
                 # SLO scheduling: preempt/resume traffic, parked
                 # requests, host-KV-tier bytes, and TTFT-deadline
                 # goodput (fraction of deadline-carrying completions
@@ -2412,17 +2426,19 @@ class ContinuousScheduler:
                           "chunk_index": int(req.prefill_chunks),
                           "final": bool(final)}):
                 self._ensure_blocks(req, off + chunk)
-                tok_dev, self._cache, self._counts = (
-                    self.engine.prefill_into_slots(
-                        self._cache, req.prompt[None, off:off + chunk],
-                        [req.slot],
-                        sampling=sampling_lib.pack(
-                            [req.sampling], [len(req.tokens)]),
-                        counts=self._counts, commit=np.array([final]),
-                        counter=self._next_counter(),
-                        params=req.gen.params,
-                        start_offsets=[off] if off else None,
-                        **self._paged_call_kwargs()))
+                with grouped_matmul.record_forms(self._moe_forms,
+                                                 "slot_prefill"):
+                    tok_dev, self._cache, self._counts = (
+                        self.engine.prefill_into_slots(
+                            self._cache, req.prompt[None, off:off + chunk],
+                            [req.slot],
+                            sampling=sampling_lib.pack(
+                                [req.sampling], [len(req.tokens)]),
+                            counts=self._counts, commit=np.array([final]),
+                            counter=self._next_counter(),
+                            params=req.gen.params,
+                            start_offsets=[off] if off else None,
+                            **self._paged_call_kwargs()))
                 spent += chunk
                 req.next_prefill_offset = off + chunk
                 req.prefill_chunks += 1
@@ -2680,16 +2696,18 @@ class ContinuousScheduler:
                 slots = by_gen[generation]
                 active = np.zeros((self.num_slots,), bool)
                 active[slots] = True
-                (toks_dev, carry, steps_dev, clock, self._cache,
-                 self._counts, *moe_dev) = (
-                    self.engine.decode_megastep(
-                        self._cache, carry, active, horizon, steps=K,
-                        eos_rows=eos_rows,
-                        sampling=samp, counts=self._counts,
-                        counter=self._next_counter(K),
-                        params=decoding[slots[0]].gen.params,
-                        fresh_tokens=fresh_tokens, fresh=fresh, clock=clock,
-                        **self._paged_call_kwargs()))
+                with grouped_matmul.record_forms(self._moe_forms,
+                                                 "slot_megastep"):
+                    (toks_dev, carry, steps_dev, clock, self._cache,
+                     self._counts, *moe_dev) = (
+                        self.engine.decode_megastep(
+                            self._cache, carry, active, horizon, steps=K,
+                            eos_rows=eos_rows,
+                            sampling=samp, counts=self._counts,
+                            counter=self._next_counter(K),
+                            params=decoding[slots[0]].gen.params,
+                            fresh_tokens=fresh_tokens, fresh=fresh,
+                            clock=clock, **self._paged_call_kwargs()))
                 fresh = fresh_tokens = None  # the first launch merged them
                 launches.append((slots, toks_dev, steps_dev))
                 moe_devs.extend(moe_dev)

@@ -1815,6 +1815,27 @@ class ServeEngine:
         out = self._megastep_apply(steps, paged, params, cache, *rest)
         return out + (moe_counts_of(out[4]) - before,)
 
+    def expert_forms_record(self) -> Dict[str, Tuple[str, int]]:
+        """The engine's record of the form each traced program's expert
+        layers took (``expert_forms``), itself: a scheduler asks for it
+        where it is built and hands it to ``ops.grouped_matmul.
+        record_forms`` round its launches, the way ``_recording_paths``
+        keeps the attention's path.  The choice is made while ``jax.jit``
+        traces, from the call's static shape, so this record is the only
+        place it shows; it is the engine's because the programs are.
+        (Made at the first ask and kept down here: the compile-cache keys
+        of the programs with a kernel carry the line numbers of this file
+        above ``_megastep_apply``, ``__init__`` among them.)"""
+        return self.__dict__.setdefault("_expert_forms", {})
+
+    def expert_forms(self) -> Dict[str, Tuple[str, int]]:
+        """Per traced program, ``"<kind>/<tokens a call of its layers
+        sees>"``, the form its expert layers took (``grouped``: each
+        assignment once over rows grouped by expert; ``dense``: every held
+        expert over every token) and the grouped buffer's static rows.
+        Empty for a model without expert layers."""
+        return dict(self.__dict__.get("_expert_forms", {}))
+
 
 def moe_counts_of(cache: PyTree):
     """The cache tree's ``moe_counts`` leaf (expert layers that count the

@@ -149,3 +149,42 @@ def join_workers(procs, *, timeout: int, fail) -> List[str]:
             return []
         outs.append(out)
     return outs
+
+
+def expert_forms_on_record(sched, *, experts: int, chunk: int) -> None:
+    """A scheduler that has served a request over ``chunk``-token prefill
+    launches says on its own record what its programs' expert layers took:
+    ``stats()["moe_expert_form"]`` names the prefill and the decode program
+    (``"<kind>/<tokens a layer's call sees>"``) with the form the call's
+    static shape gives (``glm4_moe_lite.expert_form``), a grouped program's
+    static rows are the layout's, and the counts' leaf and keys are what
+    they were."""
+    from distributed_tensorflow_tpu.models import glm4_moe_lite as glm
+    from distributed_tensorflow_tpu.ops import grouped_matmul
+    from distributed_tensorflow_tpu.serve.engine import moe_counts_of
+
+    cfg = sched.engine.module.cfg
+    k, held = cfg.num_experts_per_tok, cfg.held
+    stats = sched.stats()
+    forms, rows = stats["moe_expert_form"], stats["moe_grouped_rows"]
+    assert forms == {p: form for p, (form, _) in
+                     sched.engine.expert_forms().items()}
+    for program, n in ((f"slot_prefill/{chunk}", chunk),
+                       (f"slot_megastep/{sched.num_slots}", sched.num_slots)):
+        form = glm.expert_form(n, k, experts)
+        assert forms[program] == form, (program, forms)
+        tm = grouped_matmul.tile_rows(n)
+        assert rows[program] == (
+            tm * grouped_matmul.static_tiles(n, k, held, tm)
+            if form == grouped_matmul.GROUPED else 0)
+    assert set(forms.values()) <= {grouped_matmul.GROUPED,
+                                   grouped_matmul.DENSE}
+    assert moe_counts_of(sched._cache).shape == (
+        cfg.n_moe_layers if hasattr(cfg, "n_moe_layers")
+        else cfg.num_hidden_layers, held + 3)
+    assert stats["moe_experts_held"] == held
+    for key in ("moe_assignments_here", "moe_assignments_absent",
+                "moe_active_experts_per_step", "moe_layer_steps",
+                "moe_load_max_over_mean"):
+        assert isinstance(stats[key], float)
+    assert stats["moe_layer_steps"] > 0

@@ -452,6 +452,49 @@ def test_serve_programs_read_the_weights_once_in_the_compute_type(topo,
 
 # -- the latent-attention, sparse-expert family at its cell's shapes ----------
 
+# The three cells whose programs run expert layers: (held, tokens of a
+# layer's call, 2 x the experts' width) of a decode step and of the longest
+# prefill launch; the scratch (bytes) of the decode and the prefill program
+# while every held expert ran over every token (my described-v5e compiles
+# of the parent's form, PR 41).
+GLM_47, MELLUM, GLM_52 = "glm-4.7-flash", "mellum2", "glm-5.2"
+EVERY_EXPERT_OVER_EVERY_TOKEN = {
+    GLM_47: ((8, 16, 3072), (8, 384, 3072), 431_387_136, 44_919_808),
+    MELLUM: ((16, 16, 1792), (16, 512, 1792), 357_766_144, 632_185_856),
+    GLM_52: ((8, 16, 4096), (8, 1024, 4096), 538_529_792, 407_471_104),
+}
+
+
+def assert_each_assignment_once(compiled, cell, program):
+    """At the cells' shapes both programs of each family take the grouped
+    form (``ops/grouped_matmul.py``): the two kernels are in the program,
+    once a layer of the loop's body or of the unrolled stack; no float32
+    ``(held, tokens, 2f)`` result exists (all the held experts' gate and up
+    products over all the tokens: 134 MB a layer of the sixth cell's
+    chunk), in any layout; and the program's scratch is what it was with
+    that form or less (a prefill launch 0.3-42 MB less; a decode program
+    the same to a thousandth, the 0.1-0.4 MB of a step's rounded ``silu(g)
+    * u`` rows over, which the dense form kept inside one fusion).  Bytes
+    and names, never a rate.  Called where each cell's test has its
+    program compiled: a second compile is half a minute, and a compiled
+    program kept for a later test would outlive its own."""
+    decode, prefill, decode_scratch, prefill_scratch = (
+        EVERY_EXPERT_OVER_EVERY_TOKEN[cell])
+    hlo = compiled.as_text()
+    layers = 4 if cell != GLM_47 else 1     # a period, the stack, the body
+    for kernel in ("expert_gate_up", "expert_down"):
+        calls = re.findall(rf"%({kernel}[\w.]*) = [^\n]*tpu_custom_call", hlo)
+        assert len(calls) == layers, (kernel, calls)
+    for shape in (decode, prefill):
+        dims = ",".join(str(n) for n in shape)
+        assert f"f32[{dims}]" not in hlo
+    scratch = compiled.memory_analysis().temp_size_in_bytes
+    if program == "decode_megastep":
+        assert scratch <= 1.002 * decode_scratch
+    else:
+        assert scratch < prefill_scratch
+
+
 def glm_cell():
     """``serve.glm-4.7-flash.reason-saturated``: its module and scheduler."""
     from benchmark.harness import program, spec
@@ -480,6 +523,7 @@ def test_latent_serve_programs_fit_one_chip_at_the_cells_shapes(topo, program):
                                         prompt=longest)
     assert pool == (21, slots * 64 + 1, 16, 640)
     compiled = lowered.compile()
+    assert_each_assignment_once(compiled, GLM_47, program)
     memory = compiled.memory_analysis()
     assert 5.0e9 < memory.argument_size_in_bytes < 5.1e9
     assert memory.temp_size_in_bytes < (0.5e9 if program == "decode_megastep"
@@ -774,6 +818,7 @@ def test_two_pool_serve_programs_fit_one_chip_at_the_cells_shapes(topo,
     assert full == (4, slots * 256 + 1, 16, 1024)
     assert window == (12, slots * 98 + 1, 16, 1024)
     compiled = lowered.compile()
+    assert_each_assignment_once(compiled, MELLUM, program)
     memory = compiled.memory_analysis()
     pools = 2 * (np.prod(full) + np.prod(window))
     assert 4.07e9 + pools < memory.argument_size_in_bytes < 4.10e9 + pools
@@ -886,6 +931,7 @@ def test_sparse_latent_serve_programs_fit_one_chip_at_the_cells_shapes(
     started = time.perf_counter()
     compiled = lowered.compile()
     assert time.perf_counter() - started < 240      # a cold start pays it
+    assert_each_assignment_once(compiled, GLM_52, program)
     memory = compiled.memory_analysis()
     pools = 2 * (np.prod(latent) + np.prod(index))
     assert 5.34e9 + pools < memory.argument_size_in_bytes < 5.36e9 + pools

@@ -27,7 +27,9 @@ from distributed_tensorflow_tpu.models.glm4_moe_lite import (
     Glm4MoeLite, Glm4MoeLiteConfig)
 from distributed_tensorflow_tpu.models.gpt2 import PagedKVConfig
 from distributed_tensorflow_tpu.obs.metrics import default_registry
+from distributed_tensorflow_tpu.ops import grouped_matmul
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
+from tests.helpers import expert_forms_on_record
 
 EXACT = precision.Exact()
 
@@ -286,6 +288,150 @@ def test_no_token_is_dropped_when_every_token_takes_one_expert():
     assert not np.asarray(none).any()
 
 
+# -- each assignment once, against every held expert over every token ---------
+
+def every_expert_layer(cfg, p, x, live):
+    """The plain form of ``expert_layer``, kept here as its reference: every
+    held expert over every token in float32 at the highest precision,
+    weighed by gates that are zero where the router chose otherwise; and
+    the ``moe_counts`` row counted as the parent counted it."""
+    exact = dict(precision=jax.lax.Precision.HIGHEST)
+    wide = lambda a: a.astype(jnp.float32)
+    chosen, weights = glm.route(cfg, p["router"], x)
+    held = cfg.first_expert + jnp.arange(cfg.held, dtype=chosen.dtype)
+    hit = chosen[:, :, None] == held[None, None, :]
+    gates = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), axis=1)
+    xd = x.astype(cfg.dtype)
+    g, u = jnp.split(jnp.einsum(
+        "nd,egd->eng", wide(xd), wide(p["experts"]["gate_up"]["kernel"]),
+        **exact), 2, axis=-1)
+    each = jnp.einsum(
+        "enf,efd->end", wide((jax.nn.silu(g) * u).astype(cfg.dtype)),
+        wide(p["experts"]["down"]["kernel"]), **exact)
+    y = jnp.sum(gates.T[:, :, None] * each, axis=0)
+    if "shared" in p:
+        y = y + glm.gated_mlp(p["shared"], xd, cfg.dtype)
+    counted = live.astype(jnp.int32)
+    tokens = jnp.sum(hit.any(axis=1) * counted[:, None], axis=0)
+    extra = [cfg.num_experts_per_tok * counted.sum() - tokens.sum(),
+             (tokens > 0).sum(), counted.sum() > 0]
+    return y, jnp.concatenate([tokens, jnp.stack(extra)]).astype(jnp.int32)
+
+
+def router_family(name, **share):
+    """A toy configuration with the family's router (``cfg.router``, its
+    scale and its norm), eight experts, two a token."""
+    if name == "mellum":
+        from distributed_tensorflow_tpu.models.mellum import MellumConfig
+        return MellumConfig.tiny(dtype=jnp.float32, **share)
+    if name == "glm_moe_dsa":
+        from distributed_tensorflow_tpu.models.glm_moe_dsa import (
+            GlmMoeDsaConfig)
+        return GlmMoeDsaConfig.tiny(dtype=jnp.float32, **share)
+    return tiny(**share)
+
+
+def toy_expert_layer(cfg, pinned, seed=11):
+    """One layer's leaves at the toy widths (64 wide, experts of 32).  The
+    first input feature is 1 for every token, so the router's first row adds
+    ``pinned``'s (expert, logit) pairs to every token's logits."""
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(
+        0.05 * rng.normal(size=shape), jnp.float32)
+    kernel = np.array(draw(64, 8))
+    kernel[0] = 0.0
+    for expert, logit in pinned:
+        kernel[0, expert] = logit
+    p = {"router": {"kernel": jnp.asarray(kernel)},
+         "experts": {"gate_up": {"kernel": draw(cfg.held, 64, 64)},
+                     "down": {"kernel": draw(cfg.held, 32, 64)}}}
+    if cfg.router == "sigmoid_bias":
+        p["router"]["bias"] = draw(8)
+        p["shared"] = {"gate_up": {"kernel": draw(64, 64)},
+                       "down": {"kernel": draw(32, 64)}}
+    return p
+
+
+# (tokens, held experts, first held, (expert, logit) pairs, share alive)
+GROUPED_CASES = {
+    # Expert 3 takes every row: two row tiles of it, the second part full.
+    "an_expert_given_every_row": (200, 4, 2, ((3, 40.0),), 1.0),
+    "an_expert_given_none": (200, 4, 2, ((4, -40.0),), 1.0),
+    # What a decode step of the sixth cell mostly sees.
+    "no_row_on_any_held_expert": (
+        200, 4, 2, tuple((e, -40.0) for e in (2, 3, 4, 5)), 1.0),
+    "one_token": (1, 4, 2, (), 1.0),
+    "tokens_not_a_multiple_of_the_row_tile": (40, 4, 2, (), 1.0),
+    "a_live_mask": (200, 4, 2, (), 0.6),
+    "no_token_alive": (200, 4, 2, (), 0.0),
+    "every_expert_held": (200, 8, 0, (), 1.0),
+}
+_grouped_layers = {}
+
+
+# A shape of its own is a trace of its own (a second each), so the cases
+# that have one meet this family's router and the kernels; the others meet
+# all three routers and both implementations (the serving tests of the
+# three families run the plain one at their own shapes all day).
+OWN_SHAPE = ("one_token", "tokens_not_a_multiple_of_the_row_tile",
+             "every_expert_held")
+
+
+@pytest.mark.parametrize("family,kernels,case", [
+    (family, kernels, case)
+    for family, kernels in (("glm4_moe_lite", False), ("mellum", False),
+                            ("glm_moe_dsa", False), ("glm4_moe_lite", True))
+    for case in GROUPED_CASES
+    if kernels or case not in OWN_SHAPE])
+def test_each_assignment_once_is_every_expert_over_every_token(
+        family, kernels, case, monkeypatch):
+    """The grouped form (``ops/grouped_matmul.py``: off the TPU by
+    ``jax.lax.ragged_dot``, and with ``kernels`` by its two Pallas kernels
+    in the interpreter) gives what every held expert over every token
+    gives, for each family's router, and counts the same row."""
+    n, held, first, pinned, alive = GROUPED_CASES[case]
+    cfg = router_family(family, experts_held=held, first_expert=first)
+    if kernels:
+        monkeypatch.setenv("DTT_PALLAS_INTERPRET", "1")
+    # Whatever form a call of this shape would take in a served program.
+    monkeypatch.setattr(glm, "expert_form",
+                        lambda *shape: grouped_matmul.GROUPED)
+    key = (family, kernels, n, held)
+    if key not in _grouped_layers:      # one trace a shape, not one a case
+        _grouped_layers[key] = jax.jit(lambda p, x, live: (
+            glm.expert_layer(cfg, p, x, live),
+            every_expert_layer(cfg, p, x, live)))
+    p = toy_expert_layer(cfg, pinned)
+    rng = np.random.default_rng(n)
+    x = np.asarray(rng.normal(size=(n, 64)), np.float32)
+    x[:, 0] = 1.0
+    live = jnp.asarray(rng.random(n) < alive)
+    with grouped_matmul.record_forms(forms := {}, "layer"):
+        (y, row), (want, want_row) = _grouped_layers[key](
+            p, jnp.asarray(x), live)
+    if forms:                           # the call that traced
+        tm = grouped_matmul.tile_rows(n)
+        assert forms == {f"layer/{n}": (grouped_matmul.GROUPED, tm * min(
+            held * -(-n // tm), n * min(2, held) // tm + held))}
+    np.testing.assert_array_equal(np.asarray(row), np.asarray(want_row))
+    counted = np.asarray(row)
+    if case == "an_expert_given_every_row":
+        assert counted[3 - first] == n
+    if case == "an_expert_given_none":
+        assert counted[4 - first] == 0 and counted[:held].sum() > 0
+    if case in ("no_row_on_any_held_expert", "no_token_alive"):
+        assert not counted[:held].any()
+    mask = np.asarray(live)
+    np.testing.assert_allclose(np.asarray(y)[mask], np.asarray(want)[mask],
+                               atol=2e-5)
+    # A token that does not count is given no row: its routed part is 0.
+    shared = (np.asarray(glm.gated_mlp(p["shared"], jnp.asarray(x),
+                                       jnp.float32))
+              if "shared" in p else 0.0)
+    np.testing.assert_allclose((np.asarray(y) - shared)[~mask], 0.0,
+                               atol=2e-5)
+
+
 # -- through the engine and the scheduler --------------------------------------
 
 SERVED = tiny(experts_held=4, first_expert=2)
@@ -386,6 +532,21 @@ def test_decode_counts_the_routers_choices(engine):
     seq = jnp.asarray(np.concatenate([prompt, answer])[None, :-1])
     here = _choices_here(SERVED, engine.params, seq)[len(prompt):]
     assert stats["moe_assignments_here"] == here.sum()
+
+
+def test_stats_name_the_form_each_programs_expert_layers_took(engine):
+    """A prompt of 16 in one prefill launch (16 tokens of 2-of-8: every
+    held expert is all but sure of a row, the dense form) and 2 slots a
+    decode step (the grouped form)."""
+    prompt, new = served_requests(SERVED)[0]
+    with ContinuousScheduler(
+            engine, num_slots=2, max_total_len=64, cache_mode="paged",
+            block_size=16, megastep=4) as sched:
+        sched.submit(prompt, max_new_tokens=new).result(timeout=300)
+        expert_forms_on_record(sched, experts=SERVED.n_routed_experts,
+                               chunk=len(prompt))
+        assert set(sched.stats()["moe_expert_form"].values()) == {
+            grouped_matmul.GROUPED, grouped_matmul.DENSE}
 
 
 def _choices_here(cfg, params, tokens):

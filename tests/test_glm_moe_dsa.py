@@ -31,6 +31,7 @@ from distributed_tensorflow_tpu.models.glm_moe_dsa import (
     GlmMoeDsa, GlmMoeDsaConfig)
 from distributed_tensorflow_tpu.obs.metrics import default_registry
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
+from tests.helpers import expert_forms_on_record
 
 EXACT = precision.Exact()
 
@@ -486,6 +487,18 @@ def test_scheduler_serves_the_reference_best_tokens(engine, megastep,
     assert set(paths["slot_prefill"]) == {dsa.MASKED}
     assert set(paths["slot_megastep"]) == {dsa.SELECTED}
     assert engine.decode_attention_launches()[dsa.SELECTED] > 0
+
+
+def test_stats_name_the_form_each_programs_expert_layers_took(engine):
+    """Chunks of 16 tokens of 2-of-8 (the dense form) and 4 slots a decode
+    step (the grouped form), through both pools."""
+    prompt, new = served_requests(SERVED)[0]
+    with ContinuousScheduler(
+            engine, num_slots=4, max_total_len=96, cache_mode="paged",
+            block_size=16, megastep=4, prefill_budget=16) as sched:
+        sched.submit(prompt, max_new_tokens=new).result(timeout=300)
+        expert_forms_on_record(sched, experts=SERVED.n_routed_experts,
+                               chunk=16)
 
 
 def test_scheduler_counts_what_a_decode_step_reads(engine):

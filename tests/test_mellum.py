@@ -30,6 +30,7 @@ from distributed_tensorflow_tpu.models.glm4_moe_lite import route
 from distributed_tensorflow_tpu.models.mellum import Mellum, MellumConfig
 from distributed_tensorflow_tpu.obs.metrics import default_registry
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
+from tests.helpers import expert_forms_on_record
 
 EXACT = precision.Exact()
 PUBLISHED_ROPE = {
@@ -500,6 +501,17 @@ def test_scheduler_serves_the_reference_best_tokens(request, monkeypatch,
                 in engine.decode_attention_launches().items()}
     assert {path for path, n in launches.items() if n} == decode
     assert len({launches[path] for path in decode}) == 1
+
+
+def test_stats_name_the_form_each_programs_expert_layers_took(engine):
+    """Chunks of 16 tokens of 2-of-8 (the dense form) and 2 slots a decode
+    step (the grouped form), through both pools."""
+    prompt = np.random.default_rng(2).integers(
+        0, SERVED.vocab_size, 2 * CHUNK, dtype=np.int32)
+    with scheduler(engine) as sched:
+        sched.submit(prompt, max_new_tokens=6).result(timeout=300)
+        expert_forms_on_record(sched, experts=SERVED.num_experts,
+                               chunk=CHUNK)
 
 
 def _held(sched):

@@ -288,6 +288,7 @@ _REGISTRY = {
     "glm4_moe_lite": "distributed_tensorflow_tpu.models.glm4_moe_lite",
     "mellum": "distributed_tensorflow_tpu.models.mellum",
     "glm_moe_dsa": "distributed_tensorflow_tpu.models.glm_moe_dsa",
+    "solar_open2": "distributed_tensorflow_tpu.models.solar_open2",
     "wide_deep": "distributed_tensorflow_tpu.models.wide_deep",
 }
 

@@ -310,6 +310,15 @@ def _continuous_instruments(registry=None):
             "second pool under the latent pool's block table (a block of "
             "the table is one of each)",
             labelnames=("kind",)),
+        "state_bytes_held": r.gauge(
+            "dtt_serve_state_bytes_held",
+            "Bytes of per-slot recurrent state (a family's linear-attention "
+            "layers: whatever the row's length) that live and prefilling "
+            "slots hold"),
+        "state_resets": r.counter(
+            "dtt_serve_state_resets_total",
+            "Admissions whose first prefill chunk started a slot's "
+            "recurrent state from zero"),
         "window_recycled": r.counter(
             "dtt_serve_window_blocks_recycled_total",
             "Blocks of a row's positions written over a ring entry whose "
@@ -771,6 +780,10 @@ class ContinuousScheduler:
             # many of a row's positions a layer reads at the most.
             self._selected = int(
                 self._kv_geometry.get("selected_positions", 0))
+            # A family with recurrent layers says what a slot's state
+            # costs, whatever the row's length.
+            self._state_bytes = int(
+                self._kv_geometry.get("state_bytes_per_slot", 0))
             ring = 0
             if self._window:
                 chunk = self.prefill_budget or self.max_total_len
@@ -816,6 +829,7 @@ class ContinuousScheduler:
             self.paged = None
             self._window = 0
             self._selected = 0
+            self._state_bytes = 0
             self._allocator = None
             self._block_tables = None
             self._slot_blocks = {}
@@ -1004,6 +1018,8 @@ class ContinuousScheduler:
         # The same, each row counted up to the selection (the latent rows a
         # layer's attention reads where a learned indexer selects them).
         self._live_selected_positions_sum = 0
+        # Admissions that started a slot's recurrent state from zero.
+        self._state_resets = 0
         self._last_occupancy = 0
         self._latencies_ms: collections.deque = collections.deque(maxlen=1024)
         self._ttft_ms: collections.deque = collections.deque(maxlen=1024)
@@ -2014,7 +2030,7 @@ class ContinuousScheduler:
                 self._preempt_recompute += 1
             self._preempted.append(req)
             self._obs["preemptions"].inc()
-            self._obs["active_slots"].set(len(self._active))
+            self._note_active_locked()
             self._cond.notify_all()
         logger.debug(
             "preempted request %d from slot %d (%s, %d token(s) emitted)",
@@ -2080,7 +2096,7 @@ class ContinuousScheduler:
             self._active[slot] = req
             self._obs["admissions"].inc()
             self._obs["swap_in_bytes"].inc(int(entry.bytes))
-            self._obs["active_slots"].set(len(self._active))
+            self._note_active_locked()
         if self._lifecycle is not None:
             self._lifecycle.record(
                 req.rid, "RESUMED", path="swap",
@@ -2152,6 +2168,13 @@ class ContinuousScheduler:
             self._allocator.used_count)
         self._obs["kv_blocks_held"].labels(kind="window").set(window)
 
+    def _note_active_locked(self) -> None:
+        """The resident set changed.  Call under ``_lock``."""
+        self._obs["active_slots"].set(len(self._active))
+        if self._state_bytes:
+            self._obs["state_bytes_held"].set(
+                len(self._active) * self._state_bytes)
+
     def _note_index_blocks_held(self) -> None:
         """Where index keys lie in a second pool under the latent pool's
         table, a block the allocator hands out is one block of each: the
@@ -2175,6 +2198,22 @@ class ContinuousScheduler:
             return {}
         g = self._kv_geometry
         iters = self._iterations
+        if self._state_bytes:
+            # Bytes a slot (the recurrent state) beside bytes a token (the
+            # K/V layers' pool, in ``kv_bytes_held``).
+            return {
+                "state_bytes_per_slot": float(self._state_bytes),
+                # Rows a decode launch ran, mean: each reads and writes
+                # its state once a step.
+                "state_slots_live": (self._occupancy_sum / iters
+                                     if iters else 0.0),
+                "state_bytes_held": float(
+                    len(self._active) * self._state_bytes),
+                "state_resets": float(self._state_resets),
+                "kv_bytes_held": float(
+                    self._allocator.used_count * self.block_size
+                    * g["bytes_per_token"]),
+            }
         if g.get("index_block_bytes"):
             # Index keys beside the latent pool, under one table: a block
             # held is one of each.
@@ -2352,7 +2391,7 @@ class ContinuousScheduler:
                 self._queue_wait_ms.append(queue_wait_s * 1000.0)
                 self._obs["admissions"].inc()
                 self._obs["queue_wait"].observe(queue_wait_s)
-                self._obs["active_slots"].set(len(self._active))
+                self._note_active_locked()
                 self._obs["prefilling_slots"].set(self._prefilling)
                 self._obs["prefill_backlog"].set(self._prefill_backlog)
             if self._lifecycle is not None:
@@ -2440,6 +2479,11 @@ class ContinuousScheduler:
                             start_offsets=[off] if off else None,
                             **self._paged_call_kwargs()))
                 spent += chunk
+                if self._state_bytes and not off:
+                    # The family starts a row at position 0 from a zero
+                    # state, whatever the slot's last occupant left.
+                    self._state_resets += 1
+                    self._obs["state_resets"].inc()
                 req.next_prefill_offset = off + chunk
                 req.prefill_chunks += 1
                 if (self._lifecycle is not None
@@ -3597,7 +3641,7 @@ class ContinuousScheduler:
             queued = len(self._queue)
             self._retired += 1
             self._obs["retirements"].inc()
-            self._obs["active_slots"].set(len(self._active))
+            self._note_active_locked()
             if was_cancelled:
                 self._cancelled += 1
                 self._obs["cancelled"].inc()

@@ -221,12 +221,15 @@ class ServeArgs:
 # smoke's config, the chip's).  For glm4_moe_lite the chip's is one chip's
 # share of an 8-chip deployment (the whole model is 60 GB in bfloat16), for
 # mellum one chip's share of a 4-chip host (24 GB whole), for glm_moe_dsa
-# one chip's share of a v5e-256 (32 chips a layer, 5 of a stage's layers).
+# one chip's share of a v5e-256 (32 chips a layer, 5 of a stage's layers),
+# for solar_open2 one chip's share of a v5e-128 (16 chips a layer, one
+# period of 4 layers).
 # Every other model is batched classification.
 _AUTO_PRESETS = {"gpt2": ("tiny", "medium"),
                  "glm4_moe_lite": ("tiny", "v5e8_share"),
                  "mellum": ("tiny", "v5e4_share"),
-                 "glm_moe_dsa": ("tiny", "v5e256_share")}
+                 "glm_moe_dsa": ("tiny", "v5e256_share"),
+                 "solar_open2": ("tiny", "v5e128_share")}
 DECODER_MODELS = tuple(_AUTO_PRESETS)
 
 

@@ -759,9 +759,9 @@ class ServeEngine:
         a classic full prefill; prefix caching passes each slot's
         block-aligned first UNCACHED position so the suffix prefill
         writes (and positions) from there, attending over the mapped
-        cached blocks below it.  K/V rows need no zeroing: the causal
-        mask hides everything past the reset index, and prefill
-        overwrites from ``start``."""
+        cached blocks below it.  K/V rows need no zeroing (the causal mask
+        hides what lies past the reset index); a per-slot recurrent state
+        is zeroed by its family at position 0 (``models/solar_open2``)."""
         def _one(path, leaf):
             name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
             if name in ("cache_index", "position"):
@@ -901,9 +901,9 @@ class ServeEngine:
 
         # Active-mask: empty slots are free compute — the step runs over
         # all (num_slots, 1) rows, but inactive slots' index rows must not
-        # advance (their state stays exactly as retirement left it; the
-        # garbage K/V an inactive row writes sits beyond its frozen index,
-        # so the causal mask never admits it).
+        # advance (the garbage K/V an inactive row writes sits beyond its
+        # frozen index, where the causal mask never admits it; a per-slot
+        # recurrent state is kept by its family, told ``active`` as live).
         def _gate(path, new, old):
             name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
             if name in ("cache_index", "position"):
@@ -1850,9 +1850,11 @@ def moe_counts_of(cache: PyTree):
 # grouped-query family's four (a program of its has a window and a full
 # path, both by the gather or both by the kernel), the learned sparse
 # attention's two (a decode step over the selected rows, a chunk under the
-# selection's mask).
+# selection's mask), the linear attention's two (the chunk-wise rule, the
+# one-step rule).
 _DECODE_PATHS = (paged_attention.KERNEL, paged_attention.GATHER,
                  "latent_absorbed", "latent_expanded",
                  "gqa_gather_window", "gqa_gather_full",
                  ) + paged_attention.GQA_KERNEL_PATHS + (
-                 "latent_sparse_selected", "latent_sparse_masked")
+                 "latent_sparse_selected", "latent_sparse_masked",
+                 "kda_chunk", "kda_step")

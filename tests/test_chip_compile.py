@@ -963,3 +963,123 @@ def test_sparse_latent_serve_programs_fit_one_chip_at_the_cells_shapes(
     else:
         assert (1, 1024, 640) in shapes          # a turn's latents
         assert chunk in (512, 1024)
+
+
+# -- the linear-attention family at its cell's shapes --------------------------
+
+def lower_recurrent_program(topo, program):
+    """``serve.solar-open2-250b.report-saturated``'s decode or prefill
+    program, lowered from shapes as ``lower_serve_program`` does, at the
+    cell's own slots, lengths and chunk."""
+    from benchmark.harness import program as program_lib, spec
+    from distributed_tensorflow_tpu.models import PagedKVConfig
+    from distributed_tensorflow_tpu.models.solar_open2 import SolarOpen2
+    from distributed_tensorflow_tpu.serve import sampling as sampling_lib
+    from distributed_tensorflow_tpu.serve.engine import ServeEngine
+
+    cell = spec.load_cell("serve.solar-open2-250b.report-saturated")
+    sched = cell.cell["scheduler"]
+    slots, total, block = (sched["num_slots"], sched["max_total_len"],
+                           sched["block_size"])
+    chunk, steps = sched["prefill_budget"], sched["megastep"]
+    module = SolarOpen2(program_lib.program_config(cell.config))
+    engine = object.__new__(ServeEngine)
+    engine.module = module
+    per_slot = total // block
+    paged = PagedKVConfig(block_size=block, num_blocks=slots * per_slot + 1)
+
+    def arg(shape, dtype=jnp.int32):
+        return one_chip(topo, shape, dtype)
+
+    variables = jax.eval_shape(lambda: module.init(
+        jax.random.key(0), jnp.zeros((slots, total), jnp.int32),
+        decode=True, slot_ids=jnp.arange(slots, dtype=jnp.int32),
+        paged=paged, block_tables=jnp.zeros((slots, per_slot), jnp.int32)))
+    params, cache = jax.tree.map(
+        lambda s: arg(s.shape, s.dtype),
+        (variables["params"], variables["cache"]))
+    counts = arg((slots, module.cfg.vocab_size))
+    tables = arg((slots, per_slot))
+    rng = arg((), jax.random.key(0).dtype)
+    sampling = lambda rows: jax.tree.map(
+        lambda a: arg(np.shape(a), np.asarray(a).dtype),
+        sampling_lib.uniform(rows, 0.0, 0))
+    if program == "decode_megastep":
+        fn = jax.jit(
+            lambda *a: engine._megastep_counting_apply(steps, paged, *a),
+            donate_argnums=(1, 2))
+        lowered = fn.lower(
+            params, cache, counts, arg((slots,)), arg((slots,), jnp.bool_),
+            arg((slots,)), arg((slots,)), tables, rng, arg(()),
+            sampling(slots), arg((slots,)), arg((slots,), jnp.bool_),
+            arg(()))
+    else:
+        fn = jax.jit(
+            lambda *a: engine._prefill_slots_apply(paged, *a),
+            donate_argnums=(1, 2))
+        lowered = fn.lower(
+            params, cache, counts, arg((1, chunk)), arg((1,)), tables,
+            rng, arg(()), arg((1,)), sampling(1), arg((1,), jnp.bool_))
+    return lowered, cache, slots, chunk
+
+
+@pytest.mark.parametrize("program", ["decode_megastep", "prefill_slots"])
+def test_recurrent_serve_programs_fit_one_chip_at_the_cells_shapes(
+        topo, program):
+    """One chip's share of the v5e-128 deployment, published layers 0-3 at
+    the published widths: 4.10 GB of bfloat16 weights, the GQA layer's pool
+    of ``slots x 320 + 1`` blocks (4 KB a position) and the three linear
+    layers' per-slot state, float32 ``(3, slots, 64, 128, 128)``, 12.6 MB
+    a slot, with the convolution's tails beside it.
+
+    The state is what a step OVERWRITES: the decode program (4 fused
+    steps, each reading every slot's state and writing it back) may hold
+    no second copy of it.  Its scratch stays under one layer's state and
+    under the largest leaf of a layer (the ``qkv`` kernel, 201 MB): a state
+    gated outside its update, gathered by slot, or a layer's leaves sliced
+    out of the stack and copied a step (ROADMAP lesson (iv)) fails here,
+    on the CPU.  A prefill chunk's scratch is the chunk-wise rule's
+    operands for 1,024 positions (float32 ``(16, 64, 64, .)`` a tensor)
+    and the chunk's float32 projections."""
+    import time
+
+    lowered, cache, slots, chunk = lower_recurrent_program(topo, program)
+    pool = cache["full_pool"].shape
+    assert pool == (1, slots * 320 + 1, 16, 2048)
+    assert cache["kda_state"].shape == (3, slots, 64, 128, 128)
+    assert cache["kda_state"].dtype == jnp.float32
+    assert cache["kda_conv"].shape == (3, slots, 3, 24576)
+    started = time.perf_counter()
+    compiled = lowered.compile()
+    assert time.perf_counter() - started < 240      # a cold start pays it
+    memory = compiled.memory_analysis()
+    layer_state = slots * 64 * 128 * 128 * 4
+    held = (2 * np.prod(pool) + 3 * layer_state + 2 * 3 * slots * 3 * 24576
+            + 4 * slots * 24576)
+    assert 4.09e9 + held < memory.argument_size_in_bytes < 4.12e9 + held
+    if program == "decode_megastep":
+        assert memory.temp_size_in_bytes < min(layer_state, 0.2e9)
+    else:
+        assert memory.temp_size_in_bytes < 1.6e9
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < V5E_HBM_BYTES
+    hlo = compiled.as_text()
+    assert " scatter(" in hlo
+    for name, op, line in pool_sized_results(hlo, pool):
+        assert op in ("parameter", "get-tuple-element", "bitcast",
+                      "scatter") or (
+            op == "fusion" and " scatter(" in fused_computation(
+                hlo, line)), f"%{name} is a pool-sized {op}"
+    assert not kernel_calls(hlo)        # no flash kernel in a serving step
+    shapes = {tuple(int(n) for n in dims.split(","))
+              for dims in re.findall(r"= bf16\[([\d,]+)\]", hlo)}
+    # No slot's whole table row of K/V gathered for a decode step (the
+    # block-table kernel reads the pool where it lies); a chunk gathers its
+    # one row.
+    whole_rows = {(slots * 320, 16, 2048), (slots, 5120, 2048)}
+    assert not shapes & whole_rows, shapes & whole_rows
+    if program == "decode_megastep":
+        assert "tpu_custom_call" in hlo
+        assert (320, 16, 2048) not in shapes
+    else:
+        assert (320, 16, 2048) in shapes and chunk == 1024

@@ -37,8 +37,30 @@ Design (round-4 schedule — FlashAttention-2 style grid streaming):
     from the stored LSE (no (T,T) buffer anywhere).
   * dK/dV: inner dim streams QUERY blocks (q/o/g/lse arrive (block_q, ·)
     at a time); each program owns one key block's dk/dv tile.
-  Both compute Δ = rowsum(dO ∘ O) from the saved output per q tile and use
-  dS = P ∘ (dP − Δ) · scale.
+  Both use dS = P ∘ (dP − Δ) · scale with Δ = rowsum(dO ∘ O): the streaming
+  kernels compute Δ from the saved output per q tile; for the resident
+  kernels XLA computes it once a call, one f32 a query, and both take it
+  as the (1, T) row the log-sum-exp is (``o`` is not their operand).
+- The resident schedule (every T whose windows fit VMEM, which is every
+  shape a cell runs) computes the causal triangle and little more
+  (``schedule``): tiles wholly under the diagonal take no mask at all; the
+  tile the diagonal crosses is walked in 128-wide pieces (dQ: 256),
+  statically unrolled, each against only the rows at or under it, and only
+  the squares the diagonal itself crosses take a mask (a local
+  ``row >= col`` compare, hoisted).  At T = 1024 that is 0.5625 of T² (dQ:
+  0.625) where whole 512-tiles were 0.75 (the triangle is 0.5).  The
+  forward updates its running maximum and sum once a tile, not once a
+  piece: a (rows, 1) column costs as many vregs as a (rows, 128) piece.  Non-causal calls and
+  T <= 128 keep one body a tile.  What the inputs show is not needed is
+  not computed: the -inf guards of the running maximum only under a
+  ``kv_mask`` (no other row can be without a valid key), and ``scale`` is
+  folded into q (or k, and the (block, D) result) once a block where that
+  is exact (a power of two, as 1/8 for head size 64), else it is applied
+  to the f32 tile.
+- The resident dK/dV kernel builds its score tile transposed, sᵀ = k · qᵀ:
+  pᵀ and dsᵀ are then the left operands of plain products (dV += pᵀ · dO,
+  dK += dsᵀ · q), and the log-sum-exp and Δ are wanted as the rows they
+  are stored as, broadcast along sublanes; no column is made in its loop.
 - Attention-probability dropout (the reference models' training recipe —
   TF's fused attention keeps it; round 3 silently dropped it on the flash
   path): implemented IN-KERNEL with the TPU PRNG
@@ -65,9 +87,10 @@ Design (round-4 schedule — FlashAttention-2 style grid streaming):
   recomputed.  The dense fallback has nothing under the names and keeps
   whole-layer remat.
 - The log-sum-exp is one f32 a query in HBM: a (1, T) row a head, which the
-  forward kernel writes and the backward kernels read by (1, block_q)
-  blocks and turn into the (block_q, 1) column a score tile needs with a
-  transpose in VMEM (``_row``/``_col``).  Broadcast over the 128 lanes, as
+  forward kernel writes (``_row``: a transpose in VMEM of the (block_q, 1)
+  column its tile has) and the backward kernels read by (1, block_q)
+  blocks; dQ turns it and Δ into columns once a query block (``_col``),
+  dK/dV takes them as they lie.  Broadcast over the 128 lanes, as
   the kernels once wrote it, it was 67 MB a call for GPT-2 medium at
   8 x 1024, four times the attention output and half of what the three
   calls moved through HBM, and far too much to keep from one pass to the
@@ -91,7 +114,7 @@ import functools
 import logging
 import math
 import os
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -102,14 +125,21 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 logger = logging.getLogger(__name__)
 
-# Block sizes: 512x512 measured best on v5e for the GPT-2 shapes (B=16,
-# T=1024, H=16, D=64) in a round of measurements that predates the current
-# chip attachment: the 128-blocks' (128, 64) x (64, 128) matmuls underfeed
-# the MXU pipeline; 512-blocks amortize the per-iteration VPU work
-# (exp/mask) over 16x the MACs.
-# Shorter sequences clamp to T (min below), so small models are unaffected.
-BLOCK_Q = int(os.environ.get("DTT_FLASH_BLOCK_Q", "512"))
-BLOCK_K = int(os.environ.get("DTT_FLASH_BLOCK_K", "512"))
+# Block sizes, measured on a v5e at GPT-2's shape (8 x 1024 x 16 x 64, causal,
+# bf16; PR 43, per call of 128 heads): with 1024 a sequence of 1024 is ONE
+# block a head, its whole triangle walked in 128-wide pieces and nothing
+# looped: forward 0.276 ms, dQ 0.439 (0.384 with DQ_PIECE), dK/dV 0.441.  The
+# same kernels (dQ in 128-pieces, as in the rest of this comment) in 512
+# blocks (one unmasked 512 x 512 tile in a loop beside two walked ones) take
+# 0.467, 0.528 and 0.576, in 256 blocks 0.819, 0.855 and 0.800; the whole
+# 512 x 512 tiles they replaced took 0.592, 0.597 and 0.866.  A (512, 512)
+# f32 tile is 256 vregs of a file of 64; a (rows, 128) piece is worked
+# through while the next one's product is in the MXU.  Shorter sequences
+# clamp to T (`_fit_block`), so small models are unaffected; a longer one
+# gets 1024-blocks, whose unmasked tiles are whole (PERF.md, Findings,
+# PR 43 has what T = 2048-8192 read).
+BLOCK_Q = int(os.environ.get("DTT_FLASH_BLOCK_Q", "1024"))
+BLOCK_K = int(os.environ.get("DTT_FLASH_BLOCK_K", "1024"))
 LANES = 128  # Mosaic minimum lane tile
 
 # What a remat round the kernel keeps of it: the forward rules name the
@@ -229,12 +259,14 @@ def _tile_dropout(seed_ref, b, qi, kj, shape, rate):
     return jnp.where(keep, 1.0 / (1.0 - rate), 0.0)
 
 
-def _causal_tile_mask(s, qi, kj, block_q, block_k):
+def _causal_tile_mask(s, qi, kj, block_q, block_k, transposed=False):
+    """``s`` with -inf above the diagonal: a (block_q, block_k) tile of
+    q-block ``qi`` and k-block ``kj``, or that tile transposed."""
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
+        jnp.int32, s.shape, 1 if transposed else 0
     )
     k_pos = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
+        jnp.int32, s.shape, 0 if transposed else 1
     )
     return jnp.where(q_pos >= k_pos, s, -jnp.inf)
 
@@ -318,18 +350,128 @@ def _fwd_kernel(*refs, causal, scale, block_q, block_k, save_lse,
 
 
 # ---------------------------------------------------------------------------
-# Resident-schedule kernels: the whole loop operand (K/V for fwd+dQ, nothing
-# extra for dK/dV, which streams) stays in VMEM and the kernel iterates it
-# with an in-register fori_loop.  Measured faster than the streaming grid at
-# production T (T=1024, v5e) in a round that predates the current chip
-# attachment: loop carries live in vector registers instead of scratch
-# round-trips and there is no per-block grid prologue.  Chosen by `_resident_*_bytes` when
-# the windows fit; the streaming kernels above are the long-T schedule.
+# Resident-schedule kernels: the whole loop operand (K/V for fwd+dQ; q, dO and
+# the two statistics rows for dK/dV) stays in VMEM and the kernel iterates it
+# with an in-register fori_loop.  Chosen by `_resident_*_bytes` when the
+# windows fit; the streaming kernels above are the long-T schedule.
+#
+# A causal call computes the triangle and little more (`schedule`): tiles
+# wholly under the diagonal take no mask, and the tile the diagonal crosses is
+# walked in PIECE-wide pieces, statically unrolled, each against only the rows
+# at or under it, with a local `row >= col` compare on the PIECE x PIECE
+# squares the diagonal itself crosses and nowhere else.
 # ---------------------------------------------------------------------------
 
+# Width of the pieces the diagonal's tile is walked in.  dQ takes wider ones:
+# its statistics are (rows, 1) columns, each broadcast across a piece's lanes,
+# and at 256 the broadcast serves two lane tiles (measured on a v5e, PR 43,
+# 128 heads of 1024 x 64 a call: dQ 0.439 ms in 128-pieces, 0.384 in 256,
+# 0.416 in 512; the forward, whose columns are made once a tile, 0.276,
+# 0.281, 0.297; dK/dV, which has no column, 0.441, 0.459, 0.547).
+PIECE = LANES
+DQ_PIECE = 2 * LANES
 
-def _fwd_kernel_resident(*refs, seq_len, causal, scale, block_q, block_k,
-                         save_lse, has_mask, dropout_rate):
+class Schedule(NamedTuple):
+    """What of the (T, T) score matrix one head's resident kernels compute.
+    The kernels' loops and the tests both read it; ``share`` is the part of
+    T² that is computed (the causal triangle itself is 0.5 + 0.5 / T)."""
+    block_q: int
+    block_k: int
+    piece: int            # 0: the diagonal's tile is not walked
+    unmasked_tiles: int   # whole tiles that take no positional mask
+    masked_tiles: int     # whole tiles under the positional mask
+    diagonal_pieces: int  # pieces of the walked tiles, over all of them
+    share: float
+
+
+def schedule(T: int, block_q: int, block_k: int, causal: bool,
+             piece: int = PIECE) -> Schedule:
+    """The rule is in what a call shows: causal, square blocks of more than
+    one ``piece``.  Everything else (non-causal, T <= 128, blocks that
+    differ) keeps one body a tile, under the positional mask where causal."""
+    nq, nk = -(-T // block_q), -(-T // block_k)
+    if not causal:
+        return Schedule(block_q, block_k, 0, nq * nk, 0, 0, 1.0)
+    if not (block_q == block_k and block_q % piece == 0
+            and block_q > piece):
+        tiles = sum(min(((i + 1) * block_q - 1) // block_k + 1, nk)
+                    for i in range(nq))
+        return Schedule(block_q, block_k, 0, 0, tiles, 0,
+                        tiles * block_q * block_k / float(T * T))
+    per_tile = block_q // piece
+    unmasked = nq * (nq - 1) // 2
+    walked = sum(block_q - lo for lo in range(0, block_q, piece)) * piece
+    return Schedule(
+        block_q, block_k, piece, unmasked, 0, nq * per_tile,
+        (unmasked * block_q * block_k + nq * walked) / float(T * T))
+
+
+_LOGGED_SCHEDULES = set()
+
+
+def _log_schedule(kernels: str, shape, sched: Schedule):
+    """One line a shape, at trace: what the kernels will compute of it."""
+    if (kernels, tuple(shape), sched) in _LOGGED_SCHEDULES:
+        return
+    _LOGGED_SCHEDULES.add((kernels, tuple(shape), sched))
+    logger.info(
+        "flash attention, %s: shape %s in %dx%d tiles: %d unmasked, %d under "
+        "the positional mask, %d diagonal pieces of %d; %.4f of T^2 computed",
+        kernels, tuple(shape), sched.block_q, sched.block_k,
+        sched.unmasked_tiles, sched.masked_tiles, sched.diagonal_pieces,
+        sched.piece, sched.share)
+
+
+def _exact_scale(scale: float) -> bool:
+    """Whether multiplying a bf16 operand by ``scale`` is exact (a power of
+    two, as 1/8 for head size 64), so that it can be folded into q or k once
+    a block in place of a pass over every f32 score tile."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _nt(a, b):
+    """a · bᵀ over the minor dimension of both, f32 out."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _lower_triangle(n, transposed=False):
+    """(n, n) bool: query >= key, queries in rows (in columns if transposed)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return col >= row if transposed else row >= col
+
+
+def _mask_square(s, under, lo=0):
+    """A piece's scores with -inf above the diagonal: only its rows from
+    ``lo`` on, as many as ``under`` has, hold the square the diagonal
+    crosses; the piece's other rows lie wholly under it."""
+    hi = lo + under.shape[0]
+    rows = [jnp.where(under, s[lo:hi], -jnp.inf)]
+    if lo:
+        rows.insert(0, s[:lo])
+    if hi < s.shape[0]:
+        rows.append(s[hi:])
+    return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
+
+
+def _rows_from(x, lo, new):
+    """x with its rows from ``lo`` on replaced by ``new``."""
+    return new if lo == 0 else jnp.concatenate([x[:lo], new], axis=0)
+
+
+def _rows_upto(x, hi, new):
+    """x with its rows up to ``hi`` replaced by ``new``."""
+    return new if hi == x.shape[0] else jnp.concatenate([new, x[hi:]], axis=0)
+
+
+def _fwd_kernel_resident(*refs, seq_len, sched, causal, scale, save_lse,
+                         has_mask, dropout_rate):
     from jax.experimental import pallas as pl
 
     refs = list(refs)
@@ -339,201 +481,287 @@ def _fwd_kernel_resident(*refs, seq_len, causal, scale, block_q, block_k,
     seed_ref = refs.pop(0) if dropout_rate > 0.0 else None
     o_ref = refs.pop(0)
     lse_ref = refs.pop(0) if save_lse else None
+    block_q, block_k, piece = sched.block_q, sched.block_k, sched.piece
     b = pl.program_id(0)
     qi = pl.program_id(1)
+    fold = _exact_scale(scale)
     q = q_ref[0]  # (block_q, D)
+    if fold:
+        q = q * scale
     D = q.shape[-1]
+    # A row with no kv_mask sees a key in the first block it is given, so
+    # its running maximum is finite from there on; only a kv_mask can leave
+    # a row with no valid key, and only then are the -inf guards needed.
+    guarded = has_mask
 
-    num_k_blocks = pl.cdiv(seq_len, block_k)
-    if causal:
-        # highest key block intersecting this q block's causal triangle
-        hi = ((qi + 1) * block_q - 1) // block_k + 1
-        hi = jnp.minimum(hi, num_k_blocks)
-    else:
-        hi = num_k_blocks
-
-    def body(j, carry):
+    def step(carry, parts):
+        """One online-softmax step over a tile given as its ``parts``, each
+        (lo, start, width, keep, drop): keys [start, start + width) against
+        the carry's rows from ``lo`` on.  A whole tile is one part; the
+        diagonal's tile is its pieces, and the statistics' columns are
+        still updated once for the tile, not once a piece (a (rows, 1)
+        column is as many vregs as a (rows, 128) piece)."""
         acc, m, l = carry
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (block_q, block_k) f32
-        if causal:
-            s = _causal_tile_mask(s, qi, j, block_q, block_k)
-        if has_mask:
-            m_blk = mask_ref[0, :, pl.ds(j * block_k, block_k)]
-            s = jnp.where(m_blk > 0, s, -jnp.inf)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe)
-        alpha = jnp.exp(jnp.where(jnp.isfinite(m), m - m_safe, -jnp.inf))
-        alpha = jnp.where(jnp.isfinite(alpha), alpha, 0.0)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if dropout_rate > 0.0:
-            p = p * _tile_dropout(seed_ref, b, qi, j,
-                                  (block_q, block_k), dropout_rate)
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return acc, m_safe, l
+        scores = []
+        for lo, start, width, keep, _ in parts:
+            s = _nt(q[lo:], k_ref[0, pl.ds(start, width), :])
+            if not fold:
+                s = s * scale
+            if keep is not None:
+                s = keep(s)
+            if has_mask:
+                s = jnp.where(mask_ref[0, :, pl.ds(start, width)] > 0, s,
+                              -jnp.inf)
+            scores.append(s)  # (rows, width) f32
+        top = scores[0]
+        for (lo, *_), s in zip(parts[1:], scores[1:]):
+            top = _rows_from(top, lo, jnp.maximum(top[lo:], s))
+        m_new = jnp.maximum(m, jnp.max(top, axis=-1, keepdims=True))
+        if guarded:
+            m_new = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            alpha = jnp.exp(jnp.where(jnp.isfinite(m), m - m_new, -jnp.inf))
+            alpha = jnp.where(jnp.isfinite(alpha), alpha, 0.0)
+        else:
+            alpha = jnp.exp(m - m_new)
+        m_wide = m_new
+        if len(parts) > 1:
+            m_wide = jnp.broadcast_to(m_new, (block_q, width))
+        total = pv = None
+        for (lo, start, width, _, drop), s in zip(parts, scores):
+            p = jnp.exp(s - m_wide[lo:])
+            total = p if lo == 0 else _rows_from(total, lo, total[lo:] + p)
+            # Softmax-dropout semantics: l sees UN-dropped p; only the PV
+            # contraction sees the dropped/rescaled probabilities.
+            if drop is not None:
+                p = p * drop
+            v_blk = v_ref[0, pl.ds(start, width), :]
+            x = _nn(p.astype(v_blk.dtype), v_blk)
+            pv = x if lo == 0 else _rows_from(pv, lo, pv[lo:] + x)
+        l = l * alpha + jnp.sum(total, axis=-1, keepdims=True)
+        return acc * alpha + pv, m_new, l
 
-    acc0 = jnp.zeros((block_q, D), jnp.float32)
-    m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, hi, body, (acc0, m0, l0))
-    l_safe = jnp.maximum(l, 1e-30)
+    def tile_drop(j):
+        if dropout_rate > 0.0:
+            return _tile_dropout(seed_ref, b, qi, j, (block_q, block_k),
+                                 dropout_rate)
+        return None
+
+    def body(j, carry, positional):
+        keep = None
+        if positional:
+            keep = lambda s: _causal_tile_mask(s, qi, j, block_q, block_k)
+        return step(carry, [(0, j * block_k, block_k, keep, tile_drop(j))])
+
+    carry = (jnp.zeros((block_q, D), jnp.float32),
+             jnp.full((block_q, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32))
+    if piece:
+        carry = jax.lax.fori_loop(
+            0, qi, functools.partial(body, positional=False), carry)
+        # The diagonal's tile: key pieces, each against the rows at or
+        # under it; the mask on the square the diagonal crosses.
+        keep = functools.partial(_mask_square, under=_lower_triangle(piece))
+        drop = tile_drop(qi)
+        carry = step(carry, [
+            (lo, qi * block_k + lo, piece, keep,
+             None if drop is None else drop[lo:, lo:lo + piece])
+            for lo in range(0, block_q, piece)])
+    else:
+        hi = pl.cdiv(seq_len, block_k)
+        if causal:
+            # highest key block intersecting this q block's causal triangle
+            hi = jnp.minimum(((qi + 1) * block_q - 1) // block_k + 1, hi)
+        carry = jax.lax.fori_loop(
+            0, hi, functools.partial(body, positional=causal), carry)
+    acc, m, l = carry
+    l_safe = jnp.maximum(l, 1e-30) if guarded else l
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
     if save_lse:
-        lse = jnp.where(l > 0, m + jnp.log(l_safe), -1e30)
+        lse = m + jnp.log(l_safe)
+        if guarded:
+            # Rows with zero valid keys (l == 0) get lse = -1e30, so a
+            # downstream exp(lse - anything) underflows to an exact no-op
+            # contribution (ring attention's cross-block combine).
+            lse = jnp.where(l > 0, lse, -1e30)
         lse_ref[0] = _row(lse)
 
 
-def _dq_kernel_resident(q_ref, k_ref, v_ref, o_ref, g_ref, lse_ref, *rest,
-                        seq_len, causal, scale, block_q, block_k,
-                        has_mask, has_glse, dropout_rate):
+def _dq_kernel_resident(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, *rest,
+                        seq_len, sched, causal, scale, has_mask,
+                        dropout_rate):
     from jax.experimental import pallas as pl
 
     rest = list(rest)
-    glse_ref = rest.pop(0) if has_glse else None
     mask_ref = rest.pop(0) if has_mask else None
     seed_ref = rest.pop(0) if dropout_rate > 0.0 else None
     dq_ref = rest.pop(0)
+    block_q, block_k, piece = sched.block_q, sched.block_k, sched.piece
     b = pl.program_id(0)
     qi = pl.program_id(1)
+    fold = _exact_scale(scale)
     q = q_ref[0]                              # (block_q, D), input dtype
+    if fold:
+        q = q * scale
     g = g_ref[0]                              # (block_q, D)
-    o = o_ref[0]                              # (block_q, D)
     lse = _col(lse_ref[0])                    # (block_q, 1)
-    delta = jnp.sum(                          # Δ = rowsum(dO ∘ O), f32
-        g.astype(jnp.float32) * o.astype(jnp.float32),
-        axis=-1, keepdims=True,
-    )
-    if has_glse:
-        delta = delta - _col(glse_ref[0])
+    delta = _col(delta_ref[0])                # Δ − g_lse, (block_q, 1)
     D = q.shape[-1]
 
-    num_k_blocks = pl.cdiv(seq_len, block_k)
-    if causal:
-        hi = ((qi + 1) * block_q - 1) // block_k + 1
-        hi = jnp.minimum(hi, num_k_blocks)
-    else:
-        hi = num_k_blocks
-
-    def body(j, dq_acc):
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            s = _causal_tile_mask(s, qi, j, block_q, block_k)
+    def tile(lo, start, width, keep, drop):
+        """What keys [start, start + width) add to dQ's rows from ``lo``
+        on: (rows, D) f32, without the scale where that is folded."""
+        k_blk = k_ref[0, pl.ds(start, width), :]
+        v_blk = v_ref[0, pl.ds(start, width), :]
+        s = _nt(q[lo:], k_blk)
+        if not fold:
+            s = s * scale
+        if keep is not None:
+            s = keep(s)
         if has_mask:
-            m_blk = mask_ref[0, :, pl.ds(j * block_k, block_k)]
-            s = jnp.where(m_blk > 0, s, -jnp.inf)
-        p = jnp.exp(s - lse)                  # masked -> exp(-inf) = 0
-        dp = jax.lax.dot_general(
-            g, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            s = jnp.where(mask_ref[0, :, pl.ds(start, width)] > 0, s,
+                          -jnp.inf)
+        p = jnp.exp(s - lse[lo:])             # masked -> exp(-inf) = 0
+        dp = _nt(g[lo:], v_blk)
+        if drop is not None:
+            dp = dp * drop
+        ds = p * (dp - delta[lo:])
+        if not fold:
+            ds = ds * scale
+        return _nn(ds.astype(k_blk.dtype), k_blk)
+
+    def tile_drop(j):
         if dropout_rate > 0.0:
-            dp = dp * _tile_dropout(seed_ref, b, qi, j,
-                                    (block_q, block_k), dropout_rate)
-        ds = p * (dp - delta) * scale
-        return dq_acc + jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            return _tile_dropout(seed_ref, b, qi, j, (block_q, block_k),
+                                 dropout_rate)
+        return None
 
-    dq0 = jnp.zeros((block_q, D), jnp.float32)
-    dq_ref[0] = jax.lax.fori_loop(0, hi, body, dq0).astype(dq_ref.dtype)
+    def body(j, dq, positional):
+        keep = None
+        if positional:
+            keep = lambda s: _causal_tile_mask(s, qi, j, block_q, block_k)
+        return dq + tile(0, j * block_k, block_k, keep, tile_drop(j))
+
+    dq = jnp.zeros((block_q, D), jnp.float32)
+    if piece:
+        dq = jax.lax.fori_loop(
+            0, qi, functools.partial(body, positional=False), dq)
+        keep = functools.partial(_mask_square, under=_lower_triangle(piece))
+        drop = tile_drop(qi)
+        for lo in range(0, block_q, piece):
+            dq = _rows_from(dq, lo, dq[lo:] + tile(
+                lo, qi * block_k + lo, piece, keep,
+                None if drop is None else drop[lo:, lo:lo + piece]))
+    else:
+        hi = pl.cdiv(seq_len, block_k)
+        if causal:
+            hi = jnp.minimum(((qi + 1) * block_q - 1) // block_k + 1, hi)
+        dq = jax.lax.fori_loop(
+            0, hi, functools.partial(body, positional=causal), dq)
+    if fold:
+        dq = dq * scale
+    dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
-def _dkv_kernel_resident(q_ref, k_ref, v_ref, o_ref, g_ref, lse_ref, *rest,
-                         seq_len, causal, scale, block_q, block_k,
-                         has_mask, has_glse, dropout_rate):
+def _dkv_kernel_resident(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                         *rest, seq_len, sched, causal, scale, has_mask,
+                         dropout_rate):
+    """One key block's dK and dV.  The score tile is built transposed,
+    sᵀ = k · qᵀ (keys in rows): pᵀ and dsᵀ are then the left operands of
+    plain products (dV += pᵀ · dO, dK += dsᵀ · q), dpᵀ = v · dOᵀ has the
+    form q · kᵀ has, and the log-sum-exp and Δ are wanted as the
+    (1, block_q) rows they are stored as, broadcast along sublanes."""
     from jax.experimental import pallas as pl
 
     rest = list(rest)
-    glse_ref = rest.pop(0) if has_glse else None
     mask_ref = rest.pop(0) if has_mask else None
     seed_ref = rest.pop(0) if dropout_rate > 0.0 else None
     dk_ref, dv_ref = rest
+    block_q, block_k, piece = sched.block_q, sched.block_k, sched.piece
     b = pl.program_id(0)
     ki = pl.program_id(1)
+    fold = _exact_scale(scale)
     k = k_ref[0]                              # (block_k, D), input dtype
     v = v_ref[0]                              # (block_k, D)
+    k_s = k * scale if fold else k
     D = k.shape[-1]
-
-    num_q_blocks = pl.cdiv(seq_len, block_q)
-    if causal:
-        lo = (ki * block_k) // block_q
-    else:
-        lo = 0
     if has_mask:
-        my_mask = mask_ref[0, :, pl.ds(ki * block_k, block_k)]
+        # The one column this kernel makes, once a key block.
+        valid = _col(mask_ref[0, :, pl.ds(ki * block_k, block_k)]) > 0
 
-    def stat_rows(ref, i):
-        """Query block i's (1, block_q) of a resident (1, T) row.  A
+    def stat_row(ref, start, width):
+        """Queries [start, start + width) of a resident (1, T) row.  A
         sequence of one block (T <= 128) need not be lane-aligned, and a
         slice that Mosaic cannot prove aligned is refused: take it whole."""
-        if seq_len == block_q:
+        if seq_len == width:
             return ref[0]
-        return ref[0, :, pl.ds(i * block_q, block_q)]
+        return ref[0, :, pl.ds(start, width)]
 
-    def body(i, carry):
-        dk_acc, dv_acc = carry
-        q_blk = q_ref[0, pl.ds(i * block_q, block_q), :]
-        g_blk = g_ref[0, pl.ds(i * block_q, block_q), :]
-        o_blk = o_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = _col(stat_rows(lse_ref, i))
-        delta = jnp.sum(
-            g_blk.astype(jnp.float32) * o_blk.astype(jnp.float32),
-            axis=-1, keepdims=True,
-        )
-        if has_glse:
-            delta = delta - _col(stat_rows(glse_ref, i))
-        s = jax.lax.dot_general(
-            q_blk, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                             # (block_q, block_k)
-        if causal:
-            s = _causal_tile_mask(s, i, ki, block_q, block_k)
+    def tile(hi, start, width, keep, drop):
+        """(dK, dV) of this block's keys up to ``hi`` from queries
+        [start, start + width), dK without the scale where that is folded."""
+        q_blk = q_ref[0, pl.ds(start, width), :]
+        g_blk = g_ref[0, pl.ds(start, width), :]
+        s = _nt(k_s[:hi], q_blk)              # (keys, queries)
+        if not fold:
+            s = s * scale
+        if keep is not None:
+            s = keep(s)
         if has_mask:
-            s = jnp.where(my_mask > 0, s, -jnp.inf)
-        p = jnp.exp(s - lse)
+            s = jnp.where(valid[:hi], s, -jnp.inf)
+        p = jnp.exp(s - stat_row(lse_ref, start, width))
+        dp = _nt(v[:hi], g_blk)
+        p_v = p
+        if drop is not None:
+            p_v, dp = p * drop, dp * drop
+        dv = _nn(p_v.astype(g_blk.dtype), g_blk)          # (P∘M)ᵀ dO
+        ds = p * (dp - stat_row(delta_ref, start, width))
+        if not fold:
+            ds = ds * scale
+        return _nn(ds.astype(q_blk.dtype), q_blk), dv     # dSᵀ Q
+
+    def tile_drop(i):
         if dropout_rate > 0.0:
-            drop = _tile_dropout(seed_ref, b, i, ki,
-                                 (block_q, block_k), dropout_rate)
-            p_v = p * drop
-        else:
-            p_v = p
-        # dV += (P∘M)^T dO
-        dv_acc = dv_acc + jax.lax.dot_general(
-            p_v.astype(g_blk.dtype), g_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            g_blk, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if dropout_rate > 0.0:
-            dp = dp * drop
-        ds = p * (dp - delta) * scale
-        # dK += dS^T Q
-        dk_acc = dk_acc + jax.lax.dot_general(
-            ds.astype(q_blk.dtype), q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return dk_acc, dv_acc
+            # Seeded and drawn as the forward drew it, queries in rows.
+            return _tile_dropout(seed_ref, b, i, ki, (block_q, block_k),
+                                 dropout_rate).T
+        return None
+
+    def body(i, carry, positional):
+        keep = None
+        if positional:
+            keep = lambda s: _causal_tile_mask(s, i, ki, block_q, block_k,
+                                               transposed=True)
+        dk, dv = tile(block_k, i * block_q, block_q, keep, tile_drop(i))
+        return carry[0] + dk, carry[1] + dv
 
     z = jnp.zeros((block_k, D), jnp.float32)
-    dk_acc, dv_acc = jax.lax.fori_loop(lo, num_q_blocks, body, (z, z))
-    dk_ref[0] = dk_acc.astype(dk_ref.dtype)
-    dv_ref[0] = dv_acc.astype(dv_ref.dtype)
+    num_q_blocks = pl.cdiv(seq_len, block_q)
+    if piece:
+        # The diagonal's tile: query pieces, each against the keys at or
+        # under it; then the query blocks wholly under the diagonal.
+        under = _lower_triangle(piece, transposed=True)
+        drop = tile_drop(ki)
+        carry = (z, z)
+        for lo in range(0, block_q, piece):
+            hi = lo + piece
+            new = tile(hi, ki * block_q + lo, piece,
+                       functools.partial(_mask_square, under=under, lo=lo),
+                       None if drop is None else drop[:hi, lo:hi])
+            carry = tuple(_rows_upto(x, hi, x[:hi] + y)
+                          for x, y in zip(carry, new))
+        carry = jax.lax.fori_loop(
+            ki + 1, num_q_blocks, functools.partial(body, positional=False),
+            carry)
+    else:
+        first = (ki * block_k) // block_q if causal else 0
+        carry = jax.lax.fori_loop(
+            first, num_q_blocks, functools.partial(body, positional=causal),
+            (z, z))
+    dk, dv = carry
+    if fold:
+        dk = dk * scale
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 # VMEM budget for keeping a kernel's loop windows resident (the windows are
@@ -552,11 +780,14 @@ def _resident_kv_bytes(T, D, itemsize):
 
 
 def _resident_dkv_bytes(T, D, itemsize, has_glse):
-    # The lse (+ g_lse) term is the (T, 128) f32 window of the measurements
-    # above; the window is a (1, T) row now, and the term stays so that the
-    # cutoff stays where it was measured.
+    # What the measured boundary above was measured with: windows of q, o
+    # and g and a (T, 128) f32 window of lse (+ one of g_lse).  The kernel
+    # now keeps q and g and two (1, T) rows (lse, and Δ with g_lse folded
+    # in), less than half of this; the estimate stays as it was so that the
+    # cutoff stays where a chip has shown both sides of it, and the ring
+    # path's g_lse still moves it as it did.
     win = 3 * T * D * itemsize + T * LANES * 4 * (2 if has_glse else 1)
-    return 2 * win  # q/o/g + lse (+ g_lse) windows, double-buffered
+    return 2 * win  # double-buffered
 
 
 def _to_heads(x):
@@ -610,10 +841,12 @@ def _flash_fwd_tpu(q, k, v, kv_mask, *, causal, scale, save_lse,
         ]
         mask_spec = pl.BlockSpec((1, 1, T), lambda b, i: (b // H, 0, 0))
         lse_spec = pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i))
+        sched = schedule(T, block_q, block_k, causal)
+        _log_schedule("forward and dK/dV", q.shape, sched)
         kernel = functools.partial(
-            _fwd_kernel_resident, seq_len=T, causal=causal, scale=scale,
-            block_q=block_q, block_k=block_k, save_lse=save_lse,
-            has_mask=has_mask, dropout_rate=dropout_rate,
+            _fwd_kernel_resident, seq_len=T, sched=sched, causal=causal,
+            scale=scale, save_lse=save_lse, has_mask=has_mask,
+            dropout_rate=dropout_rate,
         )
         scratch = []
         semantics = ("parallel", "arbitrary")
@@ -832,8 +1065,7 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
     has_glse = g_lse is not None
     has_dropout = dropout_rate > 0.0
     nq, nk = pl.cdiv(T, block_q), pl.cdiv(T, block_k)
-    qh, kh, vh = _to_heads(q), _to_heads(k), _to_heads(v)
-    gh, oh = _to_heads(g), _to_heads(o)
+    qh, kh, vh, gh = _to_heads(q), _to_heads(k), _to_heads(v), _to_heads(g)
     mask_op = (kv_mask.astype(jnp.int32).reshape(B, 1, T)
                if has_mask else None)
     # lse and its cotangent arrive (B, H, T): one (1, T) row a head.
@@ -841,33 +1073,48 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
     if has_glse:
         g_lse = g_lse.astype(jnp.float32).reshape(B * H, 1, T)
 
-    common = dict(causal=causal, scale=scale,
-                  block_q=block_q, block_k=block_k,
-                  has_mask=has_mask, has_glse=has_glse,
-                  dropout_rate=dropout_rate)
     itemsize = q.dtype.itemsize
     dq_resident = _resident_kv_bytes(T, D, itemsize) <= RESIDENT_VMEM_BUDGET
     dkv_resident = (_resident_dkv_bytes(T, D, itemsize, has_glse)
                     <= RESIDENT_VMEM_BUDGET)
+    streaming = dict(causal=causal, scale=scale,
+                     block_q=block_q, block_k=block_k,
+                     has_mask=has_mask, has_glse=has_glse,
+                     dropout_rate=dropout_rate)
+    resident = dict(seq_len=T, causal=causal, scale=scale, has_mask=has_mask,
+                    dropout_rate=dropout_rate)
+    if dq_resident or dkv_resident:
+        # Δ = rowsum(dO ∘ O), less the log-sum-exp's cotangent where there
+        # is one (∂lse/∂s = P, so dS = P ∘ (dP − Δ + g_lse)): one f32 a
+        # query, computed once by XLA from o and g as they arrive and handed
+        # to both resident kernels as the row the log-sum-exp is.
+        delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1).transpose(0, 2, 1).reshape(B * H, 1, T)
+        if has_glse:
+            delta = delta - g_lse
+    if not (dq_resident and dkv_resident):
+        oh = _to_heads(o)          # the streaming kernels make Δ themselves
 
     # dQ: resident = K/V windows stay in VMEM, fori_loop over key blocks;
     # streaming = grid (B·H, q block, streamed k block).
     if dq_resident:
         qmap = lambda b, i: (b, i, 0)
         full = lambda b, i: (b, 0, 0)
+        row = pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i))
         dq_in_specs = [
             pl.BlockSpec((1, block_q, D), qmap),             # q
             pl.BlockSpec((1, T, D), full),                   # k (resident)
             pl.BlockSpec((1, T, D), full),                   # v (resident)
-            pl.BlockSpec((1, block_q, D), qmap),             # o
             pl.BlockSpec((1, block_q, D), qmap),             # g
-            pl.BlockSpec((1, 1, block_q),                    # lse
-                         lambda b, i: (b, 0, i)),
+            row,                                             # lse
+            row,                                             # delta
         ]
-        dq_glse_spec = dq_in_specs[-1]
+        dq_operands = [qh, kh, vh, gh, lse, delta]
         dq_mask_spec = pl.BlockSpec((1, 1, T), lambda b, i: (b // H, 0, 0))
-        dq_kernel = functools.partial(_dq_kernel_resident, seq_len=T,
-                                      **common)
+        dq_sched = schedule(T, block_q, block_k, causal, DQ_PIECE)
+        _log_schedule("dQ", q.shape, dq_sched)
+        dq_kernel = functools.partial(_dq_kernel_resident, **resident,
+                                      sched=dq_sched)
         dq_grid = (B * H, nq)
         dq_out_spec = pl.BlockSpec((1, block_q, D), qmap)
         dq_scratch = []
@@ -875,27 +1122,26 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
     else:
         qmap = lambda b, i, j: (b, i, 0)
         kmap = lambda b, i, j: (b, j, 0)
+        row = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
         dq_in_specs = [
             pl.BlockSpec((1, block_q, D), qmap),             # q
             pl.BlockSpec((1, block_k, D), kmap),             # k
             pl.BlockSpec((1, block_k, D), kmap),             # v
             pl.BlockSpec((1, block_q, D), qmap),             # o
             pl.BlockSpec((1, block_q, D), qmap),             # g
-            pl.BlockSpec((1, 1, block_q),                    # lse
-                         lambda b, i, j: (b, 0, i)),
+            row,                                             # lse
         ]
-        dq_glse_spec = dq_in_specs[-1]
+        dq_operands = [qh, kh, vh, oh, gh, lse]
+        if has_glse:
+            dq_in_specs.append(row)
+            dq_operands.append(g_lse)
         dq_mask_spec = pl.BlockSpec((1, 1, block_k),
                                     lambda b, i, j: (b // H, 0, j))
-        dq_kernel = functools.partial(_bwd_dq_kernel, **common)
+        dq_kernel = functools.partial(_bwd_dq_kernel, **streaming)
         dq_grid = (B * H, nq, nk)
         dq_out_spec = pl.BlockSpec((1, block_q, D), qmap)
         dq_scratch = [pltpu.VMEM((block_q, D), jnp.float32)]
         dq_semantics = ("parallel", "parallel", "arbitrary")
-    dq_operands = [qh, kh, vh, oh, gh, lse]
-    if has_glse:
-        dq_in_specs.append(dq_glse_spec)
-        dq_operands.append(g_lse)
     if has_mask:
         dq_in_specs.append(dq_mask_spec)
         dq_operands.append(mask_op)
@@ -916,10 +1162,10 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
         name="flash_dq",
     )(*dq_operands)
 
-    # dK/dV: resident = q/o/g/lse windows stay in VMEM (fori_loop over q
-    # blocks); streaming = grid (B·H, k block, streamed q block) — the
-    # schedule that lifts the old T<=6144 cap (the resident windows abort
-    # Mosaic at T=8192).
+    # dK/dV: resident = the q and g windows and the two rows stay in VMEM
+    # (fori_loop over q blocks); streaming = grid (B·H, k block, streamed q
+    # block) — the schedule that lifts the old T<=6144 cap (the resident
+    # windows abort Mosaic at T=8192).
     if dkv_resident:
         kv_self = lambda b, ki: (b, ki, 0)
         full = lambda b, ki: (b, 0, 0)
@@ -927,14 +1173,15 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
             pl.BlockSpec((1, T, D), full),                   # q (resident)
             pl.BlockSpec((1, block_k, D), kv_self),          # k
             pl.BlockSpec((1, block_k, D), kv_self),          # v
-            pl.BlockSpec((1, T, D), full),                   # o (resident)
             pl.BlockSpec((1, T, D), full),                   # g (resident)
             pl.BlockSpec((1, 1, T), full),                   # lse (resident)
+            pl.BlockSpec((1, 1, T), full),                   # delta (resident)
         ]
-        dkv_glse_spec = dkv_in_specs[-1]
+        dkv_operands = [qh, kh, vh, gh, lse, delta]
         dkv_mask_spec = pl.BlockSpec((1, 1, T), lambda b, ki: (b // H, 0, 0))
-        dkv_kernel = functools.partial(_dkv_kernel_resident, seq_len=T,
-                                       **common)
+        dkv_kernel = functools.partial(
+            _dkv_kernel_resident, **resident,
+            sched=schedule(T, block_q, block_k, causal))
         dkv_grid = (B * H, nk)
         dkv_out_specs = [
             pl.BlockSpec((1, block_k, D), kv_self),
@@ -945,19 +1192,22 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
     else:
         kv_self = lambda b, ki, i: (b, ki, 0)
         q_stream = lambda b, ki, i: (b, i, 0)
+        row = pl.BlockSpec((1, 1, block_q), lambda b, ki, i: (b, 0, i))
         dkv_in_specs = [
             pl.BlockSpec((1, block_q, D), q_stream),         # q
             pl.BlockSpec((1, block_k, D), kv_self),          # k
             pl.BlockSpec((1, block_k, D), kv_self),          # v
             pl.BlockSpec((1, block_q, D), q_stream),         # o
             pl.BlockSpec((1, block_q, D), q_stream),         # g
-            pl.BlockSpec((1, 1, block_q),                    # lse
-                         lambda b, ki, i: (b, 0, i)),
+            row,                                             # lse
         ]
-        dkv_glse_spec = dkv_in_specs[-1]
+        dkv_operands = [qh, kh, vh, oh, gh, lse]
+        if has_glse:
+            dkv_in_specs.append(row)
+            dkv_operands.append(g_lse)
         dkv_mask_spec = pl.BlockSpec((1, 1, block_k),
                                      lambda b, ki, i: (b // H, 0, ki))
-        dkv_kernel = functools.partial(_bwd_dkv_kernel, **common)
+        dkv_kernel = functools.partial(_bwd_dkv_kernel, **streaming)
         dkv_grid = (B * H, nk, nq)
         dkv_out_specs = [
             pl.BlockSpec((1, block_k, D), kv_self),
@@ -968,10 +1218,6 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, kv_mask, g_lse, *, causal, scale,
             pltpu.VMEM((block_k, D), jnp.float32),
         ]
         dkv_semantics = ("parallel", "parallel", "arbitrary")
-    dkv_operands = [qh, kh, vh, oh, gh, lse]
-    if has_glse:
-        dkv_in_specs.append(dkv_glse_spec)
-        dkv_operands.append(g_lse)
     if has_mask:
         dkv_in_specs.append(dkv_mask_spec)
         dkv_operands.append(mask_op)

@@ -89,10 +89,11 @@ def one_chip(topo, shape, dtype=jnp.bfloat16):
 
 
 GPT2_MEDIUM = (8, 1024, 16, 64)   # one grad-accum microbatch of 32/4
+GPT2_LARGE_D2T2 = (4, 1024, 10, 64)  # train.gpt2-large.d2t2, one chip's part
 GPT2_LONG = (1, 8192, 16, 64)     # past the resident-VMEM schedule
 BERT_BASE = (32, 512, 12, 64)
 BERT_SEQ128 = (64, 128, 12, 64)
-BERT_SEQ768 = (8, 768, 12, 64)    # 768 = 2 x 384: the block is fitted
+BERT_SEQ768 = (8, 768, 12, 64)    # one whole 768 x 768 tile a head
 RING_BLOCK = (4, 512, 16, 64)     # one context=2 shard of seq 1024
 
 # (id, shape, causal, kv_mask, dropout, backward, kernel calls expected)
@@ -100,6 +101,7 @@ KERNEL_CASES = [
     ("gpt2-fwd", GPT2_MEDIUM, True, False, 0.0, False, 1),
     ("gpt2-bwd", GPT2_MEDIUM, True, False, 0.0, True, 3),
     ("gpt2-bwd-dropout", GPT2_MEDIUM, True, False, 0.1, True, 3),
+    ("gpt2-large-d2t2-bwd", GPT2_LARGE_D2T2, True, False, 0.0, True, 3),
     ("gpt2-long-bwd", GPT2_LONG, True, False, 0.0, True, 3),
     ("bert-fwd-mask", BERT_BASE, False, True, 0.0, False, 1),
     ("bert-bwd-mask", BERT_BASE, False, True, 0.0, True, 3),

@@ -421,3 +421,193 @@ class TestFlashOnMesh:
                 jax.ShapeDtypeStruct((2, 256, 4, 64), jnp.bfloat16), True)
         hits = [r for r in caplog.records if "DENSE path" in r.getMessage()]
         assert len(hits) == 1 and "seq len 200" in hits[0].getMessage()
+
+
+class TestCausalTriangle:
+    """The resident kernels' split loop: tiles under the diagonal take no
+    mask, the diagonal's tile is walked in pieces (128 wide; dQ's 256).  At
+    T = 512 blocks of 256 give the forward and dK/dV one unmasked tile and
+    two walked in two pieces (dQ keeps the positional mask: its piece is
+    the block); one block of 512 is walked whole by all three, in four
+    pieces and in dQ's two."""
+
+    T = 512
+
+    @pytest.fixture(params=[256, 512], ids=["blocks256", "one-block"])
+    def fa(self, monkeypatch, request):
+        import importlib
+
+        monkeypatch.setenv("DTT_PALLAS_INTERPRET", "1")
+        fa = importlib.import_module(
+            "distributed_tensorflow_tpu.ops.flash_attention")
+        block = request.param
+        monkeypatch.setattr(fa, "BLOCK_Q", block)
+        monkeypatch.setattr(fa, "BLOCK_K", block)
+        sched = fa.schedule(self.T, block, block, True)
+        dq = fa.schedule(self.T, block, block, True, fa.DQ_PIECE)
+        assert (sched.piece, sched.unmasked_tiles, sched.diagonal_pieces,
+                dq.piece, dq.masked_tiles, dq.diagonal_pieces) == (
+            (128, 1, 4, 0, 3, 0) if block == 256 else (128, 0, 4, 256, 0, 2))
+        return fa
+
+    @pytest.mark.parametrize("with_lse", [False, True],
+                             ids=["out", "out+lse"])
+    @pytest.mark.parametrize("masked", [False, True],
+                             ids=["nomask", "kvmask"])
+    @pytest.mark.parametrize("scale", [0.25, 0.3], ids=["pow2", "inexact"])
+    def test_split_loop_matches_dense_fwd_and_bwd(self, fa, masked, with_lse,
+                                                  scale):
+        """Forward, dQ, dK and dV against dense, with a key mask that leaves
+        one row of the batch no valid key at all (the -inf guards' case),
+        with the log-sum-exp's cotangent present (ring attention's form),
+        and with a scale that folds into q and k exactly and one that does
+        not."""
+        q, k, v = make_qkv(B=2, T=self.T, H=2, D=16, seed=31)
+        rng = np.random.RandomState(37)
+        wo = jnp.asarray(rng.randn(*q.shape).astype(np.float32))
+        wl = jnp.asarray(rng.randn(2, 2, self.T).astype(np.float32))
+        mask = None
+        if masked:
+            lens = np.array([300, 0])    # crosses a piece; wholly masked
+            mask = jnp.asarray(
+                (np.arange(self.T)[None] < lens[:, None]).astype(np.int32))
+
+        def loss(fn):
+            def f(q_, k_, v_):
+                o, lse = fn(q_, k_, v_)
+                # a row with no valid key has lse = -1e30 by contract
+                live = jnp.where(lse > -1e29, lse, 0.0)
+                return jnp.sum(o * wo) + jnp.sum(live * wl)
+            return f
+
+        if with_lse:
+            got_fn = lambda *a: fa.flash_attention_with_lse(
+                *a, causal=True, kv_mask=mask, scale=scale)
+        else:
+            got_fn = lambda *a: (fa.flash_attention(
+                *a, causal=True, kv_mask=mask, scale=scale),
+                jnp.zeros((2, 2, self.T)))
+        want_fn = lambda *a: fa._dense_with_lse(
+            *a, causal=True, kv_mask=mask, scale=scale)
+        if not with_lse:
+            dense = want_fn
+            want_fn = lambda *a: (dense(*a)[0], jnp.zeros((2, 2, self.T)))
+
+        got_o, got_l = got_fn(q, k, v)
+        want_o, want_l = want_fn(q, k, v)
+        np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
+                                   rtol=2e-5, atol=2e-5)
+        if with_lse and not masked:
+            np.testing.assert_allclose(np.asarray(got_l), np.asarray(want_l),
+                                       rtol=2e-5, atol=2e-5)
+        got = jax.grad(loss(got_fn), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(want_fn), argnums=(0, 1, 2))(q, k, v)
+        for name, a, b in zip(("dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4,
+                err_msg=f"{name} mismatch")
+
+    def test_dropout_mask_agrees_between_forward_and_backward(self, fa,
+                                                              monkeypatch):
+        """The three kernels regenerate a tile's keep mask from (b, q block,
+        k block) and take their pieces of it, the dK/dV kernel transposed.
+        The TPU PRNG has no interpreter lowering, so a keep mask that is a
+        plain function of (b, query, key) stands in for it: whichever
+        kernel took a wrong piece would disagree with the dense form under
+        the same mask."""
+        rate, B, H, T = 0.25, 1, 2, self.T
+
+        def keep_of(b, row, col):
+            return (row * 7919 + col * 104729 + b * 1000003) % 97 >= 24
+
+        def tile_dropout(seed_ref, b, qi, kj, shape, rate_):
+            row = qi * shape[0] + jax.lax.broadcasted_iota(
+                jnp.int32, shape, 0)
+            col = kj * shape[1] + jax.lax.broadcasted_iota(
+                jnp.int32, shape, 1)
+            return jnp.where(keep_of(b + seed_ref[0], row, col),
+                             1.0 / (1.0 - rate_), 0.0)
+
+        monkeypatch.setattr(fa, "_tile_dropout", tile_dropout)
+        q, k, v = make_qkv(B=B, T=T, H=H, D=16, seed=41)
+        g = jnp.asarray(
+            np.random.RandomState(43).randn(*q.shape).astype(np.float32))
+        scale = 0.25
+        seed = jnp.zeros((1,), jnp.int32)
+        out, lse = fa._flash_fwd_tpu(
+            q, k, v, None, causal=True, scale=scale, save_lse=True,
+            dropout_rate=rate, seed=seed)
+        got = (out,) + fa._flash_bwd_tpu(
+            q, k, v, out, lse, g, None, None, causal=True, scale=scale,
+            dropout_rate=rate, seed=seed)
+
+        heads = np.arange(B * H).reshape(B, H, 1, 1)
+        keep = keep_of(heads, np.arange(T).reshape(1, 1, T, 1),
+                       np.arange(T).reshape(1, 1, 1, T))
+        drop = jnp.asarray(keep / (1.0 - rate), jnp.float32)
+
+        def dense(q_, k_, v_):
+            s = jnp.einsum("bqhd,bkhd->bhqk", q_, k_) * scale
+            s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
+            return jnp.einsum("bhqk,bkhd->bqhd",
+                              jax.nn.softmax(s, axis=-1) * drop, v_)
+
+        want_out, vjp = jax.vjp(dense, q, k, v)
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got,
+                              (want_out,) + vjp(g)):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4,
+                err_msg=f"{name} mismatch under dropout")
+
+    @pytest.mark.parametrize("args,want", [
+        # cell 1 and cell 4: one tile without a mask, two walked in four
+        ((1024, 512, 512, True), (128, 1, 0, 8, 0.5625)),
+        ((1024, 256, 256, True), (128, 6, 0, 8, 0.5625)),
+        ((512, 256, 256, True), (128, 1, 0, 4, 0.625)),
+        # one block, one piece: today's single body under the mask
+        ((128, 128, 128, True), (0, 0, 1, 0, 1.0)),
+        ((384, 128, 128, True), (0, 0, 6, 0, 6 / 9)),
+        # blocks that differ are not walked
+        ((1024, 512, 256, True), (0, 0, 6, 0, 0.75)),
+        ((1024, 512, 512, False), (0, 4, 0, 0, 1.0)),
+        ((512, 512, 512, False), (0, 1, 0, 0, 1.0)),
+        # the cells since PR 43: one block, the triangle walked whole;
+        # dQ's pieces are 256 wide
+        ((1024, 1024, 1024, True), (128, 0, 0, 8, 0.5625)),
+        ((1024, 1024, 1024, True, 256), (256, 0, 0, 4, 0.625)),
+        ((512, 256, 256, True, 256), (0, 0, 3, 0, 0.75)),
+    ], ids=["t1024-b512", "t1024-b256", "t512-b256", "t128", "t384-b128",
+            "t1024-512x256", "noncausal", "noncausal-one-block",
+            "t1024-one-block", "t1024-one-block-dq", "t512-b256-dq"])
+    def test_schedule(self, args, want):
+        """The one function the kernels' loops and this test both read."""
+        import importlib
+
+        fa = importlib.import_module(
+            "distributed_tensorflow_tpu.ops.flash_attention")
+        sched = fa.schedule(*args)
+        assert (sched.block_q, sched.block_k) == args[1:3]
+        assert sched[2:6] == want[:4]
+        assert sched.share == pytest.approx(want[4])
+
+    def test_schedule_is_logged_once_per_shape(self, fa, caplog,
+                                               monkeypatch):
+        import logging
+
+        monkeypatch.setattr(fa, "_LOGGED_SCHEDULES", set())
+        q, k, v = make_qkv(B=1, T=self.T, H=1, D=16, seed=47)
+        loss = lambda q_: jnp.sum(fa.flash_attention(q_, k, v, causal=True))
+        with caplog.at_level(logging.INFO, logger=fa.__name__):
+            jax.grad(loss)(q)
+            jax.grad(loss)(q)
+        hits = sorted(r.getMessage() for r in caplog.records
+                      if "of T^2 computed" in r.getMessage())
+        assert len(hits) == 2       # the dQ kernel's, then the other two's
+        if fa.BLOCK_Q == 256:
+            assert "dQ" in hits[0] and "3 under the positional mask" \
+                in hits[0] and "0.7500" in hits[0]
+            assert "1 unmasked" in hits[1] and "4 diagonal pieces of 128" \
+                in hits[1] and "0.6250" in hits[1]
+        else:
+            assert "dQ" in hits[0] and "2 diagonal pieces of 256" in hits[0]
+            assert "4 diagonal pieces of 128" in hits[1]

@@ -48,6 +48,29 @@ def stream_fed_losses(wl, mesh, *, steps=2, total_steps=4, seed=1):
     return losses
 
 
+def fixed_reference(engine, prompt, max_new_tokens):
+    """The fixed-batch answer for one prompt: a full padded-batch greedy
+    generate, row 0.  Greedy decode is row-independent, so this is the
+    token-for-token target for the continuous path."""
+    import numpy as np
+
+    rows = engine.bucket_rows(1)
+    out = engine.generate(np.repeat(prompt[None, :], rows, axis=0),
+                          max_new_tokens)
+    return out[0]
+
+
+def zero_cache(module, tokens, **call):
+    """A decoder module's ``cache`` collection for this call, all zeros,
+    from its shapes alone (``init`` is traced, never run)."""
+    import jax
+    import jax.numpy as jnp
+
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.key(0), tokens, **call)["cache"])
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+
+
 def free_ports(n: int) -> List[int]:
     """Allocate ``n`` distinct free localhost ports.
 

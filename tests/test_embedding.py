@@ -124,12 +124,13 @@ class TestReplicatedLookup:
                   mesh=mesh_dp, dtype=jnp.float32)
         m_sh = WideDeep(**kw, replicate_wide=False)
         m_rep = WideDeep(**kw, replicate_wide=True)
-        params = m_sh.init(jax.random.key(0), batch)["params"]
+        params = jax.jit(m_sh.init)(jax.random.key(0), batch)["params"]
 
         def loss(module, p):
             return _loss_fn(module, p, batch, None)[0]
 
-        l_sh, g_sh = jax.value_and_grad(lambda p: loss(m_sh, p))(params)
+        l_sh, g_sh = jax.jit(
+            jax.value_and_grad(lambda p: loss(m_sh, p)))(params)
         l_rep, g_rep = jax.jit(
             jax.value_and_grad(lambda p: loss(m_rep, p)))(params)
         np.testing.assert_allclose(np.asarray(l_sh), np.asarray(l_rep),
@@ -211,8 +212,8 @@ class TestMultiTableEmbedding:
             )
             for fc in fcs
         }
-        vars_ = mod.init(jax.random.key(0), feats)
-        out = mod.apply(vars_, feats)
+        vars_ = jax.jit(mod.init)(jax.random.key(0), feats)
+        out = jax.jit(mod.apply)(vars_, feats)
         for fc in fcs:
             table = vars_["params"][fc.table.name]["embedding"]
             ids = feats[fc.name] % fc.table.vocabulary_size
@@ -229,7 +230,7 @@ class TestMultiTableEmbedding:
         fcs = self._small_config(num_sparse=6)  # 6 features over 3 tables
         mod = MultiTableEmbedding(fcs, mesh=mesh_expert, axis="expert")
         feats = {fc.name: jnp.zeros((4,), jnp.int32) for fc in fcs}
-        vars_ = mod.init(jax.random.key(0), feats)
+        vars_ = jax.eval_shape(mod.init, jax.random.key(0), feats)
         # exactly 3 parameter tables despite 6 features
         assert sorted(vars_["params"]) == [
             "table_large", "table_medium", "table_small",

@@ -134,6 +134,10 @@ class Slow:
     def __next__(self):
         self.n += 1
         if self.n == 30:  # both workers well into training -> safe to signal
+            # ... and the runtime's notifier is up: the hook has by now
+            # consulted the sync manager 29 times and was never refused.
+            from distributed_tensorflow_tpu.ft import preemption
+            assert not preemption._PSM_UNAVAILABLE_LOGGED
             open(marker, "w").close()
         time.sleep(0.05)
         return linear_batch()
@@ -177,9 +181,9 @@ def test_platform_preemption_notice_stops_both_workers(tmp_path):
         ))
     try:
         deadline = time.time() + 120
-        # wait until BOTH workers are ~30 steps into training (marker files)
-        # before delivering the notice: the runtime's preemption notifier
-        # must be fully up or the signal is lost.
+        # wait until BOTH workers are ~30 steps into training (marker files,
+        # written only where the runtime's preemption notifier is up: a
+        # signal before that is lost) before delivering the notice.
         while time.time() < deadline:
             if all(os.path.exists(os.path.join(str(tmp_path), f"training{i}"))
                    for i in range(2)):
@@ -189,7 +193,6 @@ def test_platform_preemption_notice_stops_both_workers(tmp_path):
             for q in procs:
                 q.kill()
             pytest.fail("workers never reached training")
-        time.sleep(10.0)
         procs[1].send_signal(signal.SIGTERM)  # scheduler preempts worker 1
         outs = []
         for p in procs:
@@ -293,8 +296,15 @@ class TestKillAWorker:
             env=env, cwd=os.path.dirname(os.path.dirname(__file__)),
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
         )
-        # give it time to compile and pass a few checkpoint intervals
-        time.sleep(60)
+        # signal once it has compiled and passed two checkpoint intervals
+        deadline = time.time() + 120
+        while not (os.path.isdir(ckpt) and any(
+                d.isdigit() and int(d) >= 40 for d in os.listdir(ckpt))):
+            if proc.poll() is not None or time.time() > deadline:
+                proc.kill()
+                pytest.fail("no second checkpoint within 120 s; output:\n"
+                            + proc.communicate()[0][-3000:])
+            time.sleep(0.5)
         proc.send_signal(signal.SIGTERM)
         try:
             out, _ = proc.communicate(timeout=120)

@@ -15,6 +15,8 @@ counts of the router's choices equal a hand count.
 
 import dataclasses
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,7 +31,7 @@ from distributed_tensorflow_tpu.models.gpt2 import PagedKVConfig
 from distributed_tensorflow_tpu.obs.metrics import default_registry
 from distributed_tensorflow_tpu.ops import grouped_matmul
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
-from tests.helpers import expert_forms_on_record
+from tests.helpers import expert_forms_on_record, zero_cache
 
 EXACT = precision.Exact()
 
@@ -55,6 +57,7 @@ def reference_config(cfg):
         parameter_dtype=jnp.dtype(cfg.dtype).name)
 
 
+@functools.cache
 def drawn_params(cfg, seed=3):
     """Random parameters (norm scales round 1, a correction bias that moves
     choices), in the type the module holds them in."""
@@ -73,6 +76,14 @@ def drawn_params(cfg, seed=3):
     return jax.tree_util.tree_map_with_path(one, abstract)
 
 
+@functools.cache
+def reference_logits(cfg):
+    """The reference's full forward pass as one program a configuration and
+    shape: run op by op it compiled its layer scans anew at every call."""
+    return jax.jit(lambda params, tokens: ref.logits(
+        EXACT, reference_config(cfg), params, tokens))
+
+
 def tokens_of(cfg, shape, seed=0):
     return jnp.asarray(np.random.default_rng(seed).integers(
         0, cfg.vocab_size, shape), jnp.int32)
@@ -86,10 +97,11 @@ def test_forward_matches_the_reference(dtype, atol):
                first_k_dense_replace=2, dtype=jnp.dtype(dtype))
     params = drawn_params(cfg)
     tokens = tokens_of(cfg, (3, 40))
-    got = Glm4MoeLite(cfg).apply({"params": params}, tokens)
+    got = jax.jit(lambda p, t: Glm4MoeLite(cfg).apply({"params": p}, t))(
+        params, tokens)
     assert got.dtype == jnp.float32
     f32 = jax.tree.map(lambda x: x.astype(jnp.float32), params)
-    want = ref.logits(EXACT, reference_config(cfg), f32, tokens)
+    want = reference_logits(cfg)(f32, tokens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol)
 
 
@@ -162,22 +174,21 @@ def test_prefill_then_decode_gives_the_reference_logits(block_size):
     slots, prompt, steps = 3, 11, 9
     paged, tables = paged_for(cfg, slots, 32, block_size)
     tokens = tokens_of(cfg, (2, prompt + steps))
-    want = np.asarray(ref.logits(EXACT, reference_config(cfg), params, tokens))
+    want = np.asarray(reference_logits(cfg)(params, tokens))
     rows = jnp.asarray([2, 0], jnp.int32)        # slot 1 stays empty
     kw = dict(decode=True, paged=paged, block_tables=tables, mutable=["cache"])
     every = jnp.arange(slots, dtype=jnp.int32)
-    cache = module.init(jax.random.key(0), jnp.zeros((slots, 1), jnp.int32),
-                        decode=True, slot_ids=every, paged=paged,
-                        block_tables=tables)["cache"]
-    cache = jax.tree.map(jnp.zeros_like, cache)
-    got, out = module.apply({"params": params, "cache": cache},
-                            tokens[:, :prompt], slot_ids=rows, **kw)
+    cache = zero_cache(module, jnp.zeros((slots, 1), jnp.int32), decode=True,
+                       slot_ids=every, paged=paged, block_tables=tables)
+    # One program a shape of call, as the engine has: the prompt's, a step's.
+    call = jax.jit(lambda cache, toks, ids: module.apply(
+        {"params": params, "cache": cache}, toks, slot_ids=ids, **kw))
+    got, out = call(cache, tokens[:, :prompt], rows)
     np.testing.assert_allclose(np.asarray(got), want[:, :prompt], atol=3e-5)
     cache = out["cache"]
     for t in range(prompt, prompt + steps):
         step = jnp.zeros((slots, 1), jnp.int32).at[rows].set(tokens[:, t:t + 1])
-        got, out = module.apply({"params": params, "cache": cache}, step,
-                                slot_ids=every, **kw)
+        got, out = call(cache, step, every)
         cache = out["cache"]
         np.testing.assert_allclose(np.asarray(got)[np.asarray(rows), 0],
                                    want[:, t], atol=3e-5)
@@ -455,8 +466,8 @@ def _gap_to_reference_best(engine, prompt, answer):
     """At every answered position, how far the served token's logit lies
     under the best logit of the reference's full forward pass."""
     seq = np.concatenate([prompt, answer])[None, :-1]
-    logits = np.asarray(ref.logits(
-        EXACT, reference_config(SERVED), engine.params, jnp.asarray(seq)))
+    logits = np.asarray(reference_logits(SERVED)(
+        engine.params, jnp.asarray(seq)))
     at = logits[0, len(prompt) - 1:]
     return at.max(-1) - at[np.arange(len(answer)), answer]
 

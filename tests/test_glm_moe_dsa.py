@@ -16,6 +16,7 @@ tolerance the sound program passes.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +32,7 @@ from distributed_tensorflow_tpu.models.glm_moe_dsa import (
     GlmMoeDsa, GlmMoeDsaConfig)
 from distributed_tensorflow_tpu.obs.metrics import default_registry
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
-from tests.helpers import expert_forms_on_record
+from tests.helpers import expert_forms_on_record, zero_cache
 
 EXACT = precision.Exact()
 
@@ -62,6 +63,7 @@ def reference_config(cfg):
         parameter_dtype=jnp.dtype(cfg.dtype).name)
 
 
+@functools.cache
 def drawn_params(cfg, seed=3):
     """Random parameters (norm scales round 1, offsets and a correction
     bias that move choices), in the type the module holds them in."""
@@ -85,17 +87,22 @@ def tokens_of(cfg, shape, seed=0):
         0, cfg.vocab_size, shape), jnp.int32)
 
 
-def reference_run(cfg, params, tokens):
-    """The reference's logits and each layer's selection mask (one
-    compiled program a shape: run op by op it is most of this file's
-    time)."""
+@functools.cache
+def _reference(cfg):
     def run(f32, tokens):
         masks = []
         logits = ref.logits(EXACT, reference_config(cfg), f32, tokens, masks)
         return logits, masks
 
+    return jax.jit(run)
+
+
+def reference_run(cfg, params, tokens):
+    """The reference's logits and each layer's selection mask (one
+    compiled program a configuration and shape: run op by op it is most of
+    this file's time)."""
     f32 = jax.tree.map(lambda x: x.astype(jnp.float32), params)
-    logits, masks = jax.jit(run)(f32, tokens)
+    logits, masks = _reference(cfg)(f32, tokens)
     return np.asarray(logits), [np.asarray(m) for m in masks]
 
 
@@ -224,7 +231,8 @@ def test_a_context_under_index_topk_is_plain_causal_latent_attention():
     cfg, wide = tiny(), tiny(index_topk=4096)
     params = drawn_params(cfg)
     tokens = tokens_of(cfg, (2, 24), seed=2)
-    want = np.asarray(GlmMoeDsa(wide).apply({"params": params}, tokens))
+    want = np.asarray(jax.jit(lambda p, t: GlmMoeDsa(wide).apply(
+        {"params": p}, t))(params, tokens))
     got = cached_logits(cfg, params, tokens, prompt=16, chunk=8,
                         block_size=8, total=96)
     np.testing.assert_allclose(got, want, atol=3e-5)
@@ -257,9 +265,8 @@ def cached_logits(cfg, params, tokens, *, prompt, chunk, block_size, total,
     every = jnp.arange(slots, dtype=jnp.int32)
     kw = dict(decode=True, paged=paged, block_tables=tables,
               mutable=["cache", "intermediates"])
-    cache = jax.tree.map(jnp.zeros_like, module.init(
-        jax.random.key(0), jnp.zeros((slots, 1), jnp.int32), decode=True,
-        slot_ids=every, paged=paged, block_tables=tables)["cache"])
+    cache = zero_cache(module, jnp.zeros((slots, 1), jnp.int32), decode=True,
+                       slot_ids=every, paged=paged, block_tables=tables)
     # One program a shape of call, as the engine has: a chunk's, a step's.
     chunk_call = jax.jit(lambda cache, toks: module.apply(
         {"params": params, "cache": cache}, toks, slot_ids=rows, **kw))

@@ -14,6 +14,7 @@ is refused with its reason; a float8 product fails the bfloat16 tolerance.
 """
 
 import dataclasses
+import functools
 import math
 import time
 
@@ -30,7 +31,7 @@ from distributed_tensorflow_tpu.models.glm4_moe_lite import route
 from distributed_tensorflow_tpu.models.mellum import Mellum, MellumConfig
 from distributed_tensorflow_tpu.obs.metrics import default_registry
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
-from tests.helpers import expert_forms_on_record
+from tests.helpers import expert_forms_on_record, zero_cache
 
 EXACT = precision.Exact()
 PUBLISHED_ROPE = {
@@ -71,6 +72,7 @@ def reference_config(cfg):
         parameter_dtype=jnp.dtype(cfg.dtype).name)
 
 
+@functools.cache
 def drawn_params(cfg, seed=3):
     module = Mellum(cfg)
     abstract = jax.eval_shape(lambda: module.init(
@@ -90,9 +92,17 @@ def tokens_of(cfg, shape, seed=0):
         0, cfg.vocab_size, shape), jnp.int32)
 
 
+@functools.cache
+def _reference(cfg, dot):
+    """One program a configuration and shape: run op by op the reference
+    compiled its layer scans anew at every call."""
+    return jax.jit(lambda f32, tokens: ref.logits(
+        dot, reference_config(cfg), f32, tokens))
+
+
 def reference_logits(cfg, params, tokens, dot=EXACT):
     f32 = jax.tree.map(lambda x: x.astype(jnp.float32), params)
-    return np.asarray(ref.logits(dot, reference_config(cfg), f32, tokens))
+    return np.asarray(_reference(cfg, dot)(f32, tokens))
 
 
 # -- the full forward pass -----------------------------------------------------
@@ -113,7 +123,8 @@ def test_forward_matches_the_reference(dtype):
     cfg = tiny(experts_held=4, first_expert=2, dtype=jnp.dtype(dtype))
     params = drawn_params(cfg)
     tokens = tokens_of(cfg, (3, 60))        # past the window of 24
-    got = Mellum(cfg).apply({"params": params}, tokens)
+    got = jax.jit(lambda p, t: Mellum(cfg).apply({"params": p}, t))(
+        params, tokens)
     assert got.dtype == jnp.float32
     gap = np.abs(np.asarray(got) - reference_logits(cfg, params, tokens))
     most, mean = TOLERANCE[dtype]
@@ -280,8 +291,7 @@ def test_chunked_prefill_then_decode_gives_the_reference_logits(block, chunk,
     slot_ids = jnp.arange(2, dtype=jnp.int32)
     call = dict(decode=True, slot_ids=slot_ids, paged=paged,
                 block_tables=tables)
-    cache = jax.tree.map(jnp.zeros_like, module.init(
-        jax.random.key(0), tokens[:, :chunk], **call)["cache"])
+    cache = zero_cache(module, tokens[:, :chunk], **call)
     step = jax.jit(lambda c, t: module.apply(
         {"params": params, "cache": c}, t, mutable=["cache"], **call))
     got, at = [], 0

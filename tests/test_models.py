@@ -107,11 +107,11 @@ class TestResNet:
         np.testing.assert_array_equal(np.asarray(a1), np.asarray(a1b))
 
         # train loss sees different views per step rng; eval loss does not
-        variables = dict(wl.module.init(jax.random.key(0),
-                                        wl.init_batch["image"]))
+        variables = dict(jax.jit(wl.module.init)(jax.random.key(0),
+                                                 wl.init_batch["image"]))
         params = variables.pop("params")
-        train_fn = _wrap_from_record(wl, wl.loss_fn, train=True)
-        eval_fn = _wrap_from_record(wl, wl.eval_loss_fn)
+        train_fn = jax.jit(_wrap_from_record(wl, wl.loss_fn, train=True))
+        eval_fn = jax.jit(_wrap_from_record(wl, wl.eval_loss_fn))
         lt1 = float(train_fn(params, variables, staged,
                              jax.random.key(1))[0])
         lt2 = float(train_fn(params, variables, staged,
@@ -356,11 +356,11 @@ class TestGPT2:
         batch = {"tokens": tokens}
         m_full = GPT2(cfg)
         m_chunk = GPT2(dataclasses.replace(cfg, ce_chunk=32))
-        params = m_full.init(jax.random.key(0), tokens)["params"]
-        l1, g1 = jax.value_and_grad(
-            lambda p: _loss_fn(m_full, True, p, batch, None)[0])(params)
-        l2, g2 = jax.value_and_grad(
-            lambda p: _loss_fn(m_chunk, True, p, batch, None)[0])(params)
+        params = jax.jit(m_full.init)(jax.random.key(0), tokens)["params"]
+        l1, g1 = jax.jit(jax.value_and_grad(
+            lambda p: _loss_fn(m_full, True, p, batch, None)[0]))(params)
+        l2, g2 = jax.jit(jax.value_and_grad(
+            lambda p: _loss_fn(m_chunk, True, p, batch, None)[0]))(params)
         np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)
         jax.tree.map(
             lambda a, b: np.testing.assert_allclose(
@@ -464,15 +464,16 @@ class TestBert:
         lengths = batch["input_mask"].sum(1)
         assert lengths.min() < 64, "variable lengths expected"
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        params = BertPretrain(cfg).init(jax.random.key(0), batch)["params"]
+        params = jax.jit(BertPretrain(cfg).init)(
+            jax.random.key(0), batch)["params"]
 
         def loss_for(c, mesh=None):
             m = BertPretrain(c, mesh=mesh)
             return lambda p: _loss_fn(m, True, p, batch, None)[0]
 
-        l_dense, g_dense = jax.value_and_grad(loss_for(cfg))(params)
-        l_flash, g_flash = jax.value_and_grad(loss_for(
-            dataclasses.replace(cfg, use_flash_attention=True)))(params)
+        l_dense, g_dense = jax.jit(jax.value_and_grad(loss_for(cfg)))(params)
+        l_flash, g_flash = jax.jit(jax.value_and_grad(loss_for(
+            dataclasses.replace(cfg, use_flash_attention=True))))(params)
         l_ring, g_ring = jax.jit(jax.value_and_grad(
             loss_for(cfg, mesh_4d)))(params)
         np.testing.assert_allclose(float(l_dense), float(l_flash), rtol=1e-6)

@@ -375,9 +375,9 @@ def test_model_decode_steps_match_the_gather_path(monkeypatch, scan_layers):
     slot_ids = jnp.arange(slots, dtype=jnp.int32)
     rng = np.random.default_rng(5)
     prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, (slots, 20)))
-    variables = model.init(
+    variables = jax.jit(lambda: model.init(
         jax.random.key(0), jnp.zeros((slots, total), jnp.int32), decode=True,
-        slot_ids=slot_ids, paged=paged, block_tables=tables)
+        slot_ids=slot_ids, paged=paged, block_tables=tables))()
     params = variables["params"]
     empty = jax.tree.map(jnp.zeros_like, variables["cache"])
     steps = [jnp.asarray(rng.integers(0, cfg.vocab_size, (slots, 1)))

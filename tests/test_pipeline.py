@@ -72,7 +72,7 @@ class TestPipeline:
         def loss_seq(stages_list):
             return jnp.sum(sequential(stages_list, x) ** 2)
 
-        g_pp = jax.grad(loss_pp)(stacked_sharded)
+        g_pp = jax.jit(jax.grad(loss_pp))(stacked_sharded)
         g_seq = jax.grad(loss_seq)(stages)
         g_seq_stacked = stack_stage_params(g_seq)
         for a, b in zip(jax.tree.leaves(g_pp), jax.tree.leaves(g_seq_stacked)):
@@ -93,14 +93,13 @@ class TestPipeline:
         def loss_fn(y, t):
             return jnp.mean((y - t) ** 2)
 
-        l_1f1b, g_1f1b, dx_1f1b, _ = pipeline_value_and_grad(
-            stage_fn, loss_fn, stacked, x, tgt, mesh=mesh_pp,
-            schedule="1f1b",
-        )
-        l_gp, g_gp, dx_gp, _ = pipeline_value_and_grad(
-            stage_fn, loss_fn, stacked, x, tgt, mesh=mesh_pp,
-            schedule="gpipe",
-        )
+        def run(schedule):
+            return jax.jit(lambda p, xx, t: pipeline_value_and_grad(
+                stage_fn, loss_fn, p, xx, t, mesh=mesh_pp,
+                schedule=schedule))(stacked, x, tgt)
+
+        l_1f1b, g_1f1b, dx_1f1b, _ = run("1f1b")
+        l_gp, g_gp, dx_gp, _ = run("gpipe")
 
         def loss_seq(stages_list, xx):
             y = sequential(stages_list, xx)
@@ -177,7 +176,7 @@ class TestPipeline:
         dstages_ref = stack_stage_params(dstages_ref)
 
         for schedule in ("1f1b", "gpipe"):
-            loss, grads, dE = run(schedule)
+            loss, grads, dE = jax.jit(run, static_argnums=0)(schedule)
             np.testing.assert_allclose(float(loss), float(l_ref), rtol=1e-5)
             np.testing.assert_allclose(np.asarray(dE), np.asarray(dE_ref),
                                        rtol=1e-4, atol=1e-5)

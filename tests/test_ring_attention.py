@@ -2,6 +2,8 @@
 softmax attention (it is exact attention, not an approximation).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,13 +30,29 @@ def make_qkv(B=2, T=32, H=4, D=8, seed=0):
     return mk(), mk(), mk()
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "causal", "rate", "chunk_size", "use_flash"))
+def ring(q, k, v, rng=None, kv_mask=None, *, mesh, causal=True, rate=0.0,
+         chunk_size=None, use_flash=None):
+    """``ring_attention`` as one program of its arrays: the dropout key is
+    an argument, so calls that differ only in the key share one compile."""
+    return ring_attention(q, k, v, mesh=mesh, causal=causal,
+                          chunk_size=chunk_size, kv_mask=kv_mask,
+                          use_flash=use_flash, dropout_rate=rate,
+                          dropout_rng=rng)
+
+
+def sharded(mesh, *arrays):
+    sh = NamedSharding(mesh, P(None, "context"))
+    return [jax.device_put(x, sh) for x in arrays]
+
+
 class TestRingAttention:
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_dense(self, mesh_ctx, causal):
         q, k, v = make_qkv()
-        sh = NamedSharding(mesh_ctx, P(None, "context"))
-        qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
-        got = ring_attention(qs, ks, vs, mesh=mesh_ctx, causal=causal)
+        qs, ks, vs = sharded(mesh_ctx, q, k, v)
+        got = ring(qs, ks, vs, mesh=mesh_ctx, causal=causal)
         want = _dense_attention(q, k, v, causal=causal,
                                 scale=1.0 / np.sqrt(q.shape[-1]))
         np.testing.assert_allclose(
@@ -43,27 +61,22 @@ class TestRingAttention:
 
     def test_output_stays_sequence_sharded(self, mesh_ctx):
         q, k, v = make_qkv()
-        sh = NamedSharding(mesh_ctx, P(None, "context"))
-        qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
-        out = jax.jit(
-            lambda a, b, c: ring_attention(a, b, c, mesh=mesh_ctx)
-        )(qs, ks, vs)
+        qs, ks, vs = sharded(mesh_ctx, q, k, v)
+        out = ring(qs, ks, vs, mesh=mesh_ctx)
         assert not out.sharding.is_fully_replicated
 
     def test_gradients_match_dense(self, mesh_ctx):
         q, k, v = make_qkv(T=16)
-        sh = NamedSharding(mesh_ctx, P(None, "context"))
-        qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
+        qs, ks, vs = sharded(mesh_ctx, q, k, v)
 
         def loss_ring(q, k, v):
-            return jnp.sum(ring_attention(q, k, v, mesh=mesh_ctx,
-                                          causal=True) ** 2)
+            return jnp.sum(ring(q, k, v, mesh=mesh_ctx) ** 2)
 
         def loss_dense(q, k, v):
             return jnp.sum(_dense_attention(
                 q, k, v, causal=True, scale=1.0 / np.sqrt(q.shape[-1])) ** 2)
 
-        g_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(qs, ks, vs)
+        g_ring = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(qs, ks, vs)
         g_dense = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
         for gr, gd in zip(g_ring, g_dense):
             np.testing.assert_allclose(
@@ -75,10 +88,9 @@ class TestRingAttention:
         """chunk_size < per-shard block length: the kv block is consumed
         in chunks under a scan (bounded score tile) — result unchanged."""
         q, k, v = make_qkv(seed=11)
-        sh = NamedSharding(mesh_ctx, P(None, "context"))
-        qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
-        got = ring_attention(qs, ks, vs, mesh=mesh_ctx, causal=causal,
-                             chunk_size=2)  # per-shard block is 4
+        qs, ks, vs = sharded(mesh_ctx, q, k, v)
+        got = ring(qs, ks, vs, mesh=mesh_ctx, causal=causal,
+                   chunk_size=2)  # per-shard block is 4
         want = _dense_attention(q, k, v, causal=causal,
                                 scale=1.0 / np.sqrt(q.shape[-1]))
         np.testing.assert_allclose(
@@ -87,18 +99,16 @@ class TestRingAttention:
 
     def test_chunked_gradients_match_dense(self, mesh_ctx):
         q, k, v = make_qkv(T=16, seed=13)
-        sh = NamedSharding(mesh_ctx, P(None, "context"))
-        qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
+        qs, ks, vs = sharded(mesh_ctx, q, k, v)
 
         def loss_ring(q, k, v):
-            return jnp.sum(ring_attention(
-                q, k, v, mesh=mesh_ctx, causal=True, chunk_size=1) ** 2)
+            return jnp.sum(ring(q, k, v, mesh=mesh_ctx, chunk_size=1) ** 2)
 
         def loss_dense(q, k, v):
             return jnp.sum(_dense_attention(
                 q, k, v, causal=True, scale=1.0 / np.sqrt(q.shape[-1])) ** 2)
 
-        g_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(qs, ks, vs)
+        g_ring = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(qs, ks, vs)
         g_dense = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
         for gr, gd in zip(g_ring, g_dense):
             np.testing.assert_allclose(
@@ -114,21 +124,19 @@ class TestRingAttention:
         lens = np.array([T - 5, T // 2])
         mask = jnp.asarray(
             (np.arange(T)[None, :] < lens[:, None]).astype(np.int32))
-        sh = NamedSharding(mesh_ctx, P(None, "context"))
-        qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
-        ms = jax.device_put(mask, NamedSharding(mesh_ctx, P(None, "context")))
+        qs, ks, vs = sharded(mesh_ctx, q, k, v)
+        ms, = sharded(mesh_ctx, mask)
         scale = 1.0 / np.sqrt(q.shape[-1])
 
-        got = ring_attention(qs, ks, vs, mesh=mesh_ctx, causal=causal,
-                             kv_mask=ms)
+        got = ring(qs, ks, vs, kv_mask=ms, mesh=mesh_ctx, causal=causal)
         want = _dense_attention(q, k, v, causal=causal, scale=scale,
                                 kv_mask=mask)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
-        g_ring = jax.grad(lambda a, b, c: jnp.sum(ring_attention(
-            a, b, c, mesh=mesh_ctx, causal=causal, kv_mask=ms) ** 2),
-            argnums=(0, 1, 2))(qs, ks, vs)
+        g_ring = jax.jit(jax.grad(lambda a, b, c: jnp.sum(ring(
+            a, b, c, kv_mask=ms, mesh=mesh_ctx, causal=causal) ** 2),
+            argnums=(0, 1, 2)))(qs, ks, vs)
         g_dense = jax.grad(lambda a, b, c: jnp.sum(_dense_attention(
             a, b, c, causal=causal, scale=scale, kv_mask=mask) ** 2),
             argnums=(0, 1, 2))(q, k, v)
@@ -156,23 +164,21 @@ class TestRingAttention:
             lens = np.array([900, 640])
             mask = jnp.asarray(
                 (np.arange(T)[None, :] < lens[:, None]).astype(np.int32))
-            mask_dev = jax.device_put(
-                mask, NamedSharding(mesh_ctx, P(None, "context")))
-        sh = NamedSharding(mesh_ctx, P(None, "context"))
-        qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
+            mask_dev, = sharded(mesh_ctx, mask)
+        qs, ks, vs = sharded(mesh_ctx, q, k, v)
         scale = 1.0 / np.sqrt(D)
 
-        got = ring_attention(qs, ks, vs, mesh=mesh_ctx, causal=causal,
-                             kv_mask=mask_dev, use_flash=True)
+        got = ring(qs, ks, vs, kv_mask=mask_dev, mesh=mesh_ctx,
+                   causal=causal, use_flash=True)
         want = _dense_attention(q, k, v, causal=causal, scale=scale,
                                 kv_mask=mask)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
         w = jnp.asarray(rng.randn(B, T, H, D).astype(np.float32))
-        g_ring = jax.grad(lambda a, b, c: jnp.sum(ring_attention(
-            a, b, c, mesh=mesh_ctx, causal=causal, kv_mask=mask_dev,
-            use_flash=True) * w), argnums=(0, 1, 2))(qs, ks, vs)
+        g_ring = jax.jit(jax.grad(lambda a, b, c: jnp.sum(ring(
+            a, b, c, kv_mask=mask_dev, mesh=mesh_ctx, causal=causal,
+            use_flash=True) * w), argnums=(0, 1, 2)))(qs, ks, vs)
         g_dense = jax.grad(lambda a, b, c: jnp.sum(_dense_attention(
             a, b, c, causal=causal, scale=scale, kv_mask=mask) * w),
             argnums=(0, 1, 2))(q, k, v)
@@ -196,11 +202,8 @@ class TestRingDropout:
     under the lse combine, so the ring path no longer changes the recipe."""
 
     def _ring(self, mesh_ctx, q, k, v, rate, rng, causal=True):
-        sh = NamedSharding(mesh_ctx, P(None, "context"))
-        qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
-        return np.asarray(ring_attention(
-            qs, ks, vs, mesh=mesh_ctx, causal=causal,
-            dropout_rate=rate, dropout_rng=rng))
+        return np.asarray(ring(*sharded(mesh_ctx, q, k, v), rng,
+                               mesh=mesh_ctx, causal=causal, rate=rate))
 
     def test_rate_zero_matches_dense_exactly(self, mesh_ctx):
         q, k, v = make_qkv(seed=21)
@@ -233,27 +236,23 @@ class TestRingDropout:
 
     def test_chunked_blocks_support_dropout(self, mesh_ctx):
         q, k, v = make_qkv(seed=24)
-        sh = NamedSharding(mesh_ctx, P(None, "context"))
-        qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
-        out = np.asarray(ring_attention(
-            qs, ks, vs, mesh=mesh_ctx, causal=True, chunk_size=2,
-            dropout_rate=0.2, dropout_rng=jax.random.key(7)))
+        qs, ks, vs = sharded(mesh_ctx, q, k, v)
+        out = np.asarray(ring(qs, ks, vs, jax.random.key(7), mesh=mesh_ctx,
+                              chunk_size=2, rate=0.2))
         assert np.isfinite(out).all()
         base = self._ring(mesh_ctx, q, k, v, 0.0, None)
         assert not np.allclose(out, base)
 
     def test_gradients_flow_through_dropout(self, mesh_ctx):
         q, k, v = make_qkv(B=1, T=16, H=2, D=8, seed=25)
-        sh = NamedSharding(mesh_ctx, P(None, "context"))
-        qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
+        qs, ks, vs = sharded(mesh_ctx, q, k, v)
         rng = jax.random.key(9)
 
         def loss(q_, k_, v_):
-            out = ring_attention(q_, k_, v_, mesh=mesh_ctx, causal=True,
-                                 dropout_rate=0.2, dropout_rng=rng)
+            out = ring(q_, k_, v_, rng, mesh=mesh_ctx, rate=0.2)
             return jnp.sum(out.astype(jnp.float32) ** 2)
 
-        g = jax.grad(loss, argnums=(0, 1, 2))(qs, ks, vs)
+        g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(qs, ks, vs)
         for arr in g:
             a = np.asarray(arr)
             assert np.isfinite(a).all()

@@ -30,6 +30,7 @@ import numpy as np
 import pytest
 
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
+from tests.helpers import fixed_reference
 
 # Ring depth for the async schedulers under test.  2 is today's double
 # buffer; the t1.sh DTT_SERVE_ASYNC pass exports 4 so every parity and
@@ -45,13 +46,6 @@ def _mixed_requests(vocab, seed=3):
         reqs.append((rng.integers(0, vocab, size=(length,), dtype=np.int32),
                      horizon))
     return reqs
-
-
-def _fixed_reference(engine, prompt, max_new_tokens):
-    rows = engine.bucket_rows(1)
-    out = engine.generate(np.repeat(prompt[None, :], rows, axis=0),
-                          max_new_tokens)
-    return out[0]
 
 
 def _run_all(sched, reqs):
@@ -137,7 +131,7 @@ class TestAsyncParity:
                                                 overlapped):
             np.testing.assert_array_equal(out, base)
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, horizon))
+                out, fixed_reference(gpt2_engine, prompt, horizon))
 
     def test_parity_on_2d_mesh(self, mesh_2d):
         """data=4 x tensor=2, paged (the harder case: device-resident
@@ -198,7 +192,7 @@ class TestDepthOneIsSynchronous:
         for (prompt, horizon), a, b in zip(reqs, sync_outs, ring_outs):
             np.testing.assert_array_equal(a, b)
             np.testing.assert_array_equal(
-                a, _fixed_reference(gpt2_engine, prompt, horizon))
+                a, fixed_reference(gpt2_engine, prompt, horizon))
 
 
 @pytest.mark.serve_slow
@@ -269,11 +263,15 @@ class TestAsyncComposition:
                                 (8, 15), (5, 13), (6, 16)):
             base = rng.integers(0, vocab, size=(length,), dtype=np.int32)
             reqs.append((np.concatenate([base, base]), horizon))
-        kwargs = dict(num_slots=8, max_total_len=64)
-        with ContinuousScheduler(gpt2_engine, **kwargs) as sched:
+        # The classic scheduler's rows are as long as the speculating one's
+        # (``max_total_len + spec_k``): one cache shape, so the second run
+        # is served by the prefill programs of the first.
+        with ContinuousScheduler(gpt2_engine, num_slots=8,
+                                 max_total_len=64 + 2) as sched:
             baseline = _run_all(sched, reqs)
         with ContinuousScheduler(gpt2_engine, spec_k=2, async_decode=True,
-                                 async_depth=_DEPTH, **kwargs) as sched:
+                                 async_depth=_DEPTH, num_slots=8,
+                                 max_total_len=64) as sched:
             specced = _run_all(sched, reqs)
             stats = sched.stats()
             assert stats["async_sync_fallbacks"] == 0.0
@@ -311,7 +309,7 @@ class TestAsyncComposition:
             assert post.generation == gen0 + 7
             assert sched.generation == gen0 + 7
         np.testing.assert_array_equal(
-            out, _fixed_reference(gpt2_engine, whale, 6))
+            out, fixed_reference(gpt2_engine, whale, 6))
 
 
 @pytest.mark.serve_slow
@@ -377,7 +375,7 @@ class TestLaunchRing:
             sched.close(timeout=5.0)
         assert not sched._ring          # close() drained the ring
         np.testing.assert_array_equal(
-            out, _fixed_reference(gpt2_engine, prompt, 12))
+            out, fixed_reference(gpt2_engine, prompt, 12))
 
     def test_on_token_streams_post_trim_in_order(self, gpt2_engine):
         """``on_token`` fires per resolved megastep AFTER horizon trim
@@ -460,7 +458,7 @@ class TestLaunchRing:
         finally:
             sched.close(timeout=5.0)
         np.testing.assert_array_equal(
-            out_a, _fixed_reference(gpt2_engine, prompt_a, 12))
+            out_a, fixed_reference(gpt2_engine, prompt_a, 12))
 
 
 @pytest.mark.serve_slow

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
+from tests.helpers import fixed_reference
 
 
 def _mixed_requests(vocab, seed=3):
@@ -30,13 +31,6 @@ def _mixed_requests(vocab, seed=3):
         reqs.append((rng.integers(0, vocab, size=(length,), dtype=np.int32),
                      horizon))
     return reqs
-
-
-def _fixed_reference(engine, prompt, max_new_tokens):
-    rows = engine.bucket_rows(1)
-    out = engine.generate(np.repeat(prompt[None, :], rows, axis=0),
-                          max_new_tokens)
-    return out[0]
 
 
 def _run_all(sched, reqs):
@@ -90,7 +84,7 @@ class TestChunkedParity:
         for (prompt, horizon), base, out in zip(reqs, baseline, chunked):
             np.testing.assert_array_equal(out, base)
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, horizon))
+                out, fixed_reference(gpt2_engine, prompt, horizon))
 
     def test_parity_on_2d_mesh(self, mesh_2d):
         """data=4 x tensor=2: chunk offsets must compose with sharded
@@ -112,7 +106,7 @@ class TestChunkedParity:
         chunk: 10 = 4 + 4 + 2."""
         vocab = gpt2_engine.module.cfg.vocab_size
         prompt = (np.arange(10, dtype=np.int32) * 7) % vocab
-        ref = _fixed_reference(gpt2_engine, prompt, 4)
+        ref = fixed_reference(gpt2_engine, prompt, 4)
         with ContinuousScheduler(gpt2_engine, num_slots=8, max_total_len=32,
                                  prefill_budget=4) as sched:
             out = sched.submit(prompt, max_new_tokens=4).result(timeout=300)
@@ -146,8 +140,8 @@ class TestChunkedScheduling:
             for f in short_futs:
                 f.add_done_callback(record)
             sched._thread.start()
-            whale_ref = _fixed_reference(gpt2_engine, whale, 2)
-            short_refs = [_fixed_reference(gpt2_engine, s, 2)
+            whale_ref = fixed_reference(gpt2_engine, whale, 2)
+            short_refs = [fixed_reference(gpt2_engine, s, 2)
                           for s in shorts]
             np.testing.assert_array_equal(
                 whale_fut.result(timeout=300), whale_ref)
@@ -210,7 +204,7 @@ class TestChunkedReload:
             assert post.generation == gen0 + 7
             assert sched.generation == gen0 + 7
         np.testing.assert_array_equal(
-            out, _fixed_reference(gpt2_engine, whale, 2))
+            out, fixed_reference(gpt2_engine, whale, 2))
 
 
 class TestChunkedPrefix:

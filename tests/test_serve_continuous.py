@@ -24,6 +24,7 @@ from distributed_tensorflow_tpu.serve import (
     ServeEngine,
     ServeOverloadedError,
 )
+from tests.helpers import fixed_reference
 
 
 def _mixed_requests(vocab, n=20, seed=1):
@@ -37,16 +38,6 @@ def _mixed_requests(vocab, n=20, seed=1):
         reqs.append((rng.integers(0, vocab, size=(length,), dtype=np.int32),
                      horizon))
     return reqs
-
-
-def _fixed_reference(engine, prompt, max_new_tokens):
-    """The fixed-batch answer for one prompt: a full padded-batch greedy
-    generate, row 0.  Greedy decode is row-independent, so this is the
-    token-for-token target for the continuous path."""
-    rows = engine.bucket_rows(1)
-    out = engine.generate(np.repeat(prompt[None, :], rows, axis=0),
-                          max_new_tokens)
-    return out[0]
 
 
 # ---------------------------------------------------------------------------
@@ -155,7 +146,7 @@ class TestEngineSlotAPIs:
                 streams[slot].append(int(toks[slot]))
                 last[slot, 0] = toks[slot]
         for prompt, slot in zip(prompts, (6, 1, 3)):
-            ref = _fixed_reference(gpt2_engine, prompt, 5)
+            ref = fixed_reference(gpt2_engine, prompt, 5)
             np.testing.assert_array_equal(np.asarray(streams[slot]), ref)
 
     def test_inactive_slots_do_not_advance(self, gpt2_engine):
@@ -210,7 +201,7 @@ class TestContinuousScheduler:
         for (prompt, horizon), out in zip(reqs, outs):
             assert out.shape == (horizon,) and out.dtype == np.int32
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, horizon))
+                out, fixed_reference(gpt2_engine, prompt, horizon))
 
     def test_parity_under_tensor_parallel_mesh(self, mesh_2d):
         """Same parity on the data=4 x tensor=2 mesh (the --tensor=2
@@ -225,14 +216,14 @@ class TestContinuousScheduler:
                 outs = [f.result(timeout=300) for f in futs]
             for (prompt, horizon), out in zip(reqs, outs):
                 np.testing.assert_array_equal(
-                    out, _fixed_reference(eng, prompt, horizon))
+                    out, fixed_reference(eng, prompt, horizon))
 
     def test_eos_retires_slot_early(self, gpt2_engine):
         """A request whose greedy stream hits its eos token retires at the
         eos, shorter than its horizon."""
         vocab = gpt2_engine.module.cfg.vocab_size
         prompt = np.arange(6, dtype=np.int32) % vocab
-        ref = _fixed_reference(gpt2_engine, prompt, 8)
+        ref = fixed_reference(gpt2_engine, prompt, 8)
         eos = int(ref[3])  # force an eos hit mid-stream
         cut = int(np.flatnonzero(ref == eos)[0]) + 1  # first occurrence
         with ContinuousScheduler(gpt2_engine, num_slots=8,
@@ -287,7 +278,7 @@ class TestSampling:
             outs = [f.result(timeout=300) for f in futs]
         for (prompt, horizon), out in zip(reqs, outs):
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, horizon))
+                out, fixed_reference(gpt2_engine, prompt, horizon))
 
     def test_sampled_generate_valid_and_seeded(self, gpt2_engine):
         vocab = gpt2_engine.module.cfg.vocab_size
@@ -321,7 +312,7 @@ class TestIterationLevelBatcher:
         assert s["completed"] == float(len(reqs))
         for (prompt, horizon), out in zip(reqs, outs):
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, horizon))
+                out, fixed_reference(gpt2_engine, prompt, horizon))
 
     def test_iteration_level_requires_scheduler(self):
         with pytest.raises(ValueError, match="scheduler"):

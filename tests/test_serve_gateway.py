@@ -31,6 +31,7 @@ from distributed_tensorflow_tpu.serve.gateway import (
     DepthMeter,
     TokenStream,
 )
+from tests.helpers import fixed_reference
 
 
 def _wait_until(pred, timeout=10.0, interval=0.005):
@@ -436,13 +437,6 @@ def gpt2_engine(request):
     eng.close()
 
 
-def _fixed_reference(engine, prompt, max_new_tokens):
-    rows = engine.bucket_rows(1)
-    out = engine.generate(np.repeat(prompt[None, :], rows, axis=0),
-                          max_new_tokens)
-    return out[0]
-
-
 def _mixed_requests(vocab, n=8, seed=2):
     rng = np.random.default_rng(seed)
     return [(rng.integers(0, vocab, size=((4, 6, 9)[i % 3],),
@@ -484,7 +478,7 @@ def _streamed_parity(engine, **sched_kw):
         # different decode — streamed == whole, token for token.
         assert col.tokens == [int(t) for t in out]
         np.testing.assert_array_equal(
-            out, _fixed_reference(engine, prompt, horizon))
+            out, fixed_reference(engine, prompt, horizon))
     assert stats["ttfb_p50_ms"] > 0.0
     assert stats["ttfb_p99_ms"] >= stats["ttfb_p50_ms"]
     assert stats["cancelled"] == 0.0
@@ -562,7 +556,7 @@ class TestCancellation:
             # cancellation.
             np.testing.assert_array_equal(
                 keep_f.result(timeout=300),
-                _fixed_reference(gpt2_engine, keep_p, 4))
+                fixed_reference(gpt2_engine, keep_p, 4))
             assert _wait_until(
                 lambda: sched.stats()["blocks_in_use"] == baseline,
                 timeout=60)
@@ -601,7 +595,7 @@ class TestCancellation:
             nxt = sched.submit(prompt[:6], max_new_tokens=3)
             np.testing.assert_array_equal(
                 nxt.result(timeout=300),
-                _fixed_reference(gpt2_engine, prompt[:6], 3))
+                fixed_reference(gpt2_engine, prompt[:6], 3))
 
     @pytest.mark.serve_slow
     def test_mid_megastep_cancel(self, gpt2_engine):
@@ -638,7 +632,7 @@ def live_gateway(gpt2_engine):
 
 
 class TestGatewayEndToEnd:
-    def test_streamed_tokens_match_fixed_reference(self, live_gateway):
+    def test_streamed_tokens_matchfixed_reference(self, live_gateway):
         gw, engine = live_gateway
         vocab = engine.module.cfg.vocab_size
         prompt = [int(t) for t in (np.arange(6) * 5 + 2) % vocab]
@@ -650,7 +644,7 @@ class TestGatewayEndToEnd:
         conn.close()
         toks = [t for kind, d in events if kind == "token"
                 for t in d["tokens"]]
-        ref = _fixed_reference(engine, np.asarray(prompt, np.int32), 5)
+        ref = fixed_reference(engine, np.asarray(prompt, np.int32), 5)
         assert toks == [int(t) for t in ref]
         assert events[-1][0] == "done"
         assert events[-1][1]["finish_reason"] == "length"
@@ -665,7 +659,7 @@ class TestGatewayEndToEnd:
                            timeout=300)
         body = json.loads(resp.read())
         conn.close()
-        ref = _fixed_reference(engine, np.asarray(prompt, np.int32), 4)
+        ref = fixed_reference(engine, np.asarray(prompt, np.int32), 4)
         assert body["tokens"] == [int(t) for t in ref]
 
     def test_http_cancel_stops_generation_early(self, live_gateway):

@@ -24,6 +24,7 @@ from distributed_tensorflow_tpu.models.gpt2 import GPT2Config, PagedKVConfig
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
 from distributed_tensorflow_tpu.serve import sampling as sampling_lib
 from distributed_tensorflow_tpu.serve.sampling import SamplingParams
+from tests.helpers import fixed_reference
 
 
 def _mixed_requests(vocab, seed=3):
@@ -37,13 +38,6 @@ def _mixed_requests(vocab, seed=3):
         reqs.append((rng.integers(0, vocab, size=(length,), dtype=np.int32),
                      horizon))
     return reqs
-
-
-def _fixed_reference(engine, prompt, max_new_tokens):
-    rows = engine.bucket_rows(1)
-    out = engine.generate(np.repeat(prompt[None, :], rows, axis=0),
-                          max_new_tokens)
-    return out[0]
 
 
 def _run_all(sched, reqs):
@@ -198,7 +192,7 @@ def test_default_scheduler_compiles_only_the_megastep_program(mesh_dp):
         assert stats["megastep_effective_steps"] == stats["iterations"]
         for (prompt, horizon), out in zip(reqs, outs):
             np.testing.assert_array_equal(
-                out, _fixed_reference(eng, prompt, horizon))
+                out, fixed_reference(eng, prompt, horizon))
 
 
 class TestMegastepParity:
@@ -243,7 +237,7 @@ class TestMegastepParity:
                     np.testing.assert_array_equal(out, base)
                     if cache_mode != "paged-int8":
                         np.testing.assert_array_equal(
-                            out, _fixed_reference(engine, prompt, horizon))
+                            out, fixed_reference(engine, prompt, horizon))
 
     @pytest.mark.parametrize("cache_mode", ["dense", "paged"])
     def test_parity_on_2d_mesh(self, mesh_2d, cache_mode):
@@ -273,7 +267,7 @@ class TestMegastepEos:
         vocab = gpt2_engine.module.cfg.vocab_size
         prompt = (np.arange(6, dtype=np.int32) * 5) % vocab
         horizon = 6
-        ref = _fixed_reference(gpt2_engine, prompt, horizon)
+        ref = fixed_reference(gpt2_engine, prompt, horizon)
         # Pick the first token whose value has not appeared before it:
         # greedy decode then stops exactly there, at an inner step < K.
         eos_idx = next(i for i in range(1, len(ref))
@@ -335,7 +329,7 @@ class TestMegastepReload:
             assert post.generation == gen0 + 7
             assert sched.generation == gen0 + 7
         np.testing.assert_array_equal(
-            out, _fixed_reference(gpt2_engine, whale, 6))
+            out, fixed_reference(gpt2_engine, whale, 6))
 
 
 class TestMegastepComposition:

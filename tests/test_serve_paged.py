@@ -24,6 +24,7 @@ from distributed_tensorflow_tpu.serve.paged import (
     BlockAllocator,
     BlockExhaustedError,
 )
+from tests.helpers import fixed_reference
 
 
 def _mixed_requests(vocab, n=20, seed=1):
@@ -35,13 +36,6 @@ def _mixed_requests(vocab, n=20, seed=1):
         reqs.append((rng.integers(0, vocab, size=(length,), dtype=np.int32),
                      horizon))
     return reqs
-
-
-def _fixed_reference(engine, prompt, max_new_tokens):
-    rows = engine.bucket_rows(1)
-    out = engine.generate(np.repeat(prompt[None, :], rows, axis=0),
-                          max_new_tokens)
-    return out[0]
 
 
 @pytest.fixture(scope="module")
@@ -203,7 +197,7 @@ class TestPagedParity:
             hist = sched.blocks_per_request_hist()
         for (prompt, horizon), out in zip(reqs, outs):
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, horizon))
+                out, fixed_reference(gpt2_engine, prompt, horizon))
         # every retired request returned its blocks
         assert s["blocks_in_use"] == 0.0
         assert s["blocks_high_water"] > 0.0
@@ -223,7 +217,7 @@ class TestPagedParity:
                 outs = [f.result(timeout=300) for f in futs]
             for (prompt, horizon), out in zip(reqs, outs):
                 np.testing.assert_array_equal(
-                    out, _fixed_reference(eng, prompt, horizon))
+                    out, fixed_reference(eng, prompt, horizon))
 
     def test_bfloat16_kv_dtype_is_exact(self, gpt2_engine):
         """kv_dtype naming the COMPUTE dtype is a plain cast-through —
@@ -237,7 +231,7 @@ class TestPagedParity:
             outs = [f.result(timeout=300) for f in futs]
         for (prompt, horizon), out in zip(reqs, outs):
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, horizon))
+                out, fixed_reference(gpt2_engine, prompt, horizon))
 
 
 class TestInt8KV:
@@ -357,19 +351,27 @@ class TestPoolLayout:
         dense_cache = _empty_cache(model, self.NUM_SLOTS, self.TOTAL)
         paged_cache = _empty_cache(model, self.NUM_SLOTS, self.TOTAL, **paged)
 
-        def step(cache, tokens, slots, **kwargs):
-            logits, mutated = model.apply(
-                {"params": params, "cache": cache}, tokens, decode=True,
-                slot_ids=slots, mutable=["cache"], **kwargs)
-            return np.asarray(logits[:, -1], np.float32), mutated["cache"]
-
         slots = jnp.asarray([3, 0])
+
+        def stepper(**kwargs):
+            # One program a shape of call (the prefill's, a step's), not an
+            # op at a time with the layer scan compiled anew at every call.
+            apply = jax.jit(lambda cache, tokens: model.apply(
+                {"params": params, "cache": cache}, tokens, decode=True,
+                slot_ids=slots, mutable=["cache"], **kwargs))
+
+            def step(cache, tokens):
+                logits, mutated = apply(cache, tokens)
+                return np.asarray(logits[:, -1], np.float32), mutated["cache"]
+            return step
+
+        dense_step, paged_step = stepper(), stepper(**paged)
         tokens = jax.random.randint(jax.random.key(1), (2, 6), 0,
                                     cfg.vocab_size)
         atol = 0.05 if kv_dtype == "int8" else 0.0
         for _ in range(5):
-            want, dense_cache = step(dense_cache, tokens, slots)
-            got, paged_cache = step(paged_cache, tokens, slots, **paged)
+            want, dense_cache = dense_step(dense_cache, tokens)
+            got, paged_cache = paged_step(paged_cache, tokens)
             np.testing.assert_allclose(got, want, rtol=0.0, atol=atol)
             if kv_dtype is None:
                 np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
@@ -396,7 +398,7 @@ class TestPoolLayout:
                 outs = [f.result(timeout=300) for f in futs]
             for (prompt, horizon), out in zip(reqs, outs):
                 np.testing.assert_array_equal(
-                    out, _fixed_reference(eng, prompt, horizon))
+                    out, fixed_reference(eng, prompt, horizon))
 
     @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
     def test_block_swap_round_trip_is_byte_exact(self, gpt2_engine, kv_dtype):
@@ -511,7 +513,7 @@ class TestBlockBackpressure:
         assert s["completed"] == 3.0
         for (prompt, horizon), out in zip(reqs, outs):
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, horizon))
+                out, fixed_reference(gpt2_engine, prompt, horizon))
 
     def test_pool_too_small_for_one_request_rejected_at_init(self,
                                                              gpt2_engine):

@@ -18,13 +18,7 @@ from distributed_tensorflow_tpu.serve.paged import (
     BlockExhaustedError,
     chain_block_keys,
 )
-
-
-def _fixed_reference(engine, prompt, max_new_tokens):
-    rows = engine.bucket_rows(1)
-    out = engine.generate(np.repeat(prompt[None, :], rows, axis=0),
-                          max_new_tokens)
-    return out[0]
+from tests.helpers import fixed_reference
 
 
 def _shared_prefix_requests(vocab, *, prefix_len=16, groups=2, n=8, seed=2):
@@ -240,7 +234,7 @@ class TestPrefixParity:
                                  prefix_cache=True)
         for (prompt, horizon), out in zip(reqs, outs):
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, horizon))
+                out, fixed_reference(gpt2_engine, prompt, horizon))
         assert s["prefix_hits"] == 4.0  # blocks 0-3 shared, block 4 not
 
     def test_block_aligned_prompt_recomputes_last_block(self, gpt2_engine):
@@ -254,7 +248,7 @@ class TestPrefixParity:
         reqs = [(prompt, 6), (prompt, 6), (prompt, 6)]
         outs, s = _run_scheduler(gpt2_engine, reqs, sequential=True,
                                  prefix_cache=True)
-        ref = _fixed_reference(gpt2_engine, prompt, 6)
+        ref = fixed_reference(gpt2_engine, prompt, 6)
         for out in outs:
             np.testing.assert_array_equal(out, ref)
         assert s["prefix_hits"] == 6.0  # 3 mappable blocks x 2 hits
@@ -361,7 +355,7 @@ class TestPrefixEviction:
         assert s["blocks_high_water"] <= 9.0
         for (prompt, horizon), out in zip(reqs, on):
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, horizon))
+                out, fixed_reference(gpt2_engine, prompt, horizon))
 
     def test_hot_reload_invalidates_cache(self, gpt2_engine):
         """A staged weight generation drops every cached key (cached K/V
@@ -381,7 +375,7 @@ class TestPrefixEviction:
             fut = sched.submit(prompt, max_new_tokens=4)
             np.testing.assert_array_equal(
                 fut.result(timeout=300),
-                _fixed_reference(gpt2_engine, prompt, 4))
+                fixed_reference(gpt2_engine, prompt, 4))
             assert fut.generation == 123
             # the post-swap admission found an empty map: no new hits...
             assert sched.stats()["prefix_hits"] == hits_before

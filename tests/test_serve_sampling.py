@@ -41,6 +41,7 @@ from distributed_tensorflow_tpu.serve.sampling import (
     SamplingParams,
     parse_sampling_mix,
 )
+from tests.helpers import fixed_reference
 
 
 @pytest.fixture(scope="module")
@@ -49,13 +50,6 @@ def gpt2_engine(request):
     eng = ServeEngine("gpt2", mesh=mesh_dp, preset="tiny")
     yield eng
     eng.close()
-
-
-def _fixed_reference(engine, prompt, max_new_tokens):
-    rows = engine.bucket_rows(1)
-    out = engine.generate(np.repeat(prompt[None, :], rows, axis=0),
-                          max_new_tokens)
-    return out[0]
 
 
 def _slot_program_keys(engine):
@@ -388,7 +382,7 @@ class TestHeterogeneousScheduler:
                 f.result(timeout=300)
         for (p, m), out in zip(greedy_reqs, outs):
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, p, m))
+                out, fixed_reference(gpt2_engine, p, m))
 
     @pytest.mark.parametrize("sched_kw", [
         {"megastep": 4},
@@ -399,7 +393,7 @@ class TestHeterogeneousScheduler:
         vocab = gpt2_engine.module.cfg.vocab_size
         rng = np.random.default_rng(6)
         p = rng.integers(0, vocab, size=(6,), dtype=np.int32)
-        ref = _fixed_reference(gpt2_engine, p, 6)
+        ref = fixed_reference(gpt2_engine, p, 6)
         with ContinuousScheduler(gpt2_engine, num_slots=8, max_total_len=24,
                                  **sched_kw) as sched:
             fut = sched.submit(p, max_new_tokens=6)
@@ -417,7 +411,7 @@ class TestHeterogeneousScheduler:
         vocab = gpt2_engine.module.cfg.vocab_size
         rng = np.random.default_rng(8)
         p = rng.integers(0, vocab, size=(6,), dtype=np.int32)
-        ref = _fixed_reference(gpt2_engine, p, 5)
+        ref = fixed_reference(gpt2_engine, p, 5)
         with ContinuousScheduler(gpt2_engine, num_slots=8, max_total_len=24,
                                  cache_mode="paged", block_size=4,
                                  prefill_budget=4) as sched:

@@ -25,6 +25,7 @@ import pytest
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
 from distributed_tensorflow_tpu.serve import sampling as sampling_lib
 from distributed_tensorflow_tpu.serve.continuous import _SlotRequest
+from tests.helpers import fixed_reference
 
 WHALE_LEN, WHALE_NEW = 8, 16   # a max-length request: 8 + 16 = MAX_TOTAL
 SHORT_LEN, SHORT_NEW = 4, 8
@@ -37,13 +38,6 @@ MAX_TOTAL = 24
 BLOCKS_WHALE = -(-(WHALE_LEN + WHALE_NEW - 1) // BLOCK_SIZE)
 BLOCKS_SHORT = -(-(SHORT_LEN + SHORT_NEW - 1) // BLOCK_SIZE)
 POOL = BLOCKS_WHALE + BLOCKS_SHORT  # incl. trash block 0
-
-
-def _fixed_reference(engine, prompt, max_new_tokens):
-    rows = engine.bucket_rows(1)
-    out = engine.generate(np.repeat(prompt[None, :], rows, axis=0),
-                          max_new_tokens)
-    return out[0]
 
 
 @pytest.fixture(scope="module")
@@ -150,7 +144,7 @@ class TestCtorValidation:
                                sampling={"priority": 7}).result(timeout=300)
             s = sched.stats()
         np.testing.assert_array_equal(
-            out, _fixed_reference(gpt2_engine, prompt, 5))
+            out, fixed_reference(gpt2_engine, prompt, 5))
         assert s["slo_scheduling"] == 1.0
         assert s["preemptions_total"] == 0.0
         # Dense mode exports the uniform key set with the tier zeroed.
@@ -288,10 +282,10 @@ class TestPreemptSwapResume:
             s = sched.stats()
         for prompt, out in whales:
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, WHALE_NEW))
+                out, fixed_reference(gpt2_engine, prompt, WHALE_NEW))
         for prompt, out in shorts:
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, SHORT_NEW))
+                out, fixed_reference(gpt2_engine, prompt, SHORT_NEW))
         self._assert_swap_cycle(s)
 
     @pytest.mark.serve_slow
@@ -303,10 +297,10 @@ class TestPreemptSwapResume:
             s = sched.stats()
         for prompt, out in whales:
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, WHALE_NEW))
+                out, fixed_reference(gpt2_engine, prompt, WHALE_NEW))
         for prompt, out in shorts:
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, SHORT_NEW))
+                out, fixed_reference(gpt2_engine, prompt, SHORT_NEW))
         self._assert_swap_cycle(s)
 
     @pytest.mark.serve_slow
@@ -352,10 +346,10 @@ class TestPreemptSwapResume:
             s = sched.stats()
         for prompt, out in whales:
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, WHALE_NEW))
+                out, fixed_reference(gpt2_engine, prompt, WHALE_NEW))
         for prompt, out in shorts:
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, SHORT_NEW))
+                out, fixed_reference(gpt2_engine, prompt, SHORT_NEW))
         assert s["preemptions_total"] >= 1.0
         assert s["preempt_recompute_total"] >= 1.0
         assert s["preempt_swapped_total"] == 0.0
@@ -374,10 +368,10 @@ class TestPreemptSwapResume:
                 s = sched.stats()
             for prompt, out in whales:
                 np.testing.assert_array_equal(
-                    out, _fixed_reference(eng, prompt, WHALE_NEW))
+                    out, fixed_reference(eng, prompt, WHALE_NEW))
             for prompt, out in shorts:
                 np.testing.assert_array_equal(
-                    out, _fixed_reference(eng, prompt, SHORT_NEW))
+                    out, fixed_reference(eng, prompt, SHORT_NEW))
             self._assert_swap_cycle(s)
 
     @pytest.mark.serve_slow
@@ -392,10 +386,10 @@ class TestPreemptSwapResume:
             s = sched.stats()
         for prompt, out in whales:
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, WHALE_NEW))
+                out, fixed_reference(gpt2_engine, prompt, WHALE_NEW))
         for prompt, out in shorts:
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, SHORT_NEW))
+                out, fixed_reference(gpt2_engine, prompt, SHORT_NEW))
         assert s["preemptions_total"] >= 1.0
         assert s["blocks_in_use"] == 0.0
         assert s["swapped_resident"] == 0.0
@@ -450,7 +444,7 @@ class TestHotReloadInvalidation:
                 f.result(timeout=300.0)
             s = sched.stats()
         np.testing.assert_array_equal(
-            whale_out, _fixed_reference(gpt2_engine, whale, WHALE_NEW))
+            whale_out, fixed_reference(gpt2_engine, whale, WHALE_NEW))
         assert s["preempt_swapped_total"] >= 1.0
         assert s["swap_dropped_total"] >= 1.0
         assert s["resume_swapped_total"] == 0.0

@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
+from tests.helpers import fixed_reference
 
 
 def _spec_requests(vocab, seed=3):
@@ -39,11 +40,14 @@ def _spec_requests(vocab, seed=3):
     return reqs
 
 
-def _fixed_reference(engine, prompt, max_new_tokens):
-    rows = engine.bucket_rows(1)
-    out = engine.generate(np.repeat(prompt[None, :], rows, axis=0),
-                          max_new_tokens)
-    return out[0]
+def _spec_off(engine, max_total_len, **kwargs):
+    """The spec-off scheduler with rows as long as the spec-on one's (a
+    verify launch may write ``spec_k`` positions past a row's end, so its
+    rows are ``max_total_len + spec_k`` long): the two caches have one
+    shape, and the prefill and decode programs of the first run serve the
+    second."""
+    return ContinuousScheduler(engine, max_total_len=max_total_len + 4,
+                               **kwargs)
 
 
 def _run_all(sched, reqs):
@@ -98,7 +102,7 @@ class TestSpecParity:
         kwargs = dict(num_slots=8, max_total_len=64)
         if cache_mode == "paged":
             kwargs.update(cache_mode="paged", block_size=4)
-        with ContinuousScheduler(gpt2_engine, **kwargs) as sched:
+        with _spec_off(gpt2_engine, **kwargs) as sched:
             baseline = _run_all(sched, reqs)
         with ContinuousScheduler(gpt2_engine, spec_k=4, **kwargs) as sched:
             spec = _run_all(sched, reqs)
@@ -114,7 +118,7 @@ class TestSpecParity:
         for (prompt, horizon), base, out in zip(reqs, baseline, spec):
             np.testing.assert_array_equal(out, base)
             np.testing.assert_array_equal(
-                out, _fixed_reference(gpt2_engine, prompt, horizon))
+                out, fixed_reference(gpt2_engine, prompt, horizon))
 
     @pytest.mark.parametrize("cache_mode", ["dense", "paged"])
     def test_parity_on_2d_mesh(self, mesh_2d, cache_mode):
@@ -127,7 +131,7 @@ class TestSpecParity:
             kwargs = dict(num_slots=8, max_total_len=64)
             if cache_mode == "paged":
                 kwargs.update(cache_mode="paged", block_size=4)
-            with ContinuousScheduler(eng, **kwargs) as sched:
+            with _spec_off(eng, **kwargs) as sched:
                 baseline = _run_all(sched, reqs)
             with ContinuousScheduler(eng, spec_k=4, **kwargs) as sched:
                 spec = _run_all(sched, reqs)
@@ -152,9 +156,9 @@ class TestSpecSampled:
             # scheme can align with the sequential loop — single-stream
             # is where exact equality is promised.
             outs = []
-            with ContinuousScheduler(gpt2_engine, num_slots=8,
-                                     max_total_len=64, temperature=0.8,
-                                     top_k=20, **kw) as sched:
+            make = ContinuousScheduler if kw else _spec_off
+            with make(gpt2_engine, num_slots=8, max_total_len=64,
+                      temperature=0.8, top_k=20, **kw) as sched:
                 for p, m in reqs:
                     outs.append(
                         sched.submit(p, max_new_tokens=m).result(timeout=300))
@@ -180,7 +184,7 @@ class TestSpecEmptyDraft:
                   if k[0] == "slot_verify"}
         with ContinuousScheduler(gpt2_engine, num_slots=8,
                                  max_total_len=32, spec_k=4) as sched:
-            baseline_ref = _fixed_reference(gpt2_engine, np.tile(motif, 4), 1)
+            baseline_ref = fixed_reference(gpt2_engine, np.tile(motif, 4), 1)
             out = sched.submit(np.tile(motif, 4),
                                max_new_tokens=1).result(timeout=300)
             stats = sched.stats()
@@ -197,7 +201,7 @@ class TestSpecComposition:
         vocab = gpt2_engine.module.cfg.vocab_size
         reqs = _spec_requests(vocab, seed=7)
         kwargs = dict(num_slots=8, max_total_len=64)
-        with ContinuousScheduler(gpt2_engine, **kwargs) as sched:
+        with _spec_off(gpt2_engine, **kwargs) as sched:
             baseline = _run_all(sched, reqs)
         with ContinuousScheduler(gpt2_engine, spec_k=4, prefill_budget=4,
                                  **kwargs) as sched:
@@ -222,8 +226,8 @@ class TestSpecComposition:
                       block_size=4, prefix_cache=True)
         runs = []
         for spec_k in (None, 4):
-            with ContinuousScheduler(gpt2_engine, spec_k=spec_k,
-                                     **kwargs) as sched:
+            make = ContinuousScheduler if spec_k else _spec_off
+            with make(gpt2_engine, spec_k=spec_k, **kwargs) as sched:
                 outs = [sched.submit(p, max_new_tokens=m).result(timeout=300)
                         for p, m in reqs]
                 stats = sched.stats()
@@ -242,7 +246,7 @@ class TestSpecComposition:
         vocab = gpt2_engine.module.cfg.vocab_size
         reqs = _spec_requests(vocab, seed=9)
         kwargs = dict(num_slots=8, max_total_len=64)
-        with ContinuousScheduler(gpt2_engine, **kwargs) as sched:
+        with _spec_off(gpt2_engine, **kwargs) as sched:
             baseline = _run_all(sched, reqs)
         with ContinuousScheduler(gpt2_engine, spec_k=4, megastep=4,
                                  **kwargs) as sched:
@@ -279,4 +283,4 @@ class TestSpecComposition:
             post.result(timeout=300)
             assert post.generation == gen0 + 3
         np.testing.assert_array_equal(
-            out, _fixed_reference(gpt2_engine, whale, 8))
+            out, fixed_reference(gpt2_engine, whale, 8))

@@ -8,11 +8,12 @@ Two checks:
    ``obs.exporters``) must never import jax or flax (they run in the
    metrics HTTP server and exporter threads and must stay importable
    without an accelerator runtime; ``ALLOWED`` lists the one exception,
-   the tracer's use of ``jax.profiler``), and ``models`` / ``training`` /
+   the tracer's use of ``jax.profiler``), ``models`` / ``training`` /
    ``data`` never import ``serve`` (serving sits ABOVE training, not
-   beside it).  Forbidden-edge checks look at every import, including
-   lazy function-scoped ones — moving an import inside a function does
-   not make a layering violation legal.
+   beside it), and a paged decoder family never imports another
+   (``DECODER_FAMILIES``).  Forbidden-edge checks look at every import,
+   including lazy function-scoped ones — moving an import inside a
+   function does not make a layering violation legal.
 
 2. **Cycles** — strongly-connected components of the TOP-LEVEL
    in-package import graph.  Lazy (function-scoped) imports are the
@@ -51,6 +52,16 @@ LAYER_MAP: List[Tuple[str, str, str]] = [
     (f"{_PKG}.analysis", "jax", "the analyzer must import without jax"),
     (f"{_PKG}.analysis", "flax", "the analyzer must import without jax"),
 ]
+
+# The paged decoder families.  What two of them need lives in
+# ``models.decoder_parts`` / ``models.paged_call``: a family that imports a
+# family makes its neighbour's module a library (ROADMAP D5).
+DECODER_FAMILIES = ("glm4_moe_lite", "mellum", "glm_moe_dsa", "solar_open2")
+LAYER_MAP += [
+    (f"{_PKG}.models.{family}", f"{_PKG}.models.{other}",
+     "a decoder family imports no other decoder family")
+    for family in DECODER_FAMILIES for other in DECODER_FAMILIES
+    if other != family]
 
 
 # (importer prefix, import prefix it may use all the same, why)

@@ -5,9 +5,9 @@ serving path.
 The fourth decoder family.  RMS norm, rotate-half rotary positions, the
 parameters' declaration, the latent projections (``mla_project``), the
 absorbed and the expanded latent attention (``mla_attend``), the float32
-sigmoid router, the expert layer that is told which experts it holds, the
-replicated cache's rules and the loss are ``glm4_moe_lite``'s, imported;
-what is this family's own:
+sigmoid router and the expert layer that is told which experts it holds are
+``models/decoder_parts.py``'s; a position's pool cell and the row write are
+``models/paged_call.py``'s.  What is this family's own:
 
 - **The indexer** (a ``full`` layer).  From the query's low-rank latent
   ``c_q``: ``q^I = c_q W^I_q``, ``index_n_heads`` heads of
@@ -58,7 +58,7 @@ a group of leaves of its own (``layer_0``...) and the layer loop is written
 out, not scanned: a chip of the deployment holds one pipeline stage's ten
 or so layers, never the 78.
 
-Precision as ``glm4_moe_lite``; index keys are cached in the compute type
+Precision as ``decoder_parts`` has it; index keys are cached in the compute type
 and index scores are float32 sums of bfloat16 products.  Not built: the
 multi-token-prediction layer (the main logits do not depend on it), the
 published indexer's float8 keys and its Hadamard rotation (an orthogonal
@@ -68,7 +68,6 @@ map on both sides leaves ``q^I . k^I`` as it is).
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -78,14 +77,13 @@ from flax import linen as nn
 from jax import lax
 from jax.sharding import Mesh
 
-from distributed_tensorflow_tpu.data.pipeline import synthetic_lm
 from distributed_tensorflow_tpu.models import PagedKVConfig, Workload
-from distributed_tensorflow_tpu.models.glm4_moe_lite import (
-    COUNT_EXTRA, _attention_spec, _declare, _dot, _loss_fn, _mlp_spec,
-    cache_rules, expert_layer, gated_mlp, mla_attend, mla_project,
-    mla_query_latent, rms_norm, rope)
+from distributed_tensorflow_tpu.models.decoder_parts import (
+    check_share, declare, dot, expert_layer, gated_mlp, mla_attend,
+    mla_project, mla_query_latent, mla_spec, mlp_spec, rms_norm, rope)
+from distributed_tensorflow_tpu.models.paged_call import (
+    PagedCall, decoder_workload, serve_refusals)
 from distributed_tensorflow_tpu.ops import paged_attention
-from distributed_tensorflow_tpu.parallel.sharding import ShardingRules
 
 # The cached attention's two implementations, as ``attention_paths()`` names
 # them.
@@ -139,7 +137,7 @@ class GlmMoeDsaConfig:
     first_expert: int = 0
     dtype: Any = jnp.bfloat16               # products' operands, parameters
 
-    router = "sigmoid_bias"                 # ``glm4_moe_lite.route``'s kind
+    router = "sigmoid_bias"                 # ``decoder_parts.route``'s kind
 
     def __post_init__(self):
         put = lambda name, value: object.__setattr__(self, name, value)
@@ -167,15 +165,7 @@ class GlmMoeDsaConfig:
                 f"{SPARSE!r}, got {mlps}")
         put("indexer_types", indexers)
         put("mlp_layer_types", mlps)
-        held = self.held
-        if not 1 <= held <= self.n_routed_experts:
-            raise ValueError(
-                f"experts_held {held} must be in 1..n_routed_experts "
-                f"{self.n_routed_experts}")
-        if not 0 <= self.first_expert <= self.n_routed_experts - held:
-            raise ValueError(
-                f"first_expert {self.first_expert} + experts_held {held} "
-                f"passes n_routed_experts {self.n_routed_experts}")
+        check_share(self, self.n_routed_experts, "n_routed_experts")
         if self.qk_rope_head_dim % 2:
             raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
         if self.qk_rope_head_dim > self.index_head_dim:
@@ -273,19 +263,19 @@ def _layer_spec(cfg, kind: str):
     mlp, indexer = kind.split("_")
     d = cfg.hidden_size
     spec = (("input_norm", (("scale", (d,)),)),
-            ("attn", _attention_spec(cfg)))
+            ("attn", mla_spec(cfg)))
     if indexer == FULL:
         spec += (("indexer", _indexer_spec(cfg)),)
     spec += (("post_norm", (("scale", (d,)),)),)
     if mlp == DENSE:
-        return spec + (("mlp", _mlp_spec(d, cfg.intermediate_size)),)
+        return spec + (("mlp", mlp_spec(d, cfg.intermediate_size)),)
     shared = cfg.n_shared_experts * cfg.moe_intermediate_size
     return spec + (
         ("router", (("kernel", (d, cfg.n_routed_experts)),
                     ("bias", (cfg.n_routed_experts,)))),
-        ("shared", _mlp_spec(d, shared)),
-        ("experts", _mlp_spec(d, cfg.moe_intermediate_size,
-                              lead=(cfg.held,))),
+        ("shared", mlp_spec(d, shared)),
+        ("experts", mlp_spec(d, cfg.moe_intermediate_size,
+                             lead=(cfg.held,))),
     )
 
 
@@ -318,14 +308,14 @@ def indexer_project(cfg, p, xn, cq, positions):
     Hi)`` float32, both scales folded in."""
     B, T, _ = xn.shape
     hi, di = cfg.index_n_heads, cfg.index_head_dim
-    q = _dot("btr,rf->btf", cq, p["wq_b"]["kernel"]).reshape(B, T, hi, di)
-    k = _dot("btd,df->btf", xn, p["wk"]["kernel"])
+    q = dot("btr,rf->btf", cq, p["wq_b"]["kernel"]).reshape(B, T, hi, di)
+    k = dot("btd,df->btf", xn, p["wk"]["kernel"])
     mean = jnp.mean(k, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(k - mean), axis=-1, keepdims=True)
     k = ((k - mean) * lax.rsqrt(var + cfg.index_norm_eps)
          * p["k_norm"]["scale"].astype(jnp.float32)
          + p["k_norm"]["bias"].astype(jnp.float32))
-    w = _dot("btd,dh->bth", xn, p["weights_proj"]["kernel"]) * (
+    w = dot("btd,dh->bth", xn, p["weights_proj"]["kernel"]) * (
         hi ** -0.5 * di ** -0.5)
     return (_rope_first(cfg, q, positions).astype(cfg.dtype),
             _rope_first(cfg, k, positions).astype(cfg.dtype), w)
@@ -334,7 +324,7 @@ def indexer_project(cfg, p, xn, cq, positions):
 def index_scores(q_i, w, k_i):
     """``I[t, s]`` of ``(B, T, Hi, Di)`` queries with weights ``(B, T, Hi)``
     over ``(B, S, Di)`` keys -> ``(B, T, S)`` float32."""
-    s = jax.nn.relu(_dot("bthd,bsd->bths", q_i, k_i))
+    s = jax.nn.relu(dot("bthd,bsd->bths", q_i, k_i))
     return jnp.sum(s * w[..., None], axis=2)
 
 
@@ -376,10 +366,10 @@ def _expanded_scores(cfg, p, q_n, q_r, latent, k_r):
     w = p["kv_b"]["kernel"].reshape(
         cfg.kv_lora_rank, H, cfg.qk_nope_head_dim + cfg.v_head_dim)
     w_k, w_v = w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
-    k_n = _dot("bsc,chd->bshd", latent, w_k, dt)
-    scores = (_dot("bthd,bshd->bhts", q_n, k_n)
-              + _dot("bthr,bsr->bhts", q_r, k_r)) / np.sqrt(cfg.qk_head_dim)
-    return scores, _dot("bsc,chv->bshv", latent, w_v, dt)
+    k_n = dot("bsc,chd->bshd", latent, w_k, dt)
+    scores = (dot("bthd,bshd->bhts", q_n, k_n)
+              + dot("bthr,bsr->bhts", q_r, k_r)) / np.sqrt(cfg.qk_head_dim)
+    return scores, dot("bsc,chv->bshv", latent, w_v, dt)
 
 
 # -- the module ----------------------------------------------------------------
@@ -395,52 +385,18 @@ class GlmMoeDsa(nn.Module):
                  live=None):
         cfg = self.cfg
         B, T = tokens.shape
-        if decode and (paged is None or slot_ids is None
-                       or block_tables is None):
-            raise ValueError(
-                "the latent and index-key pools are paged only: "
-                "decode=True needs slot_ids, paged=PagedKVConfig(...) and "
-                "block_tables (the continuous scheduler's "
-                "cache_mode='paged'); there is no dense-row or fixed-batch "
-                "cache of this family")
-        if not decode and (paged is not None or slot_ids is not None
-                           or block_tables is not None or live is not None):
-            raise ValueError(
-                "slot_ids, paged, block_tables and live only apply to "
-                "decode=True calls")
-        if paged is not None:
-            if paged.quantized or paged.kv_dtype is not None:
-                raise ValueError(
-                    f"kv_dtype {paged.kv_dtype!r}: "
-                    f"{SERVE_REFUSALS['kv_dtype']}")
-            if paged.data_shards != 1:
-                raise ValueError(SERVE_REFUSALS["per_shard_kv"])
-        params = _declare(self, param_spec(cfg), cfg)
+        params = declare(self, param_spec(cfg), cfg)
         dt, rank, lw = cfg.dtype, cfg.kv_lora_rank, cfg.latent_width
-        # Float32 from here to the head, as glm4_moe_lite keeps it.
+        # Float32 from here to the head.
         x = params["embed"][tokens].astype(jnp.float32)
-
+        view = PagedCall(
+            self, B, T, decode=decode, slot_ids=slot_ids, paged=paged,
+            block_tables=block_tables, live=live,
+            pools="the latent and index-key pools", refusals=SERVE_REFUSALS,
+            experts=(cfg.n_moe_layers, cfg.held))
+        positions = view.positions
         if decode:
-            bs = paged.block_size
-            latent_pool = self.variable(
-                "cache", "latent_pool", lambda: jnp.zeros(
-                    (cfg.num_hidden_layers, paged.num_blocks, bs,
-                     cfg.pool_width), dt))
-            index_pool = self.variable(
-                "cache", "index_pool", lambda: jnp.zeros(
-                    (cfg.n_full_layers, paged.num_blocks, bs,
-                     cfg.index_head_dim), dt))
-            index = self.variable(
-                "cache", "cache_index", lambda: jnp.zeros((B,), jnp.int32))
-            counts = self.variable(
-                "cache", "moe_counts", lambda: jnp.zeros(
-                    (cfg.n_moe_layers, cfg.held + COUNT_EXTRA), jnp.int32))
-            start = index.value[slot_ids]                         # (B,)
-            positions = start[:, None] + jnp.arange(T)[None, :]   # (B, T)
-            tables = jnp.maximum(block_tables, 0)[slot_ids]
-            cells = (jnp.take_along_axis(
-                tables, positions // bs, axis=1).reshape(-1),
-                (positions % bs).reshape(-1))
+            bs, tables = paged.block_size, view.table
             # The context is walked ``pages`` blocks a step, as far as the
             # longest row that counts reaches; the table is padded with the
             # trash block to whole steps.
@@ -450,20 +406,21 @@ class GlmMoeDsa(nn.Module):
             tables = jnp.pad(tables, (
                 (0, 0), (0, steps_max * pages - tables.shape[1])))
             span = steps_max * chunk
-            reach = jnp.max(start + T if live is None
-                            else jnp.where(live, start + T, 0))
+            reach = jnp.max(view.lengths)
             steps = jnp.minimum((reach + chunk - 1) // chunk, steps_max)
             causal = (jnp.arange(span)[None, None, :]
                       <= positions[:, :, None])                   # (B, T, S)
             paged_attention.note_path(SELECTED if T == 1 else MASKED)
-            index.value = index.value.at[slot_ids].set(start + T)
-            pools = (latent_pool.value, index_pool.value)
         else:
-            positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
             causal = jnp.broadcast_to(
                 jnp.tril(jnp.ones((T, T), bool))[None], (B, T, T))
-            pools = (None, None)
-        token_live = None if live is None else jnp.repeat(live, T)
+        view.advance()
+        pools = (
+            view.pool("latent_pool", cfg.num_hidden_layers, cfg.pool_width,
+                      dt),
+            view.pool("index_pool", cfg.n_full_layers, cfg.index_head_dim,
+                      dt))
+        token_live = view.token_live
 
         def context(pool, layer, j):
             """Positions ``j * chunk .. (j + 1) * chunk - 1`` of every row,
@@ -498,7 +455,7 @@ class GlmMoeDsa(nn.Module):
                 m_next = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
                 alpha, pr = jnp.exp(m - m_next), jnp.exp(s - m_next)
                 l = alpha * l + jnp.sum(pr, axis=-1, keepdims=True)
-                acc = alpha * acc + _dot("bhts,bshv->bhtv", pr.astype(dt), v)
+                acc = alpha * acc + dot("bhts,bshv->bhtv", pr.astype(dt), v)
                 return m_next, l, acc
 
             _, l, acc = lax.fori_loop(0, steps, one, (
@@ -529,15 +486,12 @@ class GlmMoeDsa(nn.Module):
                 ctx = mla_attend(cfg, p["attn"], q_n, q_r, latent, k_r,
                                  selection, False)
             else:
-                row = jnp.concatenate(
-                    [latent, k_r, jnp.zeros(
-                        (B, T, cfg.pool_width - lw), dt)], axis=-1)
-                latent_pool_v = latent_pool_v.at[(layer,) + cells].set(
-                    row.reshape(B * T, cfg.pool_width))
+                latent_pool_v = view.write(
+                    latent_pool_v, layer, jnp.concatenate(
+                        [latent, k_r, jnp.zeros(
+                            (B, T, cfg.pool_width - lw), dt)], axis=-1))
                 if full_layer is not None:
-                    index_pool_v = index_pool_v.at[
-                        (full_layer,) + cells].set(
-                        k_i.reshape(B * T, cfg.index_head_dim))
+                    index_pool_v = view.write(index_pool_v, full_layer, k_i)
                     scores = cached_scores(index_pool_v, full_layer, q_i, w)
                     if T == 1:
                         # The selection as pool cells, through the table
@@ -559,7 +513,7 @@ class GlmMoeDsa(nn.Module):
                 else:
                     ctx = masked_attention(p["attn"], latent_pool_v, layer,
                                            q_n, q_r, selection)
-            out = _dot("btf,fd->btd", ctx, p["attn"]["o"]["kernel"])
+            out = dot("btf,fd->btd", ctx, p["attn"]["o"]["kernel"])
             return x + out, (latent_pool_v, index_pool_v), selection
 
         full_layers, selection, count_rows = 0, None, []
@@ -584,13 +538,9 @@ class GlmMoeDsa(nn.Module):
                     mesh=self.mesh)
                 x = h + y.reshape(h.shape)
                 count_rows.append(row)
-        if decode:
-            latent_pool.value, index_pool.value = pools
-            if count_rows:
-                counts.value = counts.value + jnp.stack(count_rows)
-        x = rms_norm(x, params["final_norm"]["scale"],
-                     cfg.rms_norm_eps).astype(dt)
-        return _dot("btd,dv->btv", x, params["head"]["kernel"])
+        view.close(*pools,
+                   counts=jnp.stack(count_rows) if count_rows else None)
+        return view.head(params, x)
 
 
 # -- what the engine and the scheduler ask of a decoder family -----------------
@@ -629,72 +579,25 @@ def cache_geometry(cfg: GlmMoeDsaConfig, paged: PagedKVConfig
     return out
 
 
-# Scheduler features this family cannot serve yet, each with its reason: the
-# scheduler refuses them at construction (``ContinuousScheduler``), and
-# ``make_workload`` the ``tensor`` mesh before an engine exists.
-SERVE_REFUSALS = {
-    "dense_cache": (
-        "the latent and the index keys live in two paged pools under one "
-        "block table (cache_mode='paged'): there is no dense-row layout of "
-        "them"),
-    "kv_dtype": (
+SERVE_REFUSALS = serve_refusals(
+    "the latent and index-key pools",
+    kv_dtype=(
         "both pools are stored in the compute type: the published float8 "
         "index keys need a scale a block and a dequantizing score, an int8 "
         "latent its own scale layout"),
-    "per_shard_kv": (
-        "the pools are replicated: per-shard pools are not built for them"),
-    "slo_scheduling": (
-        "host tiering swaps the K and V pools block by block and knows "
-        "neither the latent pool's leaf nor the index keys'; preempting "
-        "would lose a victim's cache"),
-    "spec_k": (
+    spec_k=(
         "a verify launch is k+1 queries a row, each with its own "
         "selection, and rolls rejected positions back in both pools: not "
         "built or tested (nor is a drafter from the model's own "
         "multi-token-prediction layer)"),
-    "prefix_cache": (
+    prefix_cache=(
         "a shared prefix block would share its index keys too, and a "
-        "suffix prefill would have to select over them: not tested yet"),
-    "tensor_mesh": (
-        "the latent and the index key are shared by all heads and the "
-        "expert stack has no tensor rule: serve on a mesh without a "
-        "'tensor' axis"),
-}
+        "suffix prefill would have to select over them: not tested yet"))
 
 
-def make_workload(
-    *,
-    preset: str = "published",
-    batch_size: int = 8,
-    seq_len: Optional[int] = None,
-    config: Optional[GlmMoeDsaConfig] = None,
-    mesh: Optional[Mesh] = None,
-    **_unused,
-) -> Workload:
+def make_workload(*, preset: str = "published",
+                  config: Optional[GlmMoeDsaConfig] = None,
+                  mesh: Optional[Mesh] = None, **kw) -> Workload:
     cfg = config or getattr(GlmMoeDsaConfig, preset)()
-    if mesh is not None and mesh.shape.get("tensor", 1) > 1:
-        raise ValueError(
-            f"glm_moe_dsa on a mesh with tensor={mesh.shape['tensor']}: "
-            f"{SERVE_REFUSALS['tensor_mesh']}")
-    seq = seq_len or min(cfg.max_position_embeddings, 128)
-    module = GlmMoeDsa(cfg, mesh=mesh)
-    data = functools.partial(synthetic_lm, seq_len=seq,
-                             vocab_size=cfg.vocab_size)
-    return Workload(
-        name="glm_moe_dsa",
-        module=module,
-        loss_fn=functools.partial(_loss_fn, module),
-        init_batch={"tokens": np.zeros((2, seq), np.int32)},
-        data_fn=lambda per_host_bs: data(batch_size=per_host_bs),
-        eval_data_fn=lambda per_host_bs: data(batch_size=per_host_bs,
-                                              holdout=True),
-        rules=ShardingRules(),
-        batch_size=batch_size,
-        clip_grad_norm=1.0,
-        learning_rate=3e-4,
-        example_key="tokens",
-        init_key="tokens",
-        cache_rules=cache_rules,
-        cache_geometry=functools.partial(cache_geometry, cfg),
-        serve_refusals=dict(SERVE_REFUSALS),
-    )
+    return decoder_workload("glm_moe_dsa", GlmMoeDsa, cfg, mesh,
+                            cache_geometry, SERVE_REFUSALS, **kw)

@@ -2,11 +2,12 @@
 turns, every MLP a layer of sparse experts (``model_type`` ``mellum``), for
 the serving path.
 
-The third decoder family beside ``models/gpt2.py`` and
-``models/glm4_moe_lite.py``.  RMS norm, rotate-half rotary positions, the
-stacked parameter leaves, the float32 router and the expert layer that is
-told which experts it holds are ``glm4_moe_lite``'s, imported; what is this
-family's own:
+The third decoder family.  RMS norm, rotate-half rotary positions, the
+stacked parameter leaves, the float32 router, the expert layer that is told
+which experts it holds and the grouped-query attention itself are
+``models/decoder_parts.py``'s; the write and the read of a pool row, by the
+block-table kernel or the gather, are ``models/paged_call.py``'s.  What is
+this family's own:
 
 - **Grouped-query attention.**  ``num_attention_heads`` query heads over
   ``num_key_value_heads`` K/V heads of ``head_dim``: K/V head ``g`` serves
@@ -47,7 +48,7 @@ Every other call (a prefill chunk, the CPU without the interpreter) gathers
 its pool rows and attends densely under the mask (``GATHER_WINDOW`` /
 ``GATHER_FULL``), which is also what the kernel is tested against.
 
-Precision as ``glm4_moe_lite``: parameters and products' operands in
+Precision as ``decoder_parts`` has it: parameters and products' operands in
 ``dtype`` (bfloat16), float32 accumulation; the residual stream, norms,
 rotations, router, softmax and logits float32.  The multi-token head the
 family's description mentions has no key in ``config.json`` and the main
@@ -57,30 +58,22 @@ model's logits do not depend on it: not built.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 from flax import linen as nn
 from jax import lax
 from jax.sharding import Mesh
 
-from distributed_tensorflow_tpu.data.pipeline import synthetic_lm
 from distributed_tensorflow_tpu.models import PagedKVConfig, Workload
-from distributed_tensorflow_tpu.models.glm4_moe_lite import (
-    COUNT_EXTRA, _declare, _dot, _mlp_spec, _stacked, expert_layer, rms_norm,
-    rope)
-from distributed_tensorflow_tpu.ops import paged_attention
-from distributed_tensorflow_tpu.parallel.sharding import ShardingRules
-
-# The attention paths, as ``attention_paths()`` names them: each kind of layer
-# by the gather or by the block-table kernel.
-GATHER_WINDOW, GATHER_FULL = "gqa_gather_window", "gqa_gather_full"
-KERNEL_WINDOW, KERNEL_FULL = paged_attention.GQA_KERNEL_PATHS
+from distributed_tensorflow_tpu.models.decoder_parts import (
+    GATHER_FULL, GATHER_WINDOW, KERNEL_FULL, KERNEL_WINDOW, attention_mask,
+    check_share, declare, dot, expert_layer, layer_leaves, mlp_spec,
+    rms_norm, rope, stacked)
+from distributed_tensorflow_tpu.models.paged_call import (
+    PagedCall, decoder_workload, serve_refusals)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -122,7 +115,7 @@ class MellumConfig:
     first_expert: int = 0
     dtype: Any = jnp.bfloat16               # products' operands, parameters
 
-    router = "softmax"                      # ``glm4_moe_lite.route``'s kind
+    router = "softmax"                      # ``decoder_parts.route``'s kind
 
     def __post_init__(self):
         put = lambda name, value: object.__setattr__(self, name, value)
@@ -155,15 +148,7 @@ class MellumConfig:
                 f"layer_types must name {self.num_hidden_layers} layers, "
                 f"each {SLIDING!r} or {FULL!r}, got {types}")
         put("layer_types", types)
-        held = self.held
-        if not 1 <= held <= self.num_experts:
-            raise ValueError(
-                f"experts_held {held} must be in 1..num_experts "
-                f"{self.num_experts}")
-        if not 0 <= self.first_expert <= self.num_experts - held:
-            raise ValueError(
-                f"first_expert {self.first_expert} + experts_held {held} "
-                f"passes num_experts {self.num_experts}")
+        check_share(self, self.num_experts, "num_experts")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError(
                 f"num_attention_heads {self.num_attention_heads} must be a "
@@ -261,16 +246,6 @@ def plain_inv_freq(cfg: MellumConfig) -> np.ndarray:
             ).astype(np.float32)
 
 
-def attention_mask(q_pos, k_pos, window: Optional[int]):
-    """``(B, T)`` query and ``(B, S)`` key positions -> ``(B, T, S)``, True
-    where the key may be read: a position that exists (``>= 0``), not after
-    the query, and with ``window`` fewer than that many places before it
-    (the query's own place counted)."""
-    q, k = q_pos[:, :, None], k_pos[:, None, :]
-    ok = (k >= 0) & (k <= q)
-    return ok if window is None else ok & (q - k < window)
-
-
 # -- parameters ----------------------------------------------------------------
 
 def _layer_spec(cfg):
@@ -285,8 +260,8 @@ def _layer_spec(cfg):
         )),
         ("post_norm", (("scale", (d,)),)),
         ("router", (("kernel", (d, cfg.num_experts)),)),
-        ("experts", _mlp_spec(d, cfg.moe_intermediate_size,
-                              lead=(cfg.held,))),
+        ("experts", mlp_spec(d, cfg.moe_intermediate_size,
+                             lead=(cfg.held,))),
     )
 
 
@@ -294,7 +269,7 @@ def param_spec(cfg):
     d = cfg.hidden_size
     return (
         ("embed", (cfg.vocab_size, d)),
-        ("layers", _stacked(_layer_spec(cfg), cfg.num_hidden_layers)),
+        ("layers", stacked(_layer_spec(cfg), cfg.num_hidden_layers)),
         ("final_norm", (("scale", (d,)),)),
         ("head", (("kernel", (d, cfg.vocab_size)),)),
     )
@@ -308,27 +283,15 @@ def gqa_project(cfg, p, xn, positions, full: bool):
     B, T, _ = xn.shape
     hkv, hd = cfg.num_key_value_heads, cfg.head_dim
     g = cfg.num_attention_heads // hkv
-    q = _dot("btd,df->btf", xn, p["q"]["kernel"]).reshape(B, T, hkv, g, hd)
-    k = _dot("btd,df->btf", xn, p["k"]["kernel"]).reshape(B, T, hkv, hd)
-    v = _dot("btd,df->btf", xn, p["v"]["kernel"], cfg.dtype).reshape(
+    q = dot("btd,df->btf", xn, p["q"]["kernel"]).reshape(B, T, hkv, g, hd)
+    k = dot("btd,df->btf", xn, p["k"]["kernel"]).reshape(B, T, hkv, hd)
+    v = dot("btd,df->btf", xn, p["v"]["kernel"], cfg.dtype).reshape(
         B, T, hkv, hd)
     table = dict(inv_freq=yarn_inv_freq(cfg), scale=cfg.attention_factor) \
         if full else dict(inv_freq=plain_inv_freq(cfg))
     q = rope(q, positions, cfg.rope_theta, **table).astype(cfg.dtype)
     k = rope(k, positions, cfg.rope_theta, **table).astype(cfg.dtype)
     return q, k, v
-
-
-def gqa_attend(cfg, q, k, v, mask):
-    """``q`` ``(B, T, Hkv, G, D)`` over ``k``, ``v`` ``(B, S, Hkv, D)``
-    under ``mask`` ``(B, T, S)``; softmax in float32 -> ``(B, T, H * D)``."""
-    B, T = q.shape[:2]
-    scores = _dot("btkgd,bskd->bkgts", q, k) / np.sqrt(cfg.head_dim)
-    scores = jnp.where(mask[:, None, None], scores,
-                       jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-    out = _dot("bkgts,bskd->btkgd", probs, v, cfg.dtype)
-    return out.reshape(B, T, cfg.num_attention_heads * cfg.head_dim)
 
 
 # -- the module ----------------------------------------------------------------
@@ -344,26 +307,21 @@ class Mellum(nn.Module):
                  live=None):
         cfg = self.cfg
         B, T = tokens.shape
-        if decode and (paged is None or slot_ids is None
-                       or block_tables is None):
-            raise ValueError(
-                "the two K/V pools are paged only: decode=True needs "
-                "slot_ids, paged=PagedKVConfig(...) and block_tables (the "
-                "continuous scheduler's cache_mode='paged'); there is no "
-                "dense-row or fixed-batch cache of this family")
-        if not decode and (paged is not None or slot_ids is not None
-                           or block_tables is not None or live is not None):
-            raise ValueError(
-                "slot_ids, paged, block_tables and live only apply to "
-                "decode=True calls")
-        if paged is not None:
-            if paged.quantized or paged.kv_dtype is not None:
-                raise ValueError(
-                    f"kv_dtype {paged.kv_dtype!r}: "
-                    f"{SERVE_REFUSALS['kv_dtype']}")
-            if paged.data_shards != 1:
-                raise ValueError(SERVE_REFUSALS["per_shard_kv"])
-            if cfg.n_window_layers and not paged.window_ring:
+        params = declare(self, param_spec(cfg), cfg)
+        # Float32 from here to the head: the router reads the stream's norm
+        # unrounded.
+        x = params["embed"][tokens].astype(jnp.float32)
+        view = PagedCall(
+            self, B, T, decode=decode, slot_ids=slot_ids, paged=paged,
+            block_tables=block_tables, live=live, pools="the two K/V pools",
+            refusals=SERVE_REFUSALS, experts=(cfg.num_hidden_layers, cfg.held))
+        n_win = cfg.n_window_layers
+        positions = view.positions
+        # Full layers: the whole table row is read back, in position order.
+        full_mask = attention_mask(positions, view.key_positions(), None)
+        win_cells = win_blocks = None
+        if decode:
+            if n_win and not paged.window_ring:
                 raise ValueError(
                     "window layers need the window pool: paged.window_ring "
                     "and paged.window_blocks (PagedKVConfig), which the "
@@ -381,128 +339,59 @@ class Mellum(nn.Module):
                     f"holds {paged.window_capacity} ({paged.window_ring} "
                     f"blocks of {paged.block_size}); prefill in chunks "
                     f"(prefill_budget) or size the ring for the call")
-        params = _declare(self, param_spec(cfg), cfg)
-        # Float32 from here to the head, as glm4_moe_lite keeps it: the
-        # router reads the stream's norm unrounded.
-        x = params["embed"][tokens].astype(jnp.float32)
-
-        n_win, n_full = cfg.n_window_layers, cfg.n_full_layers
-        if decode:
-            bs = paged.block_size
-            row = (bs, cfg.kv_row)
-            full_pool = self.variable(
-                "cache", "full_pool", lambda: jnp.zeros(
-                    (n_full, paged.num_blocks) + row, cfg.dtype))
-            window_pool = self.variable(
-                "cache", "window_pool", lambda: jnp.zeros(
-                    (n_win, max(paged.window_blocks, 1)) + row, cfg.dtype))
-            index = self.variable(
-                "cache", "cache_index", lambda: jnp.zeros((B,), jnp.int32))
-            counts = self.variable(
-                "cache", "moe_counts", lambda: jnp.zeros(
-                    (cfg.num_hidden_layers, cfg.held + COUNT_EXTRA),
-                    jnp.int32))
-            start = index.value[slot_ids]                         # (B,)
-            positions = start[:, None] + jnp.arange(T)[None, :]   # (B, T)
-            full_bt, ring_bt = paged.split_tables(
-                jnp.maximum(block_tables, 0)[slot_ids])
-            flat = lambda a: a.reshape(-1)
-            # Full layers: position p in the row's p // bs-th block; the
-            # whole table row is read back, in position order.
-            full_cells = (flat(jnp.take_along_axis(
-                full_bt, positions // bs, axis=1)), flat(positions % bs))
-            full_mask = attention_mask(
-                positions, jnp.broadcast_to(
-                    jnp.arange(full_bt.shape[1] * bs)[None],
-                    (B, full_bt.shape[1] * bs)), None)
-            win_cells = win_mask = None
+            bs, win_blocks = paged.block_size, max(paged.window_blocks, 1)
+            win_mask = None
             if n_win:
                 # Window layers: position p in ring cell p % cap.  After
                 # this call's writes, cell c holds the newest position
                 # <= last with that remainder (below 0: never written).
                 cap = paged.window_capacity
                 ring_pos = positions % cap
-                win_cells = (flat(jnp.take_along_axis(
-                    ring_bt, ring_pos // bs, axis=1)), flat(ring_pos % bs))
+                win_cells = (jnp.take_along_axis(
+                    view.ring, ring_pos // bs, axis=1).reshape(-1),
+                    (ring_pos % bs).reshape(-1))
                 last = positions[:, -1:]                          # (B, 1)
                 held_pos = last - (last - jnp.arange(cap)[None]) % cap
                 win_mask = attention_mask(positions, held_pos,
                                           cfg.sliding_window)
-            index.value = index.value.at[slot_ids].set(start + T)
-            pools = (window_pool.value, full_pool.value)
-            # A decode step reads the pools where they lie; a row that is
-            # not live reads nothing.
-            kernel = paged_attention.supported(
-                query_len=T, block_size=bs, width=cfg.kv_row // 2,
-                pool_dtype=cfg.dtype, compute_dtype=cfg.dtype,
-                mesh=self.mesh, data_shards=paged.data_shards,
-                groups=cfg.num_attention_heads // cfg.num_key_value_heads)
-            lengths = start + T if live is None else jnp.where(
-                live, start + T, 0)
         else:
-            positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-            full_mask = attention_mask(positions, positions, None)
             win_mask = attention_mask(positions, positions,
                                       cfg.sliding_window)
-            pools, full_bt, ring_bt = (None, None), None, None
-            full_cells = win_cells = lengths = None
-            kernel = False
-        token_live = None if live is None else jnp.repeat(live, T)
+        view.advance()
+        pools = (
+            view.pool("window_pool", n_win, cfg.kv_row, cfg.dtype,
+                      blocks=win_blocks),
+            view.pool("full_pool", cfg.n_full_layers, cfg.kv_row, cfg.dtype))
+        lengths, token_live = view.lengths, view.token_live
+        # A full layer reads the row's own blocks; a window layer its
+        # ring's, from the window's first position on.
+        reads = {
+            True: dict(mask=full_mask, paths=(GATHER_FULL, KERNEL_FULL)),
+            False: dict(mask=win_mask, paths=(GATHER_WINDOW, KERNEL_WINDOW),
+                        table=view.ring, cells=win_cells,
+                        window=cfg.sliding_window)}
 
         def attention(p, x, pool, layer, full: bool):
             xn = rms_norm(x, p["input_norm"]["scale"],
                           cfg.rms_norm_eps).astype(cfg.dtype)
             q, k, v = gqa_project(cfg, p["attn"], xn, positions, full)
-            ctx = None
-            if pool is not None:
-                cells, table = ((full_cells, full_bt) if full
-                                else (win_cells, ring_bt))
-                half = cfg.kv_row // 2
-                pool = pool.at[(layer,) + cells].set(jnp.concatenate(
-                    [k.reshape(B * T, half), v.reshape(B * T, half)],
-                    axis=-1))
-                if kernel:
-                    # The block-table kernel: the row's own blocks, from
-                    # the window's first position on in a window layer,
-                    # whose table is its ring.
-                    paged_attention.note_path(
-                        KERNEL_FULL if full else KERNEL_WINDOW)
-                    ctx = paged_attention.paged_decode_attention(
-                        q, pool, None, table, lengths, layer=layer,
-                        firsts=None if full else jnp.maximum(
-                            lengths - cfg.sliding_window, 0),
-                    ).reshape(B, T, cfg.num_attention_heads * cfg.head_dim)
-                else:
-                    paged_attention.note_path(
-                        GATHER_FULL if full else GATHER_WINDOW)
-                    rows = pool[layer, table].reshape(B, -1, cfg.kv_row)
-                    shape = (B, rows.shape[1], cfg.num_key_value_heads,
-                             cfg.head_dim)
-                    k = rows[..., :half].reshape(shape)
-                    v = rows[..., half:].reshape(shape)
-            if ctx is None:
-                ctx = gqa_attend(cfg, q, k, v,
-                                 full_mask if full else win_mask)
-            return x + _dot("btf,fd->btd", ctx, p["attn"]["o"]["kernel"]), pool
+            ctx, pool = view.gqa(pool, layer, q, k, v, lengths=lengths,
+                                 **reads[full])
+            return x + dot("btf,fd->btd", ctx, p["attn"]["o"]["kernel"]), pool
 
         period = cfg.period
         kinds = [t == FULL for t in cfg.layer_types[:period]]
         win_per = period - sum(kinds)
 
         def one_period(carry, n):
-            x, win_pool, full_pool_v = carry
+            x, win_pool, full_pool = carry
             rows = []
             seen = [0, 0]                   # window, full layers so far
             for i, full in enumerate(kinds):
-                # One layer's leaves, taken from the stack where a product
-                # reads them: a period's leaves sliced out together are
-                # copied, 0.8 GB of expert stacks a period and step.
-                p = jax.tree.map(
-                    lambda w: lax.dynamic_index_in_dim(
-                        w, n * period + i, keepdims=False), params["layers"])
+                p = layer_leaves(params["layers"], n * period + i)
                 if full:
                     layer = n * (period - win_per) + seen[1]
-                    h, full_pool_v = attention(p, x, full_pool_v, layer, True)
+                    h, full_pool = attention(p, x, full_pool, layer, True)
                 else:
                     layer = n * win_per + seen[0]
                     h, win_pool = attention(p, x, win_pool, layer, False)
@@ -514,28 +403,16 @@ class Mellum(nn.Module):
                     layer=n * period + i, mesh=self.mesh)
                 x = h + y.reshape(h.shape)
                 rows.append(row)
-            return (x, win_pool, full_pool_v), jnp.stack(rows)
+            return (x, win_pool, full_pool), jnp.stack(rows)
 
-        n_periods = cfg.num_hidden_layers // period
-        (x, win_pool, full_pool_v), rows = lax.scan(
+        (x, *pools), rows = lax.scan(
             one_period, (x,) + pools,
-            jnp.arange(n_periods, dtype=jnp.int32))
-        if decode:
-            window_pool.value, full_pool.value = win_pool, full_pool_v
-            counts.value = counts.value + rows.reshape(counts.value.shape)
-        x = rms_norm(x, params["final_norm"]["scale"],
-                     cfg.rms_norm_eps).astype(cfg.dtype)
-        return _dot("btd,dv->btv", x, params["head"]["kernel"])
+            jnp.arange(cfg.num_hidden_layers // period, dtype=jnp.int32))
+        view.close(*pools, counts=rows)
+        return view.head(params, x)
 
 
 # -- what the engine and the scheduler ask of a decoder family -----------------
-
-def cache_rules(per_shard_pools: bool = False) -> ShardingRules:
-    """The cache collection is replicated (no ``tensor`` rule; the workload
-    refuses such a mesh)."""
-    del per_shard_pools
-    return ShardingRules()
-
 
 def cache_geometry(cfg: MellumConfig, paged: PagedKVConfig) -> Dict[str, Any]:
     """Both kinds of pool.  ``window_positions`` and the layer counts are
@@ -569,73 +446,23 @@ def cache_geometry(cfg: MellumConfig, paged: PagedKVConfig) -> Dict[str, Any]:
     return out
 
 
-SERVE_REFUSALS = {
-    "dense_cache": (
-        "window and full layers keep their K/V in two paged pools "
-        "(cache_mode='paged'): there is no dense-row layout of them"),
-    "kv_dtype": (
-        "the two pools are stored in the compute type: an int8 or cast "
-        "K/V needs scale tables for both pools and a dequantizing read"),
-    "per_shard_kv": (
-        "the pools are replicated: per-shard pools are not built for the "
-        "window ring"),
-    "slo_scheduling": (
+SERVE_REFUSALS = serve_refusals(
+    "the two K/V pools",
+    slo_scheduling=(
         "host tiering swaps one pool's blocks and does not know the window "
         "ring, whose blocks hold a row's latest positions and not its "
         "first; preempting would lose a victim's cache"),
-    "spec_k": (
+    spec_k=(
         "a verify launch rolls rejected positions back, and in the ring "
         "they have already overwritten the positions a window behind"),
-    "prefix_cache": (
+    prefix_cache=(
         "a shared prefix block of a window layer may already be "
-        "overwritten by the request that registered it"),
-    "tensor_mesh": (
-        "four K/V heads and the expert stack have no tensor rule: serve on "
-        "a mesh without a 'tensor' axis"),
-}
+        "overwritten by the request that registered it"))
 
 
-def _loss_fn(module, params, batch, rng):
-    tokens = batch["tokens"]
-    logits = module.apply({"params": params}, tokens)
-    loss = jnp.mean(optax.softmax_cross_entropy_with_integer_labels(
-        logits[:, :-1], tokens[:, 1:]))
-    return loss, {"perplexity": jnp.exp(jnp.minimum(loss, 20.0))}
-
-
-def make_workload(
-    *,
-    preset: str = "published",
-    batch_size: int = 8,
-    seq_len: Optional[int] = None,
-    config: Optional[MellumConfig] = None,
-    mesh: Optional[Mesh] = None,
-    **_unused,
-) -> Workload:
+def make_workload(*, preset: str = "published",
+                  config: Optional[MellumConfig] = None,
+                  mesh: Optional[Mesh] = None, **kw) -> Workload:
     cfg = config or getattr(MellumConfig, preset)()
-    if mesh is not None and mesh.shape.get("tensor", 1) > 1:
-        raise ValueError(
-            f"mellum on a mesh with tensor={mesh.shape['tensor']}: "
-            f"{SERVE_REFUSALS['tensor_mesh']}")
-    seq = seq_len or min(cfg.max_position_embeddings, 128)
-    module = Mellum(cfg, mesh=mesh)
-    data = functools.partial(synthetic_lm, seq_len=seq,
-                             vocab_size=cfg.vocab_size)
-    return Workload(
-        name="mellum",
-        module=module,
-        loss_fn=functools.partial(_loss_fn, module),
-        init_batch={"tokens": np.zeros((2, seq), np.int32)},
-        data_fn=lambda per_host_bs: data(batch_size=per_host_bs),
-        eval_data_fn=lambda per_host_bs: data(batch_size=per_host_bs,
-                                              holdout=True),
-        rules=ShardingRules(),
-        batch_size=batch_size,
-        clip_grad_norm=1.0,
-        learning_rate=3e-4,
-        example_key="tokens",
-        init_key="tokens",
-        cache_rules=cache_rules,
-        cache_geometry=functools.partial(cache_geometry, cfg),
-        serve_refusals=dict(SERVE_REFUSALS),
-    )
+    return decoder_workload("mellum", Mellum, cfg, mesh, cache_geometry,
+                            SERVE_REFUSALS, **kw)

@@ -4,9 +4,10 @@ softmax layer in four, every MLP a layer of sparse experts (``model_type``
 
 The fifth decoder family.  RMS norm, the stacked parameter leaves, the
 float32 sigmoid router and the expert layer that is told which experts it
-holds are ``glm4_moe_lite``'s, imported; the grouped-query attention over a
-paged K/V pool is ``mellum``'s full layer without its rotation (``use_rope``
-false: no positional term anywhere).  What is this family's own:
+holds are ``models/decoder_parts.py``'s; the grouped-query attention over a
+paged K/V pool is that module's and ``models/paged_call.py``'s, a full layer
+without rotation (``use_rope`` false: no positional term anywhere).  What is
+this family's own:
 
 - **Kimi Delta Attention** (arXiv:2510.26692) on every layer that
   ``gqa_layers`` does not name.  ``q~, k~, v~ = x W_qkv`` (each ``num_heads x
@@ -50,7 +51,7 @@ false: no positional term anywhere).  What is this family's own:
   reads and writes the leaves where they lie.  A call without (a prefill
   chunk) gathers its rows' states and scatters them back.
 
-Precision as ``glm4_moe_lite``: parameters and products' operands in
+Precision as ``decoder_parts`` has it: parameters and products' operands in
 ``dtype`` (bfloat16), float32 accumulation; the residual stream, norms,
 gates, router, softmax and logits float32.  The state, the decay (``A_log``,
 ``dt_bias``: float32 leaves, the group ``kda_decay``), its running sum in
@@ -61,25 +62,21 @@ tail is held in ``dtype``.  No multi-token head: the row's config has none.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from flax import linen as nn
 from jax import lax
 from jax.sharding import Mesh
 
-from distributed_tensorflow_tpu.data.pipeline import synthetic_lm
 from distributed_tensorflow_tpu.models import PagedKVConfig, Workload
-from distributed_tensorflow_tpu.models.glm4_moe_lite import (
-    COUNT_EXTRA, _declare, _dot, _loss_fn, _mlp_spec, _stacked, cache_rules,
-    expert_layer, rms_norm)
-from distributed_tensorflow_tpu.models.mellum import (
-    GATHER_FULL, KERNEL_FULL, attention_mask, gqa_attend)
+from distributed_tensorflow_tpu.models.decoder_parts import (
+    GATHER_FULL, KERNEL_FULL, attention_mask, check_share, declare, dot,
+    expert_layer, layer_leaves, mlp_spec, rms_norm, stacked)
+from distributed_tensorflow_tpu.models.paged_call import (
+    PagedCall, decoder_workload, serve_refusals)
 from distributed_tensorflow_tpu.ops import paged_attention
-from distributed_tensorflow_tpu.parallel.sharding import ShardingRules
 
 # The linear attention's two forms, as ``attention_paths()`` names them.
 KDA_CHUNK_PATH, KDA_STEP_PATH = "kda_chunk", "kda_step"
@@ -132,7 +129,7 @@ class SolarOpen2Config:
     first_expert: int = 0
     dtype: Any = jnp.bfloat16             # products' operands, parameters
 
-    router = "sigmoid_bias"               # ``glm4_moe_lite.route``'s kind
+    router = "sigmoid_bias"               # ``decoder_parts.route``'s kind
 
     def __post_init__(self):
         put = lambda name, value: object.__setattr__(self, name, value)
@@ -165,15 +162,7 @@ class SolarOpen2Config:
                  "a leading dense layer is not written")):
             if getattr(self, flag) != value:
                 raise ValueError(f"{flag} must be {value!r}: {why}")
-        held = self.held
-        if not 1 <= held <= self.n_routed_experts:
-            raise ValueError(
-                f"experts_held {held} must be in 1..n_routed_experts "
-                f"{self.n_routed_experts}")
-        if not 0 <= self.first_expert <= self.n_routed_experts - held:
-            raise ValueError(
-                f"first_expert {self.first_expert} + experts_held {held} "
-                f"passes n_routed_experts {self.n_routed_experts}")
+        check_share(self, self.n_routed_experts, "n_routed_experts")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError(
                 f"num_attention_heads {self.num_attention_heads} must be a "
@@ -259,9 +248,9 @@ def _common_spec(cfg):
         ("post_norm", (("scale", (d,)),)),
         ("router", (("kernel", (d, cfg.n_routed_experts)),
                     ("bias", (cfg.n_routed_experts,)))),
-        ("shared", _mlp_spec(d, shared)),
-        ("experts", _mlp_spec(d, cfg.moe_intermediate_size,
-                              lead=(cfg.held,))),
+        ("shared", mlp_spec(d, shared)),
+        ("experts", mlp_spec(d, cfg.moe_intermediate_size,
+                             lead=(cfg.held,))),
     )
 
 
@@ -308,10 +297,10 @@ def param_spec(cfg):
         ("embed", (cfg.vocab_size, d)),
         # What every layer has, stacked over all of them; then each kind of
         # mixer stacked over the layers of its kind, in their order.
-        ("layers", _stacked(_common_spec(cfg), cfg.num_hidden_layers)),
-        ("gqa", _stacked(_gqa_spec(cfg), cfg.n_gqa_layers)),
-        ("kda", _stacked(_kda_spec(cfg), cfg.n_kda_layers)),
-        ("kda_decay", _stacked(_decay_spec(cfg), cfg.n_kda_layers)),
+        ("layers", stacked(_common_spec(cfg), cfg.num_hidden_layers)),
+        ("gqa", stacked(_gqa_spec(cfg), cfg.n_gqa_layers)),
+        ("kda", stacked(_kda_spec(cfg), cfg.n_kda_layers)),
+        ("kda_decay", stacked(_decay_spec(cfg), cfg.n_kda_layers)),
         ("final_norm", (("scale", (d,)),)),
         ("head", (("kernel", (d, cfg.vocab_size)),)),
     )
@@ -330,7 +319,7 @@ def kda_project(cfg, p, xn, tail):
     H, D, f = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_width
     taps = cfg.kda_conv_size
     # The convolution reads its inputs as the tail holds them: rounded once.
-    pre = _dot("btd,df->btf", xn, p["qkv"]["kernel"], cfg.dtype)
+    pre = dot("btd,df->btf", xn, p["qkv"]["kernel"], cfg.dtype)
     run = jnp.concatenate([tail.astype(cfg.dtype), pre], axis=1)
     w = p["conv"]["scale"].astype(jnp.float32)
     mixed = sum(w[j] * run[:, j:j + T].astype(jnp.float32)
@@ -340,13 +329,13 @@ def kda_project(cfg, p, xn, tail):
     unit = lambda a: a * lax.rsqrt(
         jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
     q, k = unit(q) * D ** -0.5, unit(k)
-    low = lambda down, up: _dot(
-        "btr,rf->btf", _dot("btd,dr->btr", xn, p[down]["kernel"], cfg.dtype),
+    low = lambda down, up: dot(
+        "btr,rf->btf", dot("btd,dr->btr", xn, p[down]["kernel"], cfg.dtype),
         p[up]["kernel"])
     rate = jnp.exp(p["decay"]["A_log"])[:, None]                  # (H, 1)
     log_decay = -rate * jax.nn.softplus(
         low("a_down", "a_up") + p["decay"]["dt_bias"]).reshape(B, T, H, D)
-    beta = jax.nn.sigmoid(_dot("btd,dh->bth", xn, p["beta"]["kernel"]))
+    beta = jax.nn.sigmoid(dot("btd,dh->bth", xn, p["beta"]["kernel"]))
     if cfg.kda_allow_neg_eigval:
         beta = 2.0 * beta
     gate = jax.nn.sigmoid(low("g_down", "g_up"))
@@ -396,7 +385,7 @@ def _decayed_gram(a, k, g, dtype):
             right = (kb[..., :r, :, :] * jnp.exp(
                 edge[..., None, None, :] - gb[..., :r, :, :])
             ).reshape(lead + (r * b, D))
-            parts.append(_dot("...td,...id->...ti", left.astype(dtype),
+            parts.append(dot("...td,...id->...ti", left.astype(dtype),
                               right.astype(dtype)))
         parts.append(diag[..., r, :, :])
         if r < R - 1:
@@ -450,10 +439,10 @@ def kda_chunk(state, q, k, v, log_decay, beta, dtype=jnp.float32):
     def one_chunk(state, xs):
         q_in, a_qk, u_v, w, k_out, carry = xs
         held = state.astype(dtype)
-        u = u_v - _dot("bhck,bhkv->bhcv", w.astype(dtype), held)
-        out = (_dot("bhck,bhkv->bhcv", q_in, held)
-               + _dot("bhci,bhiv->bhcv", a_qk.astype(dtype), u.astype(dtype)))
-        state = carry[..., None] * state + _dot(
+        u = u_v - dot("bhck,bhkv->bhcv", w.astype(dtype), held)
+        out = (dot("bhck,bhkv->bhcv", q_in, held)
+               + dot("bhci,bhiv->bhcv", a_qk.astype(dtype), u.astype(dtype)))
+        state = carry[..., None] * state + dot(
             "bhck,bhcv->bhkv", k_out, u.astype(dtype))
         return state, out
 
@@ -469,7 +458,7 @@ def kda_output(cfg, p, out, gate):
     gated, through ``W_o`` -> ``(B, T, d)`` float32."""
     B, T = out.shape[:2]
     normed = rms_norm(out, p["o_norm"]["scale"], cfg.rms_norm_eps)
-    return _dot("btf,fd->btd",
+    return dot("btf,fd->btd",
                 (normed.reshape(B, T, cfg.kda_width) * gate).astype(cfg.dtype),
                 p["o"]["kernel"])
 
@@ -481,13 +470,13 @@ def gqa_project(cfg, p, xn):
     B, T, _ = xn.shape
     hkv, hd = cfg.num_key_value_heads, cfg.head_dim
     g = cfg.num_attention_heads // hkv
-    q = _dot("btd,df->btf", xn, p["q"]["kernel"], cfg.dtype).reshape(
+    q = dot("btd,df->btf", xn, p["q"]["kernel"], cfg.dtype).reshape(
         B, T, hkv, g, hd)
-    k = _dot("btd,df->btf", xn, p["k"]["kernel"], cfg.dtype).reshape(
+    k = dot("btd,df->btf", xn, p["k"]["kernel"], cfg.dtype).reshape(
         B, T, hkv, hd)
-    v = _dot("btd,df->btf", xn, p["v"]["kernel"], cfg.dtype).reshape(
+    v = dot("btd,df->btf", xn, p["v"]["kernel"], cfg.dtype).reshape(
         B, T, hkv, hd)
-    gate = jax.nn.sigmoid(_dot("btd,df->btf", xn, p["gate"]["kernel"]))
+    gate = jax.nn.sigmoid(dot("btd,df->btf", xn, p["gate"]["kernel"]))
     return q, k, v, gate
 
 
@@ -504,114 +493,48 @@ class SolarOpen2(nn.Module):
                  live=None):
         cfg = self.cfg
         B, T = tokens.shape
-        if decode and (paged is None or slot_ids is None
-                       or block_tables is None):
-            raise ValueError(
-                "the K/V pool is paged only: decode=True needs slot_ids, "
-                "paged=PagedKVConfig(...) and block_tables (the continuous "
-                "scheduler's cache_mode='paged'); there is no dense-row or "
-                "fixed-batch cache of this family")
-        if not decode and (paged is not None or slot_ids is not None
-                           or block_tables is not None or live is not None):
-            raise ValueError(
-                "slot_ids, paged, block_tables and live only apply to "
-                "decode=True calls")
-        if paged is not None:
-            if paged.quantized or paged.kv_dtype is not None:
-                raise ValueError(
-                    f"kv_dtype {paged.kv_dtype!r}: "
-                    f"{SERVE_REFUSALS['kv_dtype']}")
-            if paged.data_shards != 1:
-                raise ValueError(SERVE_REFUSALS["per_shard_kv"])
         spec = param_spec(cfg)
-        params = _declare(
+        params = declare(
             self, tuple(g for g in spec if g[0] != "kda_decay"), cfg)
-        params.update(_declare(
+        params.update(declare(
             self, tuple(g for g in spec if g[0] == "kda_decay"),
             dataclasses.replace(cfg, dtype=jnp.float32)))
         x = params["embed"][tokens].astype(jnp.float32)
+        view = PagedCall(
+            self, B, T, decode=decode, slot_ids=slot_ids, paged=paged,
+            block_tables=block_tables, live=live, pools="the K/V pool",
+            refusals=SERVE_REFUSALS, experts=(cfg.num_hidden_layers, cfg.held))
 
-        n_gqa, n_kda = cfg.n_gqa_layers, cfg.n_kda_layers
+        n_kda = cfg.n_kda_layers
         H, D = cfg.kda_num_heads, cfg.kda_head_dim
         tail_shape = (cfg.kda_conv_size - 1, 3 * cfg.kda_width)
+        pool = view.pool("full_pool", cfg.n_gqa_layers, cfg.kv_row, cfg.dtype)
+        state = view.leaf("kda_state", (n_kda, B, H, D, D), jnp.float32)
+        conv = view.leaf("kda_conv", (n_kda, B) + tail_shape, cfg.dtype)
+        positions = view.positions
+        # One of the engine's decode programs: every slot, in order.
+        in_place = live is not None
         if decode:
-            bs = paged.block_size
-            pool = self.variable(
-                "cache", "full_pool", lambda: jnp.zeros(
-                    (n_gqa, paged.num_blocks, bs, cfg.kv_row), cfg.dtype))
-            state = self.variable(
-                "cache", "kda_state", lambda: jnp.zeros(
-                    (n_kda, B, H, D, D), jnp.float32))
-            conv = self.variable(
-                "cache", "kda_conv", lambda: jnp.zeros(
-                    (n_kda, B) + tail_shape, cfg.dtype))
-            index = self.variable(
-                "cache", "cache_index", lambda: jnp.zeros((B,), jnp.int32))
-            counts = self.variable(
-                "cache", "moe_counts", lambda: jnp.zeros(
-                    (cfg.num_hidden_layers, cfg.held + COUNT_EXTRA),
-                    jnp.int32))
-            slots = state.value.shape[1]
-            # One of the engine's decode programs: every slot, in order.
-            in_place = live is not None
+            slots = state.shape[1]
             if in_place and (B != slots or T != 1):
                 raise ValueError(
                     f"a call with live is a decode step over all {slots} "
                     f"slots in order, got {B} rows of {T} positions")
-            start = index.value[slot_ids]                         # (B,)
-            positions = start[:, None] + jnp.arange(T)[None, :]   # (B, T)
-            table = jnp.maximum(block_tables, 0)[slot_ids]
-            cells = (jnp.take_along_axis(
-                table, positions // bs, axis=1).reshape(-1),
-                (positions % bs).reshape(-1))
-            mask = attention_mask(
-                positions, jnp.broadcast_to(
-                    jnp.arange(table.shape[1] * bs)[None],
-                    (B, table.shape[1] * bs)), None)
-            index.value = index.value.at[slot_ids].set(start + T)
-            carried = (pool.value, state.value, conv.value)
-            kernel = paged_attention.supported(
-                query_len=T, block_size=bs, width=cfg.kv_row // 2,
-                pool_dtype=cfg.dtype, compute_dtype=cfg.dtype,
-                mesh=self.mesh, data_shards=paged.data_shards,
-                groups=cfg.num_attention_heads // cfg.num_key_value_heads)
-            lengths = start + T if live is None else jnp.where(
-                live, start + T, 0)
-            # A row at position 0 has no history, whatever its slot held.
-            fresh = start == 0
-            keep = None if live is None else ~live.astype(bool)
-        else:
-            positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-            mask = attention_mask(positions, positions, None)
-            carried = (None, None, None)
-            cells = table = lengths = fresh = keep = None
-            kernel = in_place = False
-        token_live = None if live is None else jnp.repeat(live, T)
+        mask = attention_mask(positions, view.key_positions(), None)
+        view.advance()
+        lengths = view.lengths
+        # A row at position 0 has no history, whatever its slot held.
+        fresh = view.start == 0 if decode else None
+        keep = ~live.astype(bool) if in_place else None
+        token_live = view.token_live
 
         def gqa(p, x, xn, pool_v, layer):
             q, k, v, gate = gqa_project(cfg, p, xn)
-            ctx = None
-            if pool_v is not None:
-                half = cfg.kv_row // 2
-                pool_v = pool_v.at[(layer,) + cells].set(jnp.concatenate(
-                    [k.reshape(B * T, half), v.reshape(B * T, half)],
-                    axis=-1))
-                if kernel:
-                    paged_attention.note_path(KERNEL_FULL)
-                    ctx = paged_attention.paged_decode_attention(
-                        q, pool_v, None, table, lengths, layer=layer,
-                    ).reshape(B, T, cfg.num_attention_heads * cfg.head_dim)
-                else:
-                    paged_attention.note_path(GATHER_FULL)
-                    rows = pool_v[layer, table].reshape(B, -1, cfg.kv_row)
-                    shape = (B, rows.shape[1], cfg.num_key_value_heads,
-                             cfg.head_dim)
-                    k = rows[..., :half].reshape(shape)
-                    v = rows[..., half:].reshape(shape)
-            if ctx is None:
-                ctx = gqa_attend(cfg, q, k, v, mask)
-            return x + _dot("btf,fd->btd", (ctx * gate).astype(cfg.dtype),
-                            p["o"]["kernel"]), pool_v
+            ctx, pool_v = view.gqa(
+                pool_v, layer, q, k, v, mask=mask, lengths=lengths,
+                paths=(GATHER_FULL, KERNEL_FULL))
+            return x + dot("btf,fd->btd", (ctx * gate).astype(cfg.dtype),
+                           p["o"]["kernel"]), pool_v
 
         def kda(p, x, xn, state_v, conv_v, layer):
             if state_v is None:
@@ -656,24 +579,19 @@ class SolarOpen2(nn.Module):
             rows = []
             seen = [0, 0]                   # kda, gqa layers so far
             for i, is_gqa in enumerate(kinds):
-                # One layer's leaves, taken from the stacks where a product
-                # reads them (``mellum``: a period's leaves sliced out
-                # together are copied).
-                take = lambda stack, at: jax.tree.map(
-                    lambda w: lax.dynamic_index_in_dim(
-                        w, at, keepdims=False), stack)
-                p = take(params["layers"], n * period + i)
+                p = layer_leaves(params["layers"], n * period + i)
                 xn = rms_norm(x, p["input_norm"]["scale"],
                               cfg.rms_norm_eps).astype(cfg.dtype)
                 if is_gqa:
                     layer = n * gqa_per + seen[1]
                     with jax.named_scope("gqa"):
-                        h, pool_v = gqa(take(params["gqa"], layer), x, xn,
-                                        pool_v, layer)
+                        h, pool_v = gqa(layer_leaves(params["gqa"], layer),
+                                        x, xn, pool_v, layer)
                 else:
                     layer = n * (period - gqa_per) + seen[0]
-                    mixer = dict(take(params["kda"], layer),
-                                 decay=take(params["kda_decay"], layer))
+                    mixer = dict(
+                        layer_leaves(params["kda"], layer),
+                        decay=layer_leaves(params["kda_decay"], layer))
                     h, state_v, conv_v = kda(mixer, x, xn, state_v, conv_v,
                                              layer)
                 seen[is_gqa] += 1
@@ -686,16 +604,11 @@ class SolarOpen2(nn.Module):
                 rows.append(row)
             return (x, pool_v, state_v, conv_v), jnp.stack(rows)
 
-        n_periods = cfg.num_hidden_layers // period
-        (x, pool_v, state_v, conv_v), rows = lax.scan(
-            one_period, (x,) + carried,
-            jnp.arange(n_periods, dtype=jnp.int32))
-        if decode:
-            pool.value, state.value, conv.value = pool_v, state_v, conv_v
-            counts.value = counts.value + rows.reshape(counts.value.shape)
-        x = rms_norm(x, params["final_norm"]["scale"],
-                     cfg.rms_norm_eps).astype(cfg.dtype)
-        return _dot("btd,dv->btv", x, params["head"]["kernel"])
+        (x, *carried), rows = lax.scan(
+            one_period, (x, pool, state, conv),
+            jnp.arange(cfg.num_hidden_layers // period, dtype=jnp.int32))
+        view.close(*carried, counts=rows)
+        return view.head(params, x)
 
 
 # -- what the engine and the scheduler ask of a decoder family -----------------
@@ -727,30 +640,27 @@ def cache_geometry(cfg: SolarOpen2Config, paged: PagedKVConfig
     }
 
 
-SERVE_REFUSALS = {
-    "dense_cache": (
-        "the grouped-query layers keep their K/V in a paged pool "
-        "(cache_mode='paged'): there is no dense-row layout of it"),
-    "kv_dtype": (
+SERVE_REFUSALS = serve_refusals(
+    "the K/V pool",
+    kv_dtype=(
         "the pool is stored in the compute type, and the linear layers' "
         "state is float32: a narrower state is another model's arithmetic"),
-    "per_shard_kv": (
+    per_shard_kv=(
         "the pool and the per-slot state are replicated: per-shard pools "
         "are not built for them"),
-    "slo_scheduling": (
+    slo_scheduling=(
         "preemption with swap and host tiering move K/V blocks and have no "
         "snapshot of a slot's recurrent state: a resumed victim would "
         "decode from another request's state"),
-    "spec_k": (
+    spec_k=(
         "a verify launch advances the recurrent state over every draft "
         "position, and a rejected draft cannot be rolled back out of it"),
-    "prefix_cache": (
+    prefix_cache=(
         "a shared prefix is K/V blocks and the state after its last "
         "position, and no snapshot of a state is kept yet"),
-    "tensor_mesh": (
+    tensor_mesh=(
         "the per-slot state, eight K/V heads and the expert stack have no "
-        "tensor rule: serve on a mesh without a 'tensor' axis"),
-}
+        "tensor rule: serve on a mesh without a 'tensor' axis"))
 
 
 def _served_dtypes(cfg: SolarOpen2Config, params) -> Any:
@@ -767,40 +677,10 @@ def _served_dtypes(cfg: SolarOpen2Config, params) -> Any:
     return jax.tree_util.tree_map_with_path(one, params)
 
 
-def make_workload(
-    *,
-    preset: str = "published",
-    batch_size: int = 8,
-    seq_len: Optional[int] = None,
-    config: Optional[SolarOpen2Config] = None,
-    mesh: Optional[Mesh] = None,
-    **_unused,
-) -> Workload:
+def make_workload(*, preset: str = "published",
+                  config: Optional[SolarOpen2Config] = None,
+                  mesh: Optional[Mesh] = None, **kw) -> Workload:
     cfg = config or getattr(SolarOpen2Config, preset)()
-    if mesh is not None and mesh.shape.get("tensor", 1) > 1:
-        raise ValueError(
-            f"solar_open2 on a mesh with tensor={mesh.shape['tensor']}: "
-            f"{SERVE_REFUSALS['tensor_mesh']}")
-    seq = seq_len or min(cfg.max_position_embeddings, 128)
-    module = SolarOpen2(cfg, mesh=mesh)
-    data = functools.partial(synthetic_lm, seq_len=seq,
-                             vocab_size=cfg.vocab_size)
-    return Workload(
-        name="solar_open2",
-        module=module,
-        loss_fn=functools.partial(_loss_fn, module),
-        init_batch={"tokens": np.zeros((2, seq), np.int32)},
-        data_fn=lambda per_host_bs: data(batch_size=per_host_bs),
-        eval_data_fn=lambda per_host_bs: data(batch_size=per_host_bs,
-                                              holdout=True),
-        rules=ShardingRules(),
-        batch_size=batch_size,
-        clip_grad_norm=1.0,
-        learning_rate=3e-4,
-        example_key="tokens",
-        init_key="tokens",
-        cache_rules=cache_rules,
-        cache_geometry=functools.partial(cache_geometry, cfg),
-        serve_refusals=dict(SERVE_REFUSALS),
-        served_dtypes=functools.partial(_served_dtypes, cfg),
-    )
+    return decoder_workload("solar_open2", SolarOpen2, cfg, mesh,
+                            cache_geometry, SERVE_REFUSALS, _served_dtypes,
+                            **kw)

@@ -6,7 +6,7 @@ A layer of sparse experts holds a stack of gated MLPs, ``gate_up`` ``(held,
 2f, d)`` and ``down`` ``(held, f, d)``, and a router that gives each of
 ``N`` tokens ``k`` experts.  The plain way to apply the stack is every held
 expert over every token, weighed by gates that are zero where the router
-chose otherwise (``models/glm4_moe_lite.py: expert_layer``, its dense
+chose otherwise (``models/decoder_parts.py: expert_layer``, its dense
 form): ``held`` products a token where the router asked for ``k * held /
 experts``, and every expert's ``3 * f * d`` weights read whether a token
 chose it or not.

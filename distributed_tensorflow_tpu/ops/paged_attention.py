@@ -27,8 +27,8 @@ Sixteen-fold redundant arithmetic on the MXU buys what matters here: the
 step is bound by the bytes of K and V, and no array with ``head_dim`` in the
 minor dimension exists anywhere.
 
-The same walk serves the grouped-query family (``models/mellum.py``), by
-what the call's shapes and one more argument say:
+The same walk serves the grouped-query families (``PagedCall.gqa`` in
+``models/paged_call.py``), by what its shapes and one more argument say:
 
 - **grouped heads**: ``q`` ``(B, 1, Hkv, G, D)``.  The ``G`` query heads of a
   K/V head share its columns, so the kernel's query is ``(G, Hkv * D)`` (row
@@ -47,7 +47,7 @@ what the call's shapes and one more argument say:
 One algorithm, two implementations: ``supported`` says whether the kernel
 runs for a call, from what the call can observe (decode shape, the pool's
 storage type, the platform, one device); everything else keeps the gather
-paths in ``models/gpt2.py`` and ``models/mellum.py``, which are also the
+paths in ``models/gpt2.py`` and ``models/paged_call.py``, which are also the
 references the kernel is tested against (``tests/test_paged_attention.py``).
 """
 
@@ -85,7 +85,7 @@ _MASKED = -1e30  # finite: exp(_MASKED - m) is exactly 0, no inf - inf
 
 KERNEL, GATHER = "kernel", "gather"
 # The grouped form's calls are on record by the kind of layer they serve
-# (``models/mellum.py``); every path that reads a pool through the kernel:
+# (``decoder_parts.py``); every path that reads a pool through the kernel:
 GQA_KERNEL_PATHS = ("gqa_kernel_window", "gqa_kernel_full")
 KERNEL_PATHS = (KERNEL,) + GQA_KERNEL_PATHS
 

@@ -179,10 +179,10 @@ def expert_forms_on_record(sched, *, experts: int, chunk: int) -> None:
     launches says on its own record what its programs' expert layers took:
     ``stats()["moe_expert_form"]`` names the prefill and the decode program
     (``"<kind>/<tokens a layer's call sees>"``) with the form the call's
-    static shape gives (``glm4_moe_lite.expert_form``), a grouped program's
+    static shape gives (``decoder_parts.expert_form``), a grouped program's
     static rows are the layout's, and the counts' leaf and keys are what
     they were."""
-    from distributed_tensorflow_tpu.models import glm4_moe_lite as glm
+    from distributed_tensorflow_tpu.models import decoder_parts as parts
     from distributed_tensorflow_tpu.ops import grouped_matmul
     from distributed_tensorflow_tpu.serve.engine import moe_counts_of
 
@@ -194,7 +194,7 @@ def expert_forms_on_record(sched, *, experts: int, chunk: int) -> None:
                      sched.engine.expert_forms().items()}
     for program, n in ((f"slot_prefill/{chunk}", chunk),
                        (f"slot_megastep/{sched.num_slots}", sched.num_slots)):
-        form = glm.expert_form(n, k, experts)
+        form = parts.expert_form(n, k, experts)
         assert forms[program] == form, (program, forms)
         tm = grouped_matmul.tile_rows(n)
         assert rows[program] == (

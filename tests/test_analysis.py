@@ -326,6 +326,17 @@ class TestLayering:
         assert len(layer) == 1 and layer[0].line == 2
         assert "even lazily" in layer[0].message
 
+    def test_a_decoder_family_must_not_import_another(self, tmp_path):
+        findings = lint_source(
+            tmp_path, """\
+            from distributed_tensorflow_tpu.models.decoder_parts import dot
+            from distributed_tensorflow_tpu.models.glm4_moe_lite import route
+            """,
+            filename="distributed_tensorflow_tpu/models/mellum.py")
+        layer = by_rule(findings, "layering")
+        assert len(layer) == 1 and layer[0].line == 2
+        assert "no other decoder family" in layer[0].message
+
     def test_toplevel_cycle_detected(self, tmp_path):
         a = tmp_path / "distributed_tensorflow_tpu" / "x.py"
         b = tmp_path / "distributed_tensorflow_tpu" / "y.py"

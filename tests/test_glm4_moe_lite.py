@@ -24,6 +24,7 @@ import pytest
 
 from benchmark.reference import glm4_moe_lite as ref
 from benchmark.reference import precision
+from distributed_tensorflow_tpu.models import decoder_parts as parts
 from distributed_tensorflow_tpu.models import glm4_moe_lite as glm
 from distributed_tensorflow_tpu.models.glm4_moe_lite import (
     Glm4MoeLite, Glm4MoeLiteConfig)
@@ -124,7 +125,7 @@ def test_rope_scores_depend_on_the_distance_alone():
 
     def score(pq, pk):
         at = lambda p: jnp.full((1, 1), p, jnp.int32)
-        return float(jnp.sum(glm.rope(q, at(pq), 1e6) * glm.rope(k, at(pk), 1e6)))
+        return float(jnp.sum(parts.rope(q, at(pq), 1e6) * parts.rope(k, at(pk), 1e6)))
 
     assert score(7, 3) == pytest.approx(score(104, 100), abs=1e-5)
     assert score(7, 3) != pytest.approx(score(7, 4), abs=1e-3)
@@ -146,8 +147,8 @@ def test_absorbed_and_expanded_attention_agree(queries):
     mask = jnp.arange(S)[None, None, :] <= (S - queries + jnp.arange(queries)
                                             )[None, :, None]
     mask = jnp.broadcast_to(mask, (B, queries, S))
-    absorbed = glm.mla_attend(cfg, p, q_n, q_r, latent, k_r, mask, True)
-    expanded = glm.mla_attend(cfg, p, q_n, q_r, latent, k_r, mask, False)
+    absorbed = parts.mla_attend(cfg, p, q_n, q_r, latent, k_r, mask, True)
+    expanded = parts.mla_attend(cfg, p, q_n, q_r, latent, k_r, mask, False)
     assert absorbed.shape == (B, queries, H * cfg.v_head_dim)
     np.testing.assert_allclose(np.asarray(absorbed), np.asarray(expanded),
                                atol=2e-5)
@@ -222,7 +223,7 @@ def test_router_is_float32_whatever_the_compute_type():
     p = one_expert_layer(cfg)["router"]
     x = jnp.asarray(np.random.default_rng(4).normal(size=(96, 64)),
                     jnp.bfloat16)
-    chosen, weights = glm.route(cfg, p, x)
+    chosen, weights = parts.route(cfg, p, x)
     assert weights.dtype == jnp.float32
     dense = np.zeros((96, 64), np.float32)
     np.put_along_axis(dense, np.asarray(chosen), np.asarray(weights), axis=1)
@@ -247,7 +248,7 @@ def test_correction_bias_orders_the_choice_and_weighs_nothing():
     p = one_expert_layer(cfg)["router"]
     x = jnp.asarray(np.random.default_rng(6).normal(size=(32, 64)), jnp.float32)
     forced = dict(p, bias=jnp.zeros((8,)).at[jnp.asarray([1, 6])].set(10.0))
-    chosen, weights = glm.route(cfg, forced, x)
+    chosen, weights = parts.route(cfg, forced, x)
     assert set(np.unique(np.asarray(chosen))) == {1, 6}
     np.testing.assert_allclose(np.asarray(weights.sum(-1)),
                                cfg.routed_scaling_factor, rtol=1e-6)
@@ -265,20 +266,20 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     whole = tiny(n_routed_experts=64, num_experts_per_tok=4)
     p = one_expert_layer(whole)
     x = jnp.asarray(np.random.default_rng(7).normal(size=(40, 64)), jnp.float32)
-    shared = glm.gated_mlp(p["shared"], x, jnp.float32)
+    shared = parts.gated_mlp(p["shared"], x, jnp.float32)
     total, assigned = shared, 0
     for chip in range(8):
         cfg = dataclasses.replace(whole, experts_held=8, first_expert=8 * chip)
         mine = dict(p, experts=jax.tree.map(
             lambda w: w[8 * chip:8 * chip + 8], p["experts"]))
-        y, row = glm.expert_layer(cfg, mine, x)
+        y, row = parts.expert_layer(cfg, mine, x)
         total = total + (y - shared)
         assigned += int(row[:8].sum())
-        assert int(row[:8].sum() + row[8 + glm.COUNT_ABSENT]) == 4 * 40
+        assert int(row[:8].sum() + row[8 + parts.COUNT_ABSENT]) == 4 * 40
     assert assigned == 4 * 40      # every choice fell on exactly one chip
     want = ref.expert_ffn(EXACT, reference_config(whole), x, p)
     np.testing.assert_allclose(np.asarray(total), np.asarray(want), atol=2e-5)
-    uncut, _ = glm.expert_layer(whole, p, x)
+    uncut, _ = parts.expert_layer(whole, p, x)
     np.testing.assert_allclose(np.asarray(uncut), np.asarray(want), atol=2e-5)
 
 
@@ -289,13 +290,13 @@ def test_no_token_is_dropped_when_every_token_takes_one_expert():
         jnp.asarray([2, 5])].set(10.0))    # expert 2 is held, 5 is not
     x = jnp.asarray(np.random.default_rng(8).normal(size=(200, 64)),
                     jnp.float32)
-    y, row = glm.expert_layer(cfg, p, x)
+    y, row = parts.expert_layer(cfg, p, x)
     assert list(np.asarray(row)) == [0, 0, 200, 0, 200, 1, 1]
     want = ref.expert_ffn(EXACT, reference_config(cfg), x, p)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5)
-    _, masked = glm.expert_layer(cfg, p, x, live=jnp.arange(200) < 50)
+    _, masked = parts.expert_layer(cfg, p, x, live=jnp.arange(200) < 50)
     assert list(np.asarray(masked)) == [0, 0, 50, 0, 50, 1, 1]
-    _, none = glm.expert_layer(cfg, p, x, live=jnp.zeros((200,), bool))
+    _, none = parts.expert_layer(cfg, p, x, live=jnp.zeros((200,), bool))
     assert not np.asarray(none).any()
 
 
@@ -308,7 +309,7 @@ def every_expert_layer(cfg, p, x, live):
     the ``moe_counts`` row counted as the parent counted it."""
     exact = dict(precision=jax.lax.Precision.HIGHEST)
     wide = lambda a: a.astype(jnp.float32)
-    chosen, weights = glm.route(cfg, p["router"], x)
+    chosen, weights = parts.route(cfg, p["router"], x)
     held = cfg.first_expert + jnp.arange(cfg.held, dtype=chosen.dtype)
     hit = chosen[:, :, None] == held[None, None, :]
     gates = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), axis=1)
@@ -321,7 +322,7 @@ def every_expert_layer(cfg, p, x, live):
         wide(p["experts"]["down"]["kernel"]), **exact)
     y = jnp.sum(gates.T[:, :, None] * each, axis=0)
     if "shared" in p:
-        y = y + glm.gated_mlp(p["shared"], xd, cfg.dtype)
+        y = y + parts.gated_mlp(p["shared"], xd, cfg.dtype)
     counted = live.astype(jnp.int32)
     tokens = jnp.sum(hit.any(axis=1) * counted[:, None], axis=0)
     extra = [cfg.num_experts_per_tok * counted.sum() - tokens.sum(),
@@ -405,12 +406,12 @@ def test_each_assignment_once_is_every_expert_over_every_token(
     if kernels:
         monkeypatch.setenv("DTT_PALLAS_INTERPRET", "1")
     # Whatever form a call of this shape would take in a served program.
-    monkeypatch.setattr(glm, "expert_form",
+    monkeypatch.setattr(parts, "expert_form",
                         lambda *shape: grouped_matmul.GROUPED)
     key = (family, kernels, n, held)
     if key not in _grouped_layers:      # one trace a shape, not one a case
         _grouped_layers[key] = jax.jit(lambda p, x, live: (
-            glm.expert_layer(cfg, p, x, live),
+            parts.expert_layer(cfg, p, x, live),
             every_expert_layer(cfg, p, x, live)))
     p = toy_expert_layer(cfg, pinned)
     rng = np.random.default_rng(n)
@@ -436,7 +437,7 @@ def test_each_assignment_once_is_every_expert_over_every_token(
     np.testing.assert_allclose(np.asarray(y)[mask], np.asarray(want)[mask],
                                atol=2e-5)
     # A token that does not count is given no row: its routed part is 0.
-    shared = (np.asarray(glm.gated_mlp(p["shared"], jnp.asarray(x),
+    shared = (np.asarray(parts.gated_mlp(p["shared"], jnp.asarray(x),
                                        jnp.float32))
               if "shared" in p else 0.0)
     np.testing.assert_allclose((np.asarray(y) - shared)[~mask], 0.0,

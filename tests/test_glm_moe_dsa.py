@@ -26,7 +26,7 @@ import pytest
 from benchmark.reference import glm_moe_dsa as ref
 from benchmark.reference import precision
 from distributed_tensorflow_tpu.models import PagedKVConfig
-from distributed_tensorflow_tpu.models import glm4_moe_lite as glm
+from distributed_tensorflow_tpu.models import decoder_parts as parts
 from distributed_tensorflow_tpu.models import glm_moe_dsa as dsa
 from distributed_tensorflow_tpu.models.glm_moe_dsa import (
     GlmMoeDsa, GlmMoeDsaConfig)
@@ -189,10 +189,10 @@ def test_index_scores_and_selected_sets_are_the_references():
     # The scores themselves, layer 0's indexer on the model's own inputs.
     p = params["layer_0"]
     x = params["embed"][tokens]
-    xn = glm.rms_norm(x, p["input_norm"]["scale"], cfg.rms_norm_eps)
+    xn = parts.rms_norm(x, p["input_norm"]["scale"], cfg.rms_norm_eps)
     rcfg = reference_config(cfg)
     positions = jnp.broadcast_to(jnp.arange(70)[None], (2, 70))
-    cq = glm.mla_query_latent(cfg, p["attn"], xn)
+    cq = parts.mla_query_latent(cfg, p["attn"], xn)
     q_i, k_i, w = dsa.indexer_project(cfg, p["indexer"], xn, cq, positions)
     mine = np.asarray(dsa.index_scores(q_i, w, k_i))
     theirs = np.asarray(ref.index_scores(EXACT, rcfg, xn, cq, p["indexer"]))
@@ -391,13 +391,13 @@ def test_the_32_shares_add_up_to_the_uncut_layer():
     whole = tiny(n_routed_experts=256, num_experts_per_tok=8)
     p = drawn_params(whole)["layer_1"]
     x = jnp.asarray(np.random.default_rng(7).normal(size=(24, 64)), jnp.float32)
-    shared = glm.gated_mlp(p["shared"], x, jnp.float32)
+    shared = parts.gated_mlp(p["shared"], x, jnp.float32)
     total, assigned = shared, 0
     for chip in range(32):
         cfg = dataclasses.replace(whole, experts_held=8, first_expert=8 * chip)
         mine = dict(p, experts=jax.tree.map(
             lambda w: w[8 * chip:8 * chip + 8], p["experts"]))
-        y, row = glm.expert_layer(cfg, mine, x)
+        y, row = parts.expert_layer(cfg, mine, x)
         total = total + (y - shared)
         assigned += int(row[:8].sum())
     assert assigned == 8 * 24      # every choice fell on exactly one chip

@@ -26,10 +26,12 @@ import pytest
 from benchmark.reference import mellum as ref
 from benchmark.reference import precision
 from distributed_tensorflow_tpu.models import PagedKVConfig, get_workload
+from distributed_tensorflow_tpu.models import decoder_parts as parts
 from distributed_tensorflow_tpu.models import mellum
-from distributed_tensorflow_tpu.models.glm4_moe_lite import route
+from distributed_tensorflow_tpu.models.decoder_parts import route
 from distributed_tensorflow_tpu.models.mellum import Mellum, MellumConfig
 from distributed_tensorflow_tpu.obs.metrics import default_registry
+from distributed_tensorflow_tpu.ops import paged_attention
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
 from tests.helpers import expert_forms_on_record, zero_cache
 
@@ -217,7 +219,7 @@ def test_full_layers_rotate_by_the_scaled_table():
     table = dict(inv_freq=mellum.yarn_inv_freq(cfg),
                  scale=cfg.attention_factor)
     at = lambda p: jnp.full((1, 1), p, jnp.int32)
-    rot = lambda x, p: mellum.rope(x, at(p), cfg.rope_theta, **table)
+    rot = lambda x, p: parts.rope(x, at(p), cfg.rope_theta, **table)
     assert float(jnp.linalg.norm(rot(q, 9))) == pytest.approx(
         cfg.attention_factor * float(jnp.linalg.norm(q)), rel=1e-5)
     score = lambda pq, pk: float(jnp.sum(rot(q, pq) * rot(k, pk)))
@@ -229,7 +231,7 @@ def test_full_layers_rotate_by_the_scaled_table():
 def test_banded_mask_matches_its_closed_form(window):
     q = jnp.asarray([[3, 10, 30, 31]])
     k = jnp.asarray([list(range(-2, 38))])          # -2, -1: never written
-    got = np.asarray(mellum.attention_mask(q, k, window))[0]
+    got = np.asarray(parts.attention_mask(q, k, window))[0]
     for a, i in enumerate([3, 10, 30, 31]):
         for b, j in enumerate(range(-2, 38)):
             want = 0 <= j <= i and (window is None or i - j < window)
@@ -266,8 +268,8 @@ def kernel(request, monkeypatch):
     return request.param
 
 
-GATHERS = {mellum.GATHER_WINDOW, mellum.GATHER_FULL}
-KERNELS = {mellum.KERNEL_WINDOW, mellum.KERNEL_FULL}
+GATHERS = {parts.GATHER_WINDOW, parts.GATHER_FULL}
+KERNELS = {parts.KERNEL_WINDOW, parts.KERNEL_FULL}
 
 
 @pytest.mark.parametrize("block,chunk", [(8, 16), (16, 16), (8, 40)])
@@ -299,7 +301,7 @@ def test_chunked_prefill_then_decode_gives_the_reference_logits(block, chunk,
     paths = {chunk: set(), 1: set()}
     while at < total:
         n = chunk if at < prefilled else 1
-        with mellum.paged_attention.record_paths() as traced:
+        with paged_attention.record_paths() as traced:
             out, mutated = step(cache, tokens[:, at:at + n])
         paths[n].update(traced)         # what the call's first trace took
         cache = mutated["cache"]
@@ -391,7 +393,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
         cfg = tiny(experts_held=2, first_expert=first)
         share = dict(layer, experts=jax.tree.map(
             lambda w: w[first:first + 2], layer["experts"]))
-        part, row = mellum.expert_layer(cfg, share, x)
+        part, row = parts.expert_layer(cfg, share, x)
         np.testing.assert_allclose(
             np.asarray(part), np.asarray(ref.expert_ffn(
                 EXACT, reference_config(cfg), x, share)), atol=2e-5)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_tensorflow_tpu.models import decoder_parts as parts
 from distributed_tensorflow_tpu.models import mellum
 from distributed_tensorflow_tpu.models.gpt2 import (
     GPT2, GPT2Config, PagedKVConfig)
@@ -163,8 +164,8 @@ def gqa_gather_path(q, pool, tables, lengths, window, layer):
     held = jnp.broadcast_to(jnp.arange(cells)[None], (B, cells))
     if window is not None:
         held = last - (last - held) % cells
-    mask = mellum.attention_mask(last, held, window)
-    return mellum.gqa_attend(cfg, q, k, v, mask).reshape(q.shape)
+    mask = parts.attention_mask(last, held, window)
+    return parts.gqa_attend(cfg, q, k, v, mask).reshape(q.shape)
 
 
 def grouped_case(name, lengths, *, kv_heads, groups, head_dim, window=None,
@@ -268,8 +269,8 @@ SELECTION = [
 # The grouped-query family's own call: the same fields, then the heads
 # (query heads, K/V heads, head size).  Its pools are stored in the compute
 # type or refused, so there is no ``kv_dtype`` to choose by.
-GQA_KERNEL = {mellum.KERNEL_WINDOW, mellum.KERNEL_FULL}
-GQA_GATHER = {mellum.GATHER_WINDOW, mellum.GATHER_FULL}
+GQA_KERNEL = {parts.KERNEL_WINDOW, parts.KERNEL_FULL}
+GQA_GATHER = {parts.GATHER_WINDOW, parts.GATHER_FULL}
 GQA_SELECTION = [
     ("gqa-decode-tpu", 1, None, None, False, "tpu", GQA_KERNEL, (16, 2, 64)),
     ("gqa-decode-interpreter", 1, None, None, True, "cpu", GQA_KERNEL,

@@ -21,6 +21,7 @@ import pytest
 from benchmark.reference import precision
 from benchmark.reference import solar_open2 as ref
 from distributed_tensorflow_tpu.models import PagedKVConfig, get_workload
+from distributed_tensorflow_tpu.models import decoder_parts as parts
 from distributed_tensorflow_tpu.models import solar_open2 as so
 from distributed_tensorflow_tpu.models.solar_open2 import (
     SolarOpen2, SolarOpen2Config)
@@ -278,8 +279,8 @@ def test_chunked_prefill_then_decode_gives_the_reference_logits(block, chunk,
         cache = mutated["cache"]
         got.append(np.asarray(out))
         at += n
-    assert paths[True] == {so.GATHER_FULL, so.KDA_CHUNK_PATH}
-    assert paths[False] == {so.KERNEL_FULL if kernel else so.GATHER_FULL,
+    assert paths[True] == {parts.GATHER_FULL, so.KDA_CHUNK_PATH}
+    assert paths[False] == {parts.KERNEL_FULL if kernel else parts.GATHER_FULL,
                             so.KDA_STEP_PATH}
     want = reference_logits(cfg, params, tokens)
     np.testing.assert_allclose(np.concatenate(got, axis=1), want, atol=1e-4)
@@ -332,7 +333,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
                    experts_held=1, first_expert=first)
         share = dict(layer, experts=jax.tree.map(
             lambda w: w[first:first + 1], layer["experts"]))
-        part, row = so.expert_layer(cfg, share, x)
+        part, row = parts.expert_layer(cfg, share, x)
         np.testing.assert_allclose(
             np.asarray(part), np.asarray(ref.expert_ffn(
                 EXACT, reference_config(cfg), x, share)), atol=2e-5)
@@ -514,8 +515,8 @@ def test_scheduler_serves_the_reference_best_tokens(request, kernel,
         assert len(answer) == new
         assert _gap_to_reference_best(engine, prompt, answer).max() <= 2e-4
     paths = engine.attention_paths()
-    full = so.KERNEL_FULL if kernel else so.GATHER_FULL
-    assert {so.GATHER_FULL, so.KDA_CHUNK_PATH} <= set(paths["slot_prefill"])
+    full = parts.KERNEL_FULL if kernel else parts.GATHER_FULL
+    assert {parts.GATHER_FULL, so.KDA_CHUNK_PATH} <= set(paths["slot_prefill"])
     assert set(paths["slot_megastep"]) == {full, so.KDA_STEP_PATH}
 
 

@@ -56,7 +56,8 @@ LAYER_MAP: List[Tuple[str, str, str]] = [
 # The paged decoder families.  What two of them need lives in
 # ``models.decoder_parts`` / ``models.paged_call``: a family that imports a
 # family makes its neighbour's module a library (ROADMAP D5).
-DECODER_FAMILIES = ("glm4_moe_lite", "mellum", "glm_moe_dsa", "solar_open2")
+DECODER_FAMILIES = ("glm4_moe_lite", "mellum", "glm_moe_dsa", "solar_open2",
+                    "dots3_note")
 LAYER_MAP += [
     (f"{_PKG}.models.{family}", f"{_PKG}.models.{other}",
      "a decoder family imports no other decoder family")

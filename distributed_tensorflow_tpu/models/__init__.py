@@ -289,6 +289,7 @@ _REGISTRY = {
     "mellum": "distributed_tensorflow_tpu.models.mellum",
     "glm_moe_dsa": "distributed_tensorflow_tpu.models.glm_moe_dsa",
     "solar_open2": "distributed_tensorflow_tpu.models.solar_open2",
+    "dots3_note": "distributed_tensorflow_tpu.models.dots3_note",
     "wide_deep": "distributed_tensorflow_tpu.models.wide_deep",
 }
 

@@ -1,16 +1,28 @@
 """The layers of the paged decoder families that belong to no one of them.
 
-``glm4_moe_lite``, ``mellum``, ``glm_moe_dsa`` and ``solar_open2`` each
-write a config, a ``param_spec``, a mixer and a layer loop, and call what
-is here: the parameters' declaration from a spec, the products' one rule of
-precision (``dot``), RMS norm, rotate-half rotary positions, the gated MLP,
-the float32 router and the expert layer that is told which experts it
-holds, the latent attention two of them share (``mla_*``), the grouped-query
-attention two of them share (``gqa_attend``, ``attention_mask`` and the four
-path names), the replicated cache's rules and the language-model loss.
-Nothing here keeps state, and nothing here knows a family by name: a config
-says what it is through its fields (``cfg.router``, ``cfg.held``...).  The
-cache side of a call is ``models/paged_call.py``.
+``glm4_moe_lite``, ``mellum``, ``glm_moe_dsa``, ``solar_open2`` and
+``dots3_note`` each write a config, a ``param_spec``, a mixer and a layer
+loop, and call what is here: the parameters' declaration from a spec, the
+products' one rule of precision (``dot``), RMS norm, rotate-half rotary
+positions, the gated MLP, the float32 router and the expert layer that is
+told which experts it holds, the latent attention three of them share
+(``mla_*``), the learned indexer two of them share (``indexer_*``,
+``index_scores``, ``select_mask``, ``select_top``: moved here from
+``glm_moe_dsa`` when a second family got one), the grouped-query attention
+two of them share (``gqa_attend`` and the four path names), the mask of a
+causal or a window read (``attention_mask``), the replicated cache's rules
+and the language-model loss.  Nothing here keeps state, and nothing here
+knows a family by name: a config says what it is through its fields
+(``cfg.router``, ``cfg.held``...).  The cache side of a call is
+``models/paged_call.py``.
+
+**A kind's sizes.**  The latent attention reads its head count, both ranks,
+the three head sizes, ``rope_theta``, the two latents' scales and whether
+its output is gated from an ``MlaSizes``, not from the config: a model whose
+window layers have their own ranks and head count has two kinds of latent
+layer.  A model with one kind builds its group from its config's published
+keys (``mla_sizes``: scales of 1, no gate, and the same program as before
+the group existed).
 
 **The expert layer.**  The router scores all the experts in float32
 (``route``).  The layer holds ``cfg.held`` consecutive experts from
@@ -37,6 +49,7 @@ best (PERF.md Findings, PR 33).
 
 from __future__ import annotations
 
+import dataclasses
 import importlib
 from typing import Any, Optional
 
@@ -79,18 +92,79 @@ def check_share(cfg, experts: int, key: str):
 
 # -- parameters ----------------------------------------------------------------
 
-def mla_spec(cfg):
+@dataclasses.dataclass(frozen=True)
+class MlaSizes:
+    """One kind of latent-attention layer's sizes.  A model with one kind
+    builds its group from its config's published keys (``mla_sizes``); one
+    whose window layers have their own ranks and head count builds two.
+    ``q_scale`` and ``kv_scale`` multiply the two normalized latents (in
+    float32, with the norm, before the one rounding) and ``gated`` gives
+    the layer a sigmoid gate a head on its output (``mla_output``)."""
+
+    heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
+    gated: bool = False
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """Values cached a token and layer: the latent and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def pool_width(self) -> int:
+        """``latent_width`` rounded up to whole 128-lane tiles."""
+        return -(-self.latent_width // 128) * 128
+
+
+def mla_sizes(cfg) -> MlaSizes:
+    """The one group of a config that names its latent attention's sizes by
+    the published keys: no scale, no gate."""
+    return MlaSizes(
+        heads=cfg.num_attention_heads, q_lora_rank=cfg.q_lora_rank,
+        kv_lora_rank=cfg.kv_lora_rank, qk_nope_head_dim=cfg.qk_nope_head_dim,
+        qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+        rope_theta=cfg.rope_theta)
+
+
+def mla_spec(cfg, sizes: MlaSizes):
     """The latent attention's leaves."""
-    d, h = cfg.hidden_size, cfg.num_attention_heads
+    d, h = cfg.hidden_size, sizes.heads
+    spec = (
+        ("q_a", (("kernel", (d, sizes.q_lora_rank)),)),
+        ("q_a_norm", (("scale", (sizes.q_lora_rank,)),)),
+        ("q_b", (("kernel", (sizes.q_lora_rank, h * sizes.qk_head_dim)),)),
+        ("kv_a", (("kernel", (d, sizes.latent_width)),)),
+        ("kv_a_norm", (("scale", (sizes.kv_lora_rank,)),)),
+        ("kv_b", (("kernel", (
+            sizes.kv_lora_rank,
+            h * (sizes.qk_nope_head_dim + sizes.v_head_dim))),)),
+        ("o", (("kernel", (h * sizes.v_head_dim, d)),)),
+    )
+    if sizes.gated:
+        spec += (("gate", (("kernel", (d, h)),)),)
+    return spec
+
+
+def indexer_spec(cfg, sizes: MlaSizes):
+    """A learned indexer's leaves, beside the latent attention whose query
+    latent it reads."""
+    d, hi, di = cfg.hidden_size, cfg.index_n_heads, cfg.index_head_dim
     return (
-        ("q_a", (("kernel", (d, cfg.q_lora_rank)),)),
-        ("q_a_norm", (("scale", (cfg.q_lora_rank,)),)),
-        ("q_b", (("kernel", (cfg.q_lora_rank, h * cfg.qk_head_dim)),)),
-        ("kv_a", (("kernel", (d, cfg.latent_width)),)),
-        ("kv_a_norm", (("scale", (cfg.kv_lora_rank,)),)),
-        ("kv_b", (("kernel", (cfg.kv_lora_rank,
-                              h * (cfg.qk_nope_head_dim + cfg.v_head_dim))),)),
-        ("o", (("kernel", (h * cfg.v_head_dim, d)),)),
+        ("wq_b", (("kernel", (sizes.q_lora_rank, hi * di)),)),
+        ("wk", (("kernel", (d, di)),)),
+        ("k_norm", (("scale", (di,)), ("bias", (di,)))),
+        ("weights_proj", (("kernel", (d, hi)),)),
     )
 
 
@@ -218,46 +292,70 @@ def gated_mlp(p, x, dtype):
 
 # -- latent attention (MLA) ----------------------------------------------------
 
-def mla_query_latent(cfg, p, xn):
-    """The query's normalized low-rank latent ``c_q``, in the compute type
-    (a learned indexer projects its own queries from it)."""
-    return rms_norm(dot("btd,dr->btr", xn, p["q_a"]["kernel"]),
-                    p["q_a_norm"]["scale"], cfg.rms_norm_eps).astype(cfg.dtype)
+def _scaled(y, scale: float):
+    return y if scale == 1.0 else y * scale
 
 
-def mla_project(cfg, p, xn, positions, cq=None):
+def mla_query_latent(cfg, sizes: MlaSizes, p, xn):
+    """The query's normalized (and scaled) low-rank latent ``c_q``, in the
+    compute type (a learned indexer projects its own queries from it)."""
+    return _scaled(
+        rms_norm(dot("btd,dr->btr", xn, p["q_a"]["kernel"]),
+                 p["q_a_norm"]["scale"], cfg.rms_norm_eps),
+        sizes.q_scale).astype(cfg.dtype)
+
+
+def mla_project(cfg, sizes: MlaSizes, p, xn, positions, cq=None):
     """``xn`` (the normalized input, in the compute type) -> the query's
     two parts, and what is cached of the keys and values: the normalized
-    latent and the rotary key.  Norms and rotations are taken in float32 on
-    the products' float32 results; each output is rounded once, to the
-    compute type.  ``cq`` is ``mla_query_latent``'s result where the caller
-    has it already."""
+    (and scaled) latent and the rotary key.  Norms, scales and rotations
+    are taken in float32 on the products' float32 results; each output is
+    rounded once, to the compute type.  ``cq`` is ``mla_query_latent``'s
+    result where the caller has it already."""
     B, T, _ = xn.shape
     dt = cfg.dtype
     if cq is None:
-        cq = mla_query_latent(cfg, p, xn)
+        cq = mla_query_latent(cfg, sizes, p, xn)
     q = dot("btr,rf->btf", cq, p["q_b"]["kernel"]).reshape(
-        B, T, cfg.num_attention_heads, cfg.qk_head_dim)
-    q_n, q_r = q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
+        B, T, sizes.heads, sizes.qk_head_dim)
+    q_n, q_r = (q[..., :sizes.qk_nope_head_dim],
+                q[..., sizes.qk_nope_head_dim:])
     kva = dot("btd,dc->btc", xn, p["kv_a"]["kernel"])
-    latent = rms_norm(kva[..., :cfg.kv_lora_rank], p["kv_a_norm"]["scale"],
-                      cfg.rms_norm_eps)
-    k_r = rope(kva[..., cfg.kv_lora_rank:], positions, cfg.rope_theta)
-    return (q_n.astype(dt), rope(q_r, positions, cfg.rope_theta).astype(dt),
+    latent = _scaled(
+        rms_norm(kva[..., :sizes.kv_lora_rank], p["kv_a_norm"]["scale"],
+                 cfg.rms_norm_eps), sizes.kv_scale)
+    k_r = rope(kva[..., sizes.kv_lora_rank:], positions, sizes.rope_theta)
+    return (q_n.astype(dt), rope(q_r, positions, sizes.rope_theta).astype(dt),
             latent.astype(dt), k_r.astype(dt))
 
 
-def mla_attend(cfg, p, q_n, q_r, latent, k_r, mask, absorb: bool):
+def mla_cache_row(cfg, sizes: MlaSizes, latent, k_r):
+    """What a position caches, ``(B, T, pool_width)``: the latent, the
+    rotary key, and zeros up to whole lane tiles."""
+    B, T, _ = latent.shape
+    return jnp.concatenate([latent, k_r, jnp.zeros(
+        (B, T, sizes.pool_width - sizes.latent_width), cfg.dtype)], axis=-1)
+
+
+def _kv_b(sizes: MlaSizes, p):
+    """``kv_b`` as the keys' and the values' halves, ``(rank, H, .)``."""
+    w = p["kv_b"]["kernel"].reshape(
+        sizes.kv_lora_rank, sizes.heads,
+        sizes.qk_nope_head_dim + sizes.v_head_dim)
+    return w[..., :sizes.qk_nope_head_dim], w[..., sizes.qk_nope_head_dim:]
+
+
+def mla_attend(cfg, sizes: MlaSizes, p, q_n, q_r, latent, k_r, mask,
+               absorb: bool):
     """Softmax attention of ``(B, T, H, .)`` queries over ``(B, S, .)``
     latents and rotary keys; ``mask`` ``(B, T, S)`` is True where a key may
     be read.  ``absorb`` folds ``kv_b`` into the query and the output (one
-    shared 576-wide head); otherwise the latents are expanded to per-head
-    keys and values.  -> ``(B, T, H * v_head_dim)`` before ``o``."""
+    shared head as wide as the latent row); otherwise the latents are
+    expanded to per-head keys and values.  -> ``(B, T, H * v_head_dim)``
+    before the gate and ``o`` (``mla_output``)."""
     B, T, H, _ = q_n.shape
     dt = cfg.dtype
-    w = p["kv_b"]["kernel"].reshape(
-        cfg.kv_lora_rank, H, cfg.qk_nope_head_dim + cfg.v_head_dim)
-    w_k, w_v = w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+    w_k, w_v = _kv_b(sizes, p)
     rotary = dot("bthr,bsr->bhts", q_r, k_r)
     if absorb:
         q_lat = dot("bthd,chd->bthc", q_n, w_k, dt)
@@ -265,7 +363,7 @@ def mla_attend(cfg, p, q_n, q_r, latent, k_r, mask, absorb: bool):
     else:
         k_n = dot("bsc,chd->bshd", latent, w_k, dt)
         scores = dot("bthd,bshd->bhts", q_n, k_n) + rotary
-    scores = scores / np.sqrt(cfg.qk_head_dim)
+    scores = scores / np.sqrt(sizes.qk_head_dim)
     scores = jnp.where(mask[:, None], scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(dt)
     if absorb:
@@ -274,7 +372,104 @@ def mla_attend(cfg, p, q_n, q_r, latent, k_r, mask, absorb: bool):
     else:
         v = dot("bsc,chv->bshv", latent, w_v, dt)
         out = dot("bhts,bshv->bthv", probs, v, dt)
-    return out.reshape(B, T, H * cfg.v_head_dim)
+    return out.reshape(B, T, H * sizes.v_head_dim)
+
+
+def mla_expanded_scores(cfg, sizes: MlaSizes, p, q_n, q_r, latent, k_r):
+    """The expanded form's scaled scores ``(B, H, T, S)`` float32 and values
+    ``(B, S, H, v_head_dim)`` of ``(B, S, .)`` latents and rotary keys: what
+    a walk over a long context attends a piece at a time
+    (``paged_call.ContextWalk``)."""
+    dt = cfg.dtype
+    w_k, w_v = _kv_b(sizes, p)
+    k_n = dot("bsc,chd->bshd", latent, w_k, dt)
+    scores = (dot("bthd,bshd->bhts", q_n, k_n)
+              + dot("bthr,bsr->bhts", q_r, k_r)) / np.sqrt(sizes.qk_head_dim)
+    return scores, dot("bsc,chv->bshv", latent, w_v, dt)
+
+
+def mla_output(cfg, sizes: MlaSizes, p, xn, ctx):
+    """The heads' outputs ``ctx`` ``(B, T, H * v_head_dim)`` -> the layer's
+    ``(B, T, d)`` float32.  A gated kind weighs each head's output by ``g =
+    sigmoid(xn W_g)``, one scalar a head and position, in float32, and
+    rounds the product once, as ``o``'s operand."""
+    if sizes.gated:
+        B, T, _ = ctx.shape
+        g = jax.nn.sigmoid(dot("btd,dh->bth", xn, p["gate"]["kernel"]))
+        ctx = (g[..., None] * ctx.reshape(
+            B, T, sizes.heads, sizes.v_head_dim).astype(jnp.float32)
+               ).astype(cfg.dtype).reshape(ctx.shape)
+    return dot("btf,fd->btd", ctx, p["o"]["kernel"])
+
+
+# -- a learned indexer and its selection ---------------------------------------
+
+def _rope_first(x, positions, dims: int, theta: float):
+    """The first ``dims`` values of the last dimension rotated."""
+    return jnp.concatenate(
+        [rope(x[..., :dims], positions, theta),
+         x[..., dims:].astype(jnp.float32)], axis=-1)
+
+
+def indexer_project(cfg, sizes: MlaSizes, p, xn, cq, positions):
+    """``xn`` (normalized input) and ``cq`` (the query's latent), both in
+    the compute type -> the index queries ``(B, T, Hi, Di)`` and the index
+    key ``(B, T, Di)``, each rounded once, and the heads' weights ``(B, T,
+    Hi)`` float32, both scales folded in.  The first ``qk_rope_head_dim``
+    of a query's and a key's dimensions are rotated by the layer's own
+    ``rope_theta`` (``sizes``)."""
+    B, T, _ = xn.shape
+    hi, di = cfg.index_n_heads, cfg.index_head_dim
+    rot, theta = sizes.qk_rope_head_dim, sizes.rope_theta
+    q = dot("btr,rf->btf", cq, p["wq_b"]["kernel"]).reshape(B, T, hi, di)
+    k = dot("btd,df->btf", xn, p["wk"]["kernel"])
+    mean = jnp.mean(k, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(k - mean), axis=-1, keepdims=True)
+    k = ((k - mean) * lax.rsqrt(var + cfg.index_norm_eps)
+         * p["k_norm"]["scale"].astype(jnp.float32)
+         + p["k_norm"]["bias"].astype(jnp.float32))
+    w = dot("btd,dh->bth", xn, p["weights_proj"]["kernel"]) * (
+        hi ** -0.5 * di ** -0.5)
+    return (_rope_first(q, positions, rot, theta).astype(cfg.dtype),
+            _rope_first(k, positions, rot, theta).astype(cfg.dtype), w)
+
+
+def index_scores(q_i, w, k_i):
+    """``I[t, s]`` of ``(B, T, Hi, Di)`` queries with weights ``(B, T, Hi)``
+    over ``(B, S, Di)`` keys -> ``(B, T, S)`` float32."""
+    s = jax.nn.relu(dot("bthd,bsd->bths", q_i, k_i))
+    return jnp.sum(s * w[..., None], axis=2)
+
+
+def select_mask(scores, k: int):
+    """``(..., S)`` float32 scores -> a mask of the ``k`` largest of each
+    row, the lower position first on a tie (all of a row shorter than
+    ``k``).  The ``k``-th largest is found by bisection on the scores'
+    bits, which order as the scores do: 32 counts, and no sort."""
+    if scores.shape[-1] <= k:
+        return jnp.ones(scores.shape, bool)
+    bits = lax.bitcast_convert_type(scores, jnp.uint32)
+    keys = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def raise_bit(i, floor):
+        tried = floor | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        enough = jnp.sum(keys >= tried[..., None], axis=-1,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, tried, floor)
+
+    kth = lax.fori_loop(0, 32, raise_bit,
+                        jnp.zeros(scores.shape[:-1], jnp.uint32))[..., None]
+    above, ties = keys > kth, keys == kth
+    room = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    return above | (ties & (jnp.cumsum(ties, axis=-1, dtype=jnp.int32)
+                            <= room))
+
+
+def select_top(scores, k: int):
+    """``(B, S)`` scores -> the positions ``(B, k)`` of the ``k`` largest of
+    each row, the lower position first on a tie: a decode step's selection,
+    which is gathered and so needs the positions themselves."""
+    return lax.top_k(scores, k)[1]
 
 
 # -- grouped-query attention ---------------------------------------------------

@@ -48,8 +48,8 @@ from jax.sharding import Mesh
 
 from distributed_tensorflow_tpu.models import PagedKVConfig, Workload
 from distributed_tensorflow_tpu.models.decoder_parts import (
-    check_share, declare, dot, expert_layer, gated_mlp, mla_attend,
-    mla_project, mla_spec, mlp_spec, rms_norm, stacked)
+    check_share, declare, expert_layer, gated_mlp, mla_attend, mla_cache_row,
+    mla_output, mla_project, mla_sizes, mla_spec, mlp_spec, rms_norm, stacked)
 from distributed_tensorflow_tpu.models.paged_call import (
     PagedCall, decoder_workload, serve_refusals)
 from distributed_tensorflow_tpu.ops import paged_attention
@@ -169,7 +169,7 @@ class Glm4MoeLiteConfig:
 def _layer_spec(cfg, moe: bool):
     d = cfg.hidden_size
     common = (("input_norm", (("scale", (d,)),)),
-              ("attn", mla_spec(cfg)),
+              ("attn", mla_spec(cfg, mla_sizes(cfg))),
               ("post_norm", (("scale", (d,)),)))
     if not moe:
         return common + (("mlp", mlp_spec(d, cfg.intermediate_size)),)
@@ -209,6 +209,7 @@ class Glm4MoeLite(nn.Module):
         cfg = self.cfg
         B, T = tokens.shape
         params = declare(self, param_spec(cfg), cfg)
+        sizes = mla_sizes(cfg)
         # The residual stream is float32 from here to the head: what a
         # layer adds is a product's float32 result, and the router reads
         # the stream's norm unrounded (a rounded one flips its near ties).
@@ -236,21 +237,19 @@ class Glm4MoeLite(nn.Module):
         def attention(p, x, pool_value, layer):
             xn = rms_norm(x, p["input_norm"]["scale"],
                           cfg.rms_norm_eps).astype(cfg.dtype)
-            q_n, q_r, latent, k_r = mla_project(cfg, p["attn"], xn, positions)
+            q_n, q_r, latent, k_r = mla_project(
+                cfg, sizes, p["attn"], xn, positions)
             if pool_value is not None:
-                pool_value = view.write(pool_value, layer, jnp.concatenate(
-                    [latent, k_r, jnp.zeros(
-                        (B, T, cfg.pool_width - cfg.latent_width),
-                        cfg.dtype)], axis=-1))
+                pool_value = view.write(
+                    pool_value, layer, mla_cache_row(cfg, sizes, latent, k_r))
                 # The slot's whole table row, gathered back: positions past
                 # the row's index (and trash entries) are masked.
                 rows = view.gather(pool_value, layer)
                 latent = rows[..., :cfg.kv_lora_rank]
                 k_r = rows[..., cfg.kv_lora_rank:cfg.latent_width]
-            ctx = mla_attend(cfg, p["attn"], q_n, q_r, latent, k_r, mask,
-                             absorb)
-            out = dot("btf,fd->btd", ctx, p["attn"]["o"]["kernel"])
-            return x + out, pool_value
+            ctx = mla_attend(cfg, sizes, p["attn"], q_n, q_r, latent, k_r,
+                             mask, absorb)
+            return x + mla_output(cfg, sizes, p["attn"], xn, ctx), pool_value
 
         def dense_layer(carry, xs):
             x, pool_value = carry

@@ -4,10 +4,13 @@ serving path.
 
 The fourth decoder family.  RMS norm, rotate-half rotary positions, the
 parameters' declaration, the latent projections (``mla_project``), the
-absorbed and the expanded latent attention (``mla_attend``), the float32
-sigmoid router and the expert layer that is told which experts it holds are
-``models/decoder_parts.py``'s; a position's pool cell and the row write are
-``models/paged_call.py``'s.  What is this family's own:
+absorbed and the expanded latent attention (``mla_attend``), the indexer's
+projections, scores and selection (``indexer_project``, ``index_scores``,
+``select_mask``, ``select_top``), the float32 sigmoid router and the expert
+layer that is told which experts it holds are ``models/decoder_parts.py``'s;
+a position's pool cell, the row write and the walk over a long context
+through the table (``ContextWalk``) are ``models/paged_call.py``'s: a second
+family has an indexer too.  What this family does with them:
 
 - **The indexer** (a ``full`` layer).  From the query's low-rank latent
   ``c_q``: ``q^I = c_q W^I_q``, ``index_n_heads`` heads of
@@ -70,19 +73,19 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 from flax import linen as nn
-from jax import lax
 from jax.sharding import Mesh
 
 from distributed_tensorflow_tpu.models import PagedKVConfig, Workload
 from distributed_tensorflow_tpu.models.decoder_parts import (
-    check_share, declare, dot, expert_layer, gated_mlp, mla_attend,
-    mla_project, mla_query_latent, mla_spec, mlp_spec, rms_norm, rope)
+    check_share, declare, expert_layer, gated_mlp, index_scores,
+    indexer_project, indexer_spec, mla_attend, mla_cache_row, mla_output,
+    mla_project,
+    mla_query_latent, mla_sizes, mla_spec, mlp_spec, rms_norm, select_mask,
+    select_top)
 from distributed_tensorflow_tpu.models.paged_call import (
-    PagedCall, decoder_workload, serve_refusals)
+    ContextWalk, PagedCall, decoder_workload, serve_refusals)
 from distributed_tensorflow_tpu.ops import paged_attention
 
 # The cached attention's two implementations, as ``attention_paths()`` names
@@ -91,10 +94,6 @@ SELECTED, MASKED = "latent_sparse_selected", "latent_sparse_masked"
 
 FULL, SHARED = "full", "shared"
 DENSE, SPARSE = "dense", "sparse"
-
-# Context positions a step of the chunked walks takes (whole blocks).
-CONTEXT_CHUNK = 1024
-_MASKED = -1e30  # finite: exp(_MASKED - m) is exactly 0, no inf - inf
 
 
 @dataclasses.dataclass(frozen=True)
@@ -249,23 +248,14 @@ class GlmMoeDsaConfig:
 
 # -- parameters ----------------------------------------------------------------
 
-def _indexer_spec(cfg):
-    d, hi, di = cfg.hidden_size, cfg.index_n_heads, cfg.index_head_dim
-    return (
-        ("wq_b", (("kernel", (cfg.q_lora_rank, hi * di)),)),
-        ("wk", (("kernel", (d, di)),)),
-        ("k_norm", (("scale", (di,)), ("bias", (di,)))),
-        ("weights_proj", (("kernel", (d, hi)),)),
-    )
-
-
 def _layer_spec(cfg, kind: str):
     mlp, indexer = kind.split("_")
     d = cfg.hidden_size
+    sizes = mla_sizes(cfg)
     spec = (("input_norm", (("scale", (d,)),)),
-            ("attn", mla_spec(cfg)))
+            ("attn", mla_spec(cfg, sizes)))
     if indexer == FULL:
-        spec += (("indexer", _indexer_spec(cfg)),)
+        spec += (("indexer", indexer_spec(cfg, sizes)),)
     spec += (("post_norm", (("scale", (d,)),)),)
     if mlp == DENSE:
         return spec + (("mlp", mlp_spec(d, cfg.intermediate_size)),)
@@ -291,87 +281,6 @@ def param_spec(cfg):
     ))
 
 
-# -- the indexer and the selection ---------------------------------------------
-
-def _rope_first(cfg, x, positions):
-    """The first ``qk_rope_head_dim`` values of the last dimension rotated."""
-    r = cfg.qk_rope_head_dim
-    return jnp.concatenate(
-        [rope(x[..., :r], positions, cfg.rope_theta),
-         x[..., r:].astype(jnp.float32)], axis=-1)
-
-
-def indexer_project(cfg, p, xn, cq, positions):
-    """``xn`` (normalized input) and ``cq`` (the query's latent), both in
-    the compute type -> the index queries ``(B, T, Hi, Di)`` and the index
-    key ``(B, T, Di)``, each rounded once, and the heads' weights ``(B, T,
-    Hi)`` float32, both scales folded in."""
-    B, T, _ = xn.shape
-    hi, di = cfg.index_n_heads, cfg.index_head_dim
-    q = dot("btr,rf->btf", cq, p["wq_b"]["kernel"]).reshape(B, T, hi, di)
-    k = dot("btd,df->btf", xn, p["wk"]["kernel"])
-    mean = jnp.mean(k, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(k - mean), axis=-1, keepdims=True)
-    k = ((k - mean) * lax.rsqrt(var + cfg.index_norm_eps)
-         * p["k_norm"]["scale"].astype(jnp.float32)
-         + p["k_norm"]["bias"].astype(jnp.float32))
-    w = dot("btd,dh->bth", xn, p["weights_proj"]["kernel"]) * (
-        hi ** -0.5 * di ** -0.5)
-    return (_rope_first(cfg, q, positions).astype(cfg.dtype),
-            _rope_first(cfg, k, positions).astype(cfg.dtype), w)
-
-
-def index_scores(q_i, w, k_i):
-    """``I[t, s]`` of ``(B, T, Hi, Di)`` queries with weights ``(B, T, Hi)``
-    over ``(B, S, Di)`` keys -> ``(B, T, S)`` float32."""
-    s = jax.nn.relu(dot("bthd,bsd->bths", q_i, k_i))
-    return jnp.sum(s * w[..., None], axis=2)
-
-
-def select_mask(scores, k: int):
-    """``(..., S)`` float32 scores -> a mask of the ``k`` largest of each
-    row, the lower position first on a tie (all of a row shorter than
-    ``k``).  The ``k``-th largest is found by bisection on the scores'
-    bits, which order as the scores do: 32 counts, and no sort."""
-    if scores.shape[-1] <= k:
-        return jnp.ones(scores.shape, bool)
-    bits = lax.bitcast_convert_type(scores, jnp.uint32)
-    keys = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
-
-    def raise_bit(i, floor):
-        tried = floor | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
-        enough = jnp.sum(keys >= tried[..., None], axis=-1,
-                         dtype=jnp.int32) >= k
-        return jnp.where(enough, tried, floor)
-
-    kth = lax.fori_loop(0, 32, raise_bit,
-                        jnp.zeros(scores.shape[:-1], jnp.uint32))[..., None]
-    above, ties = keys > kth, keys == kth
-    room = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
-    return above | (ties & (jnp.cumsum(ties, axis=-1, dtype=jnp.int32)
-                            <= room))
-
-
-def select_top(scores, k: int):
-    """``(B, S)`` scores -> the positions ``(B, k)`` of the ``k`` largest of
-    each row, the lower position first on a tie: a decode step's selection,
-    which is gathered and so needs the positions themselves."""
-    return lax.top_k(scores, k)[1]
-
-
-def _expanded_scores(cfg, p, q_n, q_r, latent, k_r):
-    """The expanded form's scaled scores ``(B, H, T, S)`` float32 and values
-    ``(B, S, H, v_head_dim)`` of ``(B, S, .)`` latents and rotary keys."""
-    H, dt = cfg.num_attention_heads, cfg.dtype
-    w = p["kv_b"]["kernel"].reshape(
-        cfg.kv_lora_rank, H, cfg.qk_nope_head_dim + cfg.v_head_dim)
-    w_k, w_v = w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
-    k_n = dot("bsc,chd->bshd", latent, w_k, dt)
-    scores = (dot("bthd,bshd->bhts", q_n, k_n)
-              + dot("bthr,bsr->bhts", q_r, k_r)) / np.sqrt(cfg.qk_head_dim)
-    return scores, dot("bsc,chv->bshv", latent, w_v, dt)
-
-
 # -- the module ----------------------------------------------------------------
 
 class GlmMoeDsa(nn.Module):
@@ -386,7 +295,8 @@ class GlmMoeDsa(nn.Module):
         cfg = self.cfg
         B, T = tokens.shape
         params = declare(self, param_spec(cfg), cfg)
-        dt, rank, lw = cfg.dtype, cfg.kv_lora_rank, cfg.latent_width
+        dt, sizes = cfg.dtype, mla_sizes(cfg)
+        rank, lw = sizes.kv_lora_rank, sizes.latent_width
         # Float32 from here to the head.
         x = params["embed"][tokens].astype(jnp.float32)
         view = PagedCall(
@@ -396,20 +306,8 @@ class GlmMoeDsa(nn.Module):
             experts=(cfg.n_moe_layers, cfg.held))
         positions = view.positions
         if decode:
-            bs, tables = paged.block_size, view.table
-            # The context is walked ``pages`` blocks a step, as far as the
-            # longest row that counts reaches; the table is padded with the
-            # trash block to whole steps.
-            pages = min(CONTEXT_CHUNK // bs, tables.shape[1])
-            chunk = pages * bs
-            steps_max = -(-tables.shape[1] // pages)
-            tables = jnp.pad(tables, (
-                (0, 0), (0, steps_max * pages - tables.shape[1])))
-            span = steps_max * chunk
-            reach = jnp.max(view.lengths)
-            steps = jnp.minimum((reach + chunk - 1) // chunk, steps_max)
-            causal = (jnp.arange(span)[None, None, :]
-                      <= positions[:, :, None])                   # (B, T, S)
+            walk = ContextWalk(view)
+            causal = walk.causal
             paged_attention.note_path(SELECTED if T == 1 else MASKED)
         else:
             causal = jnp.broadcast_to(
@@ -422,49 +320,6 @@ class GlmMoeDsa(nn.Module):
                       dt))
         token_live = view.token_live
 
-        def context(pool, layer, j):
-            """Positions ``j * chunk .. (j + 1) * chunk - 1`` of every row,
-            through the table: ``(B, chunk, width)``."""
-            blocks = lax.dynamic_slice_in_dim(tables, j * pages, pages, 1)
-            return pool[layer, blocks].reshape(B, chunk, pool.shape[-1])
-
-        def cached_scores(pool, layer, q_i, w):
-            """``I[t, s]`` over the rows' cached index keys, -inf where
-            ``s`` is after ``t`` (and past the walk)."""
-            def one(j, scores):
-                return lax.dynamic_update_slice_in_dim(
-                    scores, index_scores(q_i, w, context(pool, layer, j)),
-                    j * chunk, axis=2)
-
-            scores = lax.fori_loop(
-                0, steps, one, jnp.full((B, T, span), -jnp.inf, jnp.float32))
-            return jnp.where(causal, scores, -jnp.inf)
-
-        def masked_attention(p, pool, layer, q_n, q_r, mask):
-            """Expanded attention under ``mask`` over the cached context, a
-            chunk a step under an online softmax -> ``(B, T, H * v)``."""
-            H, vd = cfg.num_attention_heads, cfg.v_head_dim
-
-            def one(j, carry):
-                m, l, acc = carry
-                rows = context(pool, layer, j)
-                s, v = _expanded_scores(cfg, p, q_n, q_r, rows[..., :rank],
-                                        rows[..., rank:lw])
-                s = jnp.where(lax.dynamic_slice_in_dim(
-                    mask, j * chunk, chunk, 2)[:, None], s, _MASKED)
-                m_next = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-                alpha, pr = jnp.exp(m - m_next), jnp.exp(s - m_next)
-                l = alpha * l + jnp.sum(pr, axis=-1, keepdims=True)
-                acc = alpha * acc + dot("bhts,bshv->bhtv", pr.astype(dt), v)
-                return m_next, l, acc
-
-            _, l, acc = lax.fori_loop(0, steps, one, (
-                jnp.full((B, H, T, 1), _MASKED, jnp.float32),
-                jnp.zeros((B, H, T, 1), jnp.float32),
-                jnp.zeros((B, H, T, vd), jnp.float32)))
-            return (acc / l).astype(dt).transpose(0, 2, 1, 3).reshape(
-                B, T, H * vd)
-
         def attention(p, x, pools, layer, full_layer, selection):
             """One layer's attention; ``full_layer`` is the layer's place
             among the ``full`` ones (None on a ``shared`` layer, which
@@ -472,35 +327,34 @@ class GlmMoeDsa(nn.Module):
             latent_pool_v, index_pool_v = pools
             xn = rms_norm(x, p["input_norm"]["scale"],
                           cfg.rms_norm_eps).astype(dt)
-            cq = mla_query_latent(cfg, p["attn"], xn)
+            cq = mla_query_latent(cfg, sizes, p["attn"], xn)
             q_n, q_r, latent, k_r = mla_project(
-                cfg, p["attn"], xn, positions, cq=cq)
+                cfg, sizes, p["attn"], xn, positions, cq=cq)
             if full_layer is not None:
                 q_i, k_i, w = indexer_project(
-                    cfg, p["indexer"], xn, cq, positions)
+                    cfg, sizes, p["indexer"], xn, cq, positions)
             if latent_pool_v is None:
                 if full_layer is not None:
                     selection = causal & select_mask(jnp.where(
                         causal, index_scores(q_i, w, k_i), -jnp.inf),
                         cfg.index_topk)
-                ctx = mla_attend(cfg, p["attn"], q_n, q_r, latent, k_r,
-                                 selection, False)
+                ctx = mla_attend(cfg, sizes, p["attn"], q_n, q_r, latent,
+                                 k_r, selection, False)
             else:
                 latent_pool_v = view.write(
-                    latent_pool_v, layer, jnp.concatenate(
-                        [latent, k_r, jnp.zeros(
-                            (B, T, cfg.pool_width - lw), dt)], axis=-1))
+                    latent_pool_v, layer,
+                    mla_cache_row(cfg, sizes, latent, k_r))
                 if full_layer is not None:
                     index_pool_v = view.write(index_pool_v, full_layer, k_i)
-                    scores = cached_scores(index_pool_v, full_layer, q_i, w)
+                    scores = walk.index_scores(
+                        index_pool_v, full_layer, q_i, w)
                     if T == 1:
                         # The selection as pool cells, through the table
                         # once for every layer that shares it.
                         chosen = select_top(
-                            scores[:, 0], min(cfg.index_topk, span))
-                        selection = (
-                            jnp.take_along_axis(tables, chosen // bs, 1),
-                            chosen % bs, chosen <= positions)
+                            scores[:, 0], min(cfg.index_topk, walk.span))
+                        selection = (*walk.cells_of(chosen),
+                                     chosen <= positions)
                     else:
                         selection = causal & select_mask(
                             scores, cfg.index_topk)
@@ -508,12 +362,13 @@ class GlmMoeDsa(nn.Module):
                     blocks, offsets, valid = selection
                     rows = latent_pool_v[layer, blocks, offsets]
                     ctx = mla_attend(
-                        cfg, p["attn"], q_n, q_r, rows[..., :rank],
+                        cfg, sizes, p["attn"], q_n, q_r, rows[..., :rank],
                         rows[..., rank:lw], valid[:, None, :], True)
                 else:
-                    ctx = masked_attention(p["attn"], latent_pool_v, layer,
-                                           q_n, q_r, selection)
-            out = dot("btf,fd->btd", ctx, p["attn"]["o"]["kernel"])
+                    ctx = walk.masked_attention(
+                        cfg, sizes, p["attn"], latent_pool_v, layer, q_n,
+                        q_r, selection)
+            out = mla_output(cfg, sizes, p["attn"], xn, ctx)
             return x + out, (latent_pool_v, index_pool_v), selection
 
         full_layers, selection, count_rows = 0, None, []

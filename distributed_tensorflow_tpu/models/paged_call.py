@@ -9,9 +9,13 @@ and no pool leaf.  This is the models' side.  A family builds one
 used to know each for itself: which arguments go together, how a position
 becomes a pool cell, what a row that is not live reads, by how much the
 index advances and in which rows, and how a grouped-query layer's K/V is
-written and read back.  The window ring's cell arithmetic, a walked
-context and a per-slot recurrent state stay with the family that has them
-and take ``positions``, ``table`` and ``lengths`` from here.
+written and read back.  The window ring's cell arithmetic and a per-slot
+recurrent state stay with the family that has them and take ``positions``,
+``table`` and ``lengths`` from here.  A long context walked through the
+table a piece at a time (``ContextWalk``: the cached index scores of a
+learned selection, the expanded latent attention of a prefill chunk under a
+mask) is here since a second family reads a selection; the walk takes a
+kind's sizes (``decoder_parts.MlaSizes``) and names no family.
 
 The module also holds the one table of serving refusals
 (``serve_refusals``) and the one construction of a family's ``Workload``
@@ -26,11 +30,13 @@ from typing import Dict
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from distributed_tensorflow_tpu.data.pipeline import synthetic_lm
 from distributed_tensorflow_tpu.models import Workload
 from distributed_tensorflow_tpu.models.decoder_parts import (
-    COUNT_EXTRA, cache_rules, dot, gqa_attend, lm_loss, rms_norm)
+    COUNT_EXTRA, cache_rules, dot, gqa_attend, index_scores, lm_loss,
+    mla_expanded_scores, rms_norm)
 from distributed_tensorflow_tpu.ops import paged_attention
 from distributed_tensorflow_tpu.parallel.sharding import ShardingRules
 
@@ -222,6 +228,95 @@ class PagedCall:
         x = rms_norm(x, params["final_norm"]["scale"],
                      cfg.rms_norm_eps).astype(cfg.dtype)
         return dot("btd,dv->btv", x, params["head"]["kernel"])
+
+
+# -- a long context, walked through the table ----------------------------------
+
+# Context positions a step of the walk takes (whole blocks).
+CONTEXT_CHUNK = 1024
+_MASKED = -1e30  # finite: exp(_MASKED - m) is exactly 0, no inf - inf
+
+
+class ContextWalk:
+    """A cached call's context read ``pages`` blocks a step through the
+    block table, as far as the longest row that counts reaches and no
+    further: what a learned selection scores its index keys by
+    (``index_scores``) and a prefill chunk attends under a mask by
+    (``masked_attention``), without a table row gathered whole.  Built
+    once a call, before ``view.advance()``.  ``tables`` is the view's,
+    padded with the trash block to whole steps; ``span`` the positions it
+    addresses; ``causal`` ``(B, T, span)`` True where a key is not after
+    its query."""
+
+    def __init__(self, view: PagedCall):
+        bs, tables = view.paged.block_size, view.table
+        self.B, self.T, self.block_size = view.B, view.T, bs
+        self.pages = min(CONTEXT_CHUNK // bs, tables.shape[1])
+        self.chunk = self.pages * bs
+        steps_max = -(-tables.shape[1] // self.pages)
+        self.tables = jnp.pad(tables, (
+            (0, 0), (0, steps_max * self.pages - tables.shape[1])))
+        self.span = steps_max * self.chunk
+        reach = jnp.max(view.lengths)
+        self.steps = jnp.minimum(
+            (reach + self.chunk - 1) // self.chunk, steps_max)
+        self.causal = (jnp.arange(self.span)[None, None, :]
+                       <= view.positions[:, :, None])             # (B, T, S)
+
+    def context(self, pool, layer, j):
+        """Positions ``j * chunk .. (j + 1) * chunk - 1`` of every row,
+        through the table: ``(B, chunk, width)``."""
+        blocks = lax.dynamic_slice_in_dim(
+            self.tables, j * self.pages, self.pages, 1)
+        return pool[layer, blocks].reshape(
+            self.B, self.chunk, pool.shape[-1])
+
+    def index_scores(self, pool, layer, q_i, w):
+        """``I[t, s]`` over the rows' cached index keys ``(B, T, span)``,
+        -inf where ``s`` is after ``t`` (and past the walk)."""
+        def one(j, scores):
+            return lax.dynamic_update_slice_in_dim(
+                scores, index_scores(q_i, w, self.context(pool, layer, j)),
+                j * self.chunk, axis=2)
+
+        scores = lax.fori_loop(0, self.steps, one, jnp.full(
+            (self.B, self.T, self.span), -jnp.inf, jnp.float32))
+        return jnp.where(self.causal, scores, -jnp.inf)
+
+    def masked_attention(self, cfg, sizes, p, pool, layer, q_n, q_r, mask):
+        """Expanded latent attention under ``mask`` ``(B, T, span)`` over
+        the cached context, a chunk a step under an online softmax ->
+        ``(B, T, H * v)``."""
+        B, T, dt = self.B, self.T, cfg.dtype
+        H, vd = sizes.heads, sizes.v_head_dim
+        rank, lw = sizes.kv_lora_rank, sizes.latent_width
+
+        def one(j, carry):
+            m, l, acc = carry
+            rows = self.context(pool, layer, j)
+            s, v = mla_expanded_scores(cfg, sizes, p, q_n, q_r,
+                                       rows[..., :rank], rows[..., rank:lw])
+            s = jnp.where(lax.dynamic_slice_in_dim(
+                mask, j * self.chunk, self.chunk, 2)[:, None], s, _MASKED)
+            m_next = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha, pr = jnp.exp(m - m_next), jnp.exp(s - m_next)
+            l = alpha * l + jnp.sum(pr, axis=-1, keepdims=True)
+            acc = alpha * acc + dot("bhts,bshv->bhtv", pr.astype(dt), v)
+            return m_next, l, acc
+
+        _, l, acc = lax.fori_loop(0, self.steps, one, (
+            jnp.full((B, H, T, 1), _MASKED, jnp.float32),
+            jnp.zeros((B, H, T, 1), jnp.float32),
+            jnp.zeros((B, H, T, vd), jnp.float32)))
+        return (acc / l).astype(dt).transpose(0, 2, 1, 3).reshape(
+            B, T, H * vd)
+
+    def cells_of(self, chosen):
+        """Positions ``(B, k)`` -> their pool cells, (blocks, offsets),
+        through the table once."""
+        bs = self.block_size
+        return (jnp.take_along_axis(self.tables, chosen // bs, 1),
+                chosen % bs)
 
 
 # -- what the engine and the scheduler ask of a decoder family -----------------

@@ -2178,7 +2178,7 @@ class ContinuousScheduler:
     def _note_index_blocks_held(self) -> None:
         """Where index keys lie in a second pool under the latent pool's
         table, a block the allocator hands out is one block of each: the
-        gauge says so by kind."""
+        gauge says so by kind (beside the ring's, where there is one)."""
         if self._kv_geometry.get("index_block_bytes"):
             for kind in ("latent", "index"):
                 self._obs["kv_blocks_held"].labels(kind=kind).set(
@@ -2191,17 +2191,26 @@ class ContinuousScheduler:
 
     def _two_pool_stats_locked(self) -> Dict[str, float]:
         """What the rows hold in each kind of pool (call under ``_lock``);
-        nothing for a family with one.  ``kv_bytes_held_uniform`` is what
-        the same rows would hold if every layer kept a block wherever the
+        nothing for a family with one.  Each thing the geometry declares
+        adds its keys: a recurrent state, index keys under the table, a
+        window ring.  ``kv_bytes_held`` is the table's blocks at what one
+        holds on the layers under the table, and the ring's at what one
+        holds on the window layers; ``kv_bytes_held_uniform`` is what the
+        same rows would hold if every layer kept a block wherever the
         full layers do (one geometry for all)."""
         if self.paged is None:
             return {}
         g = self._kv_geometry
         iters = self._iterations
+        held = self._allocator.used_count
+        bytes_held = held * g.get(
+            "full_block_bytes",
+            self.block_size * g.get("bytes_per_token", 0))
+        out: Dict[str, float] = {}
         if self._state_bytes:
             # Bytes a slot (the recurrent state) beside bytes a token (the
             # K/V layers' pool, in ``kv_bytes_held``).
-            return {
+            out.update({
                 "state_bytes_per_slot": float(self._state_bytes),
                 # Rows a decode launch ran, mean: each reads and writes
                 # its state once a step.
@@ -2210,43 +2219,41 @@ class ContinuousScheduler:
                 "state_bytes_held": float(
                     len(self._active) * self._state_bytes),
                 "state_resets": float(self._state_resets),
-                "kv_bytes_held": float(
-                    self._allocator.used_count * self.block_size
-                    * g["bytes_per_token"]),
-            }
+            })
         if g.get("index_block_bytes"):
             # Index keys beside the latent pool, under one table: a block
             # held is one of each.
-            held = self._allocator.used_count
-            return {
+            out.update({
                 "kv_blocks_held_latent": float(held),
                 "kv_blocks_held_index": float(held),
-                "kv_bytes_held": float(held * g["block_bytes"]),
                 "kv_bytes_held_index": float(held * g["index_block_bytes"]),
                 # The full indexer layers score ``decode_live_positions``;
-                # every layer's attention reads the same rows' latents,
-                # each row counted up to the selection.
+                # the layers that attend under a selection read the same
+                # rows' latents, each row counted up to the selection.
                 "decode_selected_positions": (
                     self._live_selected_positions_sum / iters
                     if iters else 0.0),
-            }
-        if not self.paged.window_ring:
-            return {}
-        full, window = self._allocator.used_count, self._window_blocks_held_locked()
-        return {
-            "kv_blocks_held_full": float(full),
-            "kv_blocks_held_window": float(window),
-            "kv_bytes_held": float(full * g["full_block_bytes"]
-                                   + window * g["window_block_bytes"]),
-            "kv_bytes_held_uniform": float(full * (
-                g["full_block_bytes"] + g["window_block_bytes"])),
-            "window_ring_blocks": float(self.paged.window_ring),
-            "window_blocks_recycled": float(self._window_recycled),
-            # The full layers read ``decode_live_positions``; the window
-            # layers the same rows, each counted up to the window.
-            "decode_live_positions_window": (
-                self._live_window_positions_sum / iters if iters else 0.0),
-        }
+            })
+        if self.paged.window_ring:
+            window = self._window_blocks_held_locked()
+            out.update({
+                "kv_blocks_held_full": float(held),
+                "kv_blocks_held_window": float(window),
+                "kv_bytes_held_uniform": float(
+                    bytes_held + held * g["window_block_bytes"]),
+                "window_ring_blocks": float(self.paged.window_ring),
+                "window_blocks_recycled": float(self._window_recycled),
+                # The full layers read ``decode_live_positions``; the
+                # window layers the same rows, each counted up to the
+                # window.
+                "decode_live_positions_window": (
+                    self._live_window_positions_sum / iters
+                    if iters else 0.0),
+            })
+            bytes_held += window * g["window_block_bytes"]
+        if out:
+            out["kv_bytes_held"] = float(bytes_held)
+        return out
 
     def _paged_call_kwargs(self) -> Dict[str, Any]:
         """Paged kwargs for the slot programs, with the block tables kept

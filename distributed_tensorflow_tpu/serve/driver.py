@@ -223,13 +223,15 @@ class ServeArgs:
 # mellum one chip's share of a 4-chip host (24 GB whole), for glm_moe_dsa
 # one chip's share of a v5e-256 (32 chips a layer, 5 of a stage's layers),
 # for solar_open2 one chip's share of a v5e-128 (16 chips a layer, one
-# period of 4 layers).
+# period of 4 layers), for dots3_note one chip's share of a v5e-256 (32
+# chips a layer, the dense layer and one period).
 # Every other model is batched classification.
 _AUTO_PRESETS = {"gpt2": ("tiny", "medium"),
                  "glm4_moe_lite": ("tiny", "v5e8_share"),
                  "mellum": ("tiny", "v5e4_share"),
                  "glm_moe_dsa": ("tiny", "v5e256_share"),
-                 "solar_open2": ("tiny", "v5e128_share")}
+                 "solar_open2": ("tiny", "v5e128_share"),
+                 "dots3_note": ("tiny", "v5e256_share")}
 DECODER_MODELS = tuple(_AUTO_PRESETS)
 
 

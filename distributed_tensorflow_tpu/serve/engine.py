@@ -1851,10 +1851,12 @@ def moe_counts_of(cache: PyTree):
 # path, both by the gather or both by the kernel), the learned sparse
 # attention's two (a decode step over the selected rows, a chunk under the
 # selection's mask), the linear attention's two (the chunk-wise rule, the
-# one-step rule).
+# one-step rule), the window latent attention's two (a decode step over the
+# ring's live cells, a chunk over itself and the window before it).
 _DECODE_PATHS = (paged_attention.KERNEL, paged_attention.GATHER,
                  "latent_absorbed", "latent_expanded",
                  "gqa_gather_window", "gqa_gather_full",
                  ) + paged_attention.GQA_KERNEL_PATHS + (
                  "latent_sparse_selected", "latent_sparse_masked",
-                 "kda_chunk", "kda_step")
+                 "kda_chunk", "kda_step",
+                 "latent_window_step", "latent_window_chunk")

@@ -18,6 +18,8 @@ Examples:
         --prefill_budget=64 --megastep=4  # latent cache + index keys, sparse reads
     python serve.py --model=solar_open2 --continuous --cache_mode=paged \
         --prefill_budget=64 --megastep=4  # per-slot recurrent state + one K/V pool
+    python serve.py --model=dots3_note --continuous --cache_mode=paged \
+        --prefill_budget=64 --megastep=4  # window latent ring + selected latent + index keys
     python serve.py --model=gpt2 --continuous --num_slots=8 \
         --prompt_lens=8,16,24 --min_new_tokens=4             # continuous batching
     python serve.py --model=gpt2 --continuous --cache_mode=paged \
@@ -77,8 +79,8 @@ def parse_args(argv=None):
     defaults = ServeArgs()
     p = argparse.ArgumentParser(description="TPU-native batched serving")
     p.add_argument("--model", default=defaults.model,
-                   help="gpt2, glm4_moe_lite, mellum, glm_moe_dsa or "
-                        "solar_open2 (KV-cache decode) or "
+                   help="gpt2, glm4_moe_lite, mellum, glm_moe_dsa, "
+                        "solar_open2 or dots3_note (KV-cache decode) or "
                         "mnist|resnet50|bert (batched classify).  "
                         "glm4_moe_lite (latent attention, sparse experts), "
                         "mellum (grouped-query attention, window and "
@@ -89,6 +91,10 @@ def parse_args(argv=None):
                         "and solar_open2 (gated delta-rule linear "
                         "attention whose per-slot state lies beside one "
                         "grouped-query layer's paged K/V in four, sparse "
+                        "experts) and dots3_note (window latent attention "
+                        "in a ring pool of its own width beside latent "
+                        "attention over an indexer's selection and its "
+                        "index keys, head-wise output gates, sparse "
                         "experts) serve with --continuous "
                         "--cache_mode=paged only and refuse, with the "
                         "reason, --kv_dtype, --per_shard_kv, "
@@ -273,7 +279,10 @@ def parse_args(argv=None):
                         "v5e-256, on TPU); solar_open2 "
                         "tiny|v5e128_share|published (default tiny on "
                         "CPU, v5e128_share, one chip's share of a "
-                        "v5e-128, on TPU)")
+                        "v5e-128, on TPU); dots3_note "
+                        "tiny|v5e256_share|published (default tiny on "
+                        "CPU, v5e256_share, one chip's share of a "
+                        "v5e-256, on TPU)")
     for axis in ("data", "fsdp", "tensor"):
         p.add_argument(f"--{axis}", type=int,
                        default=getattr(defaults, axis),

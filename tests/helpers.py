@@ -211,3 +211,36 @@ def expert_forms_on_record(sched, *, experts: int, chunk: int) -> None:
                 "moe_load_max_over_mean"):
         assert isinstance(stats[key], float)
     assert stats["moe_layer_steps"] > 0
+
+
+# What ``ContinuousScheduler._two_pool_stats_locked`` adds to ``stats()``, by
+# what the family's cache geometry declares: a window ring, index keys under
+# the table, a per-slot recurrent state.  A family with two of them reports
+# both sets (PR 46 made the keys additive; the three families that have one
+# report what they reported before).
+POOL_STAT_KEYS = {
+    "ring": {"kv_blocks_held_full", "kv_blocks_held_window", "kv_bytes_held",
+             "kv_bytes_held_uniform", "window_ring_blocks",
+             "window_blocks_recycled", "decode_live_positions_window"},
+    "index": {"kv_blocks_held_latent", "kv_blocks_held_index",
+              "kv_bytes_held", "kv_bytes_held_index",
+              "decode_selected_positions"},
+    "state": {"state_bytes_per_slot", "state_slots_live", "state_bytes_held",
+              "state_resets", "kv_bytes_held"},
+}
+
+
+def pool_stat_keys_are(engine, *mechanisms: str) -> None:
+    """A scheduler over ``engine`` that has served nothing reports exactly
+    the pool keys of its family's ``mechanisms`` and holds nothing."""
+    from distributed_tensorflow_tpu.serve import ContinuousScheduler
+
+    with ContinuousScheduler(
+            engine, num_slots=2, max_total_len=64, cache_mode="paged",
+            block_size=16, prefill_budget=16, start=False) as sched:
+        stats = sched.stats()
+    every = set().union(*POOL_STAT_KEYS.values())
+    want = set().union(*(POOL_STAT_KEYS[m] for m in mechanisms))
+    assert set(stats) & every == want
+    if want:
+        assert stats["kv_bytes_held"] == 0

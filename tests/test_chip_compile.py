@@ -257,6 +257,66 @@ def test_sparse_latent_serve_programs_fit_one_chip_at_the_cells_shapes(
         assert chunk in (512, 1024)
 
 
+# -- the window-latent family at its cell's shapes -----------------------------
+
+@pytest.mark.parametrize("program", ["decode_megastep", "prefill_slots"])
+def test_window_latent_serve_programs_fit_one_chip_at_the_cells_shapes(
+        topo, program):
+    """One chip's share of the v5e-256 deployment, published layers 0-4 at
+    the published widths: 3.64 GB of bfloat16 weights; the two full layers'
+    latent pool of ``slots x 512 + 1`` blocks and their index keys under the
+    same block numbers; the three window layers' ring pool of ``slots x 98 +
+    1`` blocks, 1,152 wide; all three updated in place.  The assertion's
+    message carries the argument and the scratch bytes (the cell's
+    ``num_slots_arithmetic`` quotes them).
+
+    A decode step gathers the selected rows and the ring blocks that hold
+    the window (34 of 16 positions) and no table row; a prefill chunk the
+    97 ring blocks of itself and the window before it."""
+    lowered, cache, slots, chunk = lower_cell_program(
+        topo, "serve.dots3-note-prev.notes-mixed-saturated", program)
+    latent, index = cache["latent_pool"].shape, cache["index_pool"].shape
+    window = cache["window_pool"].shape
+    assert latent == (2, slots * 512 + 1, 16, 640)
+    assert index == (2, slots * 512 + 1, 16, 128)
+    assert window == (3, slots * 98 + 1, 16, 1152)
+    started = time.perf_counter()
+    compiled = lowered.compile()
+    seconds = time.perf_counter() - started
+    memory = compiled.memory_analysis()
+    said = (f"{program}: {slots} slots, arguments "
+            f"{memory.argument_size_in_bytes:,} B, scratch "
+            f"{memory.temp_size_in_bytes:,} B, compiled in {seconds:.0f} s")
+    print(said)
+    assert seconds < 240, said                      # a cold start pays it
+    pools = 2 * (np.prod(latent) + np.prod(index) + np.prod(window))
+    assert (3.64e9 + pools < memory.argument_size_in_bytes
+            < 3.66e9 + pools), said
+    assert memory.temp_size_in_bytes < 1.2e9, said
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < V5E_HBM_BYTES, said
+    hlo = compiled.as_text()
+    for kernel in ("expert_gate_up", "expert_down"):
+        calls = re.findall(rf"%({kernel}[\w.]*) = [^\n]*tpu_custom_call", hlo)
+        assert len(calls) == 4, (kernel, calls)
+    for pool in (latent, index, window):
+        assert_only_scatters_produce_pools(hlo, pool, flattened=True)
+    assert not kernel_calls(hlo)        # no flash kernel in a serving step
+    shapes = {tuple(int(n) for n in dims.split(","))
+              for dims in re.findall(r"= bf16\[([\d,]+)\]", hlo)}
+    whole_rows = {(slots * 512, 16, 640), (slots, 8192, 640),
+                  (slots * 512, 16, 128), (slots, 8192, 128),
+                  (512, 16, 640), (1, 8192, 640), (1, 8192, 128),
+                  (slots * 98, 16, 1152), (slots, 1568, 1152)}
+    assert not shapes & whole_rows, (shapes & whole_rows, said)
+    if program == "decode_megastep":
+        assert (slots, 2048, 640) in shapes, said   # the selected rows
+        assert {(slots, 34, 16, 1152), (slots, 544, 1152)} & shapes, said
+    else:
+        assert chunk == 1024
+        assert {(1, 97, 16, 1152), (1, 1552, 1152)} & shapes, said
+
+
 # -- the linear-attention family at its cell's shapes --------------------------
 
 @pytest.mark.parametrize("program", ["decode_megastep", "prefill_slots"])

@@ -32,7 +32,8 @@ from distributed_tensorflow_tpu.models.gpt2 import PagedKVConfig
 from distributed_tensorflow_tpu.obs.metrics import default_registry
 from distributed_tensorflow_tpu.ops import grouped_matmul
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
-from tests.helpers import expert_forms_on_record, zero_cache
+from tests.helpers import (
+    expert_forms_on_record, pool_stat_keys_are, zero_cache)
 
 EXACT = precision.Exact()
 
@@ -147,8 +148,11 @@ def test_absorbed_and_expanded_attention_agree(queries):
     mask = jnp.arange(S)[None, None, :] <= (S - queries + jnp.arange(queries)
                                             )[None, :, None]
     mask = jnp.broadcast_to(mask, (B, queries, S))
-    absorbed = parts.mla_attend(cfg, p, q_n, q_r, latent, k_r, mask, True)
-    expanded = parts.mla_attend(cfg, p, q_n, q_r, latent, k_r, mask, False)
+    sizes = parts.mla_sizes(cfg)
+    absorbed = parts.mla_attend(cfg, sizes, p, q_n, q_r, latent, k_r, mask,
+                                True)
+    expanded = parts.mla_attend(cfg, sizes, p, q_n, q_r, latent, k_r, mask,
+                                False)
     assert absorbed.shape == (B, queries, H * cfg.v_head_dim)
     np.testing.assert_allclose(np.asarray(absorbed), np.asarray(expanded),
                                atol=2e-5)
@@ -497,6 +501,10 @@ def test_scheduler_serves_the_reference_best_tokens(engine, megastep,
     assert set(paths["slot_prefill"]) == {glm.EXPANDED}
     assert set(paths["slot_megastep"]) == {glm.ABSORBED}
     assert engine.decode_attention_launches()[glm.ABSORBED] > 0
+
+
+def test_stats_hold_no_pool_keys_for_one_pool(engine):
+    pool_stat_keys_are(engine)
 
 
 def test_chunked_prefill_writes_the_same_latents(engine):

@@ -28,11 +28,13 @@ from benchmark.reference import precision
 from distributed_tensorflow_tpu.models import PagedKVConfig
 from distributed_tensorflow_tpu.models import decoder_parts as parts
 from distributed_tensorflow_tpu.models import glm_moe_dsa as dsa
+from distributed_tensorflow_tpu.models import paged_call
 from distributed_tensorflow_tpu.models.glm_moe_dsa import (
     GlmMoeDsa, GlmMoeDsaConfig)
 from distributed_tensorflow_tpu.obs.metrics import default_registry
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
-from tests.helpers import expert_forms_on_record, zero_cache
+from tests.helpers import (
+    expert_forms_on_record, pool_stat_keys_are, zero_cache)
 
 EXACT = precision.Exact()
 
@@ -192,8 +194,10 @@ def test_index_scores_and_selected_sets_are_the_references():
     xn = parts.rms_norm(x, p["input_norm"]["scale"], cfg.rms_norm_eps)
     rcfg = reference_config(cfg)
     positions = jnp.broadcast_to(jnp.arange(70)[None], (2, 70))
-    cq = parts.mla_query_latent(cfg, p["attn"], xn)
-    q_i, k_i, w = dsa.indexer_project(cfg, p["indexer"], xn, cq, positions)
+    sizes = parts.mla_sizes(cfg)
+    cq = parts.mla_query_latent(cfg, sizes, p["attn"], xn)
+    q_i, k_i, w = dsa.indexer_project(cfg, sizes, p["indexer"], xn, cq,
+                                      positions)
     mine = np.asarray(dsa.index_scores(q_i, w, k_i))
     theirs = np.asarray(ref.index_scores(EXACT, rcfg, xn, cq, p["indexer"]))
     causal = np.tril(np.ones((70, 70), bool))
@@ -315,7 +319,7 @@ def test_chunked_prefill_then_decode_gives_the_reference_logits(
     before it wrote), then 20 positions one at a time through both pools,
     against the reference's one full forward pass; the context is walked
     ``walk`` positions a step (several steps, or one)."""
-    monkeypatch.setattr(dsa, "CONTEXT_CHUNK", walk)
+    monkeypatch.setattr(paged_call, "CONTEXT_CHUNK", walk)
     cfg = tiny(experts_held=4, first_expert=4)
     params = drawn_params(cfg)
     tokens = tokens_of(cfg, (2, 68), seed=1)
@@ -535,6 +539,10 @@ def test_scheduler_counts_what_a_decode_step_reads(engine):
     assert gauge.labels(kind="index").value == 0
     assert stats["kv_hbm_bytes"] >= geometry["pool_bytes"]
     assert geometry["block_bytes"] == 16 * (5 * 128 + 2 * 32) * 4
+
+
+def test_stats_hold_the_index_keys_keys_and_no_other_pools(engine):
+    pool_stat_keys_are(engine, "index")
 
 
 def test_blocks_held_are_one_of_each_pool(engine):

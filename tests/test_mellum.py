@@ -33,7 +33,8 @@ from distributed_tensorflow_tpu.models.mellum import Mellum, MellumConfig
 from distributed_tensorflow_tpu.obs.metrics import default_registry
 from distributed_tensorflow_tpu.ops import paged_attention
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
-from tests.helpers import expert_forms_on_record, zero_cache
+from tests.helpers import (
+    expert_forms_on_record, pool_stat_keys_are, zero_cache)
 
 EXACT = precision.Exact()
 PUBLISHED_ROPE = {
@@ -638,6 +639,10 @@ def test_scheduler_refuses_what_two_pools_cannot_serve(engine, feature):
 def test_a_tensor_mesh_is_refused_with_its_reason(mesh_2d):
     with pytest.raises(ValueError, match="tensor"):
         ServeEngine("mellum", mesh=mesh_2d, config=SERVED)
+
+
+def test_stats_hold_the_rings_keys_and_no_other_pools(engine):
+    pool_stat_keys_are(engine, "ring")
 
 
 def test_engine_reports_both_kinds_of_pool(engine):

@@ -27,7 +27,7 @@ from distributed_tensorflow_tpu.models.solar_open2 import (
     SolarOpen2, SolarOpen2Config)
 from distributed_tensorflow_tpu.obs.metrics import default_registry
 from distributed_tensorflow_tpu.serve import ContinuousScheduler, ServeEngine
-from tests.helpers import expert_forms_on_record
+from tests.helpers import expert_forms_on_record, pool_stat_keys_are
 
 EXACT = precision.Exact()
 PUBLISHED_GROUP = {"short_conv_kernel_size": 4, "head_dim": 128,
@@ -583,6 +583,10 @@ def test_scheduler_refuses_what_a_state_cannot_serve(engine, feature):
 def test_a_tensor_mesh_is_refused_with_its_reason(mesh_2d):
     with pytest.raises(ValueError, match="tensor"):
         ServeEngine("solar_open2", mesh=mesh_2d, config=SERVED)
+
+
+def test_stats_hold_the_states_keys_and_no_other_pools(engine):
+    pool_stat_keys_are(engine, "state")
 
 
 def test_engine_reports_bytes_a_slot_beside_bytes_a_token(engine):
